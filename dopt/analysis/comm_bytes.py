@@ -138,6 +138,7 @@ def measure_comm_bytes(*, workers: int = 8, train_size: int = 2_048,
     out = {
         "workers": workers,
         "devices": jax.device_count(),
+        "platform": jax.devices()[0].platform,
         "budget_bytes": int(budget),
         "plan_kinds": list(plan.kinds),
         "plan_chunk": plan.chunk,
@@ -154,6 +155,9 @@ def measure_comm_bytes(*, workers: int = 8, train_size: int = 2_048,
 
 
 def main(argv=None) -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m dopt.analysis.comm_bytes",
         description="compiled-HLO bytes-on-wire of the dense / scatter "

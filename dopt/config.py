@@ -166,7 +166,7 @@ class FederatedConfig:
     # all-gather re-forms the replicated theta — instead of every
     # device redundantly computing the full |θ| average.  Per-bucket
     # collectives overlap with compute under the XLA latency-hiding
-    # scheduler (dopt.parallel.mesh.enable_latency_hiding_scheduler).
+    # scheduler (libtpu's default).
     # "off" compiles the exact pre-change program (bit-identical).
     # Requires aggregator='mean', no comm_dtype/staleness/compact, and
     # a flat 1-D mesh; numerics match the dense path to f32 summation

@@ -82,8 +82,9 @@ def make_batch_plan(
     ``impl='native'`` fills the plan with the C++ host runtime
     (``dopt.native``) — same contract and determinism key, different
     (xoshiro) RNG stream, so it is the throughput mode, not the
-    oracle-parity mode; silently falls back to numpy when the native
-    library is unavailable.
+    oracle-parity mode; raises ``dopt.native.NativeUnavailable`` when
+    the library cannot be built (the numpy planner draws a different
+    batch order, so it is never substituted).
     """
     worker_ids = None
     if rows is not None and workers is None:
@@ -97,13 +98,12 @@ def make_batch_plan(
     if impl == "native":
         from dopt.native import fill_batch_plan_native
 
-        out = fill_batch_plan_native(
+        idx, weight = fill_batch_plan_native(
             index_matrix, batch_size=batch_size, local_ep=local_ep,
             seed=seed, round_idx=round_idx, drop_last=drop_last,
             worker_ids=worker_ids,
         )
-        if out is not None:
-            return BatchPlan(idx=out[0], weight=out[1])
+        return BatchPlan(idx=idx, weight=weight)
     w, l = index_matrix.shape
     bs = min(batch_size, l)
     if drop_last:

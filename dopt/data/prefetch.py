@@ -1,8 +1,9 @@
 """Prefetched host staging: overlap batch planning with device compute.
 
-BENCH_r05 measured the fast leg at 2.26 wall rounds/sec against 2.51
-device rounds/sec — ~10% of every block is the host serially building
-batch plans and ``device_put``-ing them while the TPU idles.  The
+A pre-round record (since deleted; not measured on today's code) put
+the fast leg at 2.26 wall rounds/sec against 2.51 device rounds/sec —
+~10% of every block being the host serially building batch plans and
+``device_put``-ing them while the TPU idles.  The
 blocked loops' host work is *prefetchable*: batch plans and the stacked
 fault/link/corrupt inputs are (or split into parts that are) stateless
 in ``(seed, round)``, so block b+1's payload can be built and staged to

@@ -4,7 +4,7 @@ from dopt.ops.fused_update import (
     fused_sgd_momentum,
     fused_sgd_momentum_tree,
     mix_sgd_reference,
-    pallas_available,
+    pallas_interpret,
 )
 
 __all__ = [
@@ -13,5 +13,5 @@ __all__ = [
     "fused_sgd_momentum",
     "fused_sgd_momentum_tree",
     "mix_sgd_reference",
-    "pallas_available",
+    "pallas_interpret",
 ]

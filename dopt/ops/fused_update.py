@@ -17,8 +17,9 @@ so the fast path stays oracle-comparable.
 
 Layout: each leaf is viewed as a padded [rows, 128] fp32 tile grid
 (lane = 128, sublane multiple of 8 — the fp32 VMEM tile), gridded over
-row blocks.  On non-TPU backends the kernel runs in interpret mode, so
-CPU tests exercise the identical code path.
+row blocks.  The kernels compile on ``tpu`` and run in interpret mode on
+``cpu`` (so CPU tests exercise the identical code path); any other
+backend is an error (``pallas_interpret``).
 """
 
 from __future__ import annotations
@@ -30,18 +31,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dopt.parallel.collectives import (MIX_PRECISION, buckets_to_stacked,
+                                       stacked_to_buckets)
+
 _LANE = 128
 _SUBLANE = 8
 _BLOCK_ROWS = 512  # 512×128 fp32 = 256 KiB per operand block in VMEM
 
 
-def pallas_available() -> bool:
-    """True when a real TPU backend is present (compiled kernels);
-    otherwise callers fall back to interpret mode or pure jnp."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - backend probing
-        return False
+def pallas_interpret() -> bool:
+    """The ``interpret`` argument for the default backend: False on
+    ``tpu`` (Mosaic-compiled), True on ``cpu`` (the tests' interpreter).
+    These are TPU kernels — any other backend raises instead of
+    quietly interpreting them."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"dopt's Pallas kernels target TPU (interpret mode on cpu for "
+            f"tests); the default backend is {backend!r} — turn "
+            "fused_update off on this backend")
+    return backend == "cpu"
 
 
 def _make_kernel(lr: float, mu: float):
@@ -124,7 +133,8 @@ def fused_sgd_momentum(p, m, g, *, lr: float, mu: float,
 def _make_mix_kernel(lr: float):
     def kernel(w_ref, p_ref, m_ref, p_out):
         mixed = jnp.dot(w_ref[:], p_ref[:],
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32,
+                        precision=MIX_PRECISION)
         p_out[:] = mixed - lr * m_ref[:]
 
     return kernel
@@ -188,14 +198,10 @@ def fused_mix_update(params, momentum, w_matrix, spec, *, lr: float,
     default ``"off"`` compiles the exact pre-change programs, so the
     oracle-parity trace is untouched.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpret mode
-    elsewhere (same code path, testable on CPU).
+    ``interpret=None`` selects by backend (``pallas_interpret``).
     """
-    from dopt.parallel.collectives import (buckets_to_stacked,
-                                           stacked_to_buckets)
-
     if interpret is None:
-        interpret = not pallas_available()
+        interpret = pallas_interpret()
     w = jnp.asarray(w_matrix, jnp.float32)
     pb = stacked_to_buckets(params, spec)
     mb = stacked_to_buckets(momentum, spec)
@@ -212,7 +218,8 @@ def mix_sgd_reference(params, momentum, w_matrix, *, lr: float):
     w = jnp.asarray(w_matrix, jnp.float32)
 
     def leaf(p, m):
-        mixed = jnp.tensordot(w, p.astype(jnp.float32), axes=[[1], [0]])
+        mixed = jnp.tensordot(w, p.astype(jnp.float32), axes=[[1], [0]],
+                              precision=MIX_PRECISION)
         return (mixed - lr * m.astype(jnp.float32)).astype(p.dtype)
 
     return jax.tree.map(leaf, params, momentum)
@@ -222,11 +229,10 @@ def fused_sgd_momentum_tree(params, momentum, grads, *, lr: float, mu: float,
                             interpret: bool | None = None):
     """Tree-map the fused kernel over a params pytree.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpret mode
-    elsewhere (same code path, testable on CPU).
+    ``interpret=None`` selects by backend (``pallas_interpret``).
     """
     if interpret is None:
-        interpret = not pallas_available()
+        interpret = pallas_interpret()
     new_p, new_m = [], []
     p_leaves, treedef = jax.tree.flatten(params)
     m_leaves = treedef.flatten_up_to(momentum)

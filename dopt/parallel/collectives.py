@@ -36,7 +36,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dopt.parallel.mesh import WORKER_AXIS, compat_shard_map
+from dopt.parallel.mesh import WORKER_AXIS
+
+# Every [n, n] worker-axis contraction of f32 state runs at full f32
+# precision.  A TPU multiplies f32 operands in bf16 passes unless told
+# otherwise: bf16(1/3)·3 = 1.00195, so a "doubly stochastic" mix at the
+# default precision moved every leaf's worker-mean by ~8e-3 per round
+# and rounded the parameters themselves to bf16 (measured on a v5e,
+# PERF.md PR 21).  These contractions are HBM-bound (n ≤ a few dozen
+# rows), so the extra MXU passes are free; convolutions keep the
+# default.  A no-op on the CPU.
+MIX_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def mix_dense(stacked, w_matrix, mesh: Mesh | None = None,
@@ -64,7 +74,8 @@ def mix_dense(stacked, w_matrix, mesh: Mesh | None = None,
         return _mix_dense_compressed(stacked, w, mesh, comm_dtype)
 
     def mix_leaf(x):
-        y = jnp.tensordot(w.astype(x.dtype), x, axes=[[1], [0]])
+        y = jnp.tensordot(w.astype(x.dtype), x, axes=[[1], [0]],
+                          precision=MIX_PRECISION)
         y = y.astype(x.dtype)
         if mesh is not None:
             from dopt.parallel.mesh import worker_sharding
@@ -92,13 +103,14 @@ def _mix_dense_compressed(stacked, w, mesh: Mesh, comm_dtype):
         # wr: [W/D, W] f32 rows; xl: [W/D, ...] local worker shard.
         xg = jax.lax.all_gather(xl.astype(comm_dtype), ax, axis=0,
                                 tiled=True)
-        y = jnp.tensordot(wr, xg.astype(jnp.float32), axes=[[1], [0]])
+        y = jnp.tensordot(wr, xg.astype(jnp.float32), axes=[[1], [0]],
+                          precision=MIX_PRECISION)
         return y.astype(xl.dtype)
 
     def mix_leaf(x):
-        fn = compat_shard_map(per_device, mesh=mesh,
-                              in_specs=(P(ax, None), P(ax)),
-                              out_specs=P(ax))
+        fn = jax.shard_map(per_device, mesh=mesh,
+                           in_specs=(P(ax, None), P(ax)),
+                           out_specs=P(ax))
         return fn(w, x)
 
     return jax.tree.map(mix_leaf, stacked)
@@ -226,7 +238,7 @@ def mix_shifts(stacked, shift_ids, coeff_table, mesh: Mesh, comm_dtype=None):
     coeff_specs = P(None, WORKER_AXIS)  # [k, n] -> coeffs sharded on worker axis
 
     def mix_leaf(x):
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(coeff_specs, P(WORKER_AXIS)),
@@ -320,9 +332,9 @@ def _masked_average_compressed(stacked, m, denom, mesh: Mesh, comm_dtype):
         # all_gather+local-sum yields a value that IS replicated but
         # can't be statically proven so (unlike psum); skip the static
         # varying-axes check for this one collective.
-        fn = compat_shard_map(per_device, mesh=mesh,
-                              in_specs=(P(ax), P(ax)), out_specs=P(),
-                              check=False)
+        fn = jax.shard_map(per_device, mesh=mesh,
+                           in_specs=(P(ax), P(ax)), out_specs=P(),
+                           check_vma=False)
         return fn(m, x)
 
     return jax.tree.map(avg_leaf, stacked)
@@ -341,8 +353,8 @@ def _masked_average_compressed(stacked, m, denom, mesh: Mesh, comm_dtype):
 # shard it owns), the remaining update math runs on that shard, and ONE
 # all-gather restores the full view.  Issuing the collectives bucket by
 # bucket is what lets XLA's latency-hiding scheduler overlap bucket b's
-# wire time with bucket b+1's compute
-# (``dopt.parallel.mesh.enable_latency_hiding_scheduler``).
+# wire time with bucket b+1's compute (the scheduler and async
+# collective fusion are libtpu defaults; no flag is set).
 
 
 @dataclasses.dataclass(frozen=True)
@@ -473,16 +485,17 @@ def mix_dense_scatter(buckets, w_matrix, mesh: Mesh, comm_dtype=None):
         # w_cols: [n, L] — this device's lanes' columns of W;
         # x: [L, Fb] local lane slab.
         part = jnp.tensordot(w_cols, x.astype(jnp.float32),
-                             axes=[[1], [0]])          # [n, Fb] partial
+                             axes=[[1], [0]],
+                             precision=MIX_PRECISION)  # [n, Fb] partial
         if comm_dtype is not None:
             part = part.astype(comm_dtype)
         own = jax.lax.psum_scatter(part, ax, scatter_dimension=0,
                                    tiled=True)         # [L, Fb] mine
         return own.astype(x.dtype)
 
-    fn = compat_shard_map(per_device, mesh=mesh,
-                          in_specs=(P(None, ax), P(ax)),
-                          out_specs=P(ax))
+    fn = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(P(None, ax), P(ax)),
+                       out_specs=P(ax))
     with jax.named_scope("dopt_mix"):
         return [fn(w, b) for b in buckets]
 
@@ -552,9 +565,9 @@ def masked_average_scatter(stacked, mask, mesh: Mesh,
     # all_gather of identical shards IS replicated but cannot be
     # statically proven so — skip the varying-axes check, mirroring
     # _masked_average_compressed.
-    fn = compat_shard_map(per_device, mesh=mesh,
-                          in_specs=(P(ax), P(ax)), out_specs=P(),
-                          check=False)
+    fn = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(P(ax), P(ax)), out_specs=P(),
+                       check_vma=False)
     with jax.named_scope("dopt_mix"):
         out = [fn(m, b) for b in buckets]
     return buckets_to_tree(out, spec)
@@ -712,7 +725,8 @@ def _codec_mix_bucket(w_rows, x, e, lane0, kind: str, chunk: int, key,
         vg = qint_decode(payload, scale, fb, chunk=chunk, bits=bits)
     else:
         vg = vq
-    y = jnp.tensordot(w_rows, vg, axes=[[1], [0]])        # [L, Fb]
+    y = jnp.tensordot(w_rows, vg, axes=[[1], [0]],
+                      precision=MIX_PRECISION)            # [L, Fb]
     return y.astype(x.dtype), new_e
 
 
@@ -746,7 +760,7 @@ def mix_codec_gather(buckets, residuals, w_matrix, mesh: Mesh,
                     return _codec_mix_bucket(w_rows, x, er, lane0, _kind,
                                              plan.chunk, _bkey, ax)
 
-                fn = compat_shard_map(
+                fn = jax.shard_map(
                     per_device, mesh=mesh,
                     in_specs=(P(ax, None), P(ax), P(ax)),
                     out_specs=(P(ax), P(ax)))
@@ -780,7 +794,8 @@ def mix_codec_reference(buckets, residuals, w_matrix,
             cd = {"raw": None, "bf16": jnp.bfloat16,
                   "f16": jnp.float16}[kind]
             x = b if cd is None else b.astype(cd).astype(jnp.float32)
-            y = jnp.tensordot(w, x.astype(jnp.float32), axes=[[1], [0]])
+            y = jnp.tensordot(w, x.astype(jnp.float32), axes=[[1], [0]],
+                              precision=MIX_PRECISION)
             mixed.append(y.astype(b.dtype))
             new_res.append(e)
     return mixed, new_res

@@ -11,104 +11,11 @@ lanes are vmapped — that is how 32 workers run on a v5e-8
 
 from __future__ import annotations
 
-import os
-import warnings
-
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 WORKER_AXIS = "workers"
-
-# XLA latency-hiding scheduler: lets the compiler hoist collective
-# starts ahead of independent compute so a bucketed consensus step
-# (update_sharding="scatter", dopt.parallel.collectives) overlaps
-# bucket b's wire time with bucket b+1's contraction.  TPU-only flags
-# are ignored by other backends; async-collective conversion is what
-# turns each per-bucket psum_scatter/all_gather into a start/done pair
-# the scheduler can move.
-LATENCY_HIDING_XLA_FLAGS: tuple[str, ...] = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-)
-
-
-def _backend_initialized() -> bool:
-    """True once any XLA backend exists (XLA_FLAGS edits no longer
-    apply).  Best-effort across jax versions; assumes initialised when
-    the probe fails (the safe direction: we then warn instead of
-    silently setting dead flags)."""
-    try:
-        from jax._src import xla_bridge
-
-        return bool(getattr(xla_bridge, "_backends", None))
-    except Exception:  # pragma: no cover - jax internals moved
-        return True
-
-
-def _tpu_expected() -> bool:
-    """Whether this process will (or did) target a TPU backend — the
-    only backend whose XLA build knows the ``--xla_tpu_*`` flags (the
-    CPU build FATALs on unknown XLA_FLAGS, so setting them blindly
-    would kill every CPU run)."""
-    plat = os.environ.get("JAX_PLATFORMS",
-                          os.environ.get("JAX_PLATFORM_NAME", ""))
-    if plat:
-        return "tpu" in plat.lower()
-    try:
-        import libtpu  # noqa: F401  (present only where a TPU runtime is)
-
-        return True
-    except ImportError:
-        return False
-
-
-def enable_latency_hiding_scheduler() -> bool:
-    """Append ``LATENCY_HIDING_XLA_FLAGS`` to ``XLA_FLAGS`` so the
-    scatter path's per-bucket collectives overlap with compute.
-
-    Must run BEFORE the first jax backend initialisation (XLA reads the
-    env once); returns True when the flags are (already) in effect,
-    False when they cannot be applied — silently on non-TPU targets
-    (the flags are TPU-only and the CPU XLA build aborts on unknown
-    flags), with a warning when a TPU backend beat us to it.
-    ``bench.py`` calls this before importing the engines; trainer
-    construction calls it too as a best-effort for scripts that
-    configure scatter mode late."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    missing = [f for f in LATENCY_HIDING_XLA_FLAGS if f not in flags]
-    if not missing:
-        return True
-    if not _tpu_expected():
-        return False
-    if _backend_initialized():
-        warnings.warn(
-            "update_sharding='scatter' wants the XLA latency-hiding "
-            "scheduler, but an XLA backend is already initialised so "
-            "XLA_FLAGS can no longer be amended — start the process "
-            "with dopt.parallel.mesh.LATENCY_HIDING_XLA_FLAGS in "
-            "XLA_FLAGS (bench.py does this) to overlap the bucketed "
-            "collectives with compute", stacklevel=2)
-        return False
-    os.environ["XLA_FLAGS"] = " ".join([flags] + missing).strip()
-    return True
-
-
-def compat_shard_map(fn, *, mesh, in_specs, out_specs, check=True):
-    """``jax.shard_map`` across jax versions: new jax exposes it at the
-    top level with the static-varying-axes check named ``check_vma``;
-    0.4.x has it under ``jax.experimental.shard_map`` as ``check_rep``.
-    Every shard_map in dopt routes through here so the engines run on
-    both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
 
 
 def make_mesh(num_devices: int | None = None, *, devices=None) -> Mesh:
@@ -208,8 +115,8 @@ def shard_over_workers(fn, mesh: Mesh, in_specs, out_specs):
             return one(spec)
         return tuple(one(c) for c in spec)
 
-    return compat_shard_map(fn, mesh=mesh, in_specs=resolve(in_specs),
-                            out_specs=resolve(out_specs), check=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=resolve(in_specs),
+                         out_specs=resolve(out_specs), check_vma=False)
 
 
 def make_worker_mesh(num_workers: int, mesh_devices: int | None = None,

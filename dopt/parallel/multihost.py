@@ -132,22 +132,6 @@ def coordinator_handoff(path: str | Path, process_id: int, *,
     return wait_handoff(path, poll_s=poll_s, max_polls=max_polls)
 
 
-def _distributed_initialized() -> bool:
-    """Whether ``jax.distributed`` is already wired, across jax
-    versions: new jax exposes ``jax.distributed.is_initialized``; 0.4.x
-    only carries the module-level client state.  Double-initialising
-    raises, so this probe gates ``initialize_distributed``."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:  # pragma: no cover - jax internals moved
-        return False
-
-
 def initialize_distributed(
     coordinator_address: str | None = None,
     num_processes: int | None = None,
@@ -169,7 +153,7 @@ def initialize_distributed(
         process_id = int(os.environ["JAX_PROCESS_ID"])
     if coordinator_address is None and num_processes is None:
         return False
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return True   # a launcher/framework already wired the runtime
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

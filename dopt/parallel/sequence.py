@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dopt.parallel.mesh import compat_shard_map
-
 SEQ_AXIS = "sp"
 
 
@@ -195,8 +193,8 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = False,
         return num / jnp.maximum(den, 1e-30)[..., None]
 
     spec = P(None, axis, None, None)
-    fn = compat_shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     return fn(q, k, v)
 
 
@@ -232,8 +230,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, causal: bool = False,
         return heads_to_seq(out)
 
     spec = P(None, axis, None, None)
-    fn = compat_shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     return fn(q, k, v)
 
 

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dopt.parallel.collectives import MIX_PRECISION
+
 AGGREGATORS = ("mean", "trimmed_mean", "median", "krum", "multi_krum")
 
 
@@ -291,7 +293,8 @@ def byzantine_mix(x, x_send, w_matrix):
         xs_z = jnp.where(fb, xs, jnp.zeros((), xs.dtype))
         keep = diag.reshape((-1,) + (1,) * (xr.ndim - 1)).astype(jnp.float32)
         y = (keep * xr.astype(jnp.float32)
-             + jnp.tensordot(off, xs_z.astype(jnp.float32), axes=[[1], [0]]))
+             + jnp.tensordot(off, xs_z.astype(jnp.float32), axes=[[1], [0]],
+                             precision=MIX_PRECISION))
         pb = poisoned.reshape((-1,) + (1,) * (xr.ndim - 1))
         y = jnp.where(pb, jnp.nan, y)
         return y.astype(xr.dtype)
@@ -355,7 +358,8 @@ def clipped_gossip_mix(x, x_send, w_matrix, tau: float):
         keep = (1.0 - rowsum).reshape(
             (-1,) + (1,) * (xr.ndim - 1)).astype(jnp.float32)
         y = (keep * xr.astype(jnp.float32)
-             + jnp.tensordot(c, xs.astype(jnp.float32), axes=[[1], [0]]))
+             + jnp.tensordot(c, xs.astype(jnp.float32), axes=[[1], [0]],
+                             precision=MIX_PRECISION))
         return y.astype(xr.dtype)
 
     mixed = jax.tree.map(leaf, x, x_send_z)

@@ -95,6 +95,17 @@ def build_trainer(cfg):
     return GossipTrainer(cfg)
 
 
+def device_details(trainer) -> str:
+    """What the run executes on, printed beside ``exp_details`` so every
+    log names its device (a CPU run must never read as a chip run)."""
+    import jax
+
+    devs = jax.devices()
+    return (f"device: platform={devs[0].platform} "
+            f"device_kind={devs[0].device_kind!r} n_devices={len(devs)} "
+            f"mesh={dict(trainer.mesh.shape)}")
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--preset", required=True,
@@ -300,9 +311,13 @@ def main(argv: list[str] | None = None) -> int:
         ))
 
     from dopt.config import exp_details
+    from dopt.utils.compile_cache import enable_compile_cache
 
     print(exp_details(cfg), file=sys.stderr)
+    enable_compile_cache()
     trainer = build_trainer(cfg)
+    if cfg.backend == "jax":
+        print(device_details(trainer), file=sys.stderr)
     if args.resume:
         trainer.restore(args.resume)
         print(f"resumed at round {trainer.round}", file=sys.stderr)

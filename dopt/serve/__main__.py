@@ -87,6 +87,9 @@ def build_cfg(args):
 
 
 def run_daemon(args, argv: list[str]) -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.process_id is not None:
         # Fleet child: the shared bootstrap (dopt.parallel.multihost)
         # pins device flags + gloo before backend init and rendezvous

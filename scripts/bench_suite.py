@@ -315,6 +315,9 @@ def measure_preset(name: str, *, quick: bool, skip_oracle: bool) -> dict:
 
 
 def main() -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="fewer rounds / truncated oracle (CI-ish)")

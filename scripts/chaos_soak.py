@@ -481,6 +481,9 @@ def child_main(engine: str, seed: int, rounds: int, path: str,
 
 
 def main(argv: list[str] | None = None) -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--rounds", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0,

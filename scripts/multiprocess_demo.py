@@ -46,7 +46,9 @@ def child_main(args) -> int:
     # the dopt.serve fleet children.
     sys.path.insert(0, str(REPO))
     from dopt.parallel.multihost import HOST_AXIS, bootstrap_child_backend
+    from dopt.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     bootstrap_child_backend(args.handoff, args.process_id,
                             args.num_processes, args.devices_per_proc)
     import jax

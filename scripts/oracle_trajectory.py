@@ -202,6 +202,9 @@ def reference_shaped_federated(rounds: int = 20) -> ExperimentConfig:
 
 
 def main() -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--long", action="store_true",

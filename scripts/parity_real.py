@@ -62,6 +62,9 @@ def check(rows: list[dict], name: str, ok: bool, detail: str) -> None:
 
 
 def main() -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fed-only", action="store_true")
     ap.add_argument("--gossip-only", action="store_true")

@@ -121,6 +121,9 @@ def check_orderings(summary: list[dict]) -> list[str]:
 
 
 def main() -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny data / few rounds (machinery check only)")

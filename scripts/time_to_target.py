@@ -311,6 +311,9 @@ def add_dtype_control(out_path: Path, *, target: float, quick: bool,
 
 
 def main() -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--target", type=float, default=0.9)
     ap.add_argument("--quick", action="store_true",

@@ -60,6 +60,9 @@ def summarize_xplane(trace_dir: str) -> dict:
 
 
 def main() -> int:
+    from dopt.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="baseline5",
                     help="baseline1..5 or 'headline' (bench.py workload)")

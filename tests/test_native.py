@@ -91,3 +91,22 @@ def test_native_plan_worker_subset_matches_full_plan_rows():
     assert sub.idx.shape == (3, 8, 32)
     np.testing.assert_array_equal(sub.idx, full.idx[sel])
     np.testing.assert_array_equal(sub.weight, full.weight[sel])
+
+
+def test_requested_native_planner_fails_with_the_compilers_stderr(
+        tmp_path, monkeypatch):
+    """``plan_impl="native"`` never degrades to the numpy planner (which
+    draws a different batch order): an unbuildable library raises, and
+    the error carries what g++ said."""
+    import dopt.native as native
+
+    bad = tmp_path / "plan.cpp"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", str(bad))
+    monkeypatch.setattr(native, "_LIB", str(tmp_path / "libdopt_bad.so"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_error", None)
+    with pytest.raises(native.NativeUnavailable, match="error"):
+        make_batch_plan(_index_matrix(), batch_size=8, impl="native")
+    assert not native.native_available()
+    assert list(tmp_path.glob("libdopt_bad.so*")) == []   # no litter

@@ -176,3 +176,18 @@ def test_fused_mix_update_tree_over_buckets(devices):
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-6)
+
+
+def test_pallas_mode_is_chosen_by_backend_never_silently(monkeypatch):
+    """Compiled on tpu, interpreted on cpu (these tests), an error on
+    anything else — no backend gets a quiet interpreter."""
+    import pytest
+
+    from dopt.ops import fused_update
+
+    assert fused_update.pallas_interpret() is True          # the CPU mesh
+    monkeypatch.setattr(fused_update.jax, "default_backend", lambda: "tpu")
+    assert fused_update.pallas_interpret() is False
+    monkeypatch.setattr(fused_update.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        fused_update.pallas_interpret()

@@ -28,24 +28,6 @@ from dopt.models import build_model, make_stacked_apply
 
 W, B, S = 3, 8, 4
 
-# The MULTI-STEP parity tests chain S dependent SGD steps through the
-# grouped-conv forward: each step's reassociation delta (grouped conv
-# vs vmap sums channels in a different order) feeds the next step's
-# inputs, and on the CPU backend — whose conv algorithms differ more
-# between the two lowerings than the TPU's — the compounded drift
-# lands ~3% relative after 4 steps, past any tolerance that would
-# still catch real bugs.  Single-step and single-forward parity (the
-# actual contract) passes everywhere; the engine-level trajectory test
-# pins end-to-end agreement at history precision.  Pre-existing
-# failure triaged in PR 6 (ISSUE 5 satellite): expected-fail on CPU,
-# strict=False so TPU runs still assert.
-_xfail_cpu_multistep = pytest.mark.xfail(
-    jax.default_backend() == "cpu",
-    reason="CPU conv reassociation compounds over dependent SGD steps "
-           "beyond per-step float tolerance (grouped vs vmap lowering); "
-           "passes on TPU — see CHANGES.md PR 6 triage",
-    strict=False)
-
 
 def _setup(model_name, faithful):
     shape = (28, 28, 1) if model_name == "model1" else (32, 32, 3)
@@ -119,7 +101,6 @@ def test_resnet_update_parity():
                                rtol=1e-3, atol=1e-4)
 
 
-@_xfail_cpu_multistep
 @pytest.mark.parametrize("algorithm", ["sgd", "fedprox", "fedadmm",
                                        "scaffold"])
 def test_local_update_parity(algorithm):
@@ -152,7 +133,6 @@ def test_local_update_parity(algorithm):
     np.testing.assert_allclose(np.asarray(av), np.asarray(as_), atol=1e-6)
 
 
-@_xfail_cpu_multistep
 def test_gather_and_epochs_parity():
     model, stacked, x, y = _setup("model1", True)
     s_apply = make_stacked_apply(model)
