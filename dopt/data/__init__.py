@@ -6,7 +6,8 @@ from dopt.data.partition import (assign_client_shards, holdout_split,
 from dopt.data.pipeline import (BatchPlan, eval_batches, make_batch_plan,
                                 gather_batches, sharded_eval_batches,
                                 stacked_eval_batches)
-from dopt.data.prefetch import PrefetchStager, timed_build
+from dopt.data.prefetch import (PrefetchStager, next_block_rounds,
+                                timed_build)
 
 __all__ = [
     "Dataset",
@@ -25,5 +26,6 @@ __all__ = [
     "sharded_eval_batches",
     "stacked_eval_batches",
     "PrefetchStager",
+    "next_block_rounds",
     "timed_build",
 ]

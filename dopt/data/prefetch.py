@@ -46,19 +46,30 @@ import threading
 import time
 
 
+def next_block_rounds(ts: list, remaining: int, block: int,
+                      next_ckpt: int | None) -> list:
+    """The rounds of the block a prefetching loop stages while block
+    ``ts`` runs: none past the run's end (``remaining`` rounds after
+    ``ts``), and staging never crosses a scheduled checkpoint boundary
+    (the block after a checkpoint builds inline from committed state)."""
+    end_round = ts[-1] + 1
+    if remaining > 0 and (next_ckpt is None or end_round < next_ckpt):
+        return [end_round + j for j in range(min(block, remaining))]
+    return []
+
+
 def timed_build(build, timers):
     """Wrap a pure block ``build`` so its runtime accumulates into
     ``timers``' ``host_batch_plan`` totals from the stager's background
     thread (the ``PhaseTimers`` tracer spans are not meant for
-    concurrent cross-thread use, so the wrapper adds to the defaultdict
-    totals directly — the engines' inline path uses the same key, never
+    concurrent cross-thread use, so the wrapper accounts the span
+    directly — the engines' inline path uses the same key, never
     concurrently with a staged build of the same block)."""
 
     def wrapped(meta):
         t0 = time.perf_counter()  # dopt: allow-wallclock -- span timing only, never training math
         out = build(meta)
-        timers.totals["host_batch_plan"] += time.perf_counter() - t0  # dopt: allow-wallclock -- span timing only, never training math
-        timers.counts["host_batch_plan"] += 1
+        timers.add("host_batch_plan", time.perf_counter() - t0)  # dopt: allow-wallclock -- span timing only, never training math
         return out
 
     return wrapped

@@ -39,10 +39,11 @@ import numpy as np
 
 from dopt.config import ExperimentConfig
 from dopt.data import (PrefetchStager, eval_batches, load_dataset,
-                       make_batch_plan, partition, stacked_eval_batches,
-                       timed_build)
+                       make_batch_plan, next_block_rounds, partition,
+                       stacked_eval_batches, timed_build)
 from dopt.engine.local import (_stacked_eval_scan, flat_input_apply,
-                               flat_input_stacked_apply, make_evaluator,
+                               flat_input_stacked_apply, gather_rows,
+                               make_evaluator,
                                make_stacked_local_update,
                                make_stacked_local_update_epochs,
                                prepare_holdout, validate_optimizer)
@@ -731,8 +732,7 @@ class FederatedTrainer:
                 else:
                     p_t, m_t, em = local_epochs(*args, theta, alpha)
                 return p_t, m_t, em["train_loss"], em["train_acc"], em
-            bx = train_x[idx]
-            by = train_y[idx]
+            bx, by = gather_rows(train_x, train_y, idx)
             args = ((start, mom_in, bx, by, bw, limits) if may_straggle
                     else (start, mom_in, bx, by, bw))
             if algorithm == "fedavg":
@@ -743,6 +743,7 @@ class FederatedTrainer:
                 p_t, m_t, losses, accs = local(*args, theta, alpha)
             return p_t, m_t, losses, accs, {}
 
+        @jax.named_scope("dopt_local")
         def algo_step(theta, start, mom_in, duals_in, c_global, idx, bw,
                       limits, train_x, train_y, vidx, vw):
             """Local update + companion-state refresh on however many
@@ -902,8 +903,8 @@ class FederatedTrainer:
             The host-facing metrics leave as one packed vector."""
             evalm = global_eval(new_theta, ex, ey, ew)
             if eval_train_flag:
-                tx = train_x[tidx]
-                ty = train_y[tidx]
+                with jax.named_scope("dopt_eval"):
+                    tx, ty = gather_rows(train_x, train_y, tidx)
                 trainm = stacked_eval_perworker(new_p, tx, ty, tweight)
             else:
                 trainm = {"acc": jnp.zeros(w), "loss_mean": jnp.zeros(w),
@@ -1003,7 +1004,8 @@ class FederatedTrainer:
                     return (((x * mm).sum(axis=0) + (s * ss).sum(axis=0))
                             / denom.astype(x.dtype))
 
-                new_theta = jax.tree.map(wleaf, agg_in, agg_stale)
+                with jax.named_scope("dopt_mix"):
+                    new_theta = jax.tree.map(wleaf, agg_in, agg_stale)
                 alive_any = tot_w > 0
                 # Captured lanes' finished updates land in the buffer;
                 # everyone else's slot is carried unchanged.
@@ -1023,12 +1025,14 @@ class FederatedTrainer:
                         agg_mask,
                         jax.tree.map(lambda a, b: a - b, p_t, theta_b),
                         jax.tree.map(jnp.zeros_like, p_t))
-                    theta_slab = fused_mix_update(
-                        disp, theta_b, mean_weight_matrix(agg_mask),
-                        fused_spec, lr=-1.0)
+                    with jax.named_scope("dopt_mix"):
+                        theta_slab = fused_mix_update(
+                            disp, theta_b, mean_weight_matrix(agg_mask),
+                            fused_spec, lr=-1.0)
                     new_theta = jax.tree.map(lambda x: x[0], theta_slab)
                 elif agg_robust is not None:
-                    new_theta = agg_robust(agg_in, agg_mask)
+                    with jax.named_scope("dopt_mix"):
+                        new_theta = agg_robust(agg_in, agg_mask)
                 elif scatter_spec is not None:
                     new_theta = masked_average_scatter(
                         agg_in, agg_mask, agg_mesh, scatter_spec,
@@ -1173,13 +1177,16 @@ class FederatedTrainer:
                                    _where_mask(fin, m_t, _take(mom, sel))))
             agg_in = (clip_to_ball(p_keep, theta, clip_radius)
                       if clip_radius > 0 else p_keep)
-            if agg_robust is None:
-                plain = jax.tree.map(lambda x: x.mean(axis=0), agg_in)
-                masked = masked_mean(agg_in, fin)
-                new_theta = jax.tree.map(
-                    lambda a, b: jnp.where(all_fin, a, b), plain, masked)
-            else:
-                new_theta = agg_robust(agg_in, fin)
+            # The aggregation layer of this path: a local mean over the
+            # m sampled lanes (no ``masked_average``, so the scope is here).
+            with jax.named_scope("dopt_mix"):
+                if agg_robust is None:
+                    plain = jax.tree.map(lambda x: x.mean(axis=0), agg_in)
+                    masked = masked_mean(agg_in, fin)
+                    new_theta = jax.tree.map(
+                        lambda a, b: jnp.where(all_fin, a, b), plain, masked)
+                else:
+                    new_theta = agg_robust(agg_in, fin)
             any_fin = fin.sum() > 0
             new_theta = jax.tree.map(
                 lambda a, th: jnp.where(any_fin, a, th), new_theta, theta)
@@ -1420,14 +1427,15 @@ class FederatedTrainer:
                         valid, lim, idx, bw = xs
                     start = broadcast_to_workers(theta, pop_lanes)
                     mom0 = jax.tree.map(jnp.zeros_like, start)
-                    bx = train_x[idx]
-                    by = train_y[idx]
-                    args = ((start, mom0, bx, by, bw, lim) if may_straggle
-                            else (start, mom0, bx, by, bw))
-                    if algorithm == "fedprox":
-                        p_t, _m_t, losses, accs = local(*args, theta)
-                    else:
-                        p_t, _m_t, losses, accs = local(*args)
+                    with jax.named_scope("dopt_local"):
+                        bx, by = gather_rows(train_x, train_y, idx)
+                        args = ((start, mom0, bx, by, bw, lim)
+                                if may_straggle
+                                else (start, mom0, bx, by, bw))
+                        if algorithm == "fedprox":
+                            p_t, _m_t, losses, accs = local(*args, theta)
+                        else:
+                            p_t, _m_t, losses, accs = local(*args)
                     if has_corrupt:
                         # Client-keyed lies: the [lanes] mask is the
                         # population fault stream gathered at this
@@ -1444,8 +1452,10 @@ class FederatedTrainer:
                     zed = _where_mask(
                         fin, agg_in,
                         jax.tree.map(jnp.zeros_like, agg_in))
-                    acc = jax.tree.map(
-                        lambda a, x: a + x.astype(jnp.float32), acc, zed)
+                    with jax.named_scope("dopt_mix"):
+                        acc = jax.tree.map(
+                            lambda a, x: a + x.astype(jnp.float32), acc,
+                            zed)
                     acc_w = acc_w + fin
                     lane_loss = losses.mean(axis=1)
                     lane_loss = jnp.where(jnp.isfinite(lane_loss),
@@ -1985,39 +1995,54 @@ class FederatedTrainer:
 
     def _population_loop(self, rounds: int, checkpoint_every: int,
                          checkpoint_path, stager) -> None:
-        reg = self._registry
         for r in range(rounds):
-            t = self.round
-            payload = stager.take(t) if stager is not None else None
-            if payload is None:
-                with self.timers.phase("host_batch_plan"):
-                    payload = self._build_pop_round(
-                        self._draw_pop_round(t))
-            binding, rows = payload["binding"], payload["rows"]
-            step_kw = ({"cmasks": jnp.asarray(payload["cmask"])}
-                       if self._has_corrupt else {})
-            args = (self.theta, payload["idx"], payload["bw"],
-                    payload["valids"], payload["lim"], self._train_x,
-                    self._train_y, *self._eval)
-            if stager is None:
-                self.theta, packed = self.timers.measure(
-                    "round_step", self._pop_round_fn, *args, **step_kw)
-            else:
-                with self.timers.phase("round_step"):
+            # Staging never crosses a checkpoint boundary.
+            ckpt_next = (checkpoint_every
+                         and (self.round + 1) % checkpoint_every == 0)
+            with self.timers.step(self.round):
+                self._population_round(
+                    stager, stage_next=r + 1 < rounds and not ckpt_next)
+                if checkpoint_every and self.round % checkpoint_every == 0:
+                    self.save(checkpoint_path)
+
+    def _population_round(self, stager, stage_next: bool) -> None:
+        """One population round: plan (unless staged), dispatch, stage
+        the next round while the device runs (prefetch), wait, fetch,
+        record."""
+        reg = self._registry
+        t = self.round
+        payload = stager.take(t) if stager is not None else None
+        if payload is None:
+            with self.timers.phase("host_batch_plan"):
+                payload = self._build_pop_round(
+                    self._draw_pop_round(t))
+        binding, rows = payload["binding"], payload["rows"]
+        step_kw = ({"cmasks": jnp.asarray(payload["cmask"])}
+                   if self._has_corrupt else {})
+        args = (self.theta, payload["idx"], payload["bw"],
+                payload["valids"], payload["lim"], self._train_x,
+                self._train_y, *self._eval)
+        if stager is None:
+            self.theta, packed = self.timers.measure(
+                "round_step", self._pop_round_fn, *args, **step_kw)
+        else:
+            with self.timers.phase("round_step"):
+                with self.timers.phase("round_dispatch"):
                     out = self._pop_round_fn(*args, **step_kw)
-                    ckpt_next = (checkpoint_every
-                                 and (t + 1) % checkpoint_every == 0)
-                    if r + 1 < rounds and not ckpt_next:
-                        with self.timers.phase("host_batch_plan"):
-                            meta = self._draw_pop_round(t + 1)
-                        stager.stage(
-                            t + 1,
-                            timed_build(self._build_pop_round,
-                                        self.timers),
-                            meta)
+                if stage_next:
+                    with self.timers.phase("host_batch_plan"):
+                        meta = self._draw_pop_round(t + 1)
+                    stager.stage(
+                        t + 1,
+                        timed_build(self._build_pop_round,
+                                    self.timers),
+                        meta)
+                with self.timers.phase("round_wait"):
                     jax.block_until_ready(out)
-                self.theta, packed = out
+            self.theta, packed = out
+        with self.timers.phase("round_fetch"):
             packed = np.asarray(packed)   # ONE device→host fetch/round
+        with self.timers.phase("round_record"):
             ll, acc, loss_sum, t_loss, t_acc = (float(v)
                                                 for v in packed[:5])
             n = len(binding.survivors)
@@ -2043,8 +2068,6 @@ class FederatedTrainer:
             )
             self._round_telemetry(t, rows)
             self.round += 1
-            if checkpoint_every and self.round % checkpoint_every == 0:
-                self.save(checkpoint_path)
 
     def _run_blocked(self, frac: float, rounds: int, block: int,
                      checkpoint_every: int = 0,
@@ -2156,58 +2179,72 @@ class FederatedTrainer:
         while done < rounds:
             k = min(block, rounds - done)
             ts = [self.round + j for j in range(k)]
-            payload = stager.take(ts[0]) if stager is not None else None
-            if payload is None:
-                with self.timers.phase("host_batch_plan"):
-                    payload = self._build_block(
-                        self._draw_block(ts, frac, compact, fixed_c))
-            sels, frows = payload["sels"], payload["frows"]
-            lane_sels = payload["lane_sels"]
-            duals_in = self.duals if self.duals is not None else {}
-            c_in = self.c_global if self.c_global is not None else {}
-            fn = (self._compact_fault_block_fn if fixed_c
-                  else self._compact_block_fn if compact
-                  else self._block_fn)
-            step_kw = {}
-            if self._has_corrupt:
-                step_kw["cmasks"] = payload["cms"]
-            if fixed_c:
-                step_kw["valids"] = payload["valids"]
-            args = (self.theta, self.params, self.momentum, duals_in,
-                    c_in, payload["gates"], payload["limits"],
-                    payload["idx"], payload["bw"], self._train_x,
-                    self._train_y, *self._eval, self._train_eval_idx,
-                    self._train_eval_w, *self._val)
-            if stager is None:
-                out = self.timers.measure("round_step", fn, *args,
-                                          **step_kw)
-            else:
-                # dispatch → stage-next → fetch (see gossip.py): the
-                # next block's participation draw stays on this thread,
-                # its plan build overlaps this block's device time.
-                with self.timers.phase("round_step"):
+            next_ts = next_block_rounds(ts, rounds - (done + k), block,
+                                        next_ckpt)
+            with self.timers.step(ts[0]):
+                self._run_block(ts, next_ts, frac, stager, compact, fixed_c)
+                done += k
+                if next_ckpt is not None and self.round >= next_ckpt:
+                    self.save(checkpoint_path)
+                    next_ckpt = (self.round // checkpoint_every + 1) \
+                        * checkpoint_every
+
+    def _run_block(self, ts, next_ts, frac, stager, compact,
+                   fixed_c) -> None:
+        """One fused block: plan (unless staged), dispatch, stage
+        ``next_ts`` while the device runs (prefetch), wait, fetch,
+        record."""
+        payload = stager.take(ts[0]) if stager is not None else None
+        if payload is None:
+            with self.timers.phase("host_batch_plan"):
+                payload = self._build_block(
+                    self._draw_block(ts, frac, compact, fixed_c))
+        sels, frows = payload["sels"], payload["frows"]
+        lane_sels = payload["lane_sels"]
+        duals_in = self.duals if self.duals is not None else {}
+        c_in = self.c_global if self.c_global is not None else {}
+        fn = (self._compact_fault_block_fn if fixed_c
+              else self._compact_block_fn if compact
+              else self._block_fn)
+        step_kw = {}
+        if self._has_corrupt:
+            step_kw["cmasks"] = payload["cms"]
+        if fixed_c:
+            step_kw["valids"] = payload["valids"]
+        args = (self.theta, self.params, self.momentum, duals_in,
+                c_in, payload["gates"], payload["limits"],
+                payload["idx"], payload["bw"], self._train_x,
+                self._train_y, *self._eval, self._train_eval_idx,
+                self._train_eval_w, *self._val)
+        if stager is None:
+            out = self.timers.measure("round_step", fn, *args,
+                                      **step_kw)
+        else:
+            # dispatch → stage-next → fetch (see gossip.py): the
+            # next block's participation draw stays on this thread,
+            # its plan build overlaps this block's device time.
+            with self.timers.phase("round_step"):
+                with self.timers.phase("round_dispatch"):
                     out = fn(*args, **step_kw)
-                    end_round = ts[-1] + 1
-                    remaining = rounds - (done + k)
-                    if remaining > 0 and (next_ckpt is None
-                                          or end_round < next_ckpt):
-                        nk = min(block, remaining)
-                        nts = [end_round + j for j in range(nk)]
-                        with self.timers.phase("host_batch_plan"):
-                            meta = self._draw_block(nts, frac, compact,
-                                                    fixed_c)
-                        stager.stage(
-                            nts[0],
-                            timed_build(self._build_block, self.timers),
-                            meta)
+                if next_ts:
+                    with self.timers.phase("host_batch_plan"):
+                        meta = self._draw_block(next_ts, frac, compact,
+                                                fixed_c)
+                    stager.stage(
+                        next_ts[0],
+                        timed_build(self._build_block, self.timers),
+                        meta)
+                with self.timers.phase("round_wait"):
                     jax.block_until_ready(out)
-            (self.theta, self.params, self.momentum, new_duals, new_c,
-             packed) = out
-            if self.duals is not None:
-                self.duals = new_duals
-            if self.c_global is not None:
-                self.c_global = new_c
+        (self.theta, self.params, self.momentum, new_duals, new_c,
+         packed) = out
+        if self.duals is not None:
+            self.duals = new_duals
+        if self.c_global is not None:
+            self.c_global = new_c
+        with self.timers.phase("round_fetch"):
             packed = np.asarray(packed)  # ONE device→host fetch per block
+        with self.timers.phase("round_record"):
             lanes = len(lane_sels[0]) if compact else self.num_workers
             for j, t in enumerate(ts):
                 ll, acc, loss_sum, t_loss, t_acc, scr, _, em, diag = \
@@ -2234,11 +2271,6 @@ class FederatedTrainer:
                 ts[-1],
                 "compact_fault_block_fn" if fixed_c
                 else "compact_block_fn" if compact else "block_fn", fn)
-            done += k
-            if next_ckpt is not None and self.round >= next_ckpt:
-                self.save(checkpoint_path)
-                next_ckpt = (self.round // checkpoint_every + 1) \
-                    * checkpoint_every
 
     def _run_blocked_chaos(self, frac: float, rounds: int, block: int,
                            checkpoint_every: int = 0,
@@ -2288,68 +2320,81 @@ class FederatedTrainer:
     def _blocked_chaos_loop(self, frac, rounds, block, m, next_ckpt,
                             checkpoint_every, checkpoint_path,
                             stager) -> None:
-        w = self.num_workers
         done = 0
         while done < rounds:
             k = min(block, rounds - done)
             ts = [self.round + j for j in range(k)]
-            payload = stager.take(ts[0]) if stager is not None else None
-            if payload is None:
-                with self.timers.phase("host_batch_plan"):
-                    payload = self._build_block(
-                        self._draw_chaos_block(ts, frac))
-            chosen, stacks = payload["chosen"], payload["stacks"]
-            duals_in = self.duals if self.duals is not None else {}
-            c_in = self.c_global if self.c_global is not None else {}
-            sp_in = self._stale_p if self._has_stale else {}
-            args = (self.theta, self.params, self.momentum, duals_in,
-                    c_in,
-                    jnp.asarray(self._screen_streak.astype(np.int32)),
-                    jnp.asarray(self._quarantine_until.astype(np.int32)),
-                    jnp.asarray(self._stale_admit_round.astype(np.int32)),
-                    jnp.asarray(self._stale_weight.astype(np.float32)),
-                    sp_in, jnp.asarray(m, jnp.int32),
-                    jnp.asarray(ts, jnp.int32), jnp.asarray(chosen),
-                    stacks["away"], stacks["crashed"], stacks["unreach"],
-                    stacks["straggler"], stacks["up_drop"],
-                    stacks["up_delay"], stacks["late_d"],
-                    stacks["limits"], stacks["corrupt"], payload["idx"],
-                    payload["bw"], self._train_x, self._train_y,
-                    *self._eval,
-                    self._train_eval_idx, self._train_eval_w,
-                    *self._val)
-            if stager is None:
-                out = self.timers.measure("round_step",
-                                          self._chaos_block_fn, *args)
-            else:
-                # dispatch → stage-next → fetch; note the carry inputs
-                # (streaks, admission schedule) above are read at
-                # DISPATCH time, after the previous block's replay —
-                # only the plan payload is staged ahead.
-                with self.timers.phase("round_step"):
+            next_ts = next_block_rounds(ts, rounds - (done + k), block,
+                                        next_ckpt)
+            with self.timers.step(ts[0]):
+                self._run_chaos_block(ts, next_ts, frac, m, stager)
+                done += k
+                if next_ckpt is not None and self.round >= next_ckpt:
+                    self.save(checkpoint_path)
+                    next_ckpt = (self.round // checkpoint_every + 1) \
+                        * checkpoint_every
+
+    def _run_chaos_block(self, ts, next_ts, frac, m, stager) -> None:
+        """One fused chaos block: plan (unless staged), dispatch, stage
+        ``next_ts`` while the device runs (prefetch), wait, fetch,
+        replay and record."""
+        w = self.num_workers
+        payload = stager.take(ts[0]) if stager is not None else None
+        if payload is None:
+            with self.timers.phase("host_batch_plan"):
+                payload = self._build_block(
+                    self._draw_chaos_block(ts, frac))
+        chosen, stacks = payload["chosen"], payload["stacks"]
+        duals_in = self.duals if self.duals is not None else {}
+        c_in = self.c_global if self.c_global is not None else {}
+        sp_in = self._stale_p if self._has_stale else {}
+        args = (self.theta, self.params, self.momentum, duals_in,
+                c_in,
+                jnp.asarray(self._screen_streak.astype(np.int32)),
+                jnp.asarray(self._quarantine_until.astype(np.int32)),
+                jnp.asarray(self._stale_admit_round.astype(np.int32)),
+                jnp.asarray(self._stale_weight.astype(np.float32)),
+                sp_in, jnp.asarray(m, jnp.int32),
+                jnp.asarray(ts, jnp.int32), jnp.asarray(chosen),
+                stacks["away"], stacks["crashed"], stacks["unreach"],
+                stacks["straggler"], stacks["up_drop"],
+                stacks["up_delay"], stacks["late_d"],
+                stacks["limits"], stacks["corrupt"], payload["idx"],
+                payload["bw"], self._train_x, self._train_y,
+                *self._eval,
+                self._train_eval_idx, self._train_eval_w,
+                *self._val)
+        if stager is None:
+            out = self.timers.measure("round_step",
+                                      self._chaos_block_fn, *args)
+        else:
+            # dispatch → stage-next → fetch; note the carry inputs
+            # (streaks, admission schedule) above are read at
+            # DISPATCH time, after the previous block's replay —
+            # only the plan payload is staged ahead.
+            with self.timers.phase("round_step"):
+                with self.timers.phase("round_dispatch"):
                     out = self._chaos_block_fn(*args)
-                    end_round = ts[-1] + 1
-                    remaining = rounds - (done + k)
-                    if remaining > 0 and (next_ckpt is None
-                                          or end_round < next_ckpt):
-                        nk = min(block, remaining)
-                        nts = [end_round + j for j in range(nk)]
-                        with self.timers.phase("host_batch_plan"):
-                            meta = self._draw_chaos_block(nts, frac)
-                        stager.stage(
-                            nts[0],
-                            timed_build(self._build_block, self.timers),
-                            meta)
+                if next_ts:
+                    with self.timers.phase("host_batch_plan"):
+                        meta = self._draw_chaos_block(next_ts, frac)
+                    stager.stage(
+                        next_ts[0],
+                        timed_build(self._build_block, self.timers),
+                        meta)
+                with self.timers.phase("round_wait"):
                     jax.block_until_ready(out)
-            (self.theta, self.params, self.momentum, new_duals, new_c,
-             dev_stk, dev_unt, dev_sta, dev_stw, new_sp, packed) = out
-            if self.duals is not None:
-                self.duals = new_duals
-            if self.c_global is not None:
-                self.c_global = new_c
-            if self._has_stale:
-                self._stale_p = new_sp
+        (self.theta, self.params, self.momentum, new_duals, new_c,
+         dev_stk, dev_unt, dev_sta, dev_stw, new_sp, packed) = out
+        if self.duals is not None:
+            self.duals = new_duals
+        if self.c_global is not None:
+            self.c_global = new_c
+        if self._has_stale:
+            self._stale_p = new_sp
+        with self.timers.phase("round_fetch"):
             packed = np.asarray(packed)  # ONE device→host fetch per block
+        with self.timers.phase("round_record"):
             for j, t in enumerate(ts):
                 # Post-fetch ledger replay: host quarantine/staleness
                 # mirrors are current through round t-1's flags, so
@@ -2400,11 +2445,6 @@ class FederatedTrainer:
                 raise RuntimeError(
                     "fused-chaos host replay diverged from the device "
                     "scan carry")
-            done += k
-            if next_ckpt is not None and self.round >= next_ckpt:
-                self.save(checkpoint_path)
-                next_ckpt = (self.round // checkpoint_every + 1) \
-                    * checkpoint_every
 
     def run(self, frac: float | None = None, rounds: int | None = None,
             block: int | None = None, checkpoint_every: int = 0,
@@ -2447,25 +2487,38 @@ class FederatedTrainer:
                                      checkpoint_path=checkpoint_path)
         t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
         for _ in range(rounds):
-            t = self.round
-            with self.timers.phase("host_batch_plan"):
-                (fn_name, step_fn, args, step_kw, sel, sel_lanes,
-                 use_c, frows) = self._round_dispatch(t, frac)
-            out = self.timers.measure("round_step", step_fn, *args,
-                                      **step_kw)
-            (self.theta, self.params, self.momentum, new_duals,
-             new_c) = out[:5]
-            if self._has_stale:
-                self._stale_p = out[5]
-            packed = out[-1]
-            if self.duals is not None:
-                self.duals = new_duals
-            if self.c_global is not None:
-                self.c_global = new_c
-            lanes = len(sel_lanes) if use_c else self.num_workers
+            with self.timers.step(self.round):
+                self._run_round(frac)
+                if checkpoint_every and self.round % checkpoint_every == 0:
+                    self.save(checkpoint_path)
+        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
+        self._run_summary_telemetry()
+        return self.history
+
+    def _run_round(self, frac: float) -> None:
+        """One round of the per-round loop: plan, dispatch and wait,
+        fetch, record (span tree in ``dopt.utils.profiling``)."""
+        t = self.round
+        with self.timers.phase("host_batch_plan"):
+            (fn_name, step_fn, args, step_kw, sel, sel_lanes,
+             use_c, frows) = self._round_dispatch(t, frac)
+        out = self.timers.measure("round_step", step_fn, *args,
+                                  **step_kw)
+        (self.theta, self.params, self.momentum, new_duals,
+         new_c) = out[:5]
+        if self._has_stale:
+            self._stale_p = out[5]
+        packed = out[-1]
+        if self.duals is not None:
+            self.duals = new_duals
+        if self.c_global is not None:
+            self.c_global = new_c
+        lanes = len(sel_lanes) if use_c else self.num_workers
+        with self.timers.phase("round_fetch"):
+            packed = np.asarray(packed)  # ONE device→host fetch/round
+        with self.timers.phase("round_record"):
             ll, acc, loss_sum, t_loss, t_acc, scr, sscr, em, diag = \
-                self._unpack_host_metrics(
-                    np.asarray(packed), lanes)  # ONE device→host fetch/round
+                self._unpack_host_metrics(packed, lanes)
             # Compact lanes are survivors-first: the valid prefix holds
             # the real flags (padding lanes' flags are discarded).
             flags = scr[:len(sel)] if use_c else scr[sel]
@@ -2491,11 +2544,6 @@ class FederatedTrainer:
             self._round_telemetry(t, frows, diag)
             self._device_telemetry(t, fn_name, step_fn)
             self.round += 1
-            if checkpoint_every and self.round % checkpoint_every == 0:
-                self.save(checkpoint_path)
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
 
     def run_served(self, controller) -> str:
         """Resident serve-mode entry (``dopt.serve``): train one round

@@ -32,10 +32,11 @@ import numpy as np
 
 from dopt.config import ExperimentConfig
 from dopt.data import (PrefetchStager, eval_batches, load_dataset,
-                       make_batch_plan, partition, sharded_eval_batches,
-                       timed_build)
+                       make_batch_plan, next_block_rounds, partition,
+                       sharded_eval_batches, timed_build)
 from dopt.engine.local import (_stacked_eval_scan, flat_input_apply,
-                               flat_input_stacked_apply, make_evaluator,
+                               flat_input_stacked_apply, gather_rows,
+                               make_evaluator,
                                make_stacked_evaluator, make_stacked_local_update,
                                make_stacked_local_update_epochs,
                                make_stacked_local_update_gather,
@@ -1098,6 +1099,7 @@ class GossipTrainer:
             cd = (jnp.where(ok > 0, jnp.sqrt(sq), 0.0)).sum() / denom
             return jnp.stack([upd, gn, pn, lmean, spread, cd])
 
+        @jax.named_scope("dopt_local")
         def local_phase(params, mom, idx, bweight, train_x, train_y,
                         vidx, vw, limits):
             """The per-round local-training phase: flat step scan on the
@@ -1120,8 +1122,7 @@ class GossipTrainer:
                     p_t, m_t, em = local_epochs(params, mom, idx_e, bw_e,
                                                 train_x, train_y, vidx, vw)
                 return p_t, m_t, em["train_loss"], em["train_acc"], em
-            bx = train_x[idx]
-            by = train_y[idx]
+            bx, by = gather_rows(train_x, train_y, idx)
             if may_straggle:
                 p_t, m_t, losses, accs = local(params, mom, bx, by, bweight,
                                                limits)
@@ -1323,7 +1324,8 @@ class GossipTrainer:
             self._local_gather = shard_over_workers(
                 self._local_gather, self.mesh,
                 "wwwwwrr" if may_straggle else "wwwwrr", "w" * 4)
-        local_g, ev = self._local_gather, self._evaluator
+        local_g = jax.named_scope("dopt_local")(self._local_gather)
+        ev = self._evaluator
 
         def block_fn(params, mom, x_hat, w_mats, alive, limits, ts, idx, bw,
                      is_eval, train_x, train_y, ex, ey, ew, vidx, vw,
@@ -1741,78 +1743,91 @@ class GossipTrainer:
         while done < rounds:
             k = min(block, rounds - done)
             ts = [self.round + j for j in range(k)]
-            payload = stager.take(ts[0]) if stager is not None else None
-            if payload is None:
-                with self.timers.phase("host_batch_plan"):
-                    payload = self._build_block(self._draw_block(ts))
-            w_raws, frows = payload["w_raws"], payload["frows"]
-            alive, is_eval = payload["alive"], payload["is_eval"]
-            step_kw = ({"cmasks": jnp.asarray(payload["cmasks"])}
-                       if self._has_corrupt else {})
-            common = (payload["w_mats"], alive, payload["limits"],
-                      jnp.asarray(ts, jnp.int32), payload["idx"],
-                      payload["bw"], jnp.asarray(is_eval), self._train_x,
-                      self._train_y, *self._eval, *self._val)
-            if link:
-                fn = self._link_block_fn
-                args = (self.params, self.momentum, self._mass,
-                        self._link_buf, self._link_buf_mass, *common)
-            elif fused_quar:
-                step_kw.update(
-                    streak=jnp.asarray(
-                        self._screen_streak.astype(np.int32)),
-                    until=jnp.asarray(
-                        self._quarantine_until.astype(np.int32)))
-                fn = self._block_fn
-                args = (self.params, self.momentum, self.x_hat, *common)
-            else:
-                if self._async:
-                    step_kw.update(prev=self._async_prev,
-                                   wdiags=jnp.asarray(payload["wdiags"]))
-                if self._fused_on:
-                    step_kw["fbuf"] = self._fused_buf
-                if self._codec_on:
-                    step_kw["cres"] = self._comm_res
-                fn = self._block_fn
-                args = (self.params, self.momentum, self.x_hat, *common)
-            if stager is None:
-                out = self.timers.measure("round_step", fn, *args,
-                                          **step_kw)
-            else:
-                # dispatch → stage-next → fetch: the jit dispatch
-                # returns before the device finishes, the next block's
-                # staging overlaps this block's device time, and
-                # block_until_ready is the fetch barrier the old
-                # measure() call provided.
-                with self.timers.phase("round_step"):
+            next_ts = next_block_rounds(ts, rounds - (done + k), block,
+                                        next_ckpt)
+            with self.timers.step(ts[0]):
+                self._run_block(ts, next_ts, stager, link, fused_quar)
+                done += k
+                if next_ckpt is not None and self.round >= next_ckpt:
+                    self.save(checkpoint_path)
+                    next_ckpt = (self.round // checkpoint_every + 1) \
+                        * checkpoint_every
+
+    def _run_block(self, ts, next_ts, stager, link, fused_quar) -> None:
+        """One fused block of the blocked loop: plan (unless staged),
+        dispatch, stage ``next_ts`` while the device runs (prefetch),
+        wait, fetch, record."""
+        payload = stager.take(ts[0]) if stager is not None else None
+        if payload is None:
+            with self.timers.phase("host_batch_plan"):
+                payload = self._build_block(self._draw_block(ts))
+        w_raws, frows = payload["w_raws"], payload["frows"]
+        alive, is_eval = payload["alive"], payload["is_eval"]
+        step_kw = ({"cmasks": jnp.asarray(payload["cmasks"])}
+                   if self._has_corrupt else {})
+        common = (payload["w_mats"], alive, payload["limits"],
+                  jnp.asarray(ts, jnp.int32), payload["idx"],
+                  payload["bw"], jnp.asarray(is_eval), self._train_x,
+                  self._train_y, *self._eval, *self._val)
+        if link:
+            fn = self._link_block_fn
+            args = (self.params, self.momentum, self._mass,
+                    self._link_buf, self._link_buf_mass, *common)
+        elif fused_quar:
+            step_kw.update(
+                streak=jnp.asarray(
+                    self._screen_streak.astype(np.int32)),
+                until=jnp.asarray(
+                    self._quarantine_until.astype(np.int32)))
+            fn = self._block_fn
+            args = (self.params, self.momentum, self.x_hat, *common)
+        else:
+            if self._async:
+                step_kw.update(prev=self._async_prev,
+                               wdiags=jnp.asarray(payload["wdiags"]))
+            if self._fused_on:
+                step_kw["fbuf"] = self._fused_buf
+            if self._codec_on:
+                step_kw["cres"] = self._comm_res
+            fn = self._block_fn
+            args = (self.params, self.momentum, self.x_hat, *common)
+        if stager is None:
+            out = self.timers.measure("round_step", fn, *args,
+                                      **step_kw)
+        else:
+            # dispatch → stage-next → fetch: the jit dispatch
+            # returns before the device finishes, the next block's
+            # staging overlaps this block's device time, and
+            # block_until_ready is the fetch barrier the old
+            # measure() call provided.
+            with self.timers.phase("round_step"):
+                with self.timers.phase("round_dispatch"):
                     out = fn(*args, **step_kw)
-                    end_round = ts[-1] + 1
-                    remaining = rounds - (done + k)
-                    if remaining > 0 and (next_ckpt is None
-                                          or end_round < next_ckpt):
-                        nk = min(block, remaining)
-                        self._stage_block(
-                            stager, [end_round + j for j in range(nk)])
+                if next_ts:
+                    self._stage_block(stager, next_ts)
+                with self.timers.phase("round_wait"):
                     jax.block_until_ready(out)
-            dev_streak = dev_until = None
-            if link:
-                (self.params, self.momentum, self._mass, self._link_buf,
-                 self._link_buf_mass, packed) = out
-            elif fused_quar:
-                (self.params, self.momentum, self.x_hat, dev_streak,
-                 dev_until, packed) = out
-            elif self._async:
-                (self.params, self.momentum, self.x_hat,
-                 self._async_prev, packed) = out
-            elif self._fused_on:
-                (self.params, self.momentum, self.x_hat,
-                 self._fused_buf, packed) = out
-            elif self._codec_on:
-                (self.params, self.momentum, self.x_hat,
-                 self._comm_res, packed) = out
-            else:
-                (self.params, self.momentum, self.x_hat, packed) = out
+        dev_streak = dev_until = None
+        if link:
+            (self.params, self.momentum, self._mass, self._link_buf,
+             self._link_buf_mass, packed) = out
+        elif fused_quar:
+            (self.params, self.momentum, self.x_hat, dev_streak,
+             dev_until, packed) = out
+        elif self._async:
+            (self.params, self.momentum, self.x_hat,
+             self._async_prev, packed) = out
+        elif self._fused_on:
+            (self.params, self.momentum, self.x_hat,
+             self._fused_buf, packed) = out
+        elif self._codec_on:
+            (self.params, self.momentum, self.x_hat,
+             self._comm_res, packed) = out
+        else:
+            (self.params, self.momentum, self.x_hat, packed) = out
+        with self.timers.phase("round_fetch"):
             packed = np.asarray(packed)  # ONE device→host fetch per block
+        with self.timers.phase("round_record"):
             for j, t in enumerate(ts):
                 tl, ta, acc, lm, scr, em, diag = self._unpack_host_metrics(
                     packed[j])
@@ -1860,11 +1875,6 @@ class GossipTrainer:
                         "device scan carry")
             self._device_telemetry(
                 ts[-1], "link_block_fn" if link else "block_fn", fn)
-            done += k
-            if next_ckpt is not None and self.round >= next_ckpt:
-                self.save(checkpoint_path)
-                next_ckpt = (self.round // checkpoint_every + 1) \
-                    * checkpoint_every
 
     # ------------------------------------------------------------------
     def _unpack_host_metrics(self, vec: np.ndarray):
@@ -2237,28 +2247,43 @@ class GossipTrainer:
                                      checkpoint_path=checkpoint_path)
         t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
         for _ in range(rounds):
-            t = self.round
-            with self.timers.phase("host_batch_plan"):
-                (fn_name, step_fn, args, step_kw, alive, quar, frows,
-                 do_eval) = self._round_dispatch(t)
-            out = self.timers.measure("round_step", step_fn, *args,
-                                      **step_kw)
-            if self._link_mode:
-                (self.params, self.momentum, self._mass, self._link_buf,
-                 self._link_buf_mass, packed) = out
-            elif self._async:
-                (self.params, self.momentum, self.x_hat,
-                 self._async_prev, packed) = out
-            elif self._fused_on:
-                (self.params, self.momentum, self.x_hat,
-                 self._fused_buf, packed) = out
-            elif self._codec_on:
-                (self.params, self.momentum, self.x_hat,
-                 self._comm_res, packed) = out
-            else:
-                self.params, self.momentum, self.x_hat, packed = out
+            with self.timers.step(self.round):
+                self._run_round()
+                if (checkpoint_every and
+                        self.round % checkpoint_every == 0):
+                    self.save(checkpoint_path)
+        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
+        self._run_summary_telemetry()
+        return self.history
+
+    def _run_round(self) -> None:
+        """One round of the per-round loop: plan, dispatch and wait,
+        fetch, record (span tree in ``dopt.utils.profiling``)."""
+        t = self.round
+        with self.timers.phase("host_batch_plan"):
+            (fn_name, step_fn, args, step_kw, alive, quar, frows,
+             do_eval) = self._round_dispatch(t)
+        out = self.timers.measure("round_step", step_fn, *args,
+                                  **step_kw)
+        if self._link_mode:
+            (self.params, self.momentum, self._mass, self._link_buf,
+             self._link_buf_mass, packed) = out
+        elif self._async:
+            (self.params, self.momentum, self.x_hat,
+             self._async_prev, packed) = out
+        elif self._fused_on:
+            (self.params, self.momentum, self.x_hat,
+             self._fused_buf, packed) = out
+        elif self._codec_on:
+            (self.params, self.momentum, self.x_hat,
+             self._comm_res, packed) = out
+        else:
+            self.params, self.momentum, self.x_hat, packed = out
+        with self.timers.phase("round_fetch"):
+            packed = np.asarray(packed)  # ONE device→host fetch per round
+        with self.timers.phase("round_record"):
             tl, ta, acc, lm, scr, em, diag = self._unpack_host_metrics(
-                np.asarray(packed))  # ONE device→host fetch per round
+                packed)
             if self._robust_active:
                 alive_eff = (alive * (1.0 - quar) if self._fused_quar
                              else alive)
@@ -2278,12 +2303,6 @@ class GossipTrainer:
             self._round_telemetry(t, frows, diag)
             self._device_telemetry(t, fn_name, step_fn)
             self.round += 1
-            if (checkpoint_every and
-                    self.round % checkpoint_every == 0):
-                self.save(checkpoint_path)
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
 
     def run_served(self, controller) -> str:
         """Resident serve-mode entry (``dopt.serve``): train one round

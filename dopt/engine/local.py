@@ -362,6 +362,14 @@ def flat_input_stacked_apply(stacked_apply, sample_shape):
     return wrapped
 
 
+def gather_rows(train_x, train_y, idx):
+    """The on-device gather of the rows ``idx`` names (a step's batch, a
+    chunk's slab, a round's plan, a holdout) from the resident train
+    arrays, under the ``dopt_batch`` scope."""
+    with jax.named_scope("dopt_batch"):
+        return train_x[idx], train_y[idx]
+
+
 def pick_gather_chunks(steps: int, *, workers: int, batch: int,
                        sample_bytes: int,
                        budget_bytes: int = 256 * 1024 * 1024) -> int | None:
@@ -419,7 +427,7 @@ def _scan_steps_gathered(core, params, mom, idx, bw, train_x, train_y,
     if gather_chunks is None:
         def gstep(carry, batch):
             i, w = batch
-            return step(carry, (train_x[i], train_y[i], w))
+            return step(carry, (*gather_rows(train_x, train_y, i), w))
 
         carry, out = jax.lax.scan(gstep, carry0, (idx, bw))
         return strip(carry), out
@@ -433,7 +441,8 @@ def _scan_steps_gathered(core, params, mom, idx, bw, train_x, train_y,
 
     def chunk(carry, ch):
         ci, cw = ch
-        return jax.lax.scan(step, carry, (train_x[ci], train_y[ci], cw))
+        return jax.lax.scan(step, carry,
+                            (*gather_rows(train_x, train_y, ci), cw))
 
     carry, (losses, accs) = jax.lax.scan(chunk, carry0, (idx_c, bw_c))
     return strip(carry), (losses.reshape(s), accs.reshape(s))
@@ -525,7 +534,7 @@ def _scan_steps_gathered_stacked(core, params, mom, idx, bw, train_x,
     if gather_chunks is None:
         def gstep(carry, batch):
             i, w = batch
-            return step(carry, (train_x[i], train_y[i], w))
+            return step(carry, (*gather_rows(train_x, train_y, i), w))
 
         carry, (losses, accs) = jax.lax.scan(gstep, carry0,
                                              (idx_s, bw_s),
@@ -541,7 +550,8 @@ def _scan_steps_gathered_stacked(core, params, mom, idx, bw, train_x,
 
     def chunk(carry, ch):
         ci, cw = ch
-        return jax.lax.scan(step, carry, (train_x[ci], train_y[ci], cw),
+        return jax.lax.scan(step, carry,
+                            (*gather_rows(train_x, train_y, ci), cw),
                             unroll=_SCAN_UNROLL)
 
     carry, (losses, accs) = jax.lax.scan(chunk, carry0, (idx_c, bw_c))
@@ -677,8 +687,8 @@ def make_local_update_epochs(
         def step(c, b):
             p_, m_ = c
             i, w_ = b
-            p_, m_, loss, acc = core(p_, m_, train_x[i], train_y[i], w_,
-                                     theta, alpha)
+            p_, m_, loss, acc = core(p_, m_, *gather_rows(train_x, train_y, i),
+                                     w_, theta, alpha)
             return (p_, m_), (loss, acc * w_.sum(), w_.sum())
 
         def stepm(c, b):
@@ -702,7 +712,8 @@ def make_local_update_epochs(
 
         def chunk(c, ch):
             ci, cw = ch
-            return jax.lax.scan(stepm, c, (train_x[ci], train_y[ci], cw))
+            return jax.lax.scan(stepm, c,
+                                (*gather_rows(train_x, train_y, ci), cw))
 
         (p, m), (losses, corrects, counts) = jax.lax.scan(
             chunk, (p, m), (ei_c, ew_c))
@@ -716,8 +727,7 @@ def make_local_update_epochs(
             # identical inner numerics, and the single post-epoch val
             # eval sees the GATED params (a frozen straggler's val
             # metrics reflect its frozen model).
-            vx = train_x[vidx]
-            vy = train_y[vidx]
+            vx, vy = gather_rows(train_x, train_y, vidx)
 
             def epoch(carry, ep):
                 p, m = carry
@@ -750,8 +760,7 @@ def make_local_update_epochs(
 
     def local_update(params, mom, idx, bw, train_x, train_y, vidx, vw,
                      theta=None, alpha=None):
-        vx = train_x[vidx]
-        vy = train_y[vidx]
+        vx, vy = gather_rows(train_x, train_y, vidx)
 
         def epoch(carry, ep):
             p, m = carry
@@ -786,11 +795,12 @@ def _stacked_eval_scan(stacked_apply, params, ex, ey, ew):
         corr = accuracy_stacked(out, y, w) * w.sum(axis=-1)
         return c, (loss, corr, w.sum(axis=-1))
 
-    _, (losses, corrects, counts) = jax.lax.scan(step, (), (ex, ey, ew))
-    total = jnp.maximum(counts.sum(axis=0), 1.0)
-    return {"acc": corrects.sum(axis=0) / total,
-            "loss_sum": losses.sum(axis=0),
-            "loss_mean": losses.mean(axis=0), "count": total}
+    with jax.named_scope("dopt_eval"):
+        _, (losses, corrects, counts) = jax.lax.scan(step, (), (ex, ey, ew))
+        total = jnp.maximum(counts.sum(axis=0), 1.0)
+        return {"acc": corrects.sum(axis=0) / total,
+                "loss_sum": losses.sum(axis=0),
+                "loss_mean": losses.mean(axis=0), "count": total}
 
 
 def make_stacked_local_update_epochs(apply_fn, *, lr, momentum,
@@ -814,7 +824,7 @@ def make_stacked_local_update_epochs(apply_fn, *, lr, momentum,
                        theta=None, alpha=None):
                 vi_s = vi.swapaxes(0, 1)
                 vw_s = vw_.swapaxes(0, 1)
-                vx, vy = tx[vi_s], ty[vi_s]
+                vx, vy = gather_rows(tx, ty, vi_s)
                 idx_e = idx.swapaxes(0, 1)
                 bw_e = bw.swapaxes(0, 1)
 
@@ -851,7 +861,7 @@ def make_stacked_local_update_epochs(apply_fn, *, lr, momentum,
         def fn(p, m, idx, bw, tx, ty, vi, vw_, theta=None, alpha=None):
             vi_s = vi.swapaxes(0, 1)        # [Sv, W, Bv]
             vw_s = vw_.swapaxes(0, 1)
-            vx, vy = tx[vi_s], ty[vi_s]
+            vx, vy = gather_rows(tx, ty, vi_s)
             idx_e = idx.swapaxes(0, 1)      # [E, W, Se, B]
             bw_e = bw.swapaxes(0, 1)
 
@@ -940,14 +950,16 @@ def make_evaluator(apply_fn):
             correct = accuracy(out, y, w) * w.sum()  # weighted correct count
             return carry, (loss, correct, w.sum())
 
-        _, (losses, corrects, counts) = jax.lax.scan(step, (), (ex, ey, ew))
-        total = jnp.maximum(counts.sum(), 1.0)
-        return {
-            "acc": corrects.sum() / total,
-            "loss_sum": losses.sum(),            # P1 flavour (summed batch losses)
-            "loss_mean": losses.mean(),          # P2 flavour (mean per batch)
-            "count": total,
-        }
+        with jax.named_scope("dopt_eval"):
+            _, (losses, corrects, counts) = jax.lax.scan(
+                step, (), (ex, ey, ew))
+            total = jnp.maximum(counts.sum(), 1.0)
+            return {
+                "acc": corrects.sum() / total,
+                "loss_sum": losses.sum(),            # P1 flavour (summed batch losses)
+                "loss_mean": losses.mean(),          # P2 flavour (mean per batch)
+                "count": total,
+            }
 
     return evaluate
 
@@ -970,13 +982,14 @@ def make_stacked_evaluator(apply_fn, stacked_apply=None):
                 corr = accuracy_stacked(out, yw, ww) * w.sum()
                 return c, (loss, corr, w.sum())
 
-            _, (losses, corrects, counts) = jax.lax.scan(
-                step, (), (ex, ey, ew))
-            total = jnp.maximum(counts.sum(), 1.0)
-            return {"acc": corrects.sum(axis=0) / total,
-                    "loss_sum": losses.sum(axis=0),
-                    "loss_mean": losses.mean(axis=0),
-                    "count": jnp.full((w_count,), total)}
+            with jax.named_scope("dopt_eval"):
+                _, (losses, corrects, counts) = jax.lax.scan(
+                    step, (), (ex, ey, ew))
+                total = jnp.maximum(counts.sum(), 1.0)
+                return {"acc": corrects.sum(axis=0) / total,
+                        "loss_sum": losses.sum(axis=0),
+                        "loss_mean": losses.mean(axis=0),
+                        "count": jnp.full((w_count,), total)}
 
         return evaluate
     ev = make_evaluator(apply_fn)

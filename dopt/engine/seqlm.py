@@ -171,11 +171,12 @@ class SeqLMTrainer:
         t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
         logged: list[tuple[int, jnp.ndarray]] = []
         for i in range(n):
-            with self.timers.phase("host_batch_plan"):
-                toks = self._batch()
-            self.params, self.momentum, loss = self.timers.measure(
-                "round_step", self._train_step, self.params, self.momentum,
-                toks)
+            with self.timers.step(self.step):
+                with self.timers.phase("host_batch_plan"):
+                    toks = self._batch()
+                self.params, self.momentum, loss = self.timers.measure(
+                    "round_step", self._train_step, self.params,
+                    self.momentum, toks)
             # i (run-relative) decides the always-log-final-step rule so
             # resumed/continued runs still close with a loss row.  Losses
             # stay ON DEVICE until the run ends — each device→host fetch
@@ -187,9 +188,11 @@ class SeqLMTrainer:
         jax.block_until_ready(self.params)
         self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
         if logged:
-            vals = np.asarray(jnp.stack([l for _, l in logged]))
-            for (st, _), v in zip(logged, vals):
-                self.history.append(round=st, step=st, loss=float(v))
+            with self.timers.phase("round_fetch"):
+                vals = np.asarray(jnp.stack([l for _, l in logged]))
+            with self.timers.phase("round_record"):
+                for (st, _), v in zip(logged, vals):
+                    self.history.append(round=st, step=st, loss=float(v))
         return self.history
 
     @property

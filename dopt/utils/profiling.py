@@ -2,13 +2,40 @@
 
 The reference's only instrumentation is ``time.time()`` around
 ``run()`` printed as "Total Run Time" plus tqdm bars (servers.py:51,79;
-simulators.py:115-137).  dopt provides:
+simulators.py:115-137).  dopt has ONE system, always on:
 
-* ``PhaseTimers`` — named wall-clock accumulators for the round phases
-  (consensus vs local step vs eval vs host batch-planning); rounds/sec
-  is a north-star metric so phase attribution is first-class.
-* ``trace()`` — context manager wrapping ``jax.profiler`` to dump an
-  XLA trace viewable in TensorBoard/Perfetto.
+* ``PhaseTimers`` — named wall-clock accumulators for the round's host
+  phases.  Every span is also a ``jax.profiler.TraceAnnotation``, so a
+  profiler capture (``python -m dopt.run --trace DIR``, the benchmark's
+  traced run) holds the host spans and the device ops in one file on
+  one clock; ``--timers`` prints the totals of any run.
+* ``jax.named_scope`` at the source of each device layer; the scope
+  lands in the ``op_name`` metadata of the compiled round program.
+* ``trace()`` — context manager around ``jax.profiler``.
+
+Host span tree of a round (a block in the blocked loops)::
+
+    dopt_round                    StepTraceAnnotation, step_num = round
+      host_batch_plan             plan, W_t / client sample, upload
+      round_step                  the jitted call until its result is ready
+        round_dispatch            ... until the call returns (transfer, launch)
+        round_wait                block_until_ready
+      round_fetch                 the one np.asarray(packed) a round
+      round_record                unpack, screen feedback, history, telemetry
+      checkpoint                  when one is due
+
+Device scopes: ``dopt_local`` (the local phase), ``dopt_batch`` (the
+on-device gather of a step's rows), ``dopt_update`` (momentum SGD),
+``dopt_eval`` (every evaluation inside a round program; the holdout's
+per-epoch eval is nested in ``dopt_local``), ``dopt_mix`` (consensus or
+aggregation).
+
+The rule: no span or scope without a reader — each of these is read by
+a per-layer metric of ``BENCHMARK.json`` (table in PERF.md §3).  A PR
+that adds or renames a scope bumps
+``dopt.utils.compile_cache.PROGRAM_METADATA_VERSION``: the persistent
+cache's key ignores metadata, so an executable cached before the change
+would come back without the scope.
 
 Note on async dispatch: jax returns before device work finishes, so a
 ``phase()`` context around a jit call measures dispatch only.  Use
@@ -26,22 +53,34 @@ from typing import Any, Iterator
 
 import jax
 
+ROUND_STEP = "dopt_round"
+
 
 class PhaseTimers:
-    """Accumulates wall-clock per named phase.
+    """Accumulates wall-clock per named phase: total, count and the
+    longest single duration (one stalled round in seventy is invisible
+    in a mean and plain in a max).
 
-    ``tracer`` is the telemetry hook (``dopt.obs.SpanTracer`` — or
-    anything with a ``span(name)`` context manager): when set, every
-    ``phase``/``measure`` additionally records a nested host span, so
-    attaching telemetry to a trainer instruments all its existing
-    timer sites (host batch planning, the fused block dispatch,
-    checkpoint writes) with zero run-loop changes.  None (default)
-    keeps the exact pre-telemetry accounting."""
+    Every span is written to the profiler as well (a ``TraceAnnotation``
+    costs well under a microsecond while no trace runs).  ``tracer`` is
+    the telemetry hook (``dopt.obs.SpanTracer`` — or anything with a
+    ``span(name)`` context manager): when set, every ``phase``/``measure``
+    additionally records a nested host span there, so attaching
+    telemetry to a trainer instruments all its existing timer sites
+    with zero run-loop changes."""
 
     def __init__(self, tracer=None) -> None:
         self.totals: dict[str, float] = defaultdict(float)
         self.counts: dict[str, int] = defaultdict(int)
+        self.maxima: dict[str, float] = defaultdict(float)
         self.tracer = tracer
+
+    def add(self, name: str, seconds: float) -> None:
+        """Account one finished span of ``name``."""
+        self.totals[name] += seconds
+        self.counts[name] += 1
+        if seconds > self.maxima[name]:
+            self.maxima[name] = seconds
 
     @contextlib.contextmanager
     def phase(self, name: str) -> Iterator[None]:
@@ -51,23 +90,26 @@ class PhaseTimers:
                 else contextlib.nullcontext())
         t0 = time.perf_counter()  # dopt: allow-wallclock -- phase span timing, not training math
         try:
-            with span:
+            with jax.profiler.TraceAnnotation(name), span:
                 yield
         finally:
-            self.totals[name] += time.perf_counter() - t0  # dopt: allow-wallclock -- phase span timing, not training math
-            self.counts[name] += 1
+            self.add(name, time.perf_counter() - t0)  # dopt: allow-wallclock -- phase span timing, not training math
 
     def measure(self, name: str, fn, *args, **kwargs):
-        """Run fn, block on its result, attribute the time to ``name``."""
-        span = (self.tracer.span(name) if self.tracer is not None
-                else contextlib.nullcontext())
-        t0 = time.perf_counter()  # dopt: allow-wallclock -- measure span timing, not training math
-        with span:
-            out = fn(*args, **kwargs)
-            jax.block_until_ready(out)
-        self.totals[name] += time.perf_counter() - t0  # dopt: allow-wallclock -- measure span timing, not training math
-        self.counts[name] += 1
+        """Run fn, block on its result, attribute the time to ``name``
+        and, inside it, to ``round_dispatch`` (until fn returns) and
+        ``round_wait`` (until its result is ready)."""
+        with self.phase(name):
+            with self.phase("round_dispatch"):
+                out = fn(*args, **kwargs)
+            with self.phase("round_wait"):
+                jax.block_until_ready(out)
         return out
+
+    def step(self, t: int):
+        """The profiler's step annotation for round (or block start)
+        ``t``: xprof's step view, and which round a device op ran in."""
+        return jax.profiler.StepTraceAnnotation(ROUND_STEP, step_num=int(t))
 
     def summary(self) -> dict[str, dict[str, float]]:
         return {
@@ -75,15 +117,17 @@ class PhaseTimers:
                 "total_s": round(self.totals[name], 4),
                 "count": self.counts[name],
                 "mean_s": round(self.totals[name] / max(self.counts[name], 1), 5),
+                "max_s": round(self.maxima[name], 5),
             }
             for name in self.totals
         }
 
     def report(self) -> str:
-        rows = ["phase                total_s   count   mean_s"]
+        rows = ["phase                total_s   count   mean_s     max_s"]
         for name, s in sorted(self.summary().items(),
                               key=lambda kv: -kv[1]["total_s"]):
-            rows.append(f"{name:20s} {s['total_s']:8.3f} {s['count']:7d} {s['mean_s']:9.5f}")
+            rows.append(f"{name:20s} {s['total_s']:8.3f} {s['count']:7d} "
+                        f"{s['mean_s']:9.5f} {s['max_s']:9.5f}")
         return "\n".join(rows)
 
 

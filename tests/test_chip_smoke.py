@@ -152,16 +152,23 @@ def test_compile_cache_placement(tmp_path):
                 checkout / "dopt/utils/compile_cache.py")
     default = checkout / ".jax_cache"
 
-    # Placed from outside: used as given, nothing else created or set.
+    from dopt.utils.compile_cache import PROGRAM_METADATA_VERSION
+
+    sub = f"meta-v{PROGRAM_METADATA_VERSION}"
+
+    # Placed from outside: the versioned subdirectory of the given one
+    # (the cache key ignores the programs' scope metadata; the directory
+    # name does not), nothing else created.
     placed = tmp_path / "placed"
     got = _cache_child(checkout,
                        {"JAX_COMPILATION_CACHE_DIR": str(placed)})
-    assert got["dir"] == got["config"] == str(placed)
-    assert any(placed.iterdir()) and not default.exists()
+    assert got["dir"] == got["config"] == str(placed / sub)
+    assert [p.name for p in placed.iterdir()] == [sub]
+    assert any((placed / sub).iterdir()) and not default.exists()
 
-    # Not placed: the fixed <checkout>/.jax_cache; a second process of
-    # the same command compiles from it.
+    # Not placed: the fixed <checkout>/.jax_cache/<version>; a second
+    # process of the same command compiles from it.
     first = _cache_child(checkout, {})
-    assert first["dir"] == first["config"] == str(default)
-    assert first["hits"] == 0 and any(default.iterdir())
+    assert first["dir"] == first["config"] == str(default / sub)
+    assert first["hits"] == 0 and any((default / sub).iterdir())
     assert _cache_child(checkout, {})["hits"] >= 1

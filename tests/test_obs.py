@@ -385,8 +385,12 @@ def test_phase_timers_tracer_hook():
         pass
     timers.measure("round_step", lambda: 1)
     assert timers.counts["host_batch_plan"] == 1
-    assert sorted(s["name"] for s in tr.spans) == ["host_batch_plan",
-                                                   "round_step"]
+    # measure() feeds the hook its parent and the two children it times
+    assert sorted(s["name"] for s in tr.spans) == [
+        "host_batch_plan", "round_dispatch", "round_step", "round_wait"]
+    depth = {s["name"]: s["depth"] for s in tr.spans}
+    assert depth["round_step"] == 0
+    assert depth["round_dispatch"] == depth["round_wait"] == 1
 
 
 # ------------------------------------------------- engine stream contracts
