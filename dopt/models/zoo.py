@@ -48,31 +48,46 @@ def _tiled_max(x6: jnp.ndarray) -> jnp.ndarray:
     jax's equal split across ties.  Ties are NOT measure-zero in
     practice: the faithful Model1 conv has no ReLU, so zero-background
     MNIST pixels produce exact 4-way bias ties in every background
-    window (ADVICE r4)."""
+    window (ADVICE r4).
+
+    Undifferentiated calls (every eval path) are this plain reduce.
+    Under differentiation the forward makes ONE pass over ``x6`` and
+    keeps a one-byte winner code per window as its only residual;
+    the backward reads the code and the cotangent and nothing of
+    ``x6``'s size (PERF.md §5, §6 PR 26)."""
     return x6.max(axis=(2, 4))
 
 
+def _window_code(shape6) -> jnp.ndarray:
+    """int8 position code ``2·di + dj`` of every element of a
+    [b, h2, 2, w2, 2, c] tiling: torch's kernel scan order."""
+    return (2 * jax.lax.broadcasted_iota(jnp.int8, shape6, 2)
+            + jax.lax.broadcasted_iota(jnp.int8, shape6, 4))
+
+
+def _first_larger(a, b):
+    """Reduction step over (value, code) pairs: the larger value, on
+    equal values the smaller code; a NaN wins, as in ``max``."""
+    (av, ak), (bv, bk) = a, b
+    take_a = (av > bv) | ((av == bv) & (ak < bk)) | (av != av)
+    return jnp.where(take_a, av, bv), jnp.where(take_a, ak, bk)
+
+
+@jax.named_scope("dopt_pool")
 def _tiled_max_fwd(x6):
-    m = x6.max(axis=(2, 4))
-    return m, (x6, m)
+    # (max, winner code): the output and the only residual
+    return jax.lax.reduce(
+        (x6, _window_code(x6.shape)),
+        (jnp.array(-jnp.inf, x6.dtype), jnp.int8(3)),
+        _first_larger, (2, 4))
 
 
-def _tiled_max_bwd(res, g):
-    x6, m = res
-    # First-winner in torch scan order (di, dj): (0,0),(0,1),(1,0),(1,1)
-    # as a boolean cascade over the four window slices — pure
-    # elementwise masking, no extra strided reduction, measured at
-    # parity with jax's default equal-split backward and ~25% cheaper
-    # than an argmin-index formulation on v5e.
-    e = [x6[:, :, i, :, j, :] == m for i in (0, 1) for j in (0, 1)]
-    seen = e[0]
-    masks = [e[0]]
-    for k in (1, 2, 3):
-        masks.append(e[k] & ~seen)
-        seen = seen | e[k]
-    gm = [g * mk.astype(g.dtype) for mk in masks]
-    return (jnp.stack([jnp.stack([gm[0], gm[1]], axis=3),
-                       jnp.stack([gm[2], gm[3]], axis=3)], axis=2),)
+@jax.named_scope("dopt_pool")
+def _tiled_max_bwd(code, g):
+    b, h2, w2, c = code.shape
+    hit = code[:, :, None, :, None, :] == _window_code((b, h2, 2, w2, 2, c))
+    return (jnp.where(hit, g[:, :, None, :, None, :],
+                      jnp.zeros((), g.dtype)),)
 
 
 _tiled_max.defvjp(_tiled_max_fwd, _tiled_max_bwd)
@@ -83,14 +98,13 @@ def _max_pool_2x2(x: jnp.ndarray) -> jnp.ndarray:
 
     Forward-identical to ``nn.max_pool(x, (2, 2), strides=(2, 2))`` for
     even H/W (the windows are non-overlapping, so the reshape tiles them
-    exactly), but its VJP lowers to an elementwise first-winner mask
-    instead of XLA's ``select_and_scatter`` — which the reduce_window
-    backward otherwise costs us ~12% of device time on the Model1
-    training step (results/trace_headline.json).  The custom VJP
-    (``_tiled_max``) routes tie gradients to the FIRST window element in
-    torch's kernel scan order, bit-matching MaxPool2d's backward even on
-    real data with exact ties (e.g. zero-background MNIST under the
-    no-ReLU faithful conv) — not jax's default equal split.
+    exactly).  Its custom VJP (``_tiled_max``) routes tie gradients to
+    the FIRST window element in torch's kernel scan order, bit-matching
+    MaxPool2d's backward even on real data with exact ties (e.g.
+    zero-background MNIST under the no-ReLU faithful conv) — not jax's
+    default equal split — and saves an int8 winner code between the
+    passes instead of the conv activations.  What the pool costs in the
+    stacked Model1 step is ``pool_ms`` (PERF.md §3, §5).
 
     Odd spatial dims fall back to ``nn.max_pool`` (which floors), since
     the reshape tiling requires even H/W.

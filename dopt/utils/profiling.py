@@ -28,7 +28,8 @@ Device scopes: ``dopt_local`` (the local phase), ``dopt_batch`` (the
 on-device gather of a step's rows), ``dopt_update`` (momentum SGD),
 ``dopt_eval`` (every evaluation inside a round program; the holdout's
 per-epoch eval is nested in ``dopt_local``), ``dopt_mix`` (consensus or
-aggregation).
+aggregation), ``dopt_pool`` (the differentiated 2×2 max-pool's forward
+and backward, nested in ``dopt_local``; ``dopt.models.zoo``).
 
 The rule: no span or scope without a reader — each of these is read by
 a per-layer metric of ``BENCHMARK.json`` (table in PERF.md §3).  A PR

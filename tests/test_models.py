@@ -178,6 +178,134 @@ def test_max_pool_first_winner_tie_gradients_match_torch():
     np.testing.assert_array_equal(np.asarray(gj), gt)
 
 
+@jax.custom_vjp
+def _cascade_tiled_max(x6):
+    """The oracle of the pool tests: the formulation ``_tiled_max`` had
+    before PR 26 (residual ``(x6, m)``, a boolean first-winner cascade
+    over the four window slices, two levels of ``stack``)."""
+    return x6.max(axis=(2, 4))
+
+
+def _cascade_fwd(x6):
+    m = x6.max(axis=(2, 4))
+    return m, (x6, m)
+
+
+def _cascade_bwd(res, g):
+    x6, m = res
+    e = [x6[:, :, i, :, j, :] == m for i in (0, 1) for j in (0, 1)]
+    seen = e[0]
+    masks = [e[0]]
+    for k in (1, 2, 3):
+        masks.append(e[k] & ~seen)
+        seen = seen | e[k]
+    gm = [g * mk.astype(g.dtype) for mk in masks]
+    return (jnp.stack([jnp.stack([gm[0], gm[1]], axis=3),
+                       jnp.stack([gm[2], gm[3]], axis=3)], axis=2),)
+
+
+_cascade_tiled_max.defvjp(_cascade_fwd, _cascade_bwd)
+
+
+def _pool_case(name):
+    rng = np.random.default_rng(7)
+    if name == "quantised_ties":
+        return rng.integers(-2, 3, size=(3, 8, 12, 5)) * 0.5
+    if name == "all_equal_block":
+        x = rng.normal(size=(2, 8, 8, 4))
+        x[0] = 1.25
+        x[1, 2:6, 2:6] = -3.0
+        return x
+    if name == "mnist_zero_background":
+        # a bias on a zero background: exact 4-way ties in every
+        # background window, a few strokes with distinct values
+        x = np.zeros((4, 28, 28, 6))
+        x[:, 9:19, 12:16] = rng.normal(size=(4, 10, 4, 6))
+        return x + rng.normal(size=(6,))
+    if name == "stacked_4c":
+        return rng.integers(-3, 4, size=(5, 6, 6, 4 * 8)) * 0.25
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["quantised_ties", "all_equal_block",
+                                  "mnist_zero_background", "stacked_4c"])
+def test_max_pool_bit_equal_to_cascade_oracle(case, dtype):
+    """Forward and gradient of the one-pass pool (int8 winner code as
+    the only residual) equal the old cascade's bit for bit, ties and
+    all-equal windows included."""
+    from dopt.models.zoo import _max_pool_2x2
+
+    x = jnp.asarray(_pool_case(case), dtype)
+    b, h, w, c = x.shape
+    gw = jnp.asarray(np.random.default_rng(8).normal(
+        size=(b, h // 2, w // 2, c)), dtype)
+
+    def oracle(a):
+        return _cascade_tiled_max(a.reshape(b, h // 2, 2, w // 2, 2, c))
+
+    got, got_vjp = jax.vjp(_max_pool_2x2, x)
+    want, want_vjp = jax.vjp(oracle, x)
+    assert got.dtype == want.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    (gg,), (gw_,) = got_vjp(gw), want_vjp(gw)
+    assert gg.dtype == gw_.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(gg, np.float32),
+                                  np.asarray(gw_, np.float32))
+    # the undifferentiated call is the same function
+    np.testing.assert_array_equal(
+        np.asarray(_max_pool_2x2(x), np.float32),
+        np.asarray(want, np.float32))
+
+
+def test_max_pool_odd_dims_fall_back_to_flax():
+    """Odd H or W cannot be tiled: the pool is ``nn.max_pool`` (which
+    floors), forward and gradient."""
+    import flax.linen as nn
+
+    from dopt.models.zoo import _max_pool_2x2
+
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(2, 7, 6, 3)),
+                    jnp.float32)
+    ref = lambda a: nn.max_pool(a, (2, 2), strides=(2, 2))
+    assert _max_pool_2x2(x).shape == (2, 3, 3, 3)
+    np.testing.assert_array_equal(np.asarray(_max_pool_2x2(x)),
+                                  np.asarray(ref(x)))
+    np.testing.assert_array_equal(
+        np.asarray(jax.grad(lambda a: jnp.sum(_max_pool_2x2(a) ** 2))(x)),
+        np.asarray(jax.grad(lambda a: jnp.sum(ref(a) ** 2))(x)))
+
+
+def test_stacked_model1_vjp_saves_winner_codes_not_conv_outputs(capsys):
+    """What the stacked Model1 step keeps between forward and backward:
+    for each pool an int8 code of the POOLED size, and no array of a
+    conv output's size (the convs need their inputs, never their
+    outputs: the faithful stack has no activation behind them)."""
+    import re
+
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from dopt.models import make_stacked_apply
+
+    m = build_model("model1")
+    w, b = 3, 4
+    p1 = _init(m, (28, 28, 1))
+    stacked = jax.tree.map(lambda a: jnp.stack([a] * w), p1)
+    x = jnp.zeros((w, b, 28, 28, 1), jnp.float32)
+    apply = make_stacked_apply(m)
+    print_saved_residuals(lambda p: apply(p, x), stacked)
+    res = [(dt, tuple(int(d) for d in dims.split(",") if d))
+           for dt, dims in re.findall(r"^(\w+)\[([\d,]*)\]",
+                                      capsys.readouterr().out, re.M)]
+    assert len(res) > 4, res
+    conv_out = {b * 28 * 28 * w * 32, b * 14 * 14 * w * 64}
+    too_big = [r for r in res if int(np.prod(r[1])) in conv_out]
+    assert not too_big, too_big
+    codes = sorted(shape for dt, shape in res if dt == "i8")
+    assert codes == [(b, 7, 7, w * 64), (b, 14, 14, w * 32)], codes
+
+
 def test_stacked_cnn_apply_non_square_input():
     """The grouped-stacked CNN forward must handle non-square inputs
     (fc1's VALID-conv kernel reshape derives H'/W' from the activation

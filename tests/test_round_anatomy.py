@@ -201,6 +201,26 @@ def test_compiled_round_carries_the_scopes(build, fn_name, nested_eval):
         assert not both
 
 
+def test_compiled_model1_round_carries_the_pool_scope():
+    """``dopt_pool`` marks the differentiated max-pool's forward and
+    backward inside the local phase; evaluation pools without it."""
+    cfg = _gossip_cfg(num_users=4, synthetic_train_size=64,
+                      synthetic_test_size=16, local_holdout=0.25)
+    cfg = dataclasses.replace(
+        cfg, model=ModelConfig(model="model1", input_shape=(28, 28, 1)),
+        gossip=dataclasses.replace(cfg.gossip, local_ep=1, local_bs=8))
+    _, lowered = GossipTrainer(cfg, eval_every=1).lower_round()
+    with enable_compilation_cache(False):
+        text = lowered.compile().as_text()
+    pool = {s for s in re.findall(r'op_name="([^"]*)"', text)
+            if "dopt_pool" in s}
+    assert any("/jvp(dopt_pool)/reduce" in s for s in pool)
+    assert any("transpose(jvp(dopt_pool))" in s for s in pool)
+    placed = {s for s in pool if s.startswith("jit(")}
+    assert placed and all("dopt_local" in s for s in placed)
+    assert not [s for s in pool if "dopt_eval" in s]
+
+
 def test_scopes_leave_the_fingerprints_alone():
     """Scopes are metadata: the blessed default programs do not move
     (no ``--bless``).  Own process: the registry is blessed for one
