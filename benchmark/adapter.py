@@ -37,6 +37,11 @@ FEDAVG_SAMPLE_SALT = 314159
 ENGINE_SECTIONS = {"gossip": GossipConfig, "federated": FederatedConfig}
 
 
+def _field(value):
+    """A JSON value as a dataclass field: the configs' tuples are lists."""
+    return tuple(value) if isinstance(value, list) else value
+
+
 def _section(cls, *sources: dict):
     """``cls(**merged)`` where a later source may not contradict an
     earlier one (a traffic file cannot loosen a configuration's
@@ -50,7 +55,7 @@ def _section(cls, *sources: dict):
                 if i == 0:
                     continue
                 raise KeyError(f"{cls.__name__} has no field {k!r}")
-            v = tuple(v) if isinstance(v, list) else v
+            v = _field(v)
             if k in merged and merged[k] != v:
                 raise ValueError(
                     f"{cls.__name__}.{k}: {v!r} contradicts the "
@@ -76,9 +81,10 @@ def build_config(name: str, config: dict, traffic: dict, *, seed: int,
 
 
 def parity_config(cfg: ExperimentConfig, traffic: dict) -> ExperimentConfig:
-    """The cell's job cut for the parity check: full model width, full
-    fleet, full batch, float32 compute; ``steps_per_epoch`` batches of
-    data a worker and ``local_ep`` epochs."""
+    """The cell's job as the traffic file's ``parity`` cuts it: full model
+    width and full fleet always; ``local_ep``, ``steps_per_epoch``
+    (batches of data a worker), ``local_bs``, ``lr`` and ``compute_dtype``
+    where the cut names them, the cell's own where it does not."""
     cut = traffic["parity"]
     engine = traffic["engine"]
     sec = getattr(cfg, engine)
@@ -87,31 +93,47 @@ def parity_config(cfg: ExperimentConfig, traffic: dict) -> ExperimentConfig:
     # unstable regime that amplifies rounding (the check is of plumbing
     # and form, not of the job's hyper-parameters).
     bs = min(cut.get("local_bs", sec.local_bs), sec.local_bs)
-    optim = dataclasses.replace(cfg.optim, lr=cut.get("lr", cfg.optim.lr))
-    rows = cut["steps_per_epoch"] * bs
+    data = cfg.data
+    if "steps_per_epoch" in cut:
+        rows = cut["steps_per_epoch"] * bs
+        data = dataclasses.replace(
+            data, synthetic_train_size=rows * data.num_users,
+            synthetic_test_size=min(data.synthetic_test_size, 256))
     return cfg.replace(
         name=cfg.name + ".parity",
-        data=dataclasses.replace(
-            cfg.data, synthetic_train_size=rows * cfg.data.num_users,
-            synthetic_test_size=min(cfg.data.synthetic_test_size, 256)),
-        model=dataclasses.replace(cfg.model, compute_dtype="float32"),
-        optim=optim,
-        **{engine: dataclasses.replace(sec, local_ep=cut["local_ep"],
-                                       local_bs=bs)})
+        data=data,
+        model=dataclasses.replace(
+            cfg.model,
+            compute_dtype=cut.get("compute_dtype", cfg.model.compute_dtype)),
+        optim=dataclasses.replace(cfg.optim, lr=cut.get("lr", cfg.optim.lr)),
+        **{engine: dataclasses.replace(
+            sec, local_ep=cut.get("local_ep", sec.local_ep), local_bs=bs)})
 
 
-def rehearsal_config(cfg: ExperimentConfig, traffic: dict) -> ExperimentConfig:
+def rehearsal_config(cfg: ExperimentConfig, traffic: dict,
+                     overrides: dict | None = None) -> ExperimentConfig:
     """A toy of the cell for the CPU sandbox: same engine, model family
-    and switches, a fleet of ``chips``-divisible size and a few rows."""
+    and switches, a fleet of ``chips``-divisible size and a few rows.
+    ``overrides`` is the configuration file's ``rehearsal`` (``{"model":
+    {...}, "data": {...}}``), for a model whose full width the sandbox
+    cannot hold: depth, experts held, vocabulary rows, sequence length."""
+    overrides = overrides or {}
+    if not set(overrides) <= {"model", "data"}:
+        raise KeyError(f"rehearsal overrides 'model' and 'data', not "
+                       f"{sorted(set(overrides) - {'model', 'data'})}")
     engine = traffic["engine"]
     sec = getattr(cfg, engine)
     users = min(cfg.data.num_users, 4)
     bs = 8
+    small = {k: {f: _field(v) for f, v in overrides.get(k, {}).items()}
+             for k in ("model", "data")}
     return cfg.replace(
         data=dataclasses.replace(
-            cfg.data, num_users=users, synthetic_train_size=users * bs * 4,
-            synthetic_test_size=16),
-        model=dataclasses.replace(cfg.model, compute_dtype="float32"),
+            cfg.data, **{"num_users": users,
+                         "synthetic_train_size": users * bs * 4,
+                         "synthetic_test_size": 16, **small["data"]}),
+        model=dataclasses.replace(
+            cfg.model, **{"compute_dtype": "float32", **small["model"]}),
         **{engine: dataclasses.replace(sec, local_bs=bs,
                                        local_ep=min(sec.local_ep, 2))})
 

@@ -1,10 +1,13 @@
-"""Operations a configured job REQUIRES, from shapes: the matrix
-multiplications of the convolutions and dense layers listed in the
-configuration file's ``layers``.  Norms, activations, pooling, the loss
-and the optimizer are left out (under 1% of either model here), and
-recomputed or padded work never counts.  A multiply-add is 2 operations;
-training a sample is 3 forward passes' worth (forward, gradient by
-input, gradient by weight).
+"""Operations a configured job REQUIRES, from shapes: the multiply-adds
+of the layers listed in the configuration file's ``layers``.  Each
+layer names its ``op``, and an op is one file, ``ops/<op>.py``, with
+``macs(layer)`` (multiply-adds of ONE forward pass of ONE sample) and
+``params(layer)``, its formula in its docstring: a later PR adds an op
+by adding a file.  Norms, activations, pooling, the loss and the
+optimizer are left out (under 1% of either convolutional model here),
+and recomputed or padded work never counts.  A multiply-add is 2
+operations; training a sample is 3 forward passes' worth (forward,
+gradient by input, gradient by weight).
 
 XLA's cost analysis is not used: it counts what the compiler lowered,
 not what the algorithm needs.
@@ -12,33 +15,32 @@ not what the algorithm needs.
 
 from __future__ import annotations
 
+import importlib
 import json
 from pathlib import Path
 
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 
 
+def load_op(name: str):
+    """``ops/<name>.py``."""
+    try:
+        return importlib.import_module(f"benchmark.ops.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"benchmark.ops.{name}":
+            raise
+        raise ValueError(
+            f"unknown layer op {name!r}: there is no benchmark/ops/{name}.py "
+            "(macs(layer), params(layer))") from None
+
+
 def layer_macs(layer: dict) -> int:
     """Multiply-adds of one forward pass of one sample through a layer."""
-    if layer["op"] == "conv":
-        h, w = layer["out_hw"]
-        return h * w * layer["k"] ** 2 * layer["cin"] * layer["cout"]
-    if layer["op"] == "dense":
-        return layer["cin"] * layer["cout"]
-    raise ValueError(f"unknown layer op {layer['op']!r}")
+    return load_op(layer["op"]).macs(layer)
 
 
 def layer_params(layer: dict) -> int:
-    if layer["op"] == "conv":
-        n = layer["k"] ** 2 * layer["cin"] * layer["cout"]
-        # A normalised convolution carries the norm's scale and bias.
-        extra = (layer["cout"] if layer["bias"] else 0) + (
-            2 * layer["cout"] if layer["norm"] else 0)
-        return n + extra
-    if layer["op"] == "dense":
-        return layer["cin"] * layer["cout"] + (
-            layer["cout"] if layer["bias"] else 0)
-    raise ValueError(f"unknown layer op {layer['op']!r}")
+    return load_op(layer["op"]).params(layer)
 
 
 def forward_flops(layers: list[dict]) -> int:

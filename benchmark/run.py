@@ -39,7 +39,11 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent.parent
 HERE = Path(__file__).resolve().parent
 OUT = HERE / "out"
-HOST_SPANS = ("bench.run_call", "host_batch_plan", "round_step")
+# Host spans kept for ``breakdown.idle_gaps``: the harness's own call span
+# and the program's (it writes each to the profiler itself).  A gap goes to
+# the innermost of them open at its middle; no metric reads it.
+HOST_SPANS = ("bench.run_call", "host_batch_plan", "round_step",
+              "round_dispatch", "round_wait", "round_fetch", "round_record")
 
 
 def log(*a) -> None:
@@ -80,16 +84,6 @@ def percentile_line(samples_ms: list[float]) -> str:
     else:
         line += " (under 20 samples: no percentile has ten beyond it)"
     return line + f" min={s[0]:.3f}ms max={s[-1]:.3f}ms"
-
-
-class TraceSpans:
-    """Puts the program's host timer spans on the profiler's clock
-    (``PhaseTimers.tracer`` takes anything with ``span(name)``)."""
-
-    def span(self, name: str):
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
 
 
 @dataclasses.dataclass
@@ -142,14 +136,14 @@ def set_up_and_measure(args, cell, cfg, meter) -> Window:
     trainer = adapter.build_trainer(cfg, traffic)
     have = adapter.param_count(trainer)
     listed = flops.param_count(config["layers"])
-    if not have == config["parameters"] == listed:
+    # A rehearsal that overrides the model holds a smaller one than listed.
+    toy = args.rehearse and "model" in config.get("rehearsal", {})
+    if not (config["parameters"] == listed and (toy or have == listed)):
         raise SystemExit(
             f"the trainer holds {have} parameters a worker, the "
             f"configuration file says {config['parameters']} and its layer "
             f"list {listed}")
     per_call = traffic["rounds_per_call"]
-    if args.trace:
-        trainer.timers.tracer = TraceSpans()
 
     def run_call() -> float:
         t0 = time.perf_counter()
@@ -333,16 +327,17 @@ def main(argv=None) -> int:
     cfg = adapter.build_config(cell["name"], config, traffic,
                                seed=args.seed, chips=chips)
     if args.rehearse:
-        cfg = adapter.rehearsal_config(cfg, traffic)
+        cfg = adapter.rehearsal_config(cfg, traffic, config.get("rehearsal"))
     with CompileMeter() as meter:
         win = set_up_and_measure(args, cell, cfg, meter)
     # The parity job runs last, on a chip the cell has given back: it is
     # neither set-up nor window, and cannot touch the cell's peak.
     gc.collect()
     check = parity.run(cfg, config, traffic)
-    log(f"parity: error {check['error']:.3e} tolerance "
-        f"{check['tolerance']:.1e} (reference moved {check['moved']:.3e}) "
-        f"in {check['seconds']:.1f}s")
+    log(f"parity: error {check['error']:.3e} (reference moved "
+        f"{check['moved']:.3e}), held as {check['of']!r} to "
+        f"{check['tolerance']:.1e} because: {check['why']}; "
+        f"{check['seconds']:.1f}s")
 
     # ------------------------------------------------------ correctness
     first = traffic["warmup_calls"] * win.rounds_per_call
@@ -387,6 +382,16 @@ def main(argv=None) -> int:
               "failed": failed, "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # Every number ``correct`` compared, beside its limit: last in the
+    # result line and last on stderr.
+    compared = {
+        f"parity_error_{check['of']}": {"value": check["compared"],
+                                        "limit": check["tolerance"]},
+        "compiles_in_window": {"value": win.compiles_in_window, "limit": 0},
+        "rounds_without_finite_loss": {"value": len(bad), "limit": 0},
+    }
+    if wants_k:
+        compared["loss_round_missed"] = {"value": int(missed_k), "limit": 0}
     if args.rehearse:
         # A CPU run gives counts, never a number under a device metric.
         result = {"rehearsal": True, "correct": result["correct"],
@@ -394,6 +399,7 @@ def main(argv=None) -> int:
                   "device": device, "would_report": sorted(metrics),
                   "rounds": win.rounds,
                   "samples_per_round": win.samples_per_round}
+    result["compared"] = compared
     detail = {**result, "workload": cell["name"], "seed": args.seed,
               "trace": args.trace, "parity": check, "round_ms": win.round_ms,
               "whole_window_samples_per_s": raw, "slow_calls": slow,
@@ -405,6 +411,8 @@ def main(argv=None) -> int:
               "round_memory": win.round_memory}
     (OUT / f"{cell['name']}.seed{args.seed}.trace{args.trace}.json"
      ).write_text(json.dumps(detail, indent=1))
+    for name, c in compared.items():
+        log(f"compared: {name} {c['value']:.6g} limit {c['limit']:.6g}")
     print(json.dumps(result), flush=True)
     return 0
 

@@ -49,7 +49,7 @@ def test_a_wrong_neighbour_fails_the_tolerance():
     got = adapter.final_params(trainer, traffic)
     for r in rounds:
         r["w"] = np.roll(r["w"], 1, axis=1)      # every worker's neighbours shift
-    forward = reference.load_forward(config["reference"])
-    want = reference.run_gossip(forward, init, rounds, lr=pcfg.optim.lr,
+    objective = reference.load_objective(config["reference"])
+    want = reference.run_gossip(objective, init, rounds, lr=pcfg.optim.lr,
                                 momentum=pcfg.optim.momentum)
     assert reference.max_abs_error(got, want) > 10 * parity.TOLERANCE
