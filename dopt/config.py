@@ -22,7 +22,9 @@ from typing import Any, Mapping
 class DataConfig:
     """Dataset selection + partitioning (reference ``get_dataset`` args)."""
 
-    dataset: str = "mnist"  # mnist | fmnist | cifar10 | cifar100 | synthetic | a9a
+    dataset: str = "mnist"
+    # mnist | fmnist | cifar10 | cifar100 | synthetic | a9a |
+    # synthetic_tokens ([N, T] int32 id rows for a sequence model)
     iid: bool = True
     shards: int = 2          # non-IID shards per user (P2 sampling.py:11-28)
     num_users: int = 8
@@ -53,10 +55,92 @@ class DataConfig:
 
 
 @dataclass(frozen=True)
+class DecoderConfig:
+    """A gated window/full-attention mixture-of-experts decoder
+    (``dopt.models.decoder``), under the keys of its published
+    ``config.json`` (``model_type: laguna``): a key the file has and this
+    class lacks is refused, not ignored.  The lists run over the
+    PUBLISHED depth; a worker builds layers ``0 .. num_hidden_layers-1``
+    of them, so a cut in depth changes one number.
+
+    ``experts_held`` / ``expert_offset`` say which of the ``num_experts``
+    published experts of a layer this worker holds (ids ``offset ..
+    offset + held - 1``, a chip's share of an expert-parallel
+    deployment; ``None`` = all).  The router keeps its published width
+    and ``num_experts_per_tok``; what the absent experts would add is
+    left out.  The vocabulary rows held are ``ModelConfig.num_classes``,
+    the sequence length ``ModelConfig.input_shape[0]``."""
+
+    hidden_size: int
+    intermediate_size: int
+    num_hidden_layers: int
+    num_key_value_heads: int
+    head_dim: int
+    num_attention_heads_per_layer: tuple[int, ...]
+    layer_types: tuple[str, ...]          # full_attention | sliding_attention
+    mlp_layer_types: tuple[str, ...]      # dense | sparse
+    sliding_window: int
+    rope_parameters: Mapping[str, Any]
+    # {"full_attention": {...}, "sliding_attention": {...}}: rope_theta,
+    # rope_type default | yarn (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, attention_factor), partial_rotary_factor.
+    num_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    shared_expert_intermediate_size: int
+    moe_routed_scaling_factor: float
+    rms_norm_eps: float = 1e-6
+    gating: bool = True                   # one sigmoid output gate a head
+    attention_bias: bool = False
+    tie_word_embeddings: bool = False
+    moe_apply_router_weight_on_input: bool = False
+    # Stated by the source and not read by the model (they describe the
+    # whole checkpoint, or repeat a per-layer list):
+    model_type: str = "laguna"
+    vocab_size: int | None = None
+    num_attention_heads: int | None = None
+    max_position_embeddings: int | None = None
+    partial_rotary_factor: float | None = None
+    # The worker's share:
+    experts_held: int | None = None
+    expert_offset: int = 0
+
+    def __post_init__(self) -> None:
+        n = self.num_hidden_layers
+        for name in ("num_attention_heads_per_layer", "layer_types",
+                     "mlp_layer_types"):
+            per_layer = tuple(getattr(self, name))
+            object.__setattr__(self, name, per_layer)
+            if len(per_layer) < n:
+                raise ValueError(
+                    f"decoder.{name} lists {len(per_layer)} layers, "
+                    f"num_hidden_layers is {n}")
+        if (self.attention_bias or self.tie_word_embeddings
+                or self.moe_apply_router_weight_on_input or not self.gating):
+            raise ValueError(
+                "the decoder has no biases, an untied head, router weights "
+                "on the experts' outputs and a gated attention output; "
+                "attention_bias / tie_word_embeddings / "
+                "moe_apply_router_weight_on_input must be false and gating "
+                "true")
+        if any(h % self.num_key_value_heads
+               for h in self.num_attention_heads_per_layer[:n]):
+            raise ValueError(
+                "every layer's query heads must be a multiple of "
+                f"num_key_value_heads={self.num_key_value_heads}")
+        held = self.num_experts if self.experts_held is None else self.experts_held
+        if not (0 < held and 0 <= self.expert_offset
+                and self.expert_offset + held <= self.num_experts):
+            raise ValueError(
+                f"experts {self.expert_offset} .. {self.expert_offset + held - 1} "
+                f"are not among the {self.num_experts} published")
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Model zoo selection (reference ``args.model`` string dispatch)."""
 
-    model: str = "model1"    # model1 | model3 | mlp | resnet18 | logistic
+    model: str = "model1"    # model1 | model3 | mlp | resnet18 | logistic | laguna
     stage_sizes: tuple[int, ...] | None = None
     # resnet18 only: residual blocks per stage (None = the standard
     # (2, 2, 2, 2)).  Smaller values give shallow variants for tests
@@ -82,6 +166,14 @@ class ModelConfig:
     # faster than the vmap on TPU, identical math up to float
     # reassociation inside the conv), "vmap" forces the vmapped
     # per-worker path (the bit-level oracle-parity mode).
+    decoder: DecoderConfig | None = None
+    # model="laguna" only: the decoder's published configuration (a
+    # mapping is taken as DecoderConfig(**mapping)).
+
+    def __post_init__(self) -> None:
+        if isinstance(self.decoder, Mapping):
+            object.__setattr__(self, "decoder",
+                               DecoderConfig(**self.decoder))
 
 
 @dataclass(frozen=True)

@@ -271,6 +271,34 @@ def make_synthetic(
     return Dataset(name, train_x, train_y, test_x, test_y)
 
 
+def make_synthetic_tokens(
+    *,
+    seq_len: int,
+    vocab: int,
+    train_size: int = 16,
+    test_size: int = 2,
+    seed: int = 0,
+    name: str = "synthetic_tokens",
+) -> Dataset:
+    """Deterministic token rows for a sequence model: ``x`` is ``[N, T]``
+    int32 ids drawn independently from Zipf(1.0) over the ``vocab`` rows
+    of the (sliced) vocabulary (id i with probability proportional to
+    1 / (i + 1), as word frequencies fall), ``y`` the next id at every
+    position and -1 at the last, which the token loss leaves out."""
+    p = 1.0 / np.arange(1, vocab + 1)
+    p /= p.sum()
+
+    def split(n, salt):
+        r = np.random.default_rng(seed * 7919 + salt)
+        x = r.choice(vocab, size=(n, seq_len), p=p).astype(np.int32)
+        y = np.concatenate([x[:, 1:], np.full((n, 1), -1, np.int32)], axis=1)
+        return x, y
+
+    train_x, train_y = split(train_size, 1)
+    test_x, test_y = split(test_size, 2)
+    return Dataset(name, train_x, train_y, test_x, test_y)
+
+
 # --------------------------------------------------------------------
 # Entry point
 # --------------------------------------------------------------------
@@ -295,6 +323,14 @@ def load_dataset(
     name = dataset.lower()
     if name in ("cifar",):
         name = "cifar10"
+    if name == "synthetic_tokens":
+        if not input_shape or len(input_shape) != 1 or not num_classes:
+            raise ValueError(
+                "synthetic_tokens needs input_shape=(sequence length,) and "
+                "num_classes=vocabulary rows")
+        return make_synthetic_tokens(
+            seq_len=input_shape[0], vocab=num_classes,
+            train_size=train_size, test_size=test_size, seed=seed)
     roots = []
     if data_dir is not None:
         roots.append(Path(data_dir))

@@ -131,6 +131,10 @@ def partition(
     [num_users, L] with L = min user shard length (sizes are equal for
     both splitters by construction, so nothing is dropped in practice).
     """
+    if not iid and np.ndim(labels) > 1:
+        raise ValueError(
+            "the non-IID split sorts samples by their one label; token rows "
+            "([N, T] next-token labels) partition IID only")
     groups = (
         iid_split(labels, num_users, seed=seed)
         if iid

@@ -235,6 +235,6 @@ def eval_batches(
     mask = np.concatenate([np.ones(n, np.float32), np.zeros(pad, np.float32)])
     return (
         x[idx].reshape(steps, bs, *x.shape[1:]),
-        y[idx].reshape(steps, bs).astype(np.int32),
+        y[idx].reshape(steps, bs, *y.shape[1:]).astype(np.int32),
         mask.reshape(steps, bs),
     )

@@ -97,6 +97,17 @@ class FederatedTrainer:
 
         _reject_sequence_model(cfg)
         validate_optimizer(cfg)
+        self.model = build_model(
+            cfg.model.model, num_classes=cfg.model.num_classes,
+            faithful=cfg.model.faithful, dtype=cfg.model.compute_dtype,
+            stage_sizes=cfg.model.stage_sizes, decoder=cfg.model.decoder,
+        )
+        if hasattr(self.model, "loss"):
+            raise ValueError(
+                f"model={cfg.model.model!r} brings its own token loss (a "
+                "sequence objective) and trains through the gossip engine "
+                "(GossipTrainer); the federated step cores and evaluators "
+                "still assume one label a row (ROADMAP R1)")
         self.cfg = cfg
         self.eval_train = eval_train
         self.round = 0
@@ -457,11 +468,6 @@ class FederatedTrainer:
         self._train_eval_idx = jnp.asarray(ti)
         self._train_eval_w = jnp.asarray(tw)
 
-        self.model = build_model(
-            cfg.model.model, num_classes=cfg.model.num_classes,
-            faithful=cfg.model.faithful, dtype=cfg.model.compute_dtype,
-            stage_sizes=cfg.model.stage_sizes,
-        )
         key = jax.random.key(cfg.seed)
         dummy = jnp.zeros((1, *cfg.model.input_shape))
         theta0 = self.model.init(key, dummy)["params"]

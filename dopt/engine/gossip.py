@@ -34,14 +34,14 @@ from dopt.config import ExperimentConfig
 from dopt.data import (PrefetchStager, eval_batches, load_dataset,
                        make_batch_plan, next_block_rounds, partition,
                        sharded_eval_batches, timed_build)
-from dopt.engine.local import (_stacked_eval_scan, flat_input_apply,
+from dopt.engine.local import (_stacked_eval_scan,
                                flat_input_stacked_apply, gather_rows,
                                make_evaluator,
                                make_stacked_evaluator, make_stacked_local_update,
                                make_stacked_local_update_epochs,
                                make_stacked_local_update_gather,
-                               pick_gather_chunks, prepare_holdout,
-                               validate_optimizer)
+                               model_objective, pick_gather_chunks,
+                               prepare_holdout, validate_optimizer)
 from dopt.models import build_model, count_params
 from dopt.parallel.collectives import (MIX_PRECISION, buckets_to_stacked,
                                         make_codec_plan,
@@ -228,8 +228,20 @@ class GossipTrainer:
         self.model = build_model(
             cfg.model.model, num_classes=cfg.model.num_classes,
             faithful=cfg.model.faithful, dtype=cfg.model.compute_dtype,
-            stage_sizes=cfg.model.stage_sizes,
+            stage_sizes=cfg.model.stage_sizes, decoder=cfg.model.decoder,
         )
+        # A sequence model's routing counts (dopt.models.decoder): each
+        # step's metric carries them beside the accuracy and the round
+        # program packs their means for the history row.  () for every
+        # other model: python-gated, the programs are unchanged.
+        self.counters = tuple(getattr(self.model, "counters", ()))
+        # A sequence model brings its own token loss (model_objective).
+        sequence_model = hasattr(self.model, "loss")
+        if sequence_model and cfg.data.local_holdout > 0:
+            raise ValueError(
+                "the local holdout's per-epoch client rows count correct "
+                "predictions of a classifier; a sequence model trains on "
+                "its full shard (data.local_holdout=0)")
         key = jax.random.key(cfg.seed)
         dummy = jnp.zeros((1, *cfg.model.input_shape))
         params0 = self.model.init(key, dummy)["params"]
@@ -535,12 +547,14 @@ class GossipTrainer:
         s_apply = self._stacked_apply
         # Flat-row adapters for everything that trains from the resident
         # train arrays (the evaluators consume shaped host-built stacks
-        # and keep the raw apply).  (A fast-layout param codec that
+        # and keep the raw apply; a sequence model's [T] rows are flat
+        # already, and it brings its own token loss: model_objective).
+        # (A fast-layout param codec that
         # hoists the per-step kernel relayout out of the scan was
         # measured and REJECTED: carried grouped-layout kernels make
         # XLA pick worse conv layouts — headline 378→401 ms/round,
         # baseline5 2410→2572 ms/round device time.)
-        app_f = flat_input_apply(self.model.apply, self._sample_shape)
+        objective = model_objective(self.model, self._sample_shape)
         s_apply_f = (flat_input_stacked_apply(s_apply, self._sample_shape)
                      if s_apply is not None else None)
         # may_straggle keys the compiled local-update shape: the
@@ -549,14 +563,14 @@ class GossipTrainer:
         # params/momentum at its deadline.  Fault-free configs compile
         # the exact pre-fault program.
         local = make_stacked_local_update(
-            app_f, lr=cfg.optim.lr, momentum=cfg.optim.momentum,
+            objective, lr=cfg.optim.lr, momentum=cfg.optim.momentum,
             algorithm="sgd", l2=l2, update_impl=update_impl,
             stacked_apply=s_apply_f, clip_norm=cfg.optim.clip_norm,
             with_limit=may_straggle,
         )
         local_epochs = (
             make_stacked_local_update_epochs(
-                app_f, lr=cfg.optim.lr,
+                objective, lr=cfg.optim.lr,
                 momentum=cfg.optim.momentum, algorithm="sgd", l2=l2,
                 update_impl=update_impl, gather_chunks=epoch_chunks,
                 stacked_apply=s_apply_f, clip_norm=cfg.optim.clip_norm,
@@ -577,8 +591,9 @@ class GossipTrainer:
                     "wwwwwrrww" if may_straggle else "wwwwrrww", "www")
         use_holdout = self._holdout
         local_ep_n = g.local_ep
-        full_evaluator = make_stacked_evaluator(self.model.apply,
-                                                stacked_apply=s_apply)
+        full_evaluator = make_stacked_evaluator(
+            objective if sequence_model else self.model.apply,
+            stacked_apply=s_apply)
         if s_apply is not None and self.mesh.size > 1:
             full_evaluator = shard_over_workers(full_evaluator, self.mesh,
                                                 "wrrr", "w")
@@ -595,7 +610,7 @@ class GossipTrainer:
                     evaluator = shard_over_workers(evaluator, self.mesh,
                                                    "wwww", "w")
             else:
-                evaluator = jax.vmap(make_evaluator(app_f))
+                evaluator = jax.vmap(make_evaluator(objective))
         else:
             evaluator = full_evaluator
         self._full_evaluator = full_evaluator
@@ -1039,6 +1054,17 @@ class GossipTrainer:
             z = jnp.zeros(self.num_workers)
             return {"acc": z, "loss_sum": z, "loss_mean": z, "count": z}
 
+        counters = self.counters
+
+        def split_counts(accs):
+            """A sequence model's step metric is a dict: its accuracy,
+            and the routing counts of ``counters`` — returned as their
+            [K] means over workers and steps for the round's history
+            row (None for every other model)."""
+            if not counters:
+                return accs, None
+            return accs["acc"], jnp.stack([accs[k].mean() for k in counters])
+
         def train_metrics(losses, accs, alive):
             """Mean over steps per worker, then over ALIVE workers only."""
             if not has_faults:
@@ -1130,7 +1156,8 @@ class GossipTrainer:
                 p_t, m_t, losses, accs = local(params, mom, bx, by, bweight)
             return p_t, m_t, losses, accs, {}
 
-        def pack_host_metrics(tl, ta, evalm, em, screened, diag=None):
+        def pack_host_metrics(tl, ta, evalm, em, screened, diag=None,
+                              counts=None):
             """Everything the host reads per round, as ONE flat f32
             vector — every device→host fetch synchronises with the
             device, so the round's metrics
@@ -1139,7 +1166,7 @@ class GossipTrainer:
             the holdout) travel in a single transfer.  Layout (mirrored
             by ``_unpack_host_metrics``): [tl, ta, mean(acc),
             mean(loss_mean)] + [W] screened (robust runs only) +
-            4×[W·E] em blocks."""
+            4×[W·E] em blocks + [K] model counts (sequence models)."""
             parts = [tl[None], ta[None],
                      jnp.mean(evalm["acc"])[None],
                      jnp.mean(evalm["loss_mean"])[None]]
@@ -1149,6 +1176,8 @@ class GossipTrainer:
                 parts += [em["train_loss"].ravel(), em["train_acc"].ravel(),
                           em["val_acc"].ravel(),
                           em["val_loss_mean"].ravel()]
+            if counters:
+                parts.append(counts)
             if diag_on:
                 # Diagnostics block travels LAST so every earlier
                 # offset (_unpack_host_metrics) is layout-stable.
@@ -1280,12 +1309,14 @@ class GossipTrainer:
                 # and are discarded — static shapes).
                 p_t = where_mask(alive, p_t, params)
                 m_t = where_mask(alive, m_t, mom)
+            accs, counts = split_counts(accs)
             tl, ta = train_metrics(losses, accs, alive)
             # ``params`` is the post-consensus state here, so the diag
             # update norm measures the local-training displacement.
             diag = (round_diag(p_t, m_t, params, losses, alive)
                     if diag_on else None)
-            packed = pack_host_metrics(tl, ta, evalm, em, screened, diag)
+            packed = pack_host_metrics(tl, ta, evalm, em, screened, diag,
+                                       counts)
             if fused_on:
                 # Next round's contraction folds this displacement in.
                 # Dead lanes carried q (p_t == params) → a zero row:
@@ -1315,7 +1346,7 @@ class GossipTrainer:
         self._evaluator = evaluator
         self._do_mix, self._eps = do_mix, eps
         self._local_gather = make_stacked_local_update_gather(
-            app_f, lr=cfg.optim.lr, momentum=cfg.optim.momentum,
+            objective, lr=cfg.optim.lr, momentum=cfg.optim.momentum,
             algorithm="sgd", l2=l2, update_impl=update_impl,
             gather_chunks=self._gather_chunks, stacked_apply=s_apply_f,
             clip_norm=cfg.optim.clip_norm, with_limit=may_straggle,
@@ -1411,10 +1442,12 @@ class GossipTrainer:
                 if has_faults:
                     p_t = where_mask(alive_t, p_t, p)
                     m_t = where_mask(alive_t, m_t, m)
+                accs, counts = split_counts(accs)
                 tl, ta = train_metrics(losses, accs, alive_t)
                 diag = (round_diag(p_t, m_t, p, losses, alive_t)
                         if diag_on else None)
-                packed = pack_host_metrics(tl, ta, evalm, em, scr, diag)
+                packed = pack_host_metrics(tl, ta, evalm, em, scr, diag,
+                                           counts)
                 if fused_quar:
                     stk, unt = quarantine_update(stk, unt, scr, alive_t,
                                                  t_t)
@@ -1580,6 +1613,7 @@ class GossipTrainer:
                 if has_faults:
                     p_t = where_mask(alive, p_t, mixed)
                     m_t = where_mask(alive, m_t, mom)
+                accs, counts = split_counts(accs)
                 tl, ta = train_metrics(losses, accs, alive)
                 # Diagnostics on the DE-BIASED estimates (pre-rebias):
                 # under push-sum the carried numerators scale with mass,
@@ -1597,7 +1631,7 @@ class GossipTrainer:
                     p_t = jax.tree.map(rebias, p_t)
                 return (p_t, m_t, mass_out, new_buf, new_buf_mass,
                         pack_host_metrics(tl, ta, evalm, em, screened,
-                                          diag))
+                                          diag, counts))
 
             self._link_round_fn = jax.jit(link_round_core,
                                           donate_argnums=(0, 1, 2, 3, 4))
@@ -1829,8 +1863,8 @@ class GossipTrainer:
             packed = np.asarray(packed)  # ONE device→host fetch per block
         with self.timers.phase("round_record"):
             for j, t in enumerate(ts):
-                tl, ta, acc, lm, scr, em, diag = self._unpack_host_metrics(
-                    packed[j])
+                (tl, ta, acc, lm, scr, em, diag,
+                 counts) = self._unpack_host_metrics(packed[j])
                 if fused_quar:
                     # Post-fetch ledger replay: host state is now
                     # current through round t-1's flags, so this
@@ -1850,6 +1884,7 @@ class GossipTrainer:
                     "round": t,
                     "avg_train_loss": tl,
                     "avg_train_acc": ta,
+                    **counts,
                 }
                 if is_eval[j]:
                     row["avg_test_acc"] = acc
@@ -1882,7 +1917,8 @@ class GossipTrainer:
         f32 vector → (train_loss, train_acc, mean_test_acc,
         mean_test_loss, [W] screened flags (robust runs; else None), em
         dict of [W, E] arrays or {}, [6] diagnostics block
-        (diagnostics runs; else None))."""
+        (diagnostics runs; else None), {name: value} of a sequence
+        model's routing counts or {})."""
         tl, ta, acc, lm = (float(vec[0]), float(vec[1]), float(vec[2]),
                            float(vec[3]))
         off = 4
@@ -1898,8 +1934,11 @@ class GossipTrainer:
             for i, k in enumerate(("train_loss", "train_acc", "val_acc",
                                    "val_loss")):
                 em[k] = body[i * n:(i + 1) * n].reshape(w, e)
+            off += 4 * n
+        counts = {k: float(vec[off + i])
+                  for i, k in enumerate(self.counters)}
         diag = vec[-len(self._diag_keys):] if self._diag else None
-        return tl, ta, acc, lm, scr, em, diag
+        return tl, ta, acc, lm, scr, em, diag, counts
 
     def _append_client_rows(self, t: int, em: dict) -> None:
         """Per-epoch per-worker history rows (P2 Client.history schema,
@@ -2282,8 +2321,8 @@ class GossipTrainer:
         with self.timers.phase("round_fetch"):
             packed = np.asarray(packed)  # ONE device→host fetch per round
         with self.timers.phase("round_record"):
-            tl, ta, acc, lm, scr, em, diag = self._unpack_host_metrics(
-                packed)
+            (tl, ta, acc, lm, scr, em, diag,
+             counts) = self._unpack_host_metrics(packed)
             if self._robust_active:
                 alive_eff = (alive * (1.0 - quar) if self._fused_quar
                              else alive)
@@ -2293,6 +2332,7 @@ class GossipTrainer:
                 "round": t,
                 "avg_train_loss": tl,
                 "avg_train_acc": ta,
+                **counts,
             }
             if do_eval:
                 row["avg_test_acc"] = acc

@@ -18,7 +18,7 @@ TPU mapping SURVEY §2.3 calls for.
 from __future__ import annotations
 
 import os
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -96,15 +96,62 @@ def _apply_update(p, m, g, *, lr, momentum, update_impl):
         return p, st.momentum
 
 
+class Objective(NamedTuple):
+    """What a worker trains: ``(params, batch) -> loss``.
+
+    ``loss(params, x, y, w) -> (scalar, aux)`` is differentiated by the
+    step cores and called by the evaluators; ``metric(aux, y, w)`` is the
+    step's accuracy, or a dict with ``"acc"`` and the model's own
+    per-step counts beside it (the gossip engine averages those into the
+    round's history row).  ``w`` is the batch plan's 0/1 row weight."""
+
+    loss: Callable
+    metric: Callable
+
+
+def classification_objective(apply_fn) -> Objective:
+    """Cross-entropy of a flax ``apply``'s output against one label a
+    row: ``aux`` is the output, the metric its argmax accuracy."""
+
+    def loss(p, x, y, w):
+        out = apply_fn({"params": p}, x)
+        return cross_entropy(out, y, w), out
+
+    return Objective(loss, accuracy)
+
+
+def model_objective(model, sample_shape) -> Objective:
+    """The training objective of a zoo model over FLAT resident rows: a
+    sequence model brings its own ``loss(params, tokens, labels,
+    weights) -> (loss, {"acc": ..., **counts})`` over ``[B, T]`` id rows
+    (the token contract: the row weight covers every position, negative
+    labels are left out); every other model is a classifier of rows
+    reshaped to ``sample_shape``."""
+    if hasattr(model, "loss"):
+        return Objective(model.loss, lambda aux, y, w: aux)
+    return classification_objective(
+        flat_input_apply(model.apply, sample_shape))
+
+
+def _as_objective(fn) -> Objective:
+    return fn if isinstance(fn, Objective) else classification_objective(fn)
+
+
+def _acc(metric):
+    return metric["acc"] if isinstance(metric, dict) else metric
+
+
 def _make_step_core(apply_fn, *, lr, momentum, algorithm, rho, l2,
                     update_impl, clip_norm=0.0):
     """One SGD step on concrete batch arrays — the shared body of both
-    local-update variants (materialised batches and on-device gather)."""
+    local-update variants (materialised batches and on-device gather).
+    ``apply_fn`` is an ``Objective``, or a flax ``apply`` (a
+    classifier)."""
+    objective = _as_objective(apply_fn)
 
     def step_core(p, m, x, y, w, theta=None, alpha=None):
         def loss_fn(p_):
-            out = apply_fn({"params": p_}, x)
-            loss = cross_entropy(out, y, w)
+            loss, out = objective.loss(p_, x, y, w)
             if l2:
                 loss = loss + l2_regulariser(p_, l2)
             return loss, out
@@ -122,7 +169,7 @@ def _make_step_core(apply_fn, *, lr, momentum, algorithm, rho, l2,
             g = clip_by_global_norm(g, clip_norm)
         p, m = _apply_update(p, m, g, lr=lr, momentum=momentum,
                              update_impl=update_impl)
-        return p, m, loss, accuracy(out, y, w)
+        return p, m, loss, objective.metric(out, y, w)
 
     return step_core
 
@@ -445,7 +492,9 @@ def _scan_steps_gathered(core, params, mom, idx, bw, train_x, train_y,
                             (*gather_rows(train_x, train_y, ci), cw))
 
     carry, (losses, accs) = jax.lax.scan(chunk, carry0, (idx_c, bw_c))
-    return strip(carry), (losses.reshape(s), accs.reshape(s))
+    # (a sequence model's step metric is a dict of [S] counts)
+    return strip(carry), (losses.reshape(s),
+                          jax.tree.map(lambda a: a.reshape(s), accs))
 
 
 def make_local_update_gather(
@@ -940,14 +989,15 @@ def make_evaluator(apply_fn):
     P1 ``inference`` returns (acc, summed-per-batch loss)
     (``Decentralized Optimization/src/clients.py:61-75``), P2 returns
     (acc, mean-per-batch loss) (``Distributed Optimization/src/clients.py:71-86``).
+    ``apply_fn`` is an ``Objective``, or a flax ``apply`` (a classifier).
     """
+    objective = _as_objective(apply_fn)
 
     def evaluate(params, ex, ey, ew):
         def step(carry, batch):
             x, y, w = batch
-            out = apply_fn({"params": params}, x)
-            loss = cross_entropy(out, y, w)          # weighted mean over batch
-            correct = accuracy(out, y, w) * w.sum()  # weighted correct count
+            loss, out = objective.loss(params, x, y, w)  # weighted mean over batch
+            correct = _acc(objective.metric(out, y, w)) * w.sum()  # weighted correct count
             return carry, (loss, correct, w.sum())
 
         with jax.named_scope("dopt_eval"):

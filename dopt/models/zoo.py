@@ -585,6 +585,7 @@ def build_model(
     faithful: bool | None = None,
     dtype: Any = jnp.float32,
     stage_sizes: Sequence[int] | None = None,
+    decoder=None,
 ) -> nn.Module:
     """Model dispatch by name — the typed replacement for the reference's
     if/elif on ``args.model`` (``servers.py:33-40``, ``simulators.py:31-38``).
@@ -595,13 +596,24 @@ def build_model(
     ``dtype`` may be a string ("bfloat16" → MXU-native compute); params
     stay float32 (flax param_dtype default) — bf16 is compute-only.
     ``stage_sizes`` (resnet18 only) overrides the per-stage block counts
-    for shallow variants.
+    for shallow variants.  ``decoder`` (laguna only) is the
+    ``DecoderConfig``; ``num_classes`` is then the vocabulary rows held.
     """
     if isinstance(dtype, str):
         dtype = jnp.dtype(dtype)
     key = name.lower()
+    if key == "laguna":
+        from dopt.models.decoder import GatedMoEDecoder
+
+        if decoder is None:
+            raise ValueError("model='laguna' needs ModelConfig.decoder "
+                             "(dopt.config.DecoderConfig)")
+        return GatedMoEDecoder(decoder, vocab_rows=num_classes, dtype=dtype)
+    if decoder is not None:
+        raise ValueError("ModelConfig.decoder applies to model='laguna' only")
     if key not in _ZOO:
-        raise ValueError(f"unknown model {name!r}; one of {sorted(_ZOO)}")
+        raise ValueError(
+            f"unknown model {name!r}; one of {sorted([*_ZOO, 'laguna'])}")
     kwargs: dict[str, Any] = dict(num_classes=num_classes, dtype=dtype)
     if faithful is not None:
         kwargs["faithful"] = faithful

@@ -19,10 +19,10 @@ from __future__ import annotations
 
 import dataclasses
 
-from dopt.config import (DataConfig, ExperimentConfig, FaultConfig,
-                         FederatedConfig, GossipConfig, ModelConfig,
-                         OptimizerConfig, PopulationConfig, RobustConfig,
-                         SeqLMConfig)
+from dopt.config import (DataConfig, DecoderConfig, ExperimentConfig,
+                         FaultConfig, FederatedConfig, GossipConfig,
+                         ModelConfig, OptimizerConfig, PopulationConfig,
+                         RobustConfig, SeqLMConfig)
 
 MNIST_TRAIN, MNIST_TEST = 60_000, 10_000
 CIFAR_TRAIN, CIFAR_TEST = 50_000, 10_000
@@ -195,6 +195,73 @@ def seqlm_ring() -> ExperimentConfig:
     )
 
 
+def laguna_xs2_decoder(**cut) -> DecoderConfig:
+    """Laguna-XS.2's published ``config.json``
+    (https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json,
+    33.4B-A3B) key for key; ``cut`` replaces what a worker holds less of
+    (``num_hidden_layers``, ``experts_held``) or, for a toy, any width."""
+    period = ("full_attention",) + ("sliding_attention",) * 3
+    published = dict(
+        model_type="laguna", vocab_size=100352, hidden_size=2048,
+        intermediate_size=8192, num_hidden_layers=40,
+        num_attention_heads=48, num_key_value_heads=8, head_dim=128,
+        max_position_embeddings=262144, attention_bias=False,
+        rms_norm_eps=1e-06, num_experts=256, num_experts_per_tok=8,
+        moe_intermediate_size=512, shared_expert_intermediate_size=512,
+        tie_word_embeddings=False, gating=True, sliding_window=512,
+        rope_parameters={
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+                "original_max_position_embeddings": 4096, "beta_slow": 1,
+                "beta_fast": 64, "attention_factor": 1.4158883083359672,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {
+                "rope_type": "default", "rope_theta": 10000,
+                "partial_rotary_factor": 1},
+            "original_max_position_embeddings": 4096},
+        layer_types=period * 10,
+        moe_apply_router_weight_on_input=False, partial_rotary_factor=0.5,
+        mlp_layer_types=("dense",) + ("sparse",) * 39,
+        moe_routed_scaling_factor=2.5,
+        num_attention_heads_per_layer=(48, 64, 64, 64) * 10)
+    return DecoderConfig(**{**published, **cut})
+
+
+def laguna_localsgd2(toy: bool = False) -> ExperimentConfig:
+    """Two local-SGD workers (arXiv:1805.09767), each one chip's share
+    of Laguna-XS.2 (layers 0-4, experts 0-7 of 256 a layer, 12,544 of
+    100,352 vocabulary rows: 389.6 M parameters), averaging their
+    parameters every round of 8 steps x 1 row of 4,096 Zipf token ids:
+    the benchmark cell ``laguna-xs2.localsgd2.t4096`` (needs a 16 GB
+    chip).  ``toy=True`` keeps the form (full and sliding layers with 4
+    and 8 query heads, a dense layer then experts, 8 of 32 held) at
+    widths a CPU trains in seconds."""
+    if toy:
+        decoder = laguna_xs2_decoder(
+            hidden_size=64, intermediate_size=128, head_dim=16,
+            num_key_value_heads=4, num_attention_heads_per_layer=(4, 8, 8, 8),
+            num_hidden_layers=3, sliding_window=16, num_experts=32,
+            num_experts_per_tok=4, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32, experts_held=8)
+        vocab, seq = 256, 64
+    else:
+        decoder = laguna_xs2_decoder(num_hidden_layers=5, experts_held=8)
+        vocab, seq = 12544, 4096
+    return ExperimentConfig(
+        name="laguna-localsgd2-toy" if toy else "laguna-localsgd2", seed=28,
+        data=DataConfig(dataset="synthetic_tokens", num_users=2, iid=True,
+                        synthetic_train_size=16, synthetic_test_size=2),
+        model=ModelConfig(model="laguna", faithful=False, num_classes=vocab,
+                          input_shape=(seq,), decoder=decoder,
+                          compute_dtype="float32" if toy else "bfloat16"),
+        optim=OptimizerConfig(lr=0.01, momentum=0.9),
+        gossip=GossipConfig(algorithm="dsgd", topology="complete",
+                            mode="double_stochastic", self_weight=True,
+                            rounds=10, local_ep=1, local_bs=1),
+        mesh_devices=1,
+    )
+
+
 PRESETS = {
     "reference-fedavg": lambda: reference_federated("fedavg"),
     "reference-fedprox": lambda: reference_federated("fedprox"),
@@ -228,6 +295,8 @@ PRESETS = {
     "baseline4": baseline_4_admm_a9a,
     "baseline5": baseline_5_gossip32_resnet,
     "seqlm": seqlm_ring,
+    "laguna-localsgd2": laguna_localsgd2,
+    "laguna-localsgd2-toy": lambda: laguna_localsgd2(toy=True),
     # Fault-injection variants (dopt.faults.FaultPlan): the same
     # workloads under a production-shaped failure regime — per-round
     # client crashes, a straggler deadline finishing half the local

@@ -101,9 +101,15 @@ def device_details(trainer) -> str:
     import jax
 
     devs = jax.devices()
-    return (f"device: platform={devs[0].platform} "
+    line = (f"device: platform={devs[0].platform} "
             f"device_kind={devs[0].device_kind!r} n_devices={len(devs)} "
             f"mesh={dict(trainer.mesh.shape)}")
+    # A decoder says which body its attention runs at this row length
+    # (dopt.models.decoder.causal_attention): the fused kernel or jax.numpy.
+    path = getattr(getattr(trainer, "model", None), "attention_path", None)
+    if path is not None:
+        line += f" attention={path(trainer.cfg.model.input_shape[0])}"
+    return line
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -363,12 +369,19 @@ def main(argv: list[str] | None = None) -> int:
         print(f"wrote XLA trace to {args.trace}", file=sys.stderr)
     else:
         trainer.run(rounds=rounds, **run_kw)
-    for row in trainer.history.rows[-min(rounds, len(trainer.history)):]:
+    rows = trainer.history.rows[-min(rounds, len(trainer.history)):]
+    for row in rows:
         print(json.dumps(row))
     print(f"total_time_s={trainer.total_time:.2f}", file=sys.stderr)
 
     if args.timers:
         print(trainer.timers.report(), file=sys.stderr)
+        # A sequence model's routing counts (dopt.models.decoder), which
+        # each round's history row carries: their mean over this run.
+        for name in getattr(trainer, "counters", ()):
+            mean = sum(r[name] for r in rows) / len(rows)
+            print(f"counter {name}: mean {mean:.6g} over {len(rows)} rounds",
+                  file=sys.stderr)
     if args.csv:
         trainer.history.to_csv(args.csv)
         print(f"wrote {args.csv}", file=sys.stderr)

@@ -29,7 +29,12 @@ on-device gather of a step's rows), ``dopt_update`` (momentum SGD),
 ``dopt_eval`` (every evaluation inside a round program; the holdout's
 per-epoch eval is nested in ``dopt_local``), ``dopt_mix`` (consensus or
 aggregation), ``dopt_pool`` (the differentiated 2×2 max-pool's forward
-and backward, nested in ``dopt_local``; ``dopt.models.zoo``).
+and backward, nested in ``dopt_local``; ``dopt.models.zoo``), and the
+decoder's, all nested in ``dopt_local`` (``dopt.models.decoder``):
+``dopt_attn`` (normed input to gated output projection), ``dopt_moe``
+(router to combined output) with ``dopt_route`` inside it (scores,
+top-k, combine weights and their application, not the expert matmuls),
+``dopt_head`` (final norm, logits, loss).
 
 The rule: no span or scope without a reader — each of these is read by
 a per-layer metric of ``BENCHMARK.json`` (table in PERF.md §3).  A PR

@@ -1,0 +1,454 @@
+"""The gated window/full-attention mixture-of-experts decoder
+(``dopt.models.decoder``, ``model="laguna"``) against its plain
+reference (``benchmark/reference_models/laguna_xs2.py``), at toy widths
+on the CPU in float32 with seeded random weights.
+
+Tolerances.  Both sides compute in float32 on the CPU (exact products,
+no reduced-precision matmul), so what separates them is the ORDER of the
+arithmetic: the program takes attention a block of queries against a
+slice of the keys, multiplies the combine weight in before the experts'
+down projection where the reference scales its result, sums the loss a
+block of positions at a time, and forms the rotary angles in float32
+where the reference rounds float64 tables.  That is a few float32 ulps
+of the largest value after five layers: ``RTOL`` = 2e-5 of the largest
+reference value, an order of magnitude above what was read (2e-6 on
+logits, 1.3e-6 on gradients) and three below what a dropped term or a
+wrong mask gives (1e-2 and more).
+"""
+
+import functools
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import adapter, reference
+from benchmark.reference_models import laguna_xs2 as ref
+from dopt.config import (DataConfig, DecoderConfig, ExperimentConfig,
+                         GossipConfig, ModelConfig, OptimizerConfig)
+from dopt.models.decoder import (GatedMoEDecoder, _gated_mlp,
+                                 blocked_causal_attention)
+
+ROOT = Path(__file__).resolve().parents[1]
+RTOL = 2e-5
+PUBLISHED = json.loads(
+    (ROOT / "benchmark/configs/laguna-xs2.json").read_text())
+VOCAB, DIM, T = 40, 32, 21
+# The published constants at toy size: 8-wide heads, 2 key/value heads, a
+# band of 6, 16 published experts, 4 a token.
+TOY = dict(ref.PUBLISHED, head_dim=8, kv_heads=2, window=6, experts=16,
+           top_k=4)
+
+
+def toy_decoder(heads, *, full_every=4, dense_layers=1, held=4, offset=0,
+                **kw) -> DecoderConfig:
+    """The published configuration with the toy's sizes: ``heads`` query
+    heads by layer, layer i full where ``i % full_every == 0`` and dense
+    where ``i < dense_layers``, as the reference's ``spec`` has them."""
+    n = len(heads)
+    body = {**PUBLISHED["model"]["decoder"],
+            "hidden_size": DIM, "intermediate_size": 64,
+            "num_hidden_layers": n, "num_key_value_heads": TOY["kv_heads"],
+            "head_dim": TOY["head_dim"],
+            "num_attention_heads_per_layer": heads,
+            "layer_types": ["sliding_attention" if i % full_every
+                            else "full_attention" for i in range(n)],
+            "mlp_layer_types": ["dense" if i < dense_layers else "sparse"
+                                for i in range(n)],
+            "sliding_window": TOY["window"], "num_experts": TOY["experts"],
+            "num_experts_per_tok": TOY["top_k"],
+            "moe_intermediate_size": 16,
+            "shared_expert_intermediate_size": 16,
+            "experts_held": held, "expert_offset": offset, **kw}
+    return DecoderConfig(**body)
+
+
+def toy_model(heads, *, attn_block=8, head_block=16, **kw) -> GatedMoEDecoder:
+    """... as a worker whose blocks are shorter than the toy's rows (21
+    positions a row, 63 a batch), so that attention takes three blocks
+    with a band and the head pads its last one."""
+    return GatedMoEDecoder(toy_decoder(heads, **kw), vocab_rows=VOCAB,
+                           attn_block=attn_block, head_block=head_block)
+
+
+def batch(seed=1, rows=3):
+    rng = np.random.default_rng(seed)
+    x = rng.integers(0, VOCAB, (rows, T)).astype(np.int32)
+    y = np.concatenate([x[:, 1:], np.full((rows, 1), -1, np.int32)], 1)
+    w = np.ones(rows, np.float32)
+    w[-1] = 0.0                    # a padding row: no position of it counts
+    return x, y, w
+
+
+def close(got, want):
+    want = np.asarray(want)
+    return np.abs(np.asarray(got) - want).max() <= RTOL * np.abs(want).max()
+
+
+# layer kinds covered: full+dense, sliding+sparse, full+sparse (the
+# published pattern, 4 and 6 query heads standing for 48 and 64); then
+# sliding+dense with MORE heads in the full layer, a lone full+dense
+# layer, and full+sparse first.
+PATTERNS = {
+    "published-pattern": ([4, 6, 6, 6, 4], {}),
+    "sliding-dense": ([6, 4], {"dense_layers": 2}),
+    "one-layer": ([4], {}),
+    "sparse-first": ([4, 6], {"dense_layers": 0}),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(PATTERNS))
+def both(request):
+    """(program's logits, loss, gradients, aux), (reference's) for one
+    pattern of layers, from the same seeded parameters and batch."""
+    heads, kinds = PATTERNS[request.param]
+    spec = {**TOY, **kinds}
+    params = jax.tree.map(jnp.asarray, ref.init(
+        0, spec, vocab=VOCAB, dim=DIM, heads=heads, dense=64, expert=16,
+        held=4))
+    model = toy_model(heads, **kinds)
+    x, y, w = batch()
+    (loss, aux), grads = jax.value_and_grad(
+        lambda p: model.loss(p, x, y, w), has_aux=True)(params)
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: ref.objective(p, x, y, w, spec))(params)
+    return ((model.apply({"params": params}, x), loss, grads, aux),
+            (ref.forward(params, x, spec), want_loss, want_grads))
+
+
+def test_logits_equal_the_reference(both):
+    got, want = both
+    assert close(got[0], want[0])
+
+
+def test_loss_equals_the_reference(both):
+    got, want = both
+    assert abs(float(got[1]) - float(want[1])) <= RTOL * float(want[1])
+    assert 0.0 <= float(got[3]["acc"]) <= 1.0
+
+
+def test_gradients_equal_the_reference(both):
+    """Leaf by leaf, each held to its own largest value: a router's or a
+    gate's gradient is orders of magnitude under the head's."""
+    got, want = both
+    bad = {jax.tree_util.keystr(k) for (k, g), w in zip(
+        jax.tree_util.tree_leaves_with_path(got[2]),
+        jax.tree.leaves(want[2])) if not close(g, w)}
+    assert not bad
+
+
+def test_init_builds_the_reference_tree_and_the_published_count():
+    heads = [4, 6, 6, 6, 4]
+    model = toy_model(heads)
+    mine = model.init(jax.random.key(0))["params"]
+    theirs = ref.init(0, TOY, vocab=VOCAB, dim=DIM, heads=heads, dense=64,
+                      expert=16, held=4)
+    assert (jax.tree.map(lambda a: a.shape, mine)
+            == jax.tree.map(lambda a: a.shape, theirs))
+    # At the published sizes, from shapes alone (nothing is allocated).
+    body = PUBLISHED["model"]
+    full = GatedMoEDecoder(DecoderConfig(**body["decoder"]),
+                           vocab_rows=body["num_classes"])
+    shapes = jax.eval_shape(full.init, jax.random.key(0))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)) \
+        == PUBLISHED["parameters"] == 389_634_048
+
+
+# ------------------------------------------------------- the shares add up
+
+@pytest.mark.parametrize("held", [1, 4, 8, 16])
+def test_the_shares_add_up_to_the_uncut_layer(held):
+    """For every share of the 16 published experts, the layer's routed
+    part, plus the shared expert counted once, is what the uncut
+    reference layer gives: nothing stands in for the absent chips, and
+    nothing of theirs is lost or doubled."""
+    spec = {**TOY, "dense_layers": 0}
+    layer = jax.tree.map(jnp.asarray, ref.init(
+        2, spec, vocab=VOCAB, dim=DIM, heads=[4], dense=64, expert=16,
+        held=TOY["experts"])["layer0"])
+    m = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (T, DIM)).astype(np.float32))
+    want = ref._experts(layer, m, spec)
+    shared = _gated_mlp(layer["shared"], m, jnp.float32)
+    total, slots = shared, 0.0
+    for offset in range(0, TOY["experts"], held):
+        model = toy_model([4], dense_layers=0, held=held, offset=offset)
+        share = {**layer, "experts": jax.tree.map(
+            lambda a: a[offset:offset + held], layer["experts"])}
+        out, counts = model._experts(share, m)
+        total = total + (out - shared)
+        slots += float(counts["moe_held_slot_share"])
+    assert close(total, want)
+    # every routed slot reached exactly one share
+    assert abs(slots - 1.0) < 1e-6
+
+
+def test_routing_counts_are_counts():
+    model = toy_model([4, 6])
+    params = model.init(jax.random.key(1))["params"]
+    x, y, w = batch()
+    _, aux = model.loss(params, x, y, w)
+    assert set(aux) == {"acc", *model.counters}
+    # 4 of 16 held: a quarter of the slots in expectation, never over
+    # top_k * held / (top_k * 1) and the fullest expert at least the mean
+    assert 0.0 < float(aux["moe_held_slot_share"]) < 1.0
+    assert float(aux["moe_load_max_over_mean"]) >= 1.0
+
+
+# ----------------------------------------------------- banded attention
+
+@pytest.mark.parametrize("t, window, block", [
+    (21, 6, 8), (37, 6, 16), (37, None, 16), (21, 200, 8), (300, 130, 64)])
+def test_blocked_attention_equals_masked_full_attention(t, window, block):
+    """T is not a multiple of the block in any case; a band wider than
+    the row is full attention; (300, 130, 64) has a band whose aligned
+    start is past 0."""
+    rng = np.random.default_rng(t)
+    q = jnp.asarray(rng.standard_normal((2, 3, t, 8)).astype(np.float32))
+    k, v = (jnp.asarray(rng.standard_normal((2, t, 8)).astype(np.float32))
+            for _ in range(2))
+    got = blocked_causal_attention(q, k, v, window=window, block=block)
+    scores = jnp.einsum("grqd,gkd->grqk", q, k) / np.sqrt(8)
+    back = np.arange(t)[:, None] - np.arange(t)[None, :]
+    seen = (back >= 0) & (back < (window or t))
+    want = jnp.einsum("grqk,gkd->grqd", jax.nn.softmax(
+        jnp.where(seen, scores, -jnp.inf), axis=-1), v)
+    assert close(got, want)
+
+
+# -------------------------------------------------- through the engine
+
+def gossip_config(**model_kw) -> ExperimentConfig:
+    traffic = json.loads(
+        (ROOT / "benchmark/traffic/localsgd2-t4096.json").read_text())
+    return ExperimentConfig(
+        name="decoder-toy", seed=3, mesh_devices=1,
+        data=DataConfig(dataset="synthetic_tokens", num_users=2, iid=True,
+                        synthetic_train_size=8, synthetic_test_size=2),
+        model=ModelConfig(model="laguna", faithful=False, num_classes=VOCAB,
+                          input_shape=(T,),
+                          decoder=toy_decoder([4, 6, 6], **model_kw)),
+        optim=OptimizerConfig(lr=0.05, momentum=0.9),
+        gossip=GossipConfig(**{**traffic["gossip"], "local_bs": 2}))
+
+
+def test_two_gossip_rounds_equal_the_reference_loop():
+    """``GossipTrainer`` on token rows against ``benchmark.reference``'s
+    gossip loop: same initial parameters, batches and mixing matrices,
+    two rounds of two steps a worker."""
+    from dopt.engine import GossipTrainer
+
+    cfg = gossip_config()
+    traffic = {"engine": "gossip", "eval": "none"}
+    trainer = adapter.build_trainer(cfg, traffic)
+    assert isinstance(trainer, GossipTrainer)
+    init = adapter.initial_params(trainer, traffic)
+    rounds = adapter.reference_rounds(trainer, cfg, traffic, 2)
+    trainer.run(rounds=2)
+    got = adapter.final_params(trainer, traffic)
+    want = reference.run_gossip(
+        functools.partial(ref.objective, spec=TOY), init, rounds,
+        lr=cfg.optim.lr, momentum=cfg.optim.momentum)
+    moved = reference.max_abs_error(want, [init] * 2)
+    assert moved > 1e-3
+    assert reference.max_abs_error(got, want) <= 1e-4 * moved
+    rows = trainer.history.rows
+    assert [r["round"] for r in rows] == [0, 1]
+    for r in rows:
+        assert np.isfinite(r["avg_train_loss"])
+        assert 0.0 < r["moe_held_slot_share"] < 1.0
+        assert r["moe_load_max_over_mean"] >= 1.0
+
+
+def test_the_cell_averages_the_two_workers():
+    from dopt.engine import GossipTrainer
+
+    trainer = GossipTrainer(gossip_config(), eval_every=10**9)
+    for t in range(3):
+        np.testing.assert_array_equal(trainer.mixing.for_round(t),
+                                      [[0.5, 0.5], [0.5, 0.5]])
+
+
+def test_blocked_rounds_equal_per_round_rows():
+    """The fused multi-round path carries the routing counts too."""
+    from dopt.engine import GossipTrainer
+
+    a = GossipTrainer(gossip_config(), eval_every=10**9)
+    b = GossipTrainer(gossip_config(), eval_every=10**9)
+    a.run(rounds=2)
+    b.run(rounds=2, block=2)
+    for ra, rb in zip(a.history.rows, b.history.rows):
+        assert ra.keys() == rb.keys()
+        for k in ra:
+            assert ra[k] == pytest.approx(rb[k], rel=1e-5), k
+
+
+def test_refusals():
+    from dopt.data import partition
+    from dopt.engine import GossipTrainer
+    from dopt.models import build_model
+
+    with pytest.raises(ValueError, match="local_holdout"):
+        cfg = gossip_config()
+        GossipTrainer(cfg.replace(data=DataConfig(
+            **{**cfg.data.__dict__, "local_holdout": 0.1})))
+    with pytest.raises(ValueError, match="IID only"):
+        partition(np.zeros((8, T), np.int32), 2, iid=False)
+    with pytest.raises(ValueError, match="needs ModelConfig.decoder"):
+        build_model("laguna")
+    with pytest.raises(TypeError):       # a key the dataclass lacks
+        ModelConfig(model="laguna", decoder={"no_such_key": 1})
+    with pytest.raises(ValueError, match="not among"):
+        toy_decoder([4], held=4, offset=14)
+
+
+def test_compiled_round_carries_the_decoder_scopes():
+    """``dopt_attn``, ``dopt_moe`` with ``dopt_route`` inside it and
+    ``dopt_head`` mark the decoder's forward and backward inside the
+    local phase; the update and the mix keep their own scopes."""
+    import re
+
+    from jax._src.config import enable_compilation_cache
+
+    from dopt.engine import GossipTrainer
+
+    _, lowered = GossipTrainer(gossip_config(),
+                               eval_every=10**9).lower_round(1)
+    with enable_compilation_cache(False):   # the key ignores metadata
+        text = lowered.compile().as_text()
+    stacks = {s for s in re.findall(r'op_name="([^"]*)"', text)
+              if s.startswith("jit(")}
+    for scope in ("dopt_attn", "dopt_moe", "dopt_route", "dopt_head"):
+        mine = {s for s in stacks if scope in s}
+        assert mine, scope
+        assert all("dopt_local" in s for s in mine if "dopt_eval" not in s)
+        assert any("transpose(jvp(" in s for s in mine), scope
+    assert all("dopt_moe" in s for s in stacks if "dopt_route" in s)
+    assert not [s for s in stacks if "dopt_attn" in s and "dopt_moe" in s]
+    for scope in ("dopt_update", "dopt_mix", "dopt_batch"):
+        assert any(scope in s for s in stacks), scope
+
+
+def test_the_federated_engine_refuses_the_decoder_with_a_pointer():
+    from dopt.config import FederatedConfig
+    from dopt.engine import FederatedTrainer
+
+    cfg = gossip_config()
+    with pytest.raises(ValueError, match="gossip engine"):
+        FederatedTrainer(cfg.replace(gossip=None,
+                                     federated=FederatedConfig()))
+
+
+# ------------------------------------------------ the fused attention kernel
+
+@pytest.mark.parametrize("window", [None, 100])
+def test_splash_attention_equals_blocked_attention(window):
+    """The fused kernel (jax's Pallas TPU splash attention, interpreted
+    on the CPU) against the ``jax.numpy`` blocks: outputs and gradients, a
+    causal and a banded mask, 2 query heads on each of 2 key/value heads."""
+    from dopt.models.decoder import splash_causal_attention
+
+    rng = np.random.default_rng(5)
+    t, d = 256, 128
+    q = jnp.asarray(rng.standard_normal((2, 2, t, d)).astype(np.float32))
+    k, v = (jnp.asarray(rng.standard_normal((2, t, d)).astype(np.float32))
+            for _ in range(2))
+    w = jnp.asarray(rng.standard_normal((2, 2, t, d)).astype(np.float32))
+
+    def blocked(q, k, v):
+        return blocked_causal_attention(q, k, v, window=window, block=128)
+
+    def splash(q, k, v):
+        return splash_causal_attention(q / np.sqrt(d), k, v, window=window,
+                                       block=128)
+
+    assert close(splash(q, k, v), blocked(q, k, v))
+    got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2))(
+        q, k, v) for f in (splash, blocked))
+    assert all(close(g, x) for g, x in zip(got, want))
+
+
+def test_decoder_takes_the_kernel_where_the_shapes_fit():
+    """Only the kernel's shape limits choose between the two bodies of
+    ``causal_attention``: the benchmark's cell (4,096 positions, heads of
+    128, the module's own block) takes the kernel, and so does every
+    layer of the preset and of the benchmark's configuration file.  At
+    256 positions and heads of 128 a worker with blocks of 128 takes it
+    and one with blocks of 64 (not a multiple of the chip's 128 lanes)
+    ``jax.numpy``: the loss and every gradient leaf agree."""
+    from dopt.models.decoder import attention_path
+    from dopt.presets import get_preset
+
+    assert attention_path(4096, 128) == "splash"
+    assert attention_path(256, 128, 128) == "splash"
+    for t, d, block in [(256, 128, 64), (384, 128, 128), (256, 8, 128),
+                        (21, 8, 8), (48, 128, 512)]:
+        assert attention_path(t, d, block) == "blocked"
+    for model in (get_preset("laguna-localsgd2").model,
+                  ModelConfig(**PUBLISHED["model"])):
+        assert attention_path(model.input_shape[0],
+                              model.decoder.head_dim) == "splash"
+    kw = dict(head_dim=128, num_key_value_heads=1, sliding_window=100)
+    x = np.random.default_rng(7).integers(0, VOCAB, (2, 256)).astype(np.int32)
+    y = np.concatenate([x[:, 1:], np.full((2, 1), -1, np.int32)], 1)
+    w = np.ones(2, np.float32)
+    blocked, splash = (toy_model([2, 1], attn_block=block, **kw)
+                       for block in (64, 128))
+    params = blocked.init(jax.random.key(2))["params"]
+    (want, _), want_g = jax.value_and_grad(
+        lambda p: blocked.loss(p, x, y, w), has_aux=True)(params)
+    (got, _), got_g = jax.value_and_grad(
+        lambda p: splash.loss(p, x, y, w), has_aux=True)(params)
+    assert abs(float(got) - float(want)) <= RTOL * float(want)
+    assert all(jax.tree.leaves(jax.tree.map(close, got_g, want_g)))
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e (nothing is attached: the TPU's
+    compiler is installed here and compiles for it).  Only this file of
+    the suite loads the TPU's library, and only inside this fixture."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("heads, window", [(6, None), (8, 512)])
+def test_splash_kernels_compile_for_the_chip_at_any_ambient_precision(
+        v5e_chip, monkeypatch, precision, heads, window):
+    """The forward and backward attention kernels at the published sizes
+    (4,096 positions, 8 key/value heads of 128, 48 / 64 query heads)
+    through the real XLA:TPU + Mosaic compile.  ``highest`` is what the
+    benchmark's parity check sets around the whole program: Mosaic
+    refuses bfloat16 operands at that precision, so the kernels pin their
+    own (found on the chip, PERF.md PR 28).  Compile only: nothing runs."""
+    from dopt.models.decoder import splash_causal_attention
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def loss(q, k, v):
+        out = splash_causal_attention(q, k, v, window=window, block=512)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=v5e_chip)
+
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape(8, heads, 4096, 128), shape(8, 4096, 128),
+            shape(8, 4096, 128)).compile()
+    text = compiled.as_text()
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
