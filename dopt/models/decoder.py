@@ -34,8 +34,12 @@ the same mathematics arranged for the chip:
   num_experts`` of them: ROADMAP R3);
 * the output head and the loss a block of ``HEAD_BLOCK`` positions at a
   time;
-* a layer, an attention block and a head block are ``jax.checkpoint``-ed
-  (a layer keeps the fused attention kernel's output and nothing else).
+* a layer, an attention block and a head block are ``jax.checkpoint``-ed.
+  A layer keeps its matmul products (``LAYER_KEEPS``): the fused attention
+  kernel's output, q / k / v as the attention takes them, the per-head
+  gate's and the router's products, the residual stream after the output
+  projection and every gated MLP's gate and up products, so its backward
+  pass recomputes elementwise work and the router's top-k, and no matmul.
 
 Scopes (inside the engines' ``dopt_local``): ``dopt_attn`` (normed input
 to gated output projection; the splash kernels carry no name stack and
@@ -53,13 +57,29 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from dopt.config import DecoderConfig
 
-# What a layer's ``jax.checkpoint`` keeps of its forward pass: the fused
-# attention kernel's output and log-sum-exp (64 MB a layer and worker at
-# the benchmark's cell), nothing else.
+# What a layer's ``jax.checkpoint`` keeps of its forward pass
+# (``LAYER_KEEPS``).  ``ATTN_RESIDUALS``: the fused attention kernel's
+# output and log-sum-exp.  ``MATMUL_PRODUCTS``: every value whose
+# recompute would be a matmul, in the dtype the forward pass already
+# holds it in: q (rotated, cast and, for the kernel, scaled), k and v as
+# the attention takes them, the per-head gate's and the router's
+# products before their sigmoids, the float32 residual stream after the
+# output projection, and the gate and up products of the dense MLP, the
+# shared expert and the held experts.  A tag sits on the product itself,
+# not past an activation: the backward pass of ``sigmoid`` or ``top_k``
+# asks for that primitive's own output, which no name reaches.  At the
+# benchmark's cell that is 181-236 MB a layer, worker and row beside the
+# kernel's 51-68 (1.01 GB over the five layers, PERF.md, PR 29), and it
+# grows with rows x positions x layers a worker.  The recompute keeps
+# norms, casts, activations, the gated attention output, the combine
+# weights and the router's top-k.
 ATTN_RESIDUALS = "attn_residuals"
+MATMUL_PRODUCTS = "matmul_products"
+LAYER_KEEPS = (ATTN_RESIDUALS, MATMUL_PRODUCTS)
 # The routing counts ``loss`` returns beside "acc", in history-row order.
 COUNTERS = ("moe_held_slot_share", "moe_load_max_over_mean")
 # Queries a block of attention, and positions a block of the output head
@@ -71,6 +91,11 @@ HEAD_BLOCK = 1024
 # Every matrix is normal(0, INITIALIZER_RANGE), every norm weight 1 (the
 # published config carries no initializer).
 INITIALIZER_RANGE = 0.02
+
+
+def _keep(x):
+    """``x`` under the name a layer's ``jax.checkpoint`` keeps."""
+    return checkpoint_name(x, MATMUL_PRODUCTS)
 
 
 def _rms(x, weight, eps):
@@ -184,11 +209,12 @@ def causal_attention(q, k, v, *, window: int | None, block: int = ATTN_BLOCK):
     heads, t, d = q.shape
     grouped = (k.shape[0], heads // k.shape[0], t, d)
     if attention_path(t, d, block) == "splash":
-        return splash_causal_attention(
-            (q / math.sqrt(d)).astype(k.dtype).reshape(grouped), k, v,
-            window=window, block=block)
-    return blocked_causal_attention(q.astype(k.dtype).reshape(grouped), k, v,
-                                    window=window, block=block)
+        attend, q = splash_causal_attention, q / math.sqrt(d)
+    else:
+        attend = blocked_causal_attention
+    # kept across a layer's remat as the body takes it: each body's own cast
+    q = _keep(q.astype(k.dtype))
+    return attend(q.reshape(grouped), k, v, window=window, block=block)
 
 
 def splash_causal_attention(q, k, v, *, window: int | None, block: int):
@@ -250,8 +276,8 @@ def splash_causal_attention(q, k, v, *, window: int | None, block: int):
 
 
 def _gated_mlp(p, x, dtype):
-    g = jnp.dot(x, p["gate"].astype(dtype))
-    u = jnp.dot(x, p["up"].astype(dtype))
+    g = _keep(jnp.dot(x, p["gate"].astype(dtype)))
+    u = _keep(jnp.dot(x, p["up"].astype(dtype)))
     return jnp.dot(jax.nn.silu(g) * u, p["down"].astype(dtype))
 
 
@@ -332,26 +358,26 @@ class GatedMoEDecoder:
                     preferred_element_type=jnp.float32)
 
             q = _rotary(heads_of("q", heads), rope)
-            k = _rotary(heads_of("k", kv), rope).astype(dt)
-            v = heads_of("v", kv).astype(dt)
+            k = _keep(_rotary(heads_of("k", kv), rope).astype(dt))
+            v = _keep(heads_of("v", kv).astype(dt))
             window = (c.sliding_window if kind == "sliding_attention"
                       else None)
             out = causal_attention(q, k, v, window=window,
                                    block=self.attn_block)
-            gate = jax.nn.sigmoid(jnp.einsum(
+            gate = jax.nn.sigmoid(_keep(jnp.einsum(
                 "td,dn->nt", x, p["gate"].astype(dt),
-                preferred_element_type=jnp.float32))
+                preferred_element_type=jnp.float32)))
             out = out.reshape(heads, t, hd) * gate[..., None].astype(dt)
-            return h + jnp.einsum(
+            return _keep(h + jnp.einsum(
                 "nte,ned->td", out, p["o"].astype(dt).reshape(heads, hd, -1),
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32))
 
     def _route(self, router, m):
         """[T, held] combine weights (0 where a token was not routed to
         that held expert) and the step's routing counts."""
         c = self.cfg
-        scores = jax.nn.sigmoid(jnp.dot(
-            m, router, precision=jax.lax.Precision.HIGHEST))
+        scores = jax.nn.sigmoid(_keep(jnp.dot(
+            m, router, precision=jax.lax.Precision.HIGHEST)))
         top, idx = jax.lax.top_k(scores, c.num_experts_per_tok)
         top = (top / jnp.sum(top, -1, keepdims=True)
                * c.moe_routed_scaling_factor)
@@ -374,8 +400,8 @@ class GatedMoEDecoder:
             x = m.astype(dt)
             out = _gated_mlp(p["shared"], x, dt).astype(jnp.float32)
             e = p["experts"]
-            g = jnp.einsum("td,edf->tef", x, e["gate"].astype(dt))
-            u = jnp.einsum("td,edf->tef", x, e["up"].astype(dt))
+            g = _keep(jnp.einsum("td,edf->tef", x, e["gate"].astype(dt)))
+            u = _keep(jnp.einsum("td,edf->tef", x, e["up"].astype(dt)))
             mid = jax.nn.silu(g) * u
             with jax.named_scope("dopt_route"):
                 mid = mid * weight[..., None].astype(dt)
@@ -402,7 +428,7 @@ class GatedMoEDecoder:
             h, c = jax.checkpoint(
                 lambda p, h_, i=i: self._layer(p, h_, i),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    ATTN_RESIDUALS))(params[f"layer{i}"], h)
+                    *LAYER_KEEPS))(params[f"layer{i}"], h)
             if c is not None:
                 counts.append(c)
         if not counts:

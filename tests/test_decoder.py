@@ -342,6 +342,83 @@ def test_the_federated_engine_refuses_the_decoder_with_a_pointer():
                                      federated=FederatedConfig()))
 
 
+# ------------------------------------------ what a layer's checkpoint keeps
+
+def _grad_program(name):
+    """(jitted gradient of the toy's loss, its parameters) for a pattern."""
+    heads, kinds = PATTERNS[name]
+    model = toy_model(heads, **kinds)
+    params = model.init(jax.random.key(4))["params"]
+    x, y, w = batch()
+    return jax.jit(jax.grad(lambda p: model.loss(p, x, y, w)[0])), params
+
+
+def _recomputed_matmuls(grad, params):
+    """Name stacks of the compiled ``dot`` / ``convolution`` instructions
+    under ``rematted_computation``, jax's name for a checkpoint's
+    recompute, but those of the head block and of the attention block's
+    OWN checkpoint (the scope then stands before the recompute's name:
+    the ``jax.numpy`` blocks never hold their scores, which is what the
+    fused kernel's backward does inside itself)."""
+    import re
+
+    from jax._src.config import enable_compilation_cache
+
+    with enable_compilation_cache(False):   # the key ignores metadata
+        text = grad.lower(params).compile().as_text()
+    found = re.findall(r'= \S+ (?:dot|convolution)\(.*op_name="([^"]*)"', text)
+    assert any("rematted_computation" not in s for s in found)
+    return [s for s in found if "rematted_computation" in s
+            and "dopt_head" not in s
+            and "dopt_attn/checkpoint/rematted_computation" not in s]
+
+
+@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+def test_a_layer_recomputes_no_matmul(pattern):
+    """A count, on the CPU: the backward pass computes none of a layer's
+    matmuls again: q / k / v, the per-head gate, the output projection,
+    the router, and the dense, the shared and the held experts' gate and
+    up products are all kept (the down projections feed nothing the
+    backward pass needs)."""
+    assert _recomputed_matmuls(*_grad_program(pattern)) == []
+
+
+def test_the_count_sees_what_a_bare_policy_recomputes(monkeypatch):
+    """The reader of the count above, held to the policy of before: with
+    the kernel's residuals alone kept, the layer's recompute has the
+    q / k / v, output, expert and MLP projections in it."""
+    from dopt.models import decoder
+
+    monkeypatch.setattr(decoder, "LAYER_KEEPS", (decoder.ATTN_RESIDUALS,))
+    stacks = _recomputed_matmuls(*_grad_program("published-pattern"))
+    for name in ("td,dne->nte", "td,dn->nt", "nte,ned->td", "td,edf->tef",
+                 "dopt_route"):
+        assert any(name in s for s in stacks), name
+
+
+@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+def test_gradients_equal_those_without_any_checkpoint(monkeypatch, pattern):
+    """The kept values are the values the recompute would produce: every
+    gradient leaf equals that of the same layers with ``jax.checkpoint``
+    the identity (layer, head block and attention block) to 1e-6 of the
+    leaf's largest value."""
+    from dopt.models import decoder
+
+    grad, params = _grad_program(pattern)
+    got = grad(params)
+    assert "prevent_cse" in str(jax.make_jaxpr(grad)(params))
+    monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
+    monkeypatch.setattr(decoder, "_attend_block",
+                        decoder._attend_block.__wrapped__)
+    plain, _ = _grad_program(pattern)
+    assert "prevent_cse" not in str(jax.make_jaxpr(plain)(params))
+    bad = {jax.tree_util.keystr(k) for (k, g), w in zip(
+        jax.tree_util.tree_leaves_with_path(got),
+        jax.tree.leaves(plain(params)))
+        if not np.abs(g - w).max() <= 1e-6 * np.abs(w).max()}
+    assert not bad
+
+
 # ------------------------------------------------ the fused attention kernel
 
 @pytest.mark.parametrize("window", [None, 100])
