@@ -31,22 +31,20 @@ servers.py:85-93).
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from dopt.config import ExperimentConfig
-from dopt.data import (PrefetchStager, eval_batches, load_dataset,
-                       make_batch_plan, next_block_rounds, partition,
-                       stacked_eval_batches, timed_build)
+from dopt.data import (eval_batches, load_dataset, make_batch_plan,
+                       partition, stacked_eval_batches)
 from dopt.engine.local import (_stacked_eval_scan, flat_input_apply,
                                flat_input_stacked_apply, gather_rows,
                                make_evaluator,
                                make_stacked_local_update,
                                make_stacked_local_update_epochs,
                                prepare_holdout, validate_optimizer)
+from dopt.engine.loop import HostLoop, RoundPath
 from dopt.faults import FaultPlan, churn_ledger_rows, corrupt_update
 from dopt.models import build_model, count_params
 from dopt.optim import admm_dual_ascent, scaffold_control_update
@@ -65,7 +63,7 @@ from dopt.utils.profiling import PhaseTimers
 from dopt.utils.prng import host_rng
 
 
-class FederatedTrainer:
+class FederatedTrainer(HostLoop):
     """FedAvg / FedProx / FedADMM / SCAFFOLD with partial participation.
 
     SCAFFOLD exists in the reference only as commented-out dead code
@@ -125,9 +123,7 @@ class FederatedTrainer:
         # post-fetch boundary, so the compiled device programs are
         # independent of it either way.
         self.telemetry = None
-        # Serve-mode hooks (dopt.serve): see GossipTrainer — same
-        # contract, same controller protocol.
-        self._suppress_run_summary = False
+        # Serve-mode hook (dopt.serve): see GossipTrainer.
         self.checkpoint_writer = True
 
         w = cfg.data.num_users
@@ -1975,80 +1971,32 @@ class FederatedTrainer:
                                     self._pop_sharding)
         return meta
 
-    def _run_population(self, rounds: int, checkpoint_every: int = 0,
-                        checkpoint_path=None) -> History:
-        """Population-mode training loop: one jitted wave-scan dispatch
-        per round (the K-wave scan already amortises dispatch the way
-        blocked execution does for the lane engines; cohort size never
-        retraces).  With ``prefetch='on'`` the loop runs dispatch →
-        stage-next → fetch: round t+1's cohort is drawn (main thread)
-        and its wave plans built/staged (background thread) while round
-        t runs; participation is committed post-fetch, staging never
-        crosses a checkpoint boundary, and client quarantine was
-        rejected at construction (its eligibility feedback only exists
-        after the fetch)."""
-        t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        stager = PrefetchStager() if self._prefetch else None
-        try:
-            self._population_loop(rounds, checkpoint_every,
-                                  checkpoint_path, stager)
-        finally:
-            if stager is not None:
-                stager.discard()
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
-
-    def _population_loop(self, rounds: int, checkpoint_every: int,
-                         checkpoint_path, stager) -> None:
-        for r in range(rounds):
-            # Staging never crosses a checkpoint boundary.
-            ckpt_next = (checkpoint_every
-                         and (self.round + 1) % checkpoint_every == 0)
-            with self.timers.step(self.round):
-                self._population_round(
-                    stager, stage_next=r + 1 < rounds and not ckpt_next)
-                if checkpoint_every and self.round % checkpoint_every == 0:
-                    self.save(checkpoint_path)
-
-    def _population_round(self, stager, stage_next: bool) -> None:
-        """One population round: plan (unless staged), dispatch, stage
-        the next round while the device runs (prefetch), wait, fetch,
-        record."""
+    def _population_path(self) -> RoundPath:
+        """Population mode: one jitted wave-scan dispatch per round (the
+        K-wave scan already amortises dispatch the way blocked
+        execution does for the lane engines; cohort size never
+        retraces).  With ``prefetch='on'`` round t+1's cohort is drawn
+        (main thread) and its wave plans built/staged (background
+        thread) while round t runs; participation is committed
+        post-fetch, and client quarantine was rejected at construction
+        (its eligibility feedback only exists after the fetch)."""
         reg = self._registry
-        t = self.round
-        payload = stager.take(t) if stager is not None else None
-        if payload is None:
-            with self.timers.phase("host_batch_plan"):
-                payload = self._build_pop_round(
-                    self._draw_pop_round(t))
-        binding, rows = payload["binding"], payload["rows"]
-        step_kw = ({"cmasks": jnp.asarray(payload["cmask"])}
-                   if self._has_corrupt else {})
-        args = (self.theta, payload["idx"], payload["bw"],
-                payload["valids"], payload["lim"], self._train_x,
-                self._train_y, *self._eval)
-        if stager is None:
-            self.theta, packed = self.timers.measure(
-                "round_step", self._pop_round_fn, *args, **step_kw)
-        else:
-            with self.timers.phase("round_step"):
-                with self.timers.phase("round_dispatch"):
-                    out = self._pop_round_fn(*args, **step_kw)
-                if stage_next:
-                    with self.timers.phase("host_batch_plan"):
-                        meta = self._draw_pop_round(t + 1)
-                    stager.stage(
-                        t + 1,
-                        timed_build(self._build_pop_round,
-                                    self.timers),
-                        meta)
-                with self.timers.phase("round_wait"):
-                    jax.block_until_ready(out)
+
+        def launch(payload):
+            step_kw = ({"cmasks": jnp.asarray(payload["cmask"])}
+                       if self._has_corrupt else {})
+            args = (self.theta, payload["idx"], payload["bw"],
+                    payload["valids"], payload["lim"], self._train_x,
+                    self._train_y, *self._eval)
+            return "pop_round_fn", self._pop_round_fn, args, step_kw
+
+        def commit(out):
             self.theta, packed = out
-        with self.timers.phase("round_fetch"):
-            packed = np.asarray(packed)   # ONE device→host fetch/round
-        with self.timers.phase("round_record"):
+            return packed
+
+        def record(payload, packed):
+            t, binding, rows = (payload["t"], payload["binding"],
+                                payload["rows"])
             ll, acc, loss_sum, t_loss, t_acc = (float(v)
                                                 for v in packed[:5])
             n = len(binding.survivors)
@@ -2075,42 +2023,48 @@ class FederatedTrainer:
             self._round_telemetry(t, rows)
             self.round += 1
 
-    def _run_blocked(self, frac: float, rounds: int, block: int,
-                     checkpoint_every: int = 0,
-                     checkpoint_path=None) -> History:
-        """Run ``rounds`` rounds in fused blocks of up to ``block``.
-        Periodic auto-checkpoints land at block boundaries (the state
-        only exists on the host there).  Compact + faults runs
-        fixed-width validity-masked lanes; quarantine / staleness runs
-        route to ``_run_blocked_chaos`` (their round-to-round state is
-        scan carry).  With ``prefetch='on'`` both loops run dispatch →
-        stage-next → fetch: the next block's participation draws stay
-        on the main thread (in block order, so the sampling stream is
-        byte-identical) and its plan build + device staging overlap the
-        current block's device time; staging never crosses a scheduled
-        checkpoint boundary."""
-        if self._quarantine_on or self._has_stale:
-            # Both force the full-width path (run() keeps
-            # compact+quarantine per-round; staleness rejects compact).
-            return self._run_blocked_chaos(
-                frac, rounds, block, checkpoint_every=checkpoint_every,
-                checkpoint_path=checkpoint_path)
+        return RoundPath(draw=lambda ts: self._draw_pop_round(ts[0]),
+                         build=self._build_pop_round, launch=launch,
+                         commit=commit, record=record,
+                         prefetch=self._prefetch)
+
+    def _blocked_path(self, frac: float, block: int) -> RoundPath:
+        """The path that fuses up to ``block`` rounds into one scan.
+        Compact + faults runs fixed-width validity-masked lanes
+        (survivor counts are data, not shapes).  With ``prefetch='on'``
+        the next block's participation draws stay on the main thread
+        (in block order, so the sampling stream is byte-identical) and
+        its plan build + device staging overlap the current block's
+        device time."""
         compact = self._use_compact(frac)
         fixed_c = compact and self.faults.active
-        t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        next_ckpt = (self.round // checkpoint_every + 1) * checkpoint_every \
-            if checkpoint_every else None
-        stager = PrefetchStager() if self._prefetch else None
-        try:
-            self._blocked_loop(frac, rounds, block, next_ckpt,
-                               checkpoint_every, checkpoint_path, stager,
-                               compact, fixed_c)
-        finally:
-            if stager is not None:
-                stager.discard()
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
+        fn_name = ("compact_fault_block_fn" if fixed_c
+                   else "compact_block_fn" if compact else "block_fn")
+        fn = getattr(self, "_" + fn_name)
+
+        def launch(payload):
+            step_kw = {}
+            if self._has_corrupt:
+                step_kw["cmasks"] = payload["cms"]
+            if fixed_c:
+                step_kw["valids"] = payload["valids"]
+            args = (self.theta, self.params, self.momentum,
+                    *self._dual_inputs(), payload["gates"],
+                    payload["limits"], payload["idx"], payload["bw"],
+                    self._train_x, self._train_y, *self._eval,
+                    self._train_eval_idx, self._train_eval_w, *self._val)
+            return fn_name, fn, args, step_kw
+
+        def record(payload, packed):
+            lanes = len(payload["lane_sels"][0]) if compact else None
+            for j, t in enumerate(payload["ts"]):
+                self._record_round(t, packed[j], payload["sels"][j],
+                                   payload["frows"][j], lanes)
+
+        return RoundPath(
+            draw=lambda ts: self._draw_block(ts, frac, compact, fixed_c),
+            build=self._build_block, launch=launch, commit=self._commit,
+            record=record, block=block, prefetch=self._prefetch)
 
     def _draw_block(self, ts: list, frac: float, compact: bool,
                     fixed_c: bool) -> dict:
@@ -2178,109 +2132,7 @@ class FederatedTrainer:
                 np.stack([p.weight for p in plans]), block_sharding)
         return meta
 
-    def _blocked_loop(self, frac, rounds, block, next_ckpt,
-                      checkpoint_every, checkpoint_path, stager,
-                      compact, fixed_c) -> None:
-        done = 0
-        while done < rounds:
-            k = min(block, rounds - done)
-            ts = [self.round + j for j in range(k)]
-            next_ts = next_block_rounds(ts, rounds - (done + k), block,
-                                        next_ckpt)
-            with self.timers.step(ts[0]):
-                self._run_block(ts, next_ts, frac, stager, compact, fixed_c)
-                done += k
-                if next_ckpt is not None and self.round >= next_ckpt:
-                    self.save(checkpoint_path)
-                    next_ckpt = (self.round // checkpoint_every + 1) \
-                        * checkpoint_every
-
-    def _run_block(self, ts, next_ts, frac, stager, compact,
-                   fixed_c) -> None:
-        """One fused block: plan (unless staged), dispatch, stage
-        ``next_ts`` while the device runs (prefetch), wait, fetch,
-        record."""
-        payload = stager.take(ts[0]) if stager is not None else None
-        if payload is None:
-            with self.timers.phase("host_batch_plan"):
-                payload = self._build_block(
-                    self._draw_block(ts, frac, compact, fixed_c))
-        sels, frows = payload["sels"], payload["frows"]
-        lane_sels = payload["lane_sels"]
-        duals_in = self.duals if self.duals is not None else {}
-        c_in = self.c_global if self.c_global is not None else {}
-        fn = (self._compact_fault_block_fn if fixed_c
-              else self._compact_block_fn if compact
-              else self._block_fn)
-        step_kw = {}
-        if self._has_corrupt:
-            step_kw["cmasks"] = payload["cms"]
-        if fixed_c:
-            step_kw["valids"] = payload["valids"]
-        args = (self.theta, self.params, self.momentum, duals_in,
-                c_in, payload["gates"], payload["limits"],
-                payload["idx"], payload["bw"], self._train_x,
-                self._train_y, *self._eval, self._train_eval_idx,
-                self._train_eval_w, *self._val)
-        if stager is None:
-            out = self.timers.measure("round_step", fn, *args,
-                                      **step_kw)
-        else:
-            # dispatch → stage-next → fetch (see gossip.py): the
-            # next block's participation draw stays on this thread,
-            # its plan build overlaps this block's device time.
-            with self.timers.phase("round_step"):
-                with self.timers.phase("round_dispatch"):
-                    out = fn(*args, **step_kw)
-                if next_ts:
-                    with self.timers.phase("host_batch_plan"):
-                        meta = self._draw_block(next_ts, frac, compact,
-                                                fixed_c)
-                    stager.stage(
-                        next_ts[0],
-                        timed_build(self._build_block, self.timers),
-                        meta)
-                with self.timers.phase("round_wait"):
-                    jax.block_until_ready(out)
-        (self.theta, self.params, self.momentum, new_duals, new_c,
-         packed) = out
-        if self.duals is not None:
-            self.duals = new_duals
-        if self.c_global is not None:
-            self.c_global = new_c
-        with self.timers.phase("round_fetch"):
-            packed = np.asarray(packed)  # ONE device→host fetch per block
-        with self.timers.phase("round_record"):
-            lanes = len(lane_sels[0]) if compact else self.num_workers
-            for j, t in enumerate(ts):
-                ll, acc, loss_sum, t_loss, t_acc, scr, _, em, diag = \
-                    self._unpack_host_metrics(packed[j], lanes)
-                flags = (scr[:len(sels[j])] if compact else scr[sels[j]])
-                self._apply_screen_feedback(t, sels[j], flags, frows[j])
-                self.history.faults.extend(frows[j])
-                self.history.append(
-                    round=t,
-                    test_acc=acc,
-                    test_loss=loss_sum,  # P1 summed-loss flavour
-                    train_loss=t_loss,
-                    train_acc=t_acc,
-                    local_loss=ll,
-                )
-                if self._holdout:
-                    em = ({k_: v[:len(sels[j])] for k_, v in em.items()}
-                          if compact
-                          else {k_: v[sels[j]] for k_, v in em.items()})
-                    self._append_client_rows(t, em, sels[j])
-                self._round_telemetry(t, frows[j], diag)
-                self.round += 1
-            self._device_telemetry(
-                ts[-1],
-                "compact_fault_block_fn" if fixed_c
-                else "compact_block_fn" if compact else "block_fn", fn)
-
-    def _run_blocked_chaos(self, frac: float, rounds: int, block: int,
-                           checkpoint_every: int = 0,
-                           checkpoint_path=None) -> History:
+    def _chaos_path(self, frac: float, block: int) -> RoundPath:
         """Fused blocked execution for the modes whose round-to-round
         state used to pin them per-round: quarantine (streak/until) and
         staleness-aware aggregation (admission schedule + the one-slot
@@ -2288,23 +2140,75 @@ class FederatedTrainer:
         decided on device from the pre-drawn candidate lists, and the
         host replays the identical integer logic post-fetch so the
         ledger rows (and their order) are bit-identical to per-round
-        execution."""
-        w = self.num_workers
-        m = max(int(frac * w), 1)
-        t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        next_ckpt = (self.round // checkpoint_every + 1) * checkpoint_every \
-            if checkpoint_every else None
-        stager = PrefetchStager() if self._prefetch else None
-        try:
-            self._blocked_chaos_loop(frac, rounds, block, m, next_ckpt,
-                                     checkpoint_every, checkpoint_path,
-                                     stager)
-        finally:
-            if stager is not None:
-                stager.discard()
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
+        execution.  Both modes force the full-width path (``run`` keeps
+        compact+quarantine per-round; staleness rejects compact)."""
+        m = max(int(frac * self.num_workers), 1)
+        # The scan's final streak/until/admission carry, held from
+        # ``commit`` to ``record``'s check against the host replay.
+        dev_carry: list = []
+
+        def launch(payload):
+            stacks = payload["stacks"]
+            # The carry inputs (streaks, admission schedule) are read
+            # HERE, at dispatch time, after the previous block's
+            # replay — only the plan payload is staged ahead.
+            args = (self.theta, self.params, self.momentum,
+                    *self._dual_inputs(),
+                    jnp.asarray(self._screen_streak.astype(np.int32)),
+                    jnp.asarray(self._quarantine_until.astype(np.int32)),
+                    jnp.asarray(self._stale_admit_round.astype(np.int32)),
+                    jnp.asarray(self._stale_weight.astype(np.float32)),
+                    self._stale_p if self._has_stale else {},
+                    jnp.asarray(m, jnp.int32),
+                    jnp.asarray(payload["ts"], jnp.int32),
+                    jnp.asarray(payload["chosen"]),
+                    stacks["away"], stacks["crashed"], stacks["unreach"],
+                    stacks["straggler"], stacks["up_drop"],
+                    stacks["up_delay"], stacks["late_d"],
+                    stacks["limits"], stacks["corrupt"], payload["idx"],
+                    payload["bw"], self._train_x, self._train_y,
+                    *self._eval,
+                    self._train_eval_idx, self._train_eval_w,
+                    *self._val)
+            return "chaos_block_fn", self._chaos_block_fn, args, {}
+
+        def commit(out):
+            dev_carry[:] = out[5:9]
+            return self._commit(out)
+
+        def record(payload, packed):
+            for j, t in enumerate(payload["ts"]):
+                # Post-fetch ledger replay: host quarantine/staleness
+                # mirrors are current through round t-1's flags, so
+                # this regenerates exactly the per-round path's rows —
+                # and the same candidate draw is reused, not re-drawn.
+                (sel, _lim, _cm, frows, _cap,
+                 _admit) = self._round_participation(
+                     t, frac, chosen=payload["chosen"][j])
+                self._record_round(t, packed[j], sel, frows)
+            # The host replay and the device carry apply the same rule
+            # to the same flags; drift is a bug, surfaced loudly.
+            dev_stk, dev_unt, dev_sta, dev_stw = dev_carry
+            ok = (np.array_equal(np.asarray(dev_stk),
+                                 self._screen_streak.astype(np.int32))
+                  and np.array_equal(np.asarray(dev_unt),
+                                     self._quarantine_until.astype(np.int32)))
+            if self._has_stale:
+                ok = ok and np.array_equal(
+                    np.asarray(dev_sta),
+                    self._stale_admit_round.astype(np.int32))
+                ok = ok and np.array_equal(
+                    np.asarray(dev_stw),
+                    self._stale_weight.astype(np.float32))
+            if not ok:
+                raise RuntimeError(
+                    "fused-chaos host replay diverged from the device "
+                    "scan carry")
+
+        return RoundPath(
+            draw=lambda ts: self._draw_chaos_block(ts, frac),
+            build=self._build_block, launch=launch, commit=commit,
+            record=record, block=block, prefetch=self._prefetch)
 
     def _draw_chaos_block(self, ts: list, frac: float) -> dict:
         """Stateful half of one chaos block's staging: the candidate
@@ -2323,135 +2227,6 @@ class FederatedTrainer:
                                        "up_delay", "late_d", "limits",
                                        "corrupt")}}
 
-    def _blocked_chaos_loop(self, frac, rounds, block, m, next_ckpt,
-                            checkpoint_every, checkpoint_path,
-                            stager) -> None:
-        done = 0
-        while done < rounds:
-            k = min(block, rounds - done)
-            ts = [self.round + j for j in range(k)]
-            next_ts = next_block_rounds(ts, rounds - (done + k), block,
-                                        next_ckpt)
-            with self.timers.step(ts[0]):
-                self._run_chaos_block(ts, next_ts, frac, m, stager)
-                done += k
-                if next_ckpt is not None and self.round >= next_ckpt:
-                    self.save(checkpoint_path)
-                    next_ckpt = (self.round // checkpoint_every + 1) \
-                        * checkpoint_every
-
-    def _run_chaos_block(self, ts, next_ts, frac, m, stager) -> None:
-        """One fused chaos block: plan (unless staged), dispatch, stage
-        ``next_ts`` while the device runs (prefetch), wait, fetch,
-        replay and record."""
-        w = self.num_workers
-        payload = stager.take(ts[0]) if stager is not None else None
-        if payload is None:
-            with self.timers.phase("host_batch_plan"):
-                payload = self._build_block(
-                    self._draw_chaos_block(ts, frac))
-        chosen, stacks = payload["chosen"], payload["stacks"]
-        duals_in = self.duals if self.duals is not None else {}
-        c_in = self.c_global if self.c_global is not None else {}
-        sp_in = self._stale_p if self._has_stale else {}
-        args = (self.theta, self.params, self.momentum, duals_in,
-                c_in,
-                jnp.asarray(self._screen_streak.astype(np.int32)),
-                jnp.asarray(self._quarantine_until.astype(np.int32)),
-                jnp.asarray(self._stale_admit_round.astype(np.int32)),
-                jnp.asarray(self._stale_weight.astype(np.float32)),
-                sp_in, jnp.asarray(m, jnp.int32),
-                jnp.asarray(ts, jnp.int32), jnp.asarray(chosen),
-                stacks["away"], stacks["crashed"], stacks["unreach"],
-                stacks["straggler"], stacks["up_drop"],
-                stacks["up_delay"], stacks["late_d"],
-                stacks["limits"], stacks["corrupt"], payload["idx"],
-                payload["bw"], self._train_x, self._train_y,
-                *self._eval,
-                self._train_eval_idx, self._train_eval_w,
-                *self._val)
-        if stager is None:
-            out = self.timers.measure("round_step",
-                                      self._chaos_block_fn, *args)
-        else:
-            # dispatch → stage-next → fetch; note the carry inputs
-            # (streaks, admission schedule) above are read at
-            # DISPATCH time, after the previous block's replay —
-            # only the plan payload is staged ahead.
-            with self.timers.phase("round_step"):
-                with self.timers.phase("round_dispatch"):
-                    out = self._chaos_block_fn(*args)
-                if next_ts:
-                    with self.timers.phase("host_batch_plan"):
-                        meta = self._draw_chaos_block(next_ts, frac)
-                    stager.stage(
-                        next_ts[0],
-                        timed_build(self._build_block, self.timers),
-                        meta)
-                with self.timers.phase("round_wait"):
-                    jax.block_until_ready(out)
-        (self.theta, self.params, self.momentum, new_duals, new_c,
-         dev_stk, dev_unt, dev_sta, dev_stw, new_sp, packed) = out
-        if self.duals is not None:
-            self.duals = new_duals
-        if self.c_global is not None:
-            self.c_global = new_c
-        if self._has_stale:
-            self._stale_p = new_sp
-        with self.timers.phase("round_fetch"):
-            packed = np.asarray(packed)  # ONE device→host fetch per block
-        with self.timers.phase("round_record"):
-            for j, t in enumerate(ts):
-                # Post-fetch ledger replay: host quarantine/staleness
-                # mirrors are current through round t-1's flags, so
-                # this regenerates exactly the per-round path's rows —
-                # and the same candidate draw is reused, not re-drawn.
-                (sel, _lim, _cm, frows, _cap,
-                 _admit) = self._round_participation(t, frac,
-                                                     chosen=chosen[j])
-                ll, acc, loss_sum, t_loss, t_acc, scr, sscr, em, diag = \
-                    self._unpack_host_metrics(packed[j], w)
-                self._apply_screen_feedback(t, sel, scr[sel], frows)
-                if self._has_stale and sscr is not None:
-                    for i in np.nonzero(sscr > 0.5)[0]:
-                        frows.append({
-                            "round": int(t), "worker": int(i),
-                            "kind": "staleness",
-                            "action": "screened_nonfinite_on_admission"})
-                self.history.faults.extend(frows)
-                self.history.append(
-                    round=t,
-                    test_acc=acc,
-                    test_loss=loss_sum,  # P1 summed-loss flavour
-                    train_loss=t_loss,
-                    train_acc=t_acc,
-                    local_loss=ll,
-                )
-                if self._holdout:
-                    em = {k_: v[sel] for k_, v in em.items()}
-                    self._append_client_rows(t, em, sel)
-                self._round_telemetry(t, frows, diag)
-                self.round += 1
-            self._device_telemetry(ts[-1], "chaos_block_fn",
-                                   self._chaos_block_fn)
-            # The host replay and the device carry apply the same rule
-            # to the same flags; drift is a bug, surfaced loudly.
-            ok = (np.array_equal(np.asarray(dev_stk),
-                                 self._screen_streak.astype(np.int32))
-                  and np.array_equal(np.asarray(dev_unt),
-                                     self._quarantine_until.astype(np.int32)))
-            if self._has_stale:
-                ok = ok and np.array_equal(
-                    np.asarray(dev_sta),
-                    self._stale_admit_round.astype(np.int32))
-                ok = ok and np.array_equal(
-                    np.asarray(dev_stw),
-                    self._stale_weight.astype(np.float32))
-            if not ok:
-                raise RuntimeError(
-                    "fused-chaos host replay diverged from the device "
-                    "scan carry")
-
     def run(self, frac: float | None = None, rounds: int | None = None,
             block: int | None = None, checkpoint_every: int = 0,
             checkpoint_path=None) -> History:
@@ -2465,7 +2240,7 @@ class FederatedTrainer:
         resumed from the latest checkpoint is bit-identical to a
         continuous run (stateless fault/batch streams + persisted
         sampling-RNG state)."""
-        cfg, f = self.cfg, self.cfg.federated
+        f = self.cfg.federated
         frac = f.frac if frac is None else frac
         rounds = f.rounds if rounds is None else rounds
         block = f.block_rounds if block is None else block
@@ -2475,102 +2250,96 @@ class FederatedTrainer:
             # Population mode: frac/block are lane-engine knobs — the
             # cohort size comes from the registry, and each round is
             # already one fused wave-scan dispatch.
-            return self._run_population(
-                rounds, checkpoint_every=checkpoint_every,
-                checkpoint_path=checkpoint_path)
-        if block > 1 and not (self._quarantine_on
-                              and self._use_compact(frac)):
+            path = self._population_path()
+        elif block > 1 and not (self._quarantine_on
+                                and self._use_compact(frac)):
             # Every mode but compact+quarantine is blocked-eligible:
-            # compact+faults runs fixed-width validity-masked lanes
-            # (survivor counts are data, not shapes), and quarantine /
-            # staleness-aware runs fuse through the chaos scan whose
-            # carry holds the streaks, the admission schedule and the
-            # one-slot late-update buffer.  Compact+quarantine stays
-            # per-round: its gather indices are host data but depend on
-            # the device-side quarantine state.
-            return self._run_blocked(frac, rounds, block,
-                                     checkpoint_every=checkpoint_every,
-                                     checkpoint_path=checkpoint_path)
-        t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        for _ in range(rounds):
-            with self.timers.step(self.round):
-                self._run_round(frac)
-                if checkpoint_every and self.round % checkpoint_every == 0:
-                    self.save(checkpoint_path)
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
+            # quarantine / staleness-aware runs fuse through the chaos
+            # scan, whose carry holds their round-to-round state.
+            # Compact+quarantine stays per-round: its gather indices
+            # are host data but depend on the device-side quarantine
+            # state.
+            path = (self._chaos_path(frac, block)
+                    if self._quarantine_on or self._has_stale
+                    else self._blocked_path(frac, block))
+        else:
+            path = self._round_path(frac)
+        return self._run_loop(path, rounds, checkpoint_every,
+                              checkpoint_path)
 
-    def _run_round(self, frac: float) -> None:
-        """One round of the per-round loop: plan, dispatch and wait,
-        fetch, record (span tree in ``dopt.utils.profiling``)."""
-        t = self.round
-        with self.timers.phase("host_batch_plan"):
-            (fn_name, step_fn, args, step_kw, sel, sel_lanes,
-             use_c, frows) = self._round_dispatch(t, frac)
-        out = self.timers.measure("round_step", step_fn, *args,
-                                  **step_kw)
-        (self.theta, self.params, self.momentum, new_duals,
-         new_c) = out[:5]
-        if self._has_stale:
-            self._stale_p = out[5]
-        packed = out[-1]
+    def _round_path(self, frac: float) -> RoundPath:
+        """The per-round path: the payload is ``_round_dispatch``'s
+        tuple, the ONE builder ``lower_round`` also consumes.  It reads
+        the carried state, so nothing of it can be staged ahead."""
+
+        def record(dispatch, packed):
+            sel, sel_lanes, use_c, frows = dispatch[4:]
+            self._record_round(self.round, packed, sel, frows,
+                               len(sel_lanes) if use_c else None)
+
+        return RoundPath(
+            draw=lambda ts: self._round_dispatch(ts[0], frac),
+            launch=lambda dispatch: dispatch[:4], commit=self._commit,
+            record=record)
+
+    def _dual_inputs(self) -> tuple:
+        """(duals, c_global) as a lane round program takes them: an
+        algorithm without one passes an empty tree."""
+        return (self.duals if self.duals is not None else {},
+                self.c_global if self.c_global is not None else {})
+
+    def _commit(self, out):
+        """Assign the lane engines' carried state from a round
+        program's result — per-round, blocked or chaos: theta, params,
+        momentum, duals and c_global lead, the packed metrics are last
+        and a staleness run's late-update buffer sits just before
+        them — and return the packed metrics."""
+        self.theta, self.params, self.momentum, new_duals, new_c = out[:5]
         if self.duals is not None:
             self.duals = new_duals
         if self.c_global is not None:
             self.c_global = new_c
-        lanes = len(sel_lanes) if use_c else self.num_workers
-        with self.timers.phase("round_fetch"):
-            packed = np.asarray(packed)  # ONE device→host fetch/round
-        with self.timers.phase("round_record"):
-            ll, acc, loss_sum, t_loss, t_acc, scr, sscr, em, diag = \
-                self._unpack_host_metrics(packed, lanes)
-            # Compact lanes are survivors-first: the valid prefix holds
-            # the real flags (padding lanes' flags are discarded).
-            flags = scr[:len(sel)] if use_c else scr[sel]
-            self._apply_screen_feedback(t, sel, flags, frows)
-            if self._has_stale and sscr is not None:
-                for i in np.nonzero(sscr > 0.5)[0]:
-                    frows.append({"round": int(t), "worker": int(i),
-                                  "kind": "staleness",
-                                  "action": "screened_nonfinite_on_admission"})
-            self.history.faults.extend(frows)
-            self.history.append(
-                round=t,
-                test_acc=acc,
-                test_loss=loss_sum,   # P1 summed-loss flavour
-                train_loss=t_loss,
-                train_acc=t_acc,
-                local_loss=ll,
-            )
-            if self._holdout:
-                em = ({k_: v[:len(sel)] for k_, v in em.items()} if use_c
-                      else {k_: v[sel] for k_, v in em.items()})
-                self._append_client_rows(t, em, sel)
-            self._round_telemetry(t, frows, diag)
-            self._device_telemetry(t, fn_name, step_fn)
-            self.round += 1
+        if self._has_stale:
+            self._stale_p = out[-2]
+        return out[-1]
 
-    def run_served(self, controller) -> str:
-        """Resident serve-mode entry (``dopt.serve``): train one round
-        at a time until the round-boundary ``controller`` says
-        otherwise.  Same contract as ``GossipTrainer.run_served``:
-        ``controller.boundary(trainer)`` runs at every round boundary
-        and returns ``"run"`` | ``"drain"`` | ``"restart"`` |
-        ``"rebuild"``; the end-of-run summary gauge is emitted exactly
-        once, at the drain boundary."""
-        self._suppress_run_summary = True
-        try:
-            while True:
-                verdict = controller.boundary(self)
-                if verdict != "run":
-                    if verdict == "drain":
-                        self._suppress_run_summary = False
-                        self._run_summary_telemetry()
-                    return verdict
-                self.run(rounds=1)
-        finally:
-            self._suppress_run_summary = False
+    def _record_round(self, t: int, vec: np.ndarray, sel, frows: list,
+                      lanes: int | None = None) -> None:
+        """Round ``t``'s host record from its fetched metrics vector:
+        screen feedback of the surviving sampled clients ``sel`` into
+        the ledger rows and quarantine streaks, the history row, the
+        client rows, the telemetry bundle; advances ``self.round``.
+        ``lanes`` is a compact program's lane count — its lanes are
+        survivors-first, so the valid prefix holds the real flags and
+        padding lanes' are discarded; None is the full-width program,
+        indexed by worker id."""
+        ll, acc, loss_sum, t_loss, t_acc, scr, sscr, em, diag = \
+            self._unpack_host_metrics(
+                vec, self.num_workers if lanes is None else lanes)
+
+        def of_sel(v):
+            return v[sel] if lanes is None else v[:len(sel)]
+
+        self._apply_screen_feedback(t, sel, of_sel(scr), frows)
+        if sscr is not None:
+            for i in np.nonzero(sscr > 0.5)[0]:
+                frows.append({"round": int(t), "worker": int(i),
+                              "kind": "staleness",
+                              "action": "screened_nonfinite_on_admission"})
+        self.history.faults.extend(frows)
+        self.history.append(
+            round=t,
+            test_acc=acc,
+            test_loss=loss_sum,   # P1 summed-loss flavour
+            train_loss=t_loss,
+            train_acc=t_acc,
+            local_loss=ll,
+        )
+        if self._holdout:
+            self._append_client_rows(
+                t, {k: of_sel(v) for k, v in em.items()}, sel)
+        self._round_telemetry(t, frows, diag)
+        self.round += 1
 
     def _round_dispatch(self, t: int, frac: float):
         """Round ``t``'s device dispatch, fully built: ``(fn_name,
@@ -2614,8 +2383,6 @@ class FederatedTrainer:
             idx = jax.device_put(plan.idx, self._sharding)
             bweight = jax.device_put(plan.weight, self._sharding)
             lim_dev = jnp.asarray(limits)
-        duals_in = self.duals if self.duals is not None else {}
-        c_in = self.c_global if self.c_global is not None else {}
         step_fn = self._compact_fn if use_c else self._round_fn
         gate = jnp.asarray(sel_lanes) if use_c else jnp.asarray(mask)
         step_kw = ({"cmask": jnp.asarray(
@@ -2629,8 +2396,8 @@ class FederatedTrainer:
                 stale_p=self._stale_p,
                 admit_w=jnp.asarray(admit),
                 capture=jnp.asarray(cap))
-        args = (self.theta, self.params, self.momentum, duals_in, c_in,
-                gate, lim_dev, idx, bweight,
+        args = (self.theta, self.params, self.momentum,
+                *self._dual_inputs(), gate, lim_dev, idx, bweight,
                 self._train_x, self._train_y, *self._eval,
                 self._train_eval_idx, self._train_eval_w, *self._val)
         return ("compact_fn" if use_c else "round_fn", step_fn, args,
@@ -2699,75 +2466,21 @@ class FederatedTrainer:
                     val_acc=float(va[j, e]), val_loss=float(vl[j, e]),
                 )
 
-    # -- telemetry (dopt.obs) ------------------------------------------
-    def _round_telemetry(self, t: int, frows: list, diag=None) -> None:
-        """Emit round t's telemetry bundle: the fault-ledger rows as
-        typed events, the history row just appended as the ``round``
-        event, and the host-mirror state (quarantine streaks, the
-        staleness-buffer schedule, the population registry) plus the
-        fetched on-device diagnostics block (``diagnostics="on"``) as
-        ``gauge`` events.  Everything here derives from the same
-        post-fetch host-replay data on every execution path — called
-        at the identical point of the per-round, blocked, chaos-blocked
-        and population loops — so the streams are bit-identical across
-        paths; ``telemetry=None`` skips it entirely."""
-        tele = self.telemetry
-        if tele is None:
-            return
-        quarantined = int((self._quarantine_until > t).sum())
-        gauges = {
-            "quarantine_active": float(quarantined),
-            "screen_streak_max": float(self._screen_streak.max()),
-            # Denominator gauge for the monitor's fleet-fraction rules
-            # (dopt.obs.rules): lanes eligible to contribute this round.
-            "participating_lanes": float(self.num_workers - quarantined),
-        }
-        if diag is not None:
-            from dopt.obs.events import finite_diag_gauges
+    def _mirror_gauges(self) -> dict:
+        """The staleness-buffer schedule, as of the round just
+        replayed."""
+        if not self._has_stale:
+            return {}
+        return {"stale_pending": float((self._stale_weight > 0).sum()),
+                "stale_weight_total": float(self._stale_weight.sum())}
 
-            gauges.update(finite_diag_gauges(self._diag_keys, diag))
-        if self._has_stale:
-            gauges["stale_pending"] = float((self._stale_weight > 0).sum())
-            gauges["stale_weight_total"] = float(self._stale_weight.sum())
+    def _consensus_operands(self):
+        """The stacked lane params against theta; nothing in population
+        mode (clients are stateless, the stacked lane params are not
+        client state)."""
         if self._registry is not None:
-            reg = self._registry
-            gauges["cohort_size"] = float(reg.cohort_size)
-            # Denominator for the monitor's client-keyed quarantine
-            # storm (population_quarantined / population_size).
-            gauges["population_size"] = float(reg.clients)
-            gauges["population_quarantined"] = float(
-                (reg.quarantine_until > t).sum())
-            gauges["population_sampled_total"] = float(
-                (reg.participation > 0).sum())
-        tele.emit_round_bundle(t, engine=self.engine_kind,
-                               metrics=self.history.rows[-1],
-                               faults=frows, gauges=gauges)
-
-    def _device_telemetry(self, t: int, fn_name: str, fn) -> None:
-        """Non-deterministic resource/compile channel — shared impl in
-        ``dopt.utils.profiling.emit_device_resource``."""
-        from dopt.utils.profiling import emit_device_resource
-
-        emit_device_resource(self, t, fn_name, fn)
-
-    def _consensus_value(self) -> float | None:
-        """Mean over workers of ‖pᵢ − theta‖₂ from the current device
-        state, or None when there is nothing to report (round 0,
-        population mode — clients are stateless, the stacked lane
-        params are not client state — or a diverged fleet)."""
-        if self.round == 0 or self._registry is not None:
             return None
-        if jax.process_count() > 1:
-            # Multi-process fleet: this reduction is a collective over
-            # cross-process-sharded params but only the telemetry-
-            # attached leader calls it — see GossipTrainer.
-            return None
-        import math
-
-        from dopt.obs import consensus_distance
-
-        cd = consensus_distance(self.params, self._theta_single())
-        return cd if math.isfinite(cd) else None
+        return self.params, self._theta_single()
 
     def _theta_single(self):
         """The single global model: row 0 of the carried [W, ...] slab
@@ -2777,49 +2490,11 @@ class FederatedTrainer:
             return jax.tree.map(lambda x: x[0], self.theta)
         return self.theta
 
-    def _run_summary_telemetry(self) -> None:
-        """End-of-``run()`` consensus-distance gauge — one fetch per
-        run() call, so per-round and blocked execution of the same call
-        pattern emit the identical event.  Suppressed under
-        ``diagnostics="on"``: the diag block already carries the
-        per-round ``lane_dispersion`` (the same mean_i ||p_i − theta||
-        meter) in every round bundle, and the end-of-run gauge is
-        per-``run()``-CALL state — a killed-and-resumed run would emit
-        an extra one mid-stream, breaking the gauges-included canonical
-        equality diagnostics guarantees."""
-        tele = self.telemetry
-        if tele is None or self._diag or self._suppress_run_summary:
-            return
-        cd = self._consensus_value()
-        if cd is not None:
-            tele.emit("gauge", round=self.round - 1,
-                      name="consensus_distance", value=cd,
-                      engine=self.engine_kind)
-
-    def save(self, path) -> None:
-        """Checkpoint (theta, stacked params, momentum, duals, round,
-        history, sampling-RNG state).  Persisting the RNG state makes a
-        resumed run draw the SAME client samples a continuous run would
-        — without it, round t after resume replays round 0's sample."""
-        with self.timers.phase("checkpoint"):
-            self._save(path)
-        if self.telemetry is not None:
-            # Cadence telemetry for the monitor's checkpoint-cadence
-            # rule (dopt.obs.rules) — emitted AFTER the atomic save
-            # landed, so the stream never claims a checkpoint a kill
-            # could have torn.  The consensus snapshot rides the
-            # checkpoint event (params are being fetched for
-            # serialization anyway), NOT a gauge: checkpoint timing is
-            # call-pattern state, and gauges must stay identical across
-            # execution paths (ConsensusStallRule(use_checkpoints=True)
-            # opts in).
-            ev = {"round": int(self.round)}
-            cd = self._consensus_value()
-            if cd is not None:
-                ev["consensus_distance"] = cd
-            self.telemetry.emit("checkpoint", **ev)  # dopt: allow-nondet-event -- checkpoint cadence is an execution-path property, documented non-deterministic
-
     def _save(self, path) -> None:
+        """(theta, stacked params, momentum, duals, round, history,
+        sampling-RNG state).  Persisting the RNG state makes a resumed
+        run draw the SAME client samples a continuous run would —
+        without it, round t after resume replays round 0's sample."""
         from dopt.utils.checkpoint import save_checkpoint
 
         # Fused runs carry theta as the [W, ...] broadcast slab with

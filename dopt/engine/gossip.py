@@ -24,16 +24,14 @@ Round accounting follows the reference: ``self.round`` persists across
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from dopt.config import ExperimentConfig
-from dopt.data import (PrefetchStager, eval_batches, load_dataset,
-                       make_batch_plan, next_block_rounds, partition,
-                       sharded_eval_batches, timed_build)
+from dopt.data import (eval_batches, load_dataset, make_batch_plan,
+                       partition, sharded_eval_batches)
 from dopt.engine.local import (_stacked_eval_scan,
                                flat_input_stacked_apply, gather_rows,
                                make_evaluator,
@@ -42,6 +40,7 @@ from dopt.engine.local import (_stacked_eval_scan,
                                make_stacked_local_update_gather,
                                model_objective, pick_gather_chunks,
                                prepare_holdout, validate_optimizer)
+from dopt.engine.loop import HostLoop, RoundPath
 from dopt.models import build_model, count_params
 from dopt.parallel.collectives import (MIX_PRECISION, buckets_to_stacked,
                                         make_codec_plan,
@@ -97,7 +96,7 @@ def random_matching_matrix(n: int, rng: np.random.Generator) -> np.ndarray:
     return w
 
 
-class GossipTrainer:
+class GossipTrainer(HostLoop):
     """D-SGD / no-consensus / FedLCon / GossipLearning on the mesh.
 
     algorithm (cfg.gossip.algorithm):
@@ -164,12 +163,9 @@ class GossipTrainer:
         # python-gated host code after the post-fetch boundary, so the
         # compiled device programs are independent of it either way.
         self.telemetry = None
-        # Serve-mode hooks (dopt.serve): ``run_served`` drives the loop
-        # one round per controller tick and defers the end-of-run
-        # summary gauge to the drain boundary; followers of a
-        # multi-process serve fleet participate in checkpoint
-        # collectives but leave the write to the leader.
-        self._suppress_run_summary = False
+        # Serve-mode hook (dopt.serve): followers of a multi-process
+        # serve fleet participate in checkpoint collectives but leave
+        # the write to the leader.
         self.checkpoint_writer = True
 
         w = cfg.data.num_users
@@ -1722,20 +1718,8 @@ class GossipTrainer:
             [(t % self.eval_every) == 0 for t in ts], dtype=bool)
         return meta
 
-    def _stage_block(self, stager: PrefetchStager, ts: list) -> None:
-        """Draw block ``ts``'s inputs now (main thread, in order) and
-        hand the pure build to the stager's background thread."""
-        with self.timers.phase("host_batch_plan"):
-            meta = self._draw_block(ts)
-        stager.stage(ts[0], timed_build(self._build_block, self.timers),
-                     meta)
-
-    def _run_blocked(self, rounds: int, block: int,
-                     checkpoint_every: int = 0,
-                     checkpoint_path=None) -> History:
-        """Run ``rounds`` rounds in fused blocks of up to ``block``.
-        Periodic auto-checkpoints land at block boundaries (the state
-        only exists on the host there).
+    def _blocked_path(self, block: int) -> RoundPath:
+        """The path that fuses up to ``block`` rounds into one scan.
 
         EVERY gossip mode is blocked-eligible: clean/faulted runs fuse
         as before; link-mode runs (msg_drop/msg_delay/push-sum) scan
@@ -1744,162 +1728,71 @@ class GossipTrainer:
         runs carry the streak/until state on device and the host
         REPLAYS the per-round ledger logic post-fetch (same rows, same
         order — the screened flags it needs only exist after the block
-        lands).
+        lands)."""
+        link, fused_quar = self._link_mode, self._fused_quar
+        # The scan's final streak/until carry, held from ``commit`` to
+        # ``record``'s check against the host replay.
+        dev_quar: list = []
 
-        With ``prefetch='on'`` the loop runs dispatch → stage-next →
-        fetch: block b's dispatch is asynchronous, block b+1's plans
-        are drawn (main thread, in order) and built/staged (background
-        thread) while b runs on device, and the fetch barrier lands
-        after staging started.  Staging never crosses a scheduled
-        checkpoint boundary — the block after a checkpoint builds
-        inline from the committed state — so checkpoints capture
-        exactly the committed rounds and resume stays bit-exact.
-        ``prefetch='off'`` runs the exact pre-change host loop."""
-        link = self._link_mode
-        fused_quar = self._fused_quar
-        t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        next_ckpt = (self.round // checkpoint_every + 1) * checkpoint_every \
-            if checkpoint_every else None
-        stager = PrefetchStager() if self._prefetch else None
-        try:
-            self._blocked_loop(rounds, block, next_ckpt, checkpoint_every,
-                               checkpoint_path, stager, link, fused_quar)
-        finally:
-            if stager is not None:
-                stager.discard()
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
+        def launch(payload):
+            step_kw = ({"cmasks": jnp.asarray(payload["cmasks"])}
+                       if self._has_corrupt else {})
+            common = (payload["w_mats"], payload["alive"],
+                      payload["limits"],
+                      jnp.asarray(payload["ts"], jnp.int32),
+                      payload["idx"], payload["bw"],
+                      jnp.asarray(payload["is_eval"]), self._train_x,
+                      self._train_y, *self._eval, *self._val)
+            if link:
+                return ("link_block_fn", self._link_block_fn,
+                        (self.params, self.momentum, self._mass,
+                         self._link_buf, self._link_buf_mass, *common),
+                        step_kw)
+            if fused_quar:
+                step_kw.update(
+                    streak=jnp.asarray(
+                        self._screen_streak.astype(np.int32)),
+                    until=jnp.asarray(
+                        self._quarantine_until.astype(np.int32)))
+            else:
+                if self._async:
+                    step_kw.update(prev=self._async_prev,
+                                   wdiags=jnp.asarray(payload["wdiags"]))
+                if self._fused_on:
+                    step_kw["fbuf"] = self._fused_buf
+                if self._codec_on:
+                    step_kw["cres"] = self._comm_res
+            return ("block_fn", self._block_fn,
+                    (self.params, self.momentum, self.x_hat, *common),
+                    step_kw)
 
-    def _blocked_loop(self, rounds, block, next_ckpt, checkpoint_every,
-                      checkpoint_path, stager, link, fused_quar) -> None:
-        done = 0
-        while done < rounds:
-            k = min(block, rounds - done)
-            ts = [self.round + j for j in range(k)]
-            next_ts = next_block_rounds(ts, rounds - (done + k), block,
-                                        next_ckpt)
-            with self.timers.step(ts[0]):
-                self._run_block(ts, next_ts, stager, link, fused_quar)
-                done += k
-                if next_ckpt is not None and self.round >= next_ckpt:
-                    self.save(checkpoint_path)
-                    next_ckpt = (self.round // checkpoint_every + 1) \
-                        * checkpoint_every
+        def commit(out):
+            if fused_quar:
+                dev_quar[:] = out[3:5]
+                out = out[:3] + out[5:]
+            return self._commit(out)
 
-    def _run_block(self, ts, next_ts, stager, link, fused_quar) -> None:
-        """One fused block of the blocked loop: plan (unless staged),
-        dispatch, stage ``next_ts`` while the device runs (prefetch),
-        wait, fetch, record."""
-        payload = stager.take(ts[0]) if stager is not None else None
-        if payload is None:
-            with self.timers.phase("host_batch_plan"):
-                payload = self._build_block(self._draw_block(ts))
-        w_raws, frows = payload["w_raws"], payload["frows"]
-        alive, is_eval = payload["alive"], payload["is_eval"]
-        step_kw = ({"cmasks": jnp.asarray(payload["cmasks"])}
-                   if self._has_corrupt else {})
-        common = (payload["w_mats"], alive, payload["limits"],
-                  jnp.asarray(ts, jnp.int32), payload["idx"],
-                  payload["bw"], jnp.asarray(is_eval), self._train_x,
-                  self._train_y, *self._eval, *self._val)
-        if link:
-            fn = self._link_block_fn
-            args = (self.params, self.momentum, self._mass,
-                    self._link_buf, self._link_buf_mass, *common)
-        elif fused_quar:
-            step_kw.update(
-                streak=jnp.asarray(
-                    self._screen_streak.astype(np.int32)),
-                until=jnp.asarray(
-                    self._quarantine_until.astype(np.int32)))
-            fn = self._block_fn
-            args = (self.params, self.momentum, self.x_hat, *common)
-        else:
-            if self._async:
-                step_kw.update(prev=self._async_prev,
-                               wdiags=jnp.asarray(payload["wdiags"]))
-            if self._fused_on:
-                step_kw["fbuf"] = self._fused_buf
-            if self._codec_on:
-                step_kw["cres"] = self._comm_res
-            fn = self._block_fn
-            args = (self.params, self.momentum, self.x_hat, *common)
-        if stager is None:
-            out = self.timers.measure("round_step", fn, *args,
-                                      **step_kw)
-        else:
-            # dispatch → stage-next → fetch: the jit dispatch
-            # returns before the device finishes, the next block's
-            # staging overlaps this block's device time, and
-            # block_until_ready is the fetch barrier the old
-            # measure() call provided.
-            with self.timers.phase("round_step"):
-                with self.timers.phase("round_dispatch"):
-                    out = fn(*args, **step_kw)
-                if next_ts:
-                    self._stage_block(stager, next_ts)
-                with self.timers.phase("round_wait"):
-                    jax.block_until_ready(out)
-        dev_streak = dev_until = None
-        if link:
-            (self.params, self.momentum, self._mass, self._link_buf,
-             self._link_buf_mass, packed) = out
-        elif fused_quar:
-            (self.params, self.momentum, self.x_hat, dev_streak,
-             dev_until, packed) = out
-        elif self._async:
-            (self.params, self.momentum, self.x_hat,
-             self._async_prev, packed) = out
-        elif self._fused_on:
-            (self.params, self.momentum, self.x_hat,
-             self._fused_buf, packed) = out
-        elif self._codec_on:
-            (self.params, self.momentum, self.x_hat,
-             self._comm_res, packed) = out
-        else:
-            (self.params, self.momentum, self.x_hat, packed) = out
-        with self.timers.phase("round_fetch"):
-            packed = np.asarray(packed)  # ONE device→host fetch per block
-        with self.timers.phase("round_record"):
-            for j, t in enumerate(ts):
-                (tl, ta, acc, lm, scr, em, diag,
-                 counts) = self._unpack_host_metrics(packed[j])
+        def record(payload, packed):
+            for j, t in enumerate(payload["ts"]):
                 if fused_quar:
                     # Post-fetch ledger replay: host state is now
                     # current through round t-1's flags, so this
                     # regenerates exactly the per-round path's rows
                     # (and host-mirror mutations) for round t.
                     (_w, alive_j, _lim, _cm, rows_j,
-                     quar_j) = self._round_inputs(t, w_raw=w_raws[j])
-                    alive_eff = alive_j * (1.0 - quar_j)
-                    self._apply_screen_feedback(t, alive_eff, scr, rows_j)
-                    self.history.faults.extend(rows_j)
+                     quar_j) = self._round_inputs(
+                         t, w_raw=payload["w_raws"][j])
+                    alive_j = alive_j * (1.0 - quar_j)
                 else:
-                    if self._robust_active:
-                        self._apply_screen_feedback(t, alive[j], scr,
-                                                    frows[j])
-                    self.history.faults.extend(frows[j])
-                row = {
-                    "round": t,
-                    "avg_train_loss": tl,
-                    "avg_train_acc": ta,
-                    **counts,
-                }
-                if is_eval[j]:
-                    row["avg_test_acc"] = acc
-                    row["avg_test_loss"] = lm
-                self.history.append(**row)
-                if self._holdout:
-                    self._append_client_rows(t, em)
-                self._round_telemetry(t, rows_j if fused_quar else frows[j],
-                                      diag)
-                self.round += 1
+                    alive_j, rows_j = payload["alive"][j], payload["frows"][j]
+                self._record_round(t, packed[j], alive_j, rows_j,
+                                   payload["is_eval"][j])
             if fused_quar:
                 # The host replay and the device carry apply the same
                 # integer rule to the same flags — drift here means a
                 # real bug, caught loudly rather than as silent trace
                 # divergence.
+                dev_streak, dev_until = dev_quar
                 if not (np.array_equal(np.asarray(dev_streak),
                                        self._screen_streak.astype(np.int32))
                         and np.array_equal(
@@ -1908,8 +1801,73 @@ class GossipTrainer:
                     raise RuntimeError(
                         "fused-quarantine host replay diverged from the "
                         "device scan carry")
-            self._device_telemetry(
-                ts[-1], "link_block_fn" if link else "block_fn", fn)
+
+        return RoundPath(draw=self._draw_block, build=self._build_block,
+                         launch=launch, commit=commit, record=record,
+                         block=block, prefetch=self._prefetch)
+
+    def _round_path(self) -> RoundPath:
+        """The per-round path: the payload is ``_round_dispatch``'s
+        tuple, the ONE builder ``lower_round`` also consumes.  It reads
+        the carried state, so nothing of it can be staged ahead."""
+
+        def record(dispatch, packed):
+            alive, quar, frows, do_eval = dispatch[4:]
+            if self._fused_quar:
+                alive = alive * (1.0 - quar)
+            self._record_round(self.round, packed, alive, frows, do_eval)
+
+        return RoundPath(draw=lambda ts: self._round_dispatch(ts[0]),
+                         launch=lambda dispatch: dispatch[:4],
+                         commit=self._commit, record=record)
+
+    def _commit(self, out):
+        """Assign the carried state from a round program's result — one
+        round's or a block's, the tuple is the same — and return the
+        packed metrics, always its last element."""
+        *state, packed = out
+        if self._link_mode:
+            (self.params, self.momentum, self._mass, self._link_buf,
+             self._link_buf_mass) = state
+        elif self._async:
+            (self.params, self.momentum, self.x_hat,
+             self._async_prev) = state
+        elif self._fused_on:
+            (self.params, self.momentum, self.x_hat,
+             self._fused_buf) = state
+        elif self._codec_on:
+            (self.params, self.momentum, self.x_hat,
+             self._comm_res) = state
+        else:
+            self.params, self.momentum, self.x_hat = state
+        return packed
+
+    def _record_round(self, t: int, vec: np.ndarray, alive, frows: list,
+                      do_eval) -> None:
+        """Round ``t``'s host record from its fetched metrics vector:
+        screen feedback into the ledger rows and quarantine streaks
+        (``alive`` is the round's effective alive mask), the history
+        row, the client rows, the telemetry bundle; advances
+        ``self.round``."""
+        (tl, ta, acc, lm, scr, em, diag,
+         counts) = self._unpack_host_metrics(vec)
+        if self._robust_active:
+            self._apply_screen_feedback(t, alive, scr, frows)
+        self.history.faults.extend(frows)
+        row = {
+            "round": t,
+            "avg_train_loss": tl,
+            "avg_train_acc": ta,
+            **counts,
+        }
+        if do_eval:
+            row["avg_test_acc"] = acc
+            row["avg_test_loss"] = lm
+        self.history.append(**row)
+        if self._holdout:
+            self._append_client_rows(t, em)
+        self._round_telemetry(t, frows, diag)
+        self.round += 1
 
     # ------------------------------------------------------------------
     def _unpack_host_metrics(self, vec: np.ndarray):
@@ -1955,91 +1913,11 @@ class GossipTrainer:
                     val_acc=float(va[i, e]), val_loss=float(vl[i, e]),
                 )
 
-    # -- telemetry (dopt.obs) ------------------------------------------
-    def _round_telemetry(self, t: int, frows: list, diag=None) -> None:
-        """Emit round t's telemetry bundle: the fault-ledger rows as
-        typed events, the history row just appended as the ``round``
-        event, and the host-mirror state (quarantine streaks, the
-        population registry) plus the fetched on-device diagnostics
-        block (``diagnostics="on"``) as ``gauge`` events.  Derived only
-        from post-fetch host-replay data at the identical point of the
-        per-round and blocked loops, so the streams are bit-identical
-        across execution paths; ``telemetry=None`` skips it."""
-        tele = self.telemetry
-        if tele is None:
-            return
-        quarantined = int((self._quarantine_until > t).sum())
-        gauges = {
-            "quarantine_active": float(quarantined),
-            "screen_streak_max": float(self._screen_streak.max()),
-            # Denominator gauge for the monitor's fleet-fraction rules
-            # (dopt.obs.rules): lanes eligible to contribute this round.
-            "participating_lanes": float(self.num_workers - quarantined),
-        }
-        if diag is not None:
-            from dopt.obs.events import finite_diag_gauges
-
-            gauges.update(finite_diag_gauges(self._diag_keys, diag))
-        if self._registry is not None:
-            reg = self._registry
-            gauges["cohort_size"] = float(reg.cohort_size)
-            # Denominator for the monitor's client-keyed quarantine
-            # storm (population_quarantined / population_size).
-            gauges["population_size"] = float(reg.clients)
-            gauges["population_quarantined"] = float(
-                (reg.quarantine_until > t).sum())
-            gauges["population_sampled_total"] = float(
-                (reg.participation > 0).sum())
-        tele.emit_round_bundle(t, engine=self.engine_kind,
-                               metrics=self.history.rows[-1],
-                               faults=frows, gauges=gauges)
-
-    def _device_telemetry(self, t: int, fn_name: str, fn) -> None:
-        """Non-deterministic resource/compile channel — shared impl in
-        ``dopt.utils.profiling.emit_device_resource``."""
-        from dopt.utils.profiling import emit_device_resource
-
-        emit_device_resource(self, t, fn_name, fn)
-
-    def _consensus_value(self) -> float | None:
-        """Mean over workers of ‖xᵢ − x̄‖₂ on the de-biased estimates
-        (push-sum runs measure the ratio estimates — the quantity that
-        actually converges), or None when there is nothing to report
-        (round 0, or a diverged fleet)."""
-        if self.round == 0:
-            return None
-        if jax.process_count() > 1:
-            # Multi-process fleet: the reduction below is a COLLECTIVE
-            # over cross-process-sharded params, but only the telemetry
-            #-attached leader reaches this call site — computing it
-            # would strand the leader in a collective the followers
-            # never join.  Fleets report consensus via diagnostics="on"
-            # (inside the compiled round, all processes) instead.
-            return None
-        import math
-
-        from dopt.obs import consensus_distance
-
-        cd = consensus_distance(self._debiased_params())
-        return cd if math.isfinite(cd) else None
-
-    def _run_summary_telemetry(self) -> None:
-        """End-of-``run()`` consensus-distance gauge — one fetch per
-        run() call; identical across execution paths for an identical
-        call pattern.  Suppressed under ``diagnostics="on"``: the diag
-        block already carries a TRUE per-round consensus distance in
-        every round bundle (watermark-suppressed on resume), and the
-        end-of-run gauge is per-``run()``-CALL state — a killed-and-
-        resumed run would emit an extra one mid-stream, breaking the
-        gauges-included canonical equality diagnostics guarantees."""
-        tele = self.telemetry
-        if tele is None or self._diag or self._suppress_run_summary:
-            return
-        cd = self._consensus_value()
-        if cd is not None:
-            tele.emit("gauge", round=self.round - 1,
-                      name="consensus_distance", value=cd,
-                      engine=self.engine_kind)
+    def _consensus_operands(self):
+        """The de-biased estimates (push-sum runs measure the ratio
+        estimates — the quantity that actually converges), centred on
+        their own mean."""
+        return (self._debiased_params(),)
 
     def _matrix_for_round(self, t: int) -> np.ndarray:
         g = self.cfg.gossip
@@ -2259,7 +2137,7 @@ class GossipTrainer:
         """Train; mirrors ``Simulator.run(rounds)`` / ``FedLCon.run(rounds, eps)``.
 
         ``block`` (default ``cfg.gossip.block_rounds``) > 1 fuses that
-        many rounds into one jit dispatch (``_run_blocked``) — same
+        many rounds into one jit dispatch (``_blocked_path``) — same
         math, same phase order, same eval cadence; only the host/device
         round-trip count changes.
 
@@ -2268,112 +2146,16 @@ class GossipTrainer:
         resumed from the latest checkpoint is bit-identical to a
         continuous run (stateless fault/batch streams + persisted host
         RNG state)."""
-        cfg, g = self.cfg, self.cfg.gossip
+        g = self.cfg.gossip
         rounds = g.rounds if rounds is None else rounds
         if eps is not None and eps != g.eps and g.algorithm == "fedlcon":
             raise ValueError("set eps in GossipConfig (static for compilation)")
         if checkpoint_every and checkpoint_path is None:
             raise ValueError("checkpoint_every requires checkpoint_path")
         block = g.block_rounds if block is None else block
-        if block > 1:
-            # Every mode is blocked-eligible: quarantine rides the scan
-            # carry (streak/until on device, ledger replayed post-fetch),
-            # link-mode (msg_drop/msg_delay/push-sum) carries its mass +
-            # staleness buffers through the scan with the per-round
-            # [D+1, n, n] matrix stacks as stacked inputs.
-            return self._run_blocked(rounds, block,
-                                     checkpoint_every=checkpoint_every,
-                                     checkpoint_path=checkpoint_path)
-        t0 = time.time()  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        for _ in range(rounds):
-            with self.timers.step(self.round):
-                self._run_round()
-                if (checkpoint_every and
-                        self.round % checkpoint_every == 0):
-                    self.save(checkpoint_path)
-        self.total_time = time.time() - t0  # dopt: allow-wallclock -- total_time wall meter, reporting only
-        self._run_summary_telemetry()
-        return self.history
-
-    def _run_round(self) -> None:
-        """One round of the per-round loop: plan, dispatch and wait,
-        fetch, record (span tree in ``dopt.utils.profiling``)."""
-        t = self.round
-        with self.timers.phase("host_batch_plan"):
-            (fn_name, step_fn, args, step_kw, alive, quar, frows,
-             do_eval) = self._round_dispatch(t)
-        out = self.timers.measure("round_step", step_fn, *args,
-                                  **step_kw)
-        if self._link_mode:
-            (self.params, self.momentum, self._mass, self._link_buf,
-             self._link_buf_mass, packed) = out
-        elif self._async:
-            (self.params, self.momentum, self.x_hat,
-             self._async_prev, packed) = out
-        elif self._fused_on:
-            (self.params, self.momentum, self.x_hat,
-             self._fused_buf, packed) = out
-        elif self._codec_on:
-            (self.params, self.momentum, self.x_hat,
-             self._comm_res, packed) = out
-        else:
-            self.params, self.momentum, self.x_hat, packed = out
-        with self.timers.phase("round_fetch"):
-            packed = np.asarray(packed)  # ONE device→host fetch per round
-        with self.timers.phase("round_record"):
-            (tl, ta, acc, lm, scr, em, diag,
-             counts) = self._unpack_host_metrics(packed)
-            if self._robust_active:
-                alive_eff = (alive * (1.0 - quar) if self._fused_quar
-                             else alive)
-                self._apply_screen_feedback(t, alive_eff, scr, frows)
-            self.history.faults.extend(frows)
-            row = {
-                "round": t,
-                "avg_train_loss": tl,
-                "avg_train_acc": ta,
-                **counts,
-            }
-            if do_eval:
-                row["avg_test_acc"] = acc
-                row["avg_test_loss"] = lm
-            self.history.append(**row)
-            if self._holdout:
-                self._append_client_rows(t, em)
-            self._round_telemetry(t, frows, diag)
-            self._device_telemetry(t, fn_name, step_fn)
-            self.round += 1
-
-    def run_served(self, controller) -> str:
-        """Resident serve-mode entry (``dopt.serve``): train one round
-        at a time until the round-boundary ``controller`` says
-        otherwise — the "run until told otherwise" loop a daemon owns
-        instead of a ``--rounds N`` script.
-
-        ``controller.boundary(trainer)`` is called BEFORE each round
-        with the trainer at a consistent round boundary; it may apply
-        control-plane effects (membership directives, checkpoints,
-        ledgered ``control`` rows) and returns ``"run"`` to train one
-        more round or a stop verdict: ``"drain"`` (graceful stop —
-        the one end-of-run summary gauge is emitted here, matching a
-        scripted ``run()``'s cadence), ``"restart"`` (checkpoint and
-        hand control back for a process re-exec; NO summary gauge —
-        the resumed daemon's drain emits it, so an interrupted and an
-        uninterrupted serve emit identical streams), or ``"rebuild"``
-        (the daemon must reconstruct the trainer from an updated
-        config, restore, and call ``run_served`` again)."""
-        self._suppress_run_summary = True
-        try:
-            while True:
-                verdict = controller.boundary(self)
-                if verdict != "run":
-                    if verdict == "drain":
-                        self._suppress_run_summary = False
-                        self._run_summary_telemetry()
-                    return verdict
-                self.run(rounds=1)
-        finally:
-            self._suppress_run_summary = False
+        path = self._blocked_path(block) if block > 1 else self._round_path()
+        return self._run_loop(path, rounds, checkpoint_every,
+                              checkpoint_path)
 
     def _round_dispatch(self, t: int):
         """Round ``t``'s device dispatch, fully built: ``(fn_name,
@@ -2431,29 +2213,10 @@ class GossipTrainer:
         return fn_name, step_fn.lower(*args, **step_kw)
 
     # ------------------------------------------------------------------
-    def save(self, path) -> None:
-        """Checkpoint full training state: params, momentum, round,
-        history, AND host RNG state (the matching RNG is stateful — a
-        resumed 'gossip' run must not replay round-0 matchings)."""
-        with self.timers.phase("checkpoint"):
-            self._save(path)
-        if self.telemetry is not None:
-            # Cadence telemetry for the monitor's checkpoint-cadence
-            # rule (dopt.obs.rules) — emitted AFTER the atomic save
-            # landed, so the stream never claims a checkpoint a kill
-            # could have torn.  The consensus snapshot rides the
-            # checkpoint event (params are being fetched for
-            # serialization anyway), NOT a gauge: checkpoint timing is
-            # call-pattern state, and gauges must stay identical across
-            # execution paths (ConsensusStallRule(use_checkpoints=True)
-            # opts in).
-            ev = {"round": int(self.round)}
-            cd = self._consensus_value()
-            if cd is not None:
-                ev["consensus_distance"] = cd
-            self.telemetry.emit("checkpoint", **ev)  # dopt: allow-nondet-event -- checkpoint cadence is an execution-path property, documented non-deterministic
-
     def _save(self, path) -> None:
+        """Full training state: params, momentum, round, history, AND
+        host RNG state (the matching RNG is stateful — a resumed
+        'gossip' run must not replay round-0 matchings)."""
         from dopt.utils.checkpoint import save_checkpoint
 
         arrays = {"params": self.params, "momentum": self.momentum}

@@ -48,6 +48,7 @@ fetch, identical across paths for an identical call pattern), and
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Iterable, Mapping
 
 from dopt.obs.events import (DETERMINISTIC_KINDS, KINDS, SCHEMA_VERSION,
@@ -202,21 +203,38 @@ def attach(trainer, telemetry: Telemetry, *, fresh: bool = False,
 def consensus_distance(stacked, center=None) -> float:
     """Mean over workers of ‖xᵢ − c‖₂ for a worker-stacked pytree —
     the fleet-disagreement meter.  ``center`` defaults to the stacked
-    mean (gossip); the federated engines pass theta.  One device
-    reduction + one scalar fetch; deterministic for bit-identical
-    inputs, so every execution path of the same run reports the same
-    value."""
+    mean (gossip); the federated engines pass theta.  ONE compiled
+    reduction + one scalar fetch (an eager reduction is dozens of
+    separately launched multi-device ops over a sharded fleet);
+    deterministic for bit-identical inputs, so every execution path of
+    the same run reports the same value."""
+    import jax
+
+    centers = None if center is None else jax.tree.leaves(center)
+    return float(_consensus_reduction()(jax.tree.leaves(stacked), centers))
+
+
+@functools.cache
+def _consensus_reduction():
+    """The jitted body of ``consensus_distance``, built on first use
+    (importing ``dopt.obs`` must not import jax): per leaf the float32
+    sum of squares of each worker's distance from the centre, summed
+    over the leaves in tree order, then sqrt and the mean over
+    workers."""
     import jax
     import jax.numpy as jnp
 
-    leaves = jax.tree.leaves(stacked)
-    centers = (jax.tree.leaves(center) if center is not None
-               else [leaf.astype(jnp.float32).mean(axis=0)
-                     for leaf in leaves])
-    sq = None
-    for p, c in zip(leaves, centers):
-        d = (p.astype(jnp.float32)
-             - c.astype(jnp.float32)[None]).reshape(p.shape[0], -1)
-        s = (d * d).sum(axis=1)
-        sq = s if sq is None else sq + s
-    return float(jnp.sqrt(sq).mean())
+    @jax.jit
+    def reduction(leaves, centers):
+        if centers is None:
+            centers = [leaf.astype(jnp.float32).mean(axis=0)
+                       for leaf in leaves]
+        sq = None
+        for p, c in zip(leaves, centers):
+            d = (p.astype(jnp.float32)
+                 - c.astype(jnp.float32)[None]).reshape(p.shape[0], -1)
+            s = (d * d).sum(axis=1)
+            sq = s if sq is None else sq + s
+        return jnp.sqrt(sq).mean()
+
+    return reduction
