@@ -331,16 +331,100 @@ def _to_grouped_kernel(k):
 
 
 def _conv_fast(z, g_kernel, groups, *, dtype, strides=(1, 1),
-               padding="SAME", bias=None):
+               padding="SAME", bias=None, precision=None):
     """Worker-grouped conv on [B, H, Wd, G·Cin] with a pre-grouped
     [kh, kw, Cin, G·Cout] kernel (``_to_grouped_kernel`` layout)."""
     out = jax.lax.conv_general_dilated(
         z, g_kernel.astype(dtype), strides, padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        feature_group_count=groups)
+        feature_group_count=groups, precision=precision)
     if bias is not None:
         out = out + bias.astype(dtype).reshape(1, 1, 1, -1)
     return out
+
+
+# Lanes of a vector register, and columns of an MXU tile, on the chips
+# this runs on (v5e: 128).
+_LANES = 128
+
+# Stacked width from which conv1 packs at a batch that is NOT whole lane
+# tiles.  An empirical crossover with no lane mechanism behind it: conv1
+# + bias + pool on the v5e, grouped ÷ packed time (over 1: packing
+# wins; ``scripts/conv1_bench.py``, PERF.md §6 PR 31; "fwd" = forward
+# alone, else forward and gradient).  At W = 160 every point wins:
+# B 37 2.02 (fwd 1.98: the ring's holdout eval), 50 1.20 (fwd 1.50),
+# 100 1.10 (fwd 1.60).  At W = 128 they are mixed: B 50 0.98 (fwd 1.21),
+# 100 0.95 (fwd 0.93), 37 fwd 1.71.  Below, packing loses: B = 50:
+# W 8 0.60 (FedAvg's step), 16 0.65, 32 0.65, 64 0.70; B = 100: W 8 0.85,
+# 12 0.50, 32 0.60.
+_CONV1_PACK_WIDTH = 160
+
+
+def _conv1_stacked(z, g_kernel, groups, *, dtype, bias):
+    """The reference CNNs' first convolution over the stacked fleet:
+    ``_conv_fast`` with bias, under the ``dopt_conv1`` scope
+    (``conv1_ms``, PERF.md §3).
+
+    With ONE input channel a worker, ``feature_group_count = W`` is a
+    depthwise convolution with a channel multiplier, which XLA:TPU
+    gives to its vector-unit emitter: 1.4 TFLOP/s and an output in a
+    layout that a 2 GB copy then turns for the pool (PERF.md §5, §6
+    PR 31).  So ``p = 128 // C_out`` consecutive workers share a group
+    (4 at 32 output channels: one MXU tile of output lanes): the input
+    is unchanged, worker-major channels put pack-mates side by side
+    already; the kernel becomes [kh, kw, p, W·C_out], worker b's taps
+    on input channel ``b % p`` of its group and zero on its pack-mates'
+    channels.  The MXU emitter takes that form and writes the layout
+    the pool reads.  The same sums plus exact zeros: the result differs
+    from the grouped form's by float reassociation only.
+
+    The zero blocks are SELECTED (``jnp.where`` on a constant mask),
+    never multiplied in: a worker whose kernel went non-finite must not
+    reach its pack-mates (``NaN · 0 = NaN``), and the selection's
+    gradient keeps the diagonal blocks only.
+
+    The pixels stay float32.  The vector-unit emitter multiplied
+    float32 pixels by a kernel (forward) or a cotangent (weight
+    gradient) at the ambient precision; the MXU at the ambient default
+    would round the pixels to bfloat16 as well.  ``precision=(HIGHEST,
+    ambient)`` keeps the grouped form's operands (three MXU passes for
+    one: 27 ms a round on the ring, where packing gains 315; PERF.md §6
+    PR 31), and jax's transpose rule hands the same pair to the weight
+    gradient, whose first operand is again the pixels.
+
+    The rule reads what the call can see.  Whether it CAN pack: one
+    input channel a worker and a fleet that packs evenly; anything else
+    (Model3's three channels, a fleet of 6, the single global model) is
+    the grouped form, bit for bit.  Whether it PAYS: with a batch of
+    whole lane tiles (B % 128 == 0) the packed output is written
+    batch-minor, as the pool reads it, and packing wins at every width
+    measured, 1.3–3.7× from W = 4 to 160; at any other batch relayouts
+    follow the packed convolution, which only a fleet of
+    ``_CONV1_PACK_WIDTH`` or more outweighs (there the grouped form's
+    vector-unit time has grown past them).  Padding such a batch to
+    whole tiles instead was measured and rejected: FedAvg's 8 × 50
+    step +31 ms a round against the grouped form (PERF.md §6 PR 31).
+    """
+    c_out = g_kernel.shape[-1] // groups
+    p = _LANES // c_out
+    packs = g_kernel.shape[2] == 1 and p > 1 and groups % p == 0
+    pays = z.shape[0] % _LANES == 0 or groups >= _CONV1_PACK_WIDTH
+    with jax.named_scope("dopt_conv1"):
+        if not (packs and pays):
+            return _conv_fast(z, g_kernel, groups, dtype=dtype, bias=bias)
+        ambient = jax.config.jax_default_matmul_precision
+        return _conv_fast(
+            z, _pack_kernel(g_kernel, c_out, p), groups // p, dtype=dtype,
+            bias=bias, precision=(jax.lax.Precision.HIGHEST,
+                                  jax.lax.Precision(ambient or "default")))
+
+
+def _pack_kernel(g_kernel, c_out, p):
+    """[kh, kw, 1, W·C_out] grouped kernel → [kh, kw, p, W·C_out]: worker
+    b's taps on input channel ``b % p``, selected zeros on the rest."""
+    mate = (jnp.arange(g_kernel.shape[-1]) // c_out) % p    # [W·C_out]
+    mine = jnp.arange(p)[:, None] == mate[None, :]          # [p, W·C_out]
+    return jnp.where(mine, g_kernel, jnp.zeros((), g_kernel.dtype))
 
 
 def _group_norm_stacked(z, scale, bias, *, num_workers, groups_per_worker,
@@ -464,6 +548,11 @@ def _make_stacked_cnn_apply(model: "_ReferenceCNN"):
     [W, H', Wd', C2, O] with matching index order.  (Carrying the
     grouped layout through the training scan instead was measured and
     rejected — see ``_make_stacked_resnet_apply``.)
+
+    conv1 alone does not run one group a worker where it can pack:
+    with one input channel a worker that form is a depthwise
+    convolution, which the chip runs on its vector unit; four workers a
+    group run on the MXU (``_conv1_stacked``; PERF.md §5, §6 PR 31).
     """
     faithful, dtype = model.faithful, model.dtype
 
@@ -501,8 +590,8 @@ def _make_stacked_cnn_apply(model: "_ReferenceCNN"):
         # [W, B, H, Wd, C] → [B, H, Wd, W·C] (worker-major channels)
         z = jnp.moveaxis(x.astype(dtype), 0, 3)
         z = z.reshape(*z.shape[:3], -1)
-        z = _conv_fast(z, fp["conv1"]["kernel"], w, dtype=dtype,
-                       bias=fp["conv1"]["bias"])
+        z = _conv1_stacked(z, fp["conv1"]["kernel"], w, dtype=dtype,
+                           bias=fp["conv1"]["bias"])
         if not faithful:
             z = nn.relu(z)
         z = _max_pool_2x2(z)
@@ -552,7 +641,11 @@ def make_stacked_apply(model) -> "callable | None":
     [kh, kw, C, W·Cout] — group w then convolves worker w's channels
     with worker w's kernel, which is precisely the stacked-fleet
     forward.  Prototype measurement: 0.43 ms vs 1.43 ms per fused train
-    step on the headline workload (v5e).
+    step on the headline workload (v5e).  The one exception is the
+    reference CNNs' first convolution, whose single input channel a
+    worker would make that a depthwise convolution on the vector unit:
+    it packs four workers a group with selected zero blocks, so that
+    the MXU takes it (``_conv1_stacked``; PERF.md §6 PR 31).
 
     Returns ``apply(stacked_params, x)`` mapping a [W, ...]-stacked
     param pytree (the engine's native layout) and [W, B, H, Wd, C]

@@ -29,8 +29,11 @@ on-device gather of a step's rows), ``dopt_update`` (momentum SGD),
 ``dopt_eval`` (every evaluation inside a round program; the holdout's
 per-epoch eval is nested in ``dopt_local``), ``dopt_mix`` (consensus or
 aggregation), ``dopt_pool`` (the differentiated 2×2 max-pool's forward
-and backward, nested in ``dopt_local``; ``dopt.models.zoo``), and the
-decoder's, all nested in ``dopt_local`` (``dopt.models.decoder``):
+and backward, nested in ``dopt_local``; ``dopt.models.zoo``),
+``dopt_conv1`` (the reference CNNs' first convolution over the stacked
+fleet with its bias add, forward and backward, nested in ``dopt_local``
+and in ``dopt_eval``; ``dopt.models.zoo``), and the decoder's, all
+nested in ``dopt_local`` (``dopt.models.decoder``):
 ``dopt_attn`` (normed input to gated output projection), ``dopt_moe``
 (router to combined output) with ``dopt_route`` inside it (scores,
 top-k, combine weights and their application, not the expert matmuls),

@@ -306,23 +306,188 @@ def test_stacked_model1_vjp_saves_winner_codes_not_conv_outputs(capsys):
     assert codes == [(b, 7, 7, w * 64), (b, 14, 14, w * 32)], codes
 
 
-def test_stacked_cnn_apply_non_square_input():
+@pytest.mark.parametrize("w, b", [(2, 3), (4, 128)],
+                         ids=["grouped-w2", "packed-w4"])
+def test_stacked_cnn_apply_non_square_input(w, b):
     """The grouped-stacked CNN forward must handle non-square inputs
     (fc1's VALID-conv kernel reshape derives H'/W' from the activation
-    shape, not a square-root guess — ADVICE r4)."""
+    shape, not a square-root guess — ADVICE r4), with conv1 in its
+    grouped form and packed four workers a group."""
     from dopt.models import make_stacked_apply
 
     m = build_model("model1", faithful=False)
     shape = (12, 8, 1)
     p1 = _init(m, shape)
-    stacked = jax.tree.map(lambda a: jnp.stack([a, a]), p1)
+    stacked = jax.tree.map(lambda a: jnp.stack([a] * w), p1)
     x = jnp.asarray(np.random.default_rng(1).normal(
-        size=(2, 3, *shape)), jnp.float32)
+        size=(w, b, *shape)), jnp.float32)
     out = make_stacked_apply(m)(stacked, x)
-    assert out.shape == (2, 3, 10)
-    ref = m.apply({"params": p1}, x[0])
-    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
+    assert out.shape == (w, b, 10)
+    for i in (0, w - 1):
+        ref = m.apply({"params": p1}, x[i])
+        np.testing.assert_allclose(np.asarray(out[i]), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-5)
+
+
+# ---- conv1 of the stacked reference CNNs: four workers a group (PR 31)
+#      where the fleet packs evenly AND the batch is whole lane tiles
+#      (B % 128 == 0) or the fleet is 160 wide; small images keep it cheap
+def _stacked_fleet(name, w, b=128, shape=(8, 8, 1), seed=0, **model_kw):
+    """(model, [W, ...] params of W differently initialised workers,
+    [W, B, ...] inputs, [W, B, classes] cotangent)."""
+    m = build_model(name, **model_kw)
+    keys = jax.random.split(jax.random.PRNGKey(seed), w)
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (w, b, *shape))
+    params = jax.vmap(lambda k: m.init(k, x[0])["params"])(keys)
+    cot = jax.random.normal(jax.random.PRNGKey(seed + 2), (w, b, 10))
+    return m, params, x, cot
+
+
+def _grouped_conv1(monkeypatch):
+    """conv1 as the parent of PR 31 called it: one group a worker."""
+    from dopt.models import zoo
+
+    monkeypatch.setattr(
+        zoo, "_conv1_stacked",
+        lambda z, k, groups, *, dtype, bias: zoo._conv_fast(
+            z, k, groups, dtype=dtype, bias=bias))
+
+
+def _out_and_grads(apply, params, x, cot):
+    """Outputs, and the gradient leaves of ``sum(outputs · cot)``."""
+    return apply(params, x), jax.grad(
+        lambda p: jnp.sum(apply(p, x) * cot))(params)
+
+
+@pytest.mark.parametrize("w, b", [(4, 128), (8, 128), (12, 256), (160, 5)],
+                         ids=["w4-b128", "w8-b128", "w12-b256", "w160-b5"])
+def test_packed_conv1_equals_the_grouped_form(w, b, monkeypatch):
+    """Outputs and every gradient leaf of the stacked Model1 apply, conv1
+    packed four workers a group against one group a worker: the same
+    25-tap sums plus exact zeros, so float32 reassociation only."""
+    from dopt.models import make_stacked_apply
+
+    m, params, x, cot = _stacked_fleet("model1", w, b=b)
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(make_stacked_apply(m)).lower(params, x).as_text()
+        assert f"feature_group_count = {w // 4} " in text
+        out, grads = _out_and_grads(make_stacked_apply(m), params, x, cot)
+        _grouped_conv1(monkeypatch)
+        ref, ref_grads = _out_and_grads(make_stacked_apply(m), params, x,
+                                        cot)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6,
+                               atol=1e-7)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), r in zip(flat, jax.tree.leaves(ref_grads)):
+        scale = float(jnp.abs(r).max())
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(r), rtol=1e-6, atol=1e-6 * scale,
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("name, w, b, shape", [
+    ("model1", 1, 128, (8, 8, 1)), ("model1", 6, 128, (8, 8, 1)),
+    ("model3", 4, 128, (8, 8, 3)), ("model1", 8, 50, (8, 8, 1)),
+    ("model1", 128, 50, (8, 8, 1)),
+], ids=["model1-w1", "model1-w6", "model3-w4", "model1-w8-b50",
+        "model1-w128-b50"])
+def test_conv1_keeps_the_grouped_program(name, w, b, shape, monkeypatch):
+    """The single global model, a fleet that does not pack evenly, a
+    3-channel input, and a narrow fleet whose batch is not whole lane
+    tiles (FedAvg's 8 lanes of 50 rows: packing loses there on the chip)
+    lower to the parent's program, text for text."""
+    from dopt.models import make_stacked_apply
+
+    m, params, x, _ = _stacked_fleet(name, w, b=b, shape=shape)
+    text = jax.jit(make_stacked_apply(m)).lower(params, x).as_text()
+    _grouped_conv1(monkeypatch)
+    assert text == jax.jit(make_stacked_apply(m)).lower(params, x).as_text()
+    assert text.count(f"feature_group_count = {w} ") == 4
+
+
+@pytest.mark.parametrize("ambient, kernel", [
+    (None, "DEFAULT"), ("bfloat16", "DEFAULT"), ("highest", "HIGHEST"),
+], ids=["ambient-unset", "ambient-bfloat16", "ambient-highest"])
+def test_packed_conv1_keeps_the_pixels_float32(ambient, kernel):
+    """The grouped form ran on the vector unit with float32 pixels against
+    a kernel (forward) or cotangent (weight gradient) at the ambient
+    precision; the packed form asks the MXU for the same operands.  conv2,
+    fc1 and fc2 take the ambient precision on both operands, as ever."""
+    from dopt.models import make_stacked_apply
+
+    m, params, x, cot = _stacked_fleet("model1", 8, b=128)
+    apply = make_stacked_apply(m)
+    with jax.default_matmul_precision(ambient):
+        text = jax.jit(jax.grad(
+            lambda p: jnp.sum(apply(p, x) * cot))).lower(params).as_text()
+    conv1 = [line for line in text.splitlines()
+             if "stablehlo.convolution" in line
+             and ("feature_group_count = 2 " in line       # forward
+                  or "batch_group_count = 2 " in line)]    # weight gradient
+    assert len(conv1) == 2
+    for line in conv1:
+        assert (f"precision_config = [#stablehlo<precision HIGHEST>, "
+                f"#stablehlo<precision {kernel}>]") in line
+    rest = [line for line in text.splitlines()
+            if "stablehlo.convolution" in line and line not in conv1]
+    assert rest and not any("precision HIGHEST>, #stablehlo<precision DEFAULT"
+                            in line for line in rest)
+
+
+@pytest.mark.parametrize("poison", [float("nan"), float("inf")],
+                         ids=["nan", "inf"])
+def test_packed_conv1_isolates_a_nonfinite_worker(poison):
+    """Worker 1's conv1 kernel gone non-finite changes no bit of its
+    pack-mates' (0, 2, 3) outputs or gradients, nor of the next pack's:
+    the zero blocks are selected, never multiplied in.  (The corrected
+    head keeps every cotangent finite.  A non-finite COTANGENT is another
+    matter on this backend: XLA:CPU's weight gradient of any grouped
+    convolution spreads it over all groups, in the parent's form too.)"""
+    from dopt.models import make_stacked_apply
+
+    w = 8
+    m, params, x, cot = _stacked_fleet("model1", w, faithful=False)
+    apply = make_stacked_apply(m)
+    assert "feature_group_count = 2 " in jax.jit(apply).lower(
+        params, x).as_text()
+    conv1 = params["conv1"]
+    bad = {**params, "conv1": {
+        **conv1, "kernel": conv1["kernel"].at[1].set(poison)}}
+    out, grads = _out_and_grads(apply, params, x, cot)
+    bad_out, bad_grads = _out_and_grads(apply, bad, x, cot)
+    others = np.array([0, 2, 3, 4, 5, 6, 7])
+    assert np.isfinite(np.asarray(out)).all()
+    assert not np.isfinite(np.asarray(bad_out[1])).any()
+    np.testing.assert_array_equal(np.asarray(bad_out)[others],
+                                  np.asarray(out)[others])
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), bg in zip(flat, jax.tree.leaves(bad_grads)):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_array_equal(
+            np.asarray(bg)[others], np.asarray(g)[others],
+            err_msg=jax.tree_util.keystr(path))
+    assert not np.isfinite(np.asarray(bad_grads["conv2"]["kernel"][1])).all()
+
+
+def test_packed_kernel_gradient_keeps_the_diagonal_blocks():
+    """The selection's transpose: a cotangent that is NaN everywhere off
+    the diagonal blocks (what a diverged pack-mate's output gradient
+    leaves there) gives each worker its own block and nothing else."""
+    from dopt.models.zoo import _pack_kernel
+
+    w, c_out, p = 8, 32, 4
+    g_kernel = jax.random.normal(jax.random.PRNGKey(0), (5, 5, 1, w * c_out))
+    packed, vjp = jax.vjp(lambda k: _pack_kernel(k, c_out, p), g_kernel)
+    own = (np.arange(w * c_out) // c_out) % p           # [W·C_out]
+    diag = np.arange(p)[:, None] == own[None, :]        # [p, W·C_out]
+    np.testing.assert_array_equal(
+        np.asarray(packed), np.where(diag, np.asarray(g_kernel), 0.0))
+    ct = jnp.where(diag, jnp.arange(1.0, w * c_out + 1), jnp.nan)
+    (grad,) = vjp(jnp.broadcast_to(ct, packed.shape))
+    np.testing.assert_array_equal(
+        np.asarray(grad),
+        np.broadcast_to(np.arange(1.0, w * c_out + 1, dtype=np.float32),
+                        g_kernel.shape))
 
 
 def test_resnet_stage_sizes_override():

@@ -221,6 +221,63 @@ def test_compiled_model1_round_carries_the_pool_scope():
     assert not [s for s in pool if "dopt_eval" in s]
 
 
+def _model1_round_text(num_users):
+    """Compiled HLO text of a one-device Model1 gossip round with a
+    holdout: training steps of 128 rows, the holdout's eval (48 rows a
+    worker) and the fleet eval."""
+    cfg = _gossip_cfg(num_users=num_users, local_holdout=0.25,
+                      synthetic_train_size=192 * num_users,
+                      synthetic_test_size=16)
+    cfg = dataclasses.replace(
+        cfg, mesh_devices=1,
+        model=ModelConfig(model="model1", input_shape=(28, 28, 1)),
+        gossip=dataclasses.replace(cfg.gossip, local_ep=1, local_bs=128))
+    _, lowered = GossipTrainer(cfg, eval_every=1).lower_round()
+    with enable_compilation_cache(False):
+        return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("num_users, groups", [(8, 2), (6, 6)],
+                         ids=["fleet8-packed", "fleet6-grouped"])
+def test_compiled_model1_round_carries_the_conv1_scope(num_users, groups):
+    """``dopt_conv1`` marks the first convolution with its bias add:
+    forward and backward inside the local phase, forward in every
+    evaluation.  A training step of 128 rows packs a fleet of 8 four
+    workers a group; a fleet of 6 keeps one group a worker, as does the
+    narrow fleet's evaluation, whose batch is not whole lane tiles."""
+    conv1 = [(stack, line) for line in _model1_round_text(
+        num_users).splitlines()
+        for stack in re.findall(r'op_name="([^"]*dopt_conv1[^"]*)"', line)]
+    stacks = {s for s, _ in conv1 if s.startswith("jit(")}
+    assert any("dopt_local" in s and "transpose(jvp(dopt_conv1))" in s
+               for s in stacks)
+    assert all("dopt_local" in s or "dopt_eval" in s for s in stacks)
+
+    def group_counts(tail):
+        return {int(n) for s, line in conv1 if s.endswith(tail)
+                for n in re.findall(r"feature_group_count=(\d+)", line)}
+
+    assert group_counts("/jvp(dopt_conv1)/conv_general_dilated") == {groups}
+    assert group_counts("/dopt_conv1/conv_general_dilated") == {num_users}
+    assert {"dopt_eval" in s for s in stacks
+            if s.endswith("/dopt_conv1/conv_general_dilated")} == {True}
+
+
+def test_resnet_round_carries_no_conv1_scope():
+    """The ResNet's first convolution (three input channels a worker) is
+    not the reference CNNs' conv1: its program is the parent's."""
+    cfg = _gossip_cfg(num_users=4, synthetic_train_size=64,
+                      synthetic_test_size=16)
+    cfg = dataclasses.replace(
+        cfg, mesh_devices=1,
+        model=ModelConfig(model="resnet18", stage_sizes=(1, 1),
+                          faithful=False, input_shape=(8, 8, 3)),
+        gossip=dataclasses.replace(cfg.gossip, local_ep=1, local_bs=8))
+    _, lowered = GossipTrainer(cfg, eval_every=1).lower_round()
+    text = lowered.as_text(debug_info=True)
+    assert "dopt_local" in text and "dopt_conv1" not in text
+
+
 def test_scopes_leave_the_fingerprints_alone():
     """Scopes are metadata: the blessed default programs do not move
     (no ``--bless``).  Own process: the registry is blessed for one
