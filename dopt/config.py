@@ -54,77 +54,137 @@ class DataConfig:
     #                 (P2, clients.py:21-22).
 
 
+# What each published ``config.json`` brings beyond the keys every decoder
+# has, by ``model_type``: (keys it must give, keys it may give: stated by
+# the source and held to one value or not read).  A key of one type alone
+# is refused under the other.
+_DECODER_KEYS = {
+    "laguna": (("num_attention_heads_per_layer", "layer_types",
+                "mlp_layer_types", "sliding_window", "rope_parameters",
+                "shared_expert_intermediate_size",
+                "moe_routed_scaling_factor", "gating"),
+               ("moe_apply_router_weight_on_input",
+                "partial_rotary_factor")),
+    "KeyeVL2": (("sa_config", "norm_topk_prob", "decoder_sparse_step",
+                 "rope_theta", "rope_scaling"),
+                ("mlp_only_layers", "hidden_act", "max_window_layers",
+                 "num_local_experts", "use_sliding_window",
+                 "sliding_window")),
+}
+_SA_KEYS = {"indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
+            "kv_chunk_size", "q_chunk_size", "topk"}
+
+
 @dataclass(frozen=True)
 class DecoderConfig:
-    """A gated window/full-attention mixture-of-experts decoder
-    (``dopt.models.decoder``), under the keys of its published
-    ``config.json`` (``model_type: laguna``): a key the file has and this
-    class lacks is refused, not ignored.  The lists run over the
-    PUBLISHED depth; a worker builds layers ``0 .. num_hidden_layers-1``
-    of them, so a cut in depth changes one number.
+    """A mixture-of-experts decoder (``dopt.models.decoder``) under the
+    keys of its published ``config.json``; a key the file has and this
+    class lacks is refused, not ignored, and so is a key of the other
+    ``model_type``.  Two are known, and the type alone says which layer
+    is built:
 
-    ``experts_held`` / ``expert_offset`` say which of the ``num_experts``
-    published experts of a layer this worker holds (ids ``offset ..
-    offset + held - 1``, a chip's share of an expert-parallel
-    deployment; ``None`` = all).  The router keeps its published width
-    and ``num_experts_per_tok``; what the absent experts would add is
-    left out.  The vocabulary rows held are ``ModelConfig.num_classes``,
-    the sequence length ``ModelConfig.input_shape[0]``."""
+    ``laguna``: gated window/full attention with query heads, attention
+    kind, rotary and MLP kind BY LAYER (lists over the PUBLISHED depth),
+    sigmoid-routed experts scaled by ``moe_routed_scaling_factor``
+    beside a shared one.
+
+    ``KeyeVL2`` (the language model; no vision tower): every layer
+    alike, per-head RMS norms on queries and keys, one rotary, a learned
+    sparse attention (``sa_config``: a lightning indexer of
+    ``indexer_num_heads`` heads of ``indexer_head_dim`` on ONE key head
+    picks the ``topk`` keys a query attends, and an alignment term
+    trains it), no output gate, softmax-routed experts with renormalised
+    top-k weights and no shared one; layer i is sparse unless it is in
+    ``mlp_only_layers`` or ``(i + 1) % decoder_sparse_step`` is not 0.
+
+    A worker builds layers ``0 .. num_hidden_layers-1``, so a cut in
+    depth changes one number.  ``experts_held`` / ``expert_offset`` say
+    which of the ``num_experts`` published experts of a layer this
+    worker holds (ids ``offset .. offset + held - 1``, a chip's share of
+    an expert-parallel deployment; ``None`` = all).  The router keeps
+    its published width and ``num_experts_per_tok``; what the absent
+    experts would add is left out.  The vocabulary rows held are
+    ``ModelConfig.num_classes``, the sequence length
+    ``ModelConfig.input_shape[0]``."""
 
     hidden_size: int
     intermediate_size: int
     num_hidden_layers: int
     num_key_value_heads: int
     head_dim: int
-    num_attention_heads_per_layer: tuple[int, ...]
-    layer_types: tuple[str, ...]          # full_attention | sliding_attention
-    mlp_layer_types: tuple[str, ...]      # dense | sparse
-    sliding_window: int
-    rope_parameters: Mapping[str, Any]
-    # {"full_attention": {...}, "sliding_attention": {...}}: rope_theta,
-    # rope_type default | yarn (factor, original_max_position_embeddings,
-    # beta_fast, beta_slow, attention_factor), partial_rotary_factor.
     num_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
-    shared_expert_intermediate_size: int
-    moe_routed_scaling_factor: float
+    model_type: str = "laguna"
     rms_norm_eps: float = 1e-6
-    gating: bool = True                   # one sigmoid output gate a head
     attention_bias: bool = False
     tie_word_embeddings: bool = False
-    moe_apply_router_weight_on_input: bool = False
+    # model_type laguna:
+    num_attention_heads_per_layer: tuple[int, ...] | None = None
+    layer_types: tuple[str, ...] | None = None   # full_ | sliding_attention
+    mlp_layer_types: tuple[str, ...] | None = None        # dense | sparse
+    sliding_window: int | None = None
+    rope_parameters: Mapping[str, Any] | None = None
+    # {"full_attention": {...}, "sliding_attention": {...}}: rope_theta,
+    # rope_type default | yarn (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, attention_factor), partial_rotary_factor.
+    shared_expert_intermediate_size: int | None = None
+    moe_routed_scaling_factor: float | None = None
+    gating: bool | None = None            # one sigmoid output gate a head
+    moe_apply_router_weight_on_input: bool | None = None
+    # model_type KeyeVL2:
+    sa_config: Mapping[str, int] | None = None
+    norm_topk_prob: bool | None = None
+    decoder_sparse_step: int | None = None
+    mlp_only_layers: tuple[int, ...] | None = None
+    rope_theta: float | None = None
+    rope_scaling: Mapping[str, Any] | None = None
+    # {"rope_type": "default", "mrope_section": [...]}: on token rows the
+    # three position components are equal and the sections collapse to
+    # the plain rotary.
+    hidden_act: str | None = None
+    use_sliding_window: bool | None = None
     # Stated by the source and not read by the model (they describe the
-    # whole checkpoint, or repeat a per-layer list):
-    model_type: str = "laguna"
+    # whole checkpoint, or repeat another key):
     vocab_size: int | None = None
-    num_attention_heads: int | None = None
+    num_attention_heads: int | None = None    # KeyeVL2 reads it
     max_position_embeddings: int | None = None
     partial_rotary_factor: float | None = None
+    max_window_layers: int | None = None
+    num_local_experts: int | None = None
     # The worker's share:
     experts_held: int | None = None
     expert_offset: int = 0
 
     def __post_init__(self) -> None:
-        n = self.num_hidden_layers
-        for name in ("num_attention_heads_per_layer", "layer_types",
-                     "mlp_layer_types"):
-            per_layer = tuple(getattr(self, name))
-            object.__setattr__(self, name, per_layer)
-            if len(per_layer) < n:
-                raise ValueError(
-                    f"decoder.{name} lists {len(per_layer)} layers, "
-                    f"num_hidden_layers is {n}")
-        if (self.attention_bias or self.tie_word_embeddings
-                or self.moe_apply_router_weight_on_input or not self.gating):
+        if self.model_type not in _DECODER_KEYS:
             raise ValueError(
-                "the decoder has no biases, an untied head, router weights "
-                "on the experts' outputs and a gated attention output; "
-                "attention_bias / tie_word_embeddings / "
-                "moe_apply_router_weight_on_input must be false and gating "
-                "true")
-        if any(h % self.num_key_value_heads
-               for h in self.num_attention_heads_per_layer[:n]):
+                f"decoder.model_type {self.model_type!r}; one of "
+                f"{' | '.join(_DECODER_KEYS)}")
+        required, optional = _DECODER_KEYS[self.model_type]
+        for name in required:
+            if getattr(self, name) is None:
+                raise ValueError(
+                    f"decoder.{name} is required for model_type "
+                    f"{self.model_type!r}")
+        for kind, keys in _DECODER_KEYS.items():
+            for name in (*keys[0], *keys[1]):
+                if (getattr(self, name) is not None
+                        and name not in required + optional):
+                    raise ValueError(
+                        f"decoder.{name} is a key of model_type {kind!r}, "
+                        f"not of {self.model_type!r}")
+        if self.attention_bias or self.tie_word_embeddings:
+            raise ValueError(
+                "the decoder has no biases and an untied head; "
+                "attention_bias / tie_word_embeddings must be false")
+        n = self.num_hidden_layers
+        if self.model_type == "laguna":
+            self._check_laguna(n)
+        else:
+            self._check_keye()
+        if any(self.query_heads(i) % self.num_key_value_heads
+               for i in range(n)):
             raise ValueError(
                 "every layer's query heads must be a multiple of "
                 f"num_key_value_heads={self.num_key_value_heads}")
@@ -135,12 +195,98 @@ class DecoderConfig:
                 f"experts {self.expert_offset} .. {self.expert_offset + held - 1} "
                 f"are not among the {self.num_experts} published")
 
+    def _check_laguna(self, n: int) -> None:
+        for name in ("num_attention_heads_per_layer", "layer_types",
+                     "mlp_layer_types"):
+            per_layer = tuple(getattr(self, name))
+            object.__setattr__(self, name, per_layer)
+            if len(per_layer) < n:
+                raise ValueError(
+                    f"decoder.{name} lists {len(per_layer)} layers, "
+                    f"num_hidden_layers is {n}")
+        if self.moe_apply_router_weight_on_input or not self.gating:
+            raise ValueError(
+                "a laguna layer has router weights on the experts' outputs "
+                "and a gated attention output; "
+                "moe_apply_router_weight_on_input must be false and gating "
+                "true")
+
+    def _check_keye(self) -> None:
+        if self.num_attention_heads is None:
+            raise ValueError("decoder.num_attention_heads is required for "
+                             "model_type 'KeyeVL2'")
+        object.__setattr__(self, "mlp_only_layers",
+                           tuple(self.mlp_only_layers or ()))
+        sa = dict(self.sa_config)
+        if set(sa) != _SA_KEYS:
+            raise ValueError(
+                f"decoder.sa_config has the keys {sorted(_SA_KEYS)}, not "
+                f"{sorted(sa)}")
+        if sa["indexer_num_kv_heads"] != 1 or sa["topk"] < 1:
+            raise ValueError(
+                "the lightning indexer has ONE key head and keeps at least "
+                "one key a query: sa_config.indexer_num_kv_heads must be 1 "
+                "and topk positive")
+        rope = dict(self.rope_scaling)
+        if (rope.get("rope_type", "default") != "default"
+                or rope.get("type", "default") != "default"):
+            raise ValueError(
+                "a KeyeVL2 layer has the plain rotary: "
+                "rope_scaling.rope_type must be 'default'")
+        if sum(rope.get("mrope_section", ())) not in (0, self.head_dim // 2):
+            raise ValueError(
+                "rope_scaling.mrope_section must cover half a head "
+                f"({self.head_dim // 2} frequencies), not "
+                f"{sum(rope['mrope_section'])}")
+        if (not self.norm_topk_prob or self.decoder_sparse_step < 1
+                or self.sliding_window is not None
+                or self.use_sliding_window
+                or (self.hidden_act or "silu") != "silu"
+                or (self.num_local_experts or self.num_experts)
+                != self.num_experts):
+            raise ValueError(
+                "a KeyeVL2 layer renormalises its top-k router weights, "
+                "has no sliding window and a silu-gated MLP: norm_topk_prob "
+                "must be true, decoder_sparse_step positive, sliding_window "
+                "null, use_sliding_window false, hidden_act 'silu' and "
+                "num_local_experts equal to num_experts")
+
+    # What layer i is, whichever model_type's keys say it.
+    @property
+    def indexed(self) -> bool:
+        """The ``KeyeVL2`` layer: an indexer picks the keys a query
+        attends, per-head q/k norms, no output gate, a softmax router and
+        no shared expert."""
+        return self.model_type == "KeyeVL2"
+
+    def query_heads(self, i: int) -> int:
+        return (self.num_attention_heads if self.indexed
+                else self.num_attention_heads_per_layer[i])
+
+    def window(self, i: int) -> int | None:
+        """Positions back a query sees through a band; None = no band."""
+        if self.indexed or self.layer_types[i] != "sliding_attention":
+            return None
+        return self.sliding_window
+
+    def rope(self, i: int) -> Mapping[str, Any]:
+        """Layer i's ``rope_parameters`` entry."""
+        if self.indexed:
+            return {"rope_type": "default", "rope_theta": self.rope_theta}
+        return self.rope_parameters[self.layer_types[i]]
+
+    def sparse_mlp(self, i: int) -> bool:
+        if self.indexed:
+            return (i not in self.mlp_only_layers
+                    and (i + 1) % self.decoder_sparse_step == 0)
+        return self.mlp_layer_types[i] != "dense"
+
 
 @dataclass(frozen=True)
 class ModelConfig:
     """Model zoo selection (reference ``args.model`` string dispatch)."""
 
-    model: str = "model1"    # model1 | model3 | mlp | resnet18 | logistic | laguna
+    model: str = "model1"    # model1 | model3 | mlp | resnet18 | logistic | decoder
     stage_sizes: tuple[int, ...] | None = None
     # resnet18 only: residual blocks per stage (None = the standard
     # (2, 2, 2, 2)).  Smaller values give shallow variants for tests
@@ -167,8 +313,10 @@ class ModelConfig:
     # reassociation inside the conv), "vmap" forces the vmapped
     # per-worker path (the bit-level oracle-parity mode).
     decoder: DecoderConfig | None = None
-    # model="laguna" only: the decoder's published configuration (a
-    # mapping is taken as DecoderConfig(**mapping)).
+    # model="decoder" (or, as the first one was named, "laguna") only:
+    # the decoder's published configuration, whose ``model_type`` says
+    # which layer is built (a mapping is taken as
+    # DecoderConfig(**mapping)).
 
     def __post_init__(self) -> None:
         if isinstance(self.decoder, Mapping):

@@ -1,19 +1,24 @@
-"""A gated window/full-attention mixture-of-experts decoder as ONE
-worker of the gossip engine (``model="laguna"``, configured by
-``dopt.config.DecoderConfig`` under the keys of the published
-``config.json``): token ids in, next-token loss out.
+"""A mixture-of-experts decoder as ONE worker of the gossip engine
+(``model="decoder"``, configured by ``dopt.config.DecoderConfig`` under
+the keys of the published ``config.json``, whose ``model_type`` says
+which layer is built): token ids in, next-token loss out.  Two layers
+are known: ``laguna``'s gated window/full attention beside
+sigmoid-routed experts and a shared one, and ``KeyeVL2``'s learned
+sparse attention (a lightning indexer picks the ``topk`` keys a query
+attends and an alignment term trains it) beside softmax-routed experts.
 
 Not a flax module: the parameters are a plain dict tree (``embed``,
-``layer<i>`` with ``attn_norm q k v gate o mlp_norm`` and ``mlp`` or
-``router shared experts``, ``norm``, ``head``; the experts' leaves carry
-a leading EXPERT axis) and the surface the engines use is ``init(key,
-dummy)``, ``apply({"params": p}, tokens)`` and ``loss(p, tokens, labels,
+``layer<i>`` with ``attn_norm q k v o mlp_norm``, ``gate`` or ``q_norm
+k_norm indexer``, and ``mlp`` or ``router experts`` with or without
+``shared``, ``norm``, ``head``; the experts' leaves carry a leading
+EXPERT axis) and the surface the engines use is ``init(key, dummy)``,
+``apply({"params": p}, tokens)`` and ``loss(p, tokens, labels,
 weights)``.  The worker axis is the engines' ``vmap`` over the stacked
 fleet state.
 
-What a layer computes is written out in
-``benchmark/reference_models/laguna_xs2.py``'s docstring; this file is
-the same mathematics arranged for the chip:
+What a layer computes is written out in the docstrings of
+``benchmark/reference_models/laguna_xs2.py`` and ``keye_vl2.py``; this
+file is the same mathematics arranged for the chip:
 
 * matmul inputs in the compute dtype (bfloat16 on the chip) with float32
   accumulation; residual stream, norms, router scores, attention
@@ -22,7 +27,11 @@ the same mathematics arranged for the chip:
   that block can see — everything up to its end in a full layer, the
   band of ``sliding_window`` in front of it in a sliding one — so a
   banded layer never forms T x T (``causal_attention``: one entry, and
-  the one place that says which of its two bodies runs and why);
+  the one place that says which of its two bodies runs and why); where
+  the layer has an indexer the mask comes from the data and a third
+  body takes it (``indexed_causal_attention``: index scores, selection,
+  masked softmax, values and the alignment term a block of queries at a
+  time, so no T x T array is held for a row);
 * an expert layer that is TOLD which experts it holds
   (``expert_offset``, ``experts_held``), routes every token over all
   ``num_experts`` published ones and adds its own experts' part beside
@@ -43,15 +52,21 @@ the same mathematics arranged for the chip:
 
 Scopes (inside the engines' ``dopt_local``): ``dopt_attn`` (normed input
 to gated output projection; the splash kernels carry no name stack and
-go by their own names, ``splash_mqa_*``), ``dopt_moe`` (router to combined output)
-with ``dopt_route`` inside it (scores, top-k, combine weights and their
-application, not the expert matmuls), ``dopt_head`` (final norm, logits,
-loss).  ``loss`` also returns the step's routing counts, which the
-gossip engine averages into each round's history row.
+go by their own names, ``splash_mqa_*``) and, where the layer has an
+indexer, inside it ``dopt_index`` (indexer projections, index scores,
+selection, alignment term) with ``dopt_select`` inside that (the k-th
+largest score and the mask, alone) and ``dopt_attend`` (masked scores,
+softmax, value product, the head-mean); ``dopt_moe`` (router to combined
+output) with ``dopt_route`` inside it (scores, top-k, combine weights
+and their application, not the expert matmuls), ``dopt_head`` (final
+norm, logits, loss).  ``loss`` also returns the step's counts
+(``model.counters``: routing, and the indexer's two), which the gossip
+engine averages into each round's history row.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -76,18 +91,35 @@ from dopt.config import DecoderConfig
 # kernel's 51-68 (1.01 GB over the five layers, PERF.md, PR 29), and it
 # grows with rows x positions x layers a worker.  The recompute keeps
 # norms, casts, activations, the gated attention output, the combine
-# weights and the router's top-k.
+# weights and the router's top-k.  A layer with an indexer keeps q and k
+# as float32 PRODUCTS, before their per-head norms (whose backward asks
+# for them; norm, rotary and cast are recomputed), the indexer's three
+# products likewise, and under ``ATTN_RESIDUALS`` the indexed
+# attention's output, so that the blocks run forward once for the
+# layer and once inside their own checkpoints, not a third time.
 ATTN_RESIDUALS = "attn_residuals"
 MATMUL_PRODUCTS = "matmul_products"
 LAYER_KEEPS = (ATTN_RESIDUALS, MATMUL_PRODUCTS)
-# The routing counts ``loss`` returns beside "acc", in history-row order.
+# The routing counts ``loss`` returns beside "acc", in history-row order,
+# and those of a layer with an indexer: its alignment term (mean over
+# layers: does the indexer follow the attention?) and selected over
+# visible keys (0.4375 at 8,192 positions and 2,048 keys a query).
 COUNTERS = ("moe_held_slot_share", "moe_load_max_over_mean")
+INDEX_COUNTERS = ("index_align_loss", "index_keys_kept_share")
 # Queries a block of attention, and positions a block of the output head
 # (logits and loss are never held for the whole batch).  Not
 # configuration: only tests, whose rows are shorter than a block, pass
 # smaller ones to ``GatedMoEDecoder``.
 ATTN_BLOCK = 512
 HEAD_BLOCK = 1024
+# The indexed attention's blocks hold their float32 scores in HBM, for
+# every head and worker at once (0.5 GB an array at 256 queries, two
+# workers and 8,192 keys; PERF.md, PR 32).  ``INDEX_SPAN`` blocks in
+# a row share one piece of code (``jax.lax.map``) and one extent of keys,
+# the end of the last of them: 1 would compile every block apart, the
+# whole row would multiply every block with every key.
+INDEX_BLOCK = 256
+INDEX_SPAN = 4
 # Every matrix is normal(0, INITIALIZER_RANGE), every norm weight 1 (the
 # published config carries no initializer).
 INITIALIZER_RANGE = 0.02
@@ -275,6 +307,153 @@ def splash_causal_attention(q, k, v, *, window: int | None, block: int):
     return attend(q, k, v)
 
 
+def _running_count(flags):
+    """[..., N] bool -> [..., N] float32, how many of a row's flags are
+    set up to and with each position: exact, by two small matmuls over
+    chunks of 128 (counts within a chunk, chunks before it) where a
+    running sum along 8,192 positions would be a long serial pass."""
+    *lead, n = flags.shape
+    chunk = 128
+    pad = -n % chunk
+    x = jnp.pad(flags, [(0, 0)] * len(lead) + [(0, pad)])
+    x = x.reshape(*lead, -1, chunk).astype(jnp.bfloat16)    # 0 / 1: exact
+    upto = jnp.triu(jnp.ones((chunk, chunk), jnp.bfloat16))
+    within = jnp.einsum("...cj,ji->...ci", x, upto,
+                        preferred_element_type=jnp.float32)
+    chunks = x.shape[-2]
+    before = jnp.triu(jnp.ones((chunks, chunks), jnp.float32), 1)
+    offset = jnp.einsum("...c,cd->...d", within[..., -1], before,
+                        precision=jax.lax.Precision.HIGHEST)
+    return (within + offset[..., None]).reshape(*lead, -1)[..., :n]
+
+
+def select_top_keys(index, seen, count):
+    """[Tq, Tk] bool: for each query its ``count`` (>= 1, at most the
+    keys it sees) visible keys with the largest index score, a tie going
+    to the lower position; exactly ``count`` of them.  ``index`` [Tq, Tk]
+    float32, ``seen`` [Tq, Tk] bool, ``count`` [Tq] int32.
+
+    The ``count``-th largest score of a row is found by bisection on the
+    float's bit pattern, mapped to an unsigned integer in the floats'
+    order: 32 passes that each count the scores at or above a candidate.
+    No sort and no gather: ``lax.top_k`` with 2,048 of 8,192 is a full
+    sort on this chip, and its indices would still have to become a
+    mask."""
+    index = jnp.where(index == 0, 0.0, index)         # -0.0 ties with 0.0
+    bits = jax.lax.bitcast_convert_type(index, jnp.uint32)
+    order = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    order = jnp.where(seen, order, jnp.uint32(0))     # under every float
+
+    def refine(i, kth):
+        trial = kth | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = jnp.sum(order >= trial[:, None], axis=-1) >= count
+        return jnp.where(enough, trial, kth)
+
+    kth = jax.lax.fori_loop(0, 32, refine,
+                            jnp.zeros(order.shape[0], jnp.uint32))
+    above = order > kth[:, None]
+    tied = order == kth[:, None]
+    room = (count - jnp.sum(above, axis=-1)).astype(jnp.float32)
+    return above | (tied & (_running_count(tied) <= room[:, None]))
+
+
+def _apart(x):
+    """``x``, computed apart from what takes it.  A row's maximum or sum
+    that XLA:TPU fuses with the elementwise pass that takes it back
+    becomes a ``reduce-window`` two rows wide: 8.4 s of a 10.4 s round of
+    the benchmark's cell, where the passes apart take 0.3 (PERF.md,
+    PR 32)."""
+    return jax.lax.optimization_barrier(x)
+
+
+def _masked_softmax(x, keep, log: bool = False):
+    """``softmax`` (or ``log_softmax``) over the last axis of float32
+    ``x`` where ``keep``, nothing (``-inf``) elsewhere; every row keeps
+    at least one position."""
+    x = jnp.where(keep, x, -jnp.inf)
+    shifted = x - _apart(jax.lax.stop_gradient(
+        jnp.max(x, axis=-1, keepdims=True)))
+    total = _apart(jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True))
+    return shifted - jnp.log(total) if log else jnp.exp(shifted) / total
+
+
+def _indexed_block(q, k, v, qi, ki, wi, first, *, topk: int):
+    """One block of queries, at positions ``first ...``, of the indexed
+    attention against the keys ``0 .. Tk-1`` (every key the block can
+    see): q [G, R, Tq, D], k and v [G, Tk, D], and the indexer's qi
+    [J, Tq, E], ki [Tk, E] in the compute dtype, wi [Tq, J] float32 ->
+    (attention output [G, R, Tq, D], the block's alignment sum, its count
+    of selected keys).  Scores, selection, softmax and the alignment
+    term in float32."""
+    at = first + jnp.arange(q.shape[-2])
+    seen = jnp.arange(k.shape[-2])[None, :] <= at[:, None]
+    with jax.named_scope("dopt_index"):
+        dots = jnp.einsum("jqe,ke->jqk", qi, ki,
+                          preferred_element_type=jnp.float32)
+        index = jnp.sum(jax.nn.relu(dots) * wi.T[:, :, None], axis=0)
+        if k.shape[-2] > topk:
+            with jax.named_scope("dopt_select"):
+                chosen = select_top_keys(jax.lax.stop_gradient(index), seen,
+                                         jnp.minimum(at + 1, topk))
+        else:                    # statically: every visible key is kept
+            chosen = seen
+    with jax.named_scope("dopt_attend"):
+        scores = jnp.einsum("grqd,gkd->grqk", q, k,
+                            preferred_element_type=jnp.float32)
+        scores = scores / math.sqrt(q.shape[-1])
+        probs = _masked_softmax(scores, chosen)
+        out = jnp.einsum("grqk,gkd->grqd", probs.astype(v.dtype), v)
+        target = jax.lax.stop_gradient(jnp.mean(probs, axis=(0, 1)))
+    with jax.named_scope("dopt_index"):
+        logp = _masked_softmax(index, chosen, log=True)
+        align = jnp.where(
+            chosen, jax.scipy.special.xlogy(target, target) - target * logp,
+            0.0)
+    return out, jnp.sum(align), jnp.sum(chosen.astype(jnp.float32))
+
+
+def indexed_causal_attention(q, k, v, qi, ki, wi, *, topk: int,
+                             block: int = INDEX_BLOCK):
+    """Causal attention of one row in which query t attends the
+    ``min(t + 1, topk)`` keys its indexer scores highest (the third body
+    of the attention: the mask comes from the data, so no fused kernel
+    in the tree takes it): q [G, R, T, D], k and v [G, T, D], qi
+    [J, T, E], ki [T, E], wi [T, J] -> (output [G, R, T, D], the row's
+    alignment term, its share of selected among visible keys).
+
+    A block of ``block`` queries at a time, each ``jax.checkpoint``-ed,
+    ``INDEX_SPAN`` blocks in a row through one ``jax.lax.map`` against
+    the keys up to the end of the last of them; T need not be a multiple
+    of ``block`` (the remainder is a block of its own)."""
+    t = q.shape[-2]
+    body = jax.checkpoint(functools.partial(_indexed_block, topk=topk))
+    edges = list(range(0, t - t % block, block * INDEX_SPAN))
+    runs = [(s, min(s + block * INDEX_SPAN, t - t % block), block)
+            for s in edges]
+    if t % block:
+        runs.append((t - t % block, t, t % block))
+    outs, aligns, kepts = [], [], []
+    for s, e, size in runs:
+        n = (e - s) // size
+
+        def blocks(x, axis):
+            """[n, ...]: the run's queries of ``x``, a block a row."""
+            x = jax.lax.slice_in_dim(x, s, e, axis=axis)
+            x = x.reshape(*x.shape[:axis], n, size, *x.shape[axis + 1:])
+            return jnp.moveaxis(x, axis, 0)
+
+        out, align, kept = jax.lax.map(
+            lambda b, e=e: body(b[0], k[:, :e], v[:, :e], b[1], ki[:e],
+                                b[2], b[3]),
+            (blocks(q, 2), blocks(qi, 1), blocks(wi, 0),
+             s + size * jnp.arange(n)))
+        outs.append(jnp.moveaxis(out, 0, 2).reshape(*q.shape[:2], e - s, -1))
+        aligns.append(jnp.sum(align))
+        kepts.append(jnp.sum(kept))
+    out = checkpoint_name(jnp.concatenate(outs, axis=2), ATTN_RESIDUALS)
+    return out, sum(aligns) / t, sum(kepts) / (t * (t + 1) / 2)
+
+
 def _gated_mlp(p, x, dtype):
     g = _keep(jnp.dot(x, p["gate"].astype(dtype)))
     u = _keep(jnp.dot(x, p["up"].astype(dtype)))
@@ -283,23 +462,28 @@ def _gated_mlp(p, x, dtype):
 
 class GatedMoEDecoder:
     """See the module docstring.  ``vocab_rows`` is the slice of the
-    vocabulary this worker holds (ids, logits and loss are over it)."""
-
-    counters = COUNTERS
+    vocabulary this worker holds (ids, logits and loss are over it).
+    ``attn_block`` None is the body's own (``ATTN_BLOCK``, or
+    ``INDEX_BLOCK`` where the layers have an indexer)."""
 
     def __init__(self, cfg: DecoderConfig, *, vocab_rows: int,
-                 dtype=jnp.float32, attn_block: int = ATTN_BLOCK,
+                 dtype=jnp.float32, attn_block: int | None = None,
                  head_block: int = HEAD_BLOCK):
         self.cfg = cfg
         self.vocab_rows = vocab_rows
         self.dtype = jnp.dtype(dtype)
-        self.attn_block, self.head_block = attn_block, head_block
+        self.attn_block = attn_block or (INDEX_BLOCK if cfg.indexed
+                                         else ATTN_BLOCK)
+        self.head_block = head_block
         self.experts_held = (cfg.num_experts if cfg.experts_held is None
                              else cfg.experts_held)
+        self.counters = COUNTERS + (INDEX_COUNTERS if cfg.indexed else ())
 
     def attention_path(self, t: int) -> str:
-        """``"splash"`` or ``"blocked"`` for rows of ``t`` positions
-        (``dopt.run`` prints it beside the device)."""
+        """``"indexed"``, ``"splash"`` or ``"blocked"`` for rows of ``t``
+        positions (``dopt.run`` prints it beside the device)."""
+        if self.cfg.indexed:
+            return "indexed"
         return attention_path(t, self.cfg.head_dim, self.attn_block)
 
     # ---------------------------------------------------------- params
@@ -321,16 +505,26 @@ class GatedMoEDecoder:
 
         params = {"embed": mat(self.vocab_rows, d)}
         for i in range(c.num_hidden_layers):
-            h = c.num_attention_heads_per_layer[i]
+            h = c.query_heads(i)
             layer = {"attn_norm": jnp.ones(d), "q": mat(d, h * hd),
-                     "k": mat(d, kv * hd), "v": mat(d, kv * hd),
-                     "gate": mat(d, h), "o": mat(h * hd, d),
-                     "mlp_norm": jnp.ones(d)}
-            if c.mlp_layer_types[i] == "dense":
+                     "k": mat(d, kv * hd), "v": mat(d, kv * hd)}
+            if not c.indexed:
+                layer["gate"] = mat(d, h)
+            layer.update(o=mat(h * hd, d), mlp_norm=jnp.ones(d))
+            if c.indexed:
+                j, e = (c.sa_config[n] for n in ("indexer_num_heads",
+                                                 "indexer_head_dim"))
+                layer.update(
+                    q_norm=jnp.ones(hd), k_norm=jnp.ones(hd),
+                    indexer={"q": mat(d, j * e), "k": mat(d, e),
+                             "k_norm": jnp.ones(e), "k_bias": jnp.zeros(e),
+                             "w": mat(d, j)})
+            if not c.sparse_mlp(i):
                 layer["mlp"] = mlp(c.intermediate_size)
             else:
                 layer["router"] = mat(d, c.num_experts)
-                layer["shared"] = mlp(c.shared_expert_intermediate_size)
+                if not c.indexed:
+                    layer["shared"] = mlp(c.shared_expert_intermediate_size)
                 layer["experts"] = mlp(c.moe_intermediate_size,
                                        self.experts_held)
             params[f"layer{i}"] = layer
@@ -339,13 +533,39 @@ class GatedMoEDecoder:
         return {"params": params}
 
     # ---------------------------------------------------------- layers
+    def _index(self, p, x, rope):
+        """The lightning indexer's inputs to the selection, from the
+        DETACHED normed layer input x [T, d]: (qi [J, T, E], ki [T, E] in
+        the compute dtype, wi [T, J] float32).  ONE key head, a layer
+        norm on it, the layer's rotary over all E dimensions."""
+        c, dt = self.cfg, self.dtype
+        j, e = (c.sa_config[n] for n in ("indexer_num_heads",
+                                         "indexer_head_dim"))
+        with jax.named_scope("dopt_index"):
+            x = jax.lax.stop_gradient(x)
+            qi = _keep(jnp.einsum(
+                "td,dje->jte", x, p["q"].astype(dt).reshape(-1, j, e),
+                preferred_element_type=jnp.float32))
+            ki = _keep(jnp.dot(x, p["k"].astype(dt),
+                               preferred_element_type=jnp.float32))
+            ki = ki - jnp.mean(ki, -1, keepdims=True)
+            ki = (ki * jax.lax.rsqrt(jnp.mean(ki * ki, -1, keepdims=True)
+                                     + c.rms_norm_eps)
+                  * p["k_norm"] + p["k_bias"])
+            wi = _keep(jnp.dot(x, p["w"].astype(dt),
+                               preferred_element_type=jnp.float32))
+            return (_rotary(qi, rope).astype(dt),
+                    _rotary(ki[None], rope)[0].astype(dt),
+                    wi * (j ** -0.5 * e ** -0.5))
+
     def _attention(self, p, h, i):
+        """-> (the residual stream after the layer's attention, None or
+        the indexer's counts)."""
         c, dt = self.cfg, self.dtype
         t = h.shape[0]
         hd, kv = c.head_dim, c.num_key_value_heads
-        heads = c.num_attention_heads_per_layer[i]
-        kind = c.layer_types[i]
-        rope = c.rope_parameters[kind]
+        heads = c.query_heads(i)
+        rope = c.rope(i)
         with jax.named_scope("dopt_attn"):
             a = _rms(h, p["attn_norm"], c.rms_norm_eps)
             x = a.astype(dt)
@@ -357,30 +577,49 @@ class GatedMoEDecoder:
                     "td,dne->nte", x, p[name].astype(dt).reshape(-1, n, hd),
                     preferred_element_type=jnp.float32)
 
-            q = _rotary(heads_of("q", heads), rope)
-            k = _keep(_rotary(heads_of("k", kv), rope).astype(dt))
-            v = _keep(heads_of("v", kv).astype(dt))
-            window = (c.sliding_window if kind == "sliding_attention"
-                      else None)
-            out = causal_attention(q, k, v, window=window,
-                                   block=self.attn_block)
-            gate = jax.nn.sigmoid(_keep(jnp.einsum(
-                "td,dn->nt", x, p["gate"].astype(dt),
-                preferred_element_type=jnp.float32)))
-            out = out.reshape(heads, t, hd) * gate[..., None].astype(dt)
+            if c.indexed:
+                # per-head RMS norms, whose backward asks for the products
+                q = _rotary(_rms(_keep(heads_of("q", heads)), p["q_norm"],
+                                 c.rms_norm_eps), rope).astype(dt)
+                k = _rotary(_rms(_keep(heads_of("k", kv)), p["k_norm"],
+                                 c.rms_norm_eps), rope).astype(dt)
+                v = _keep(heads_of("v", kv).astype(dt))
+                out, align, kept = indexed_causal_attention(
+                    q.reshape(kv, heads // kv, t, hd), k, v,
+                    *self._index(p["indexer"], x, rope),
+                    topk=c.sa_config["topk"], block=self.attn_block)
+                counts = dict(zip(INDEX_COUNTERS, (align, kept)))
+                out = out.reshape(heads, t, hd)
+            else:
+                q = _rotary(heads_of("q", heads), rope)
+                k = _keep(_rotary(heads_of("k", kv), rope).astype(dt))
+                v = _keep(heads_of("v", kv).astype(dt))
+                out = causal_attention(q, k, v, window=c.window(i),
+                                       block=self.attn_block)
+                gate = jax.nn.sigmoid(_keep(jnp.einsum(
+                    "td,dn->nt", x, p["gate"].astype(dt),
+                    preferred_element_type=jnp.float32)))
+                out = out.reshape(heads, t, hd) * gate[..., None].astype(dt)
+                counts = None
             return _keep(h + jnp.einsum(
                 "nte,ned->td", out, p["o"].astype(dt).reshape(heads, hd, -1),
-                preferred_element_type=jnp.float32))
+                preferred_element_type=jnp.float32)), counts
 
     def _route(self, router, m):
         """[T, held] combine weights (0 where a token was not routed to
         that held expert) and the step's routing counts."""
         c = self.cfg
-        scores = jax.nn.sigmoid(_keep(jnp.dot(
-            m, router, precision=jax.lax.Precision.HIGHEST)))
-        top, idx = jax.lax.top_k(scores, c.num_experts_per_tok)
-        top = (top / jnp.sum(top, -1, keepdims=True)
-               * c.moe_routed_scaling_factor)
+        scores = _keep(jnp.dot(m, router,
+                               precision=jax.lax.Precision.HIGHEST))
+        if not c.indexed:
+            scores = jax.nn.sigmoid(scores)
+            top, idx = jax.lax.top_k(scores, c.num_experts_per_tok)
+            top = (top / jnp.sum(top, -1, keepdims=True)
+                   * c.moe_routed_scaling_factor)
+        else:             # a softmax over the published experts, top-k
+            scores = jax.nn.softmax(scores, axis=-1)   # renormalised
+            top, idx = jax.lax.top_k(scores, c.num_experts_per_tok)
+            top = top / jnp.sum(top, -1, keepdims=True)
         # [T, k, held]: slot j of token t reached held expert e
         hit = ((idx - c.expert_offset)[..., None]
                == jnp.arange(self.experts_held)).astype(jnp.float32)
@@ -398,30 +637,34 @@ class GatedMoEDecoder:
             with jax.named_scope("dopt_route"):
                 weight, counts = self._route(p["router"], m)
             x = m.astype(dt)
-            out = _gated_mlp(p["shared"], x, dt).astype(jnp.float32)
+            if "shared" in p:
+                out = _gated_mlp(p["shared"], x, dt).astype(jnp.float32)
             e = p["experts"]
             g = _keep(jnp.einsum("td,edf->tef", x, e["gate"].astype(dt)))
             u = _keep(jnp.einsum("td,edf->tef", x, e["up"].astype(dt)))
             mid = jax.nn.silu(g) * u
             with jax.named_scope("dopt_route"):
                 mid = mid * weight[..., None].astype(dt)
-            out = out + jnp.einsum("tef,efd->td", mid, e["down"].astype(dt),
-                                   preferred_element_type=jnp.float32)
-            return out, counts
+            routed = jnp.einsum("tef,efd->td", mid, e["down"].astype(dt),
+                                preferred_element_type=jnp.float32)
+            return (out + routed if "shared" in p else routed), counts
 
     def _layer(self, p, h, i):
+        """-> (the residual stream after layer i, its counts: the
+        indexer's and the router's, {} where it has neither)."""
         c = self.cfg
-        h = self._attention(p, h, i)
+        h, counts = self._attention(p, h, i)
+        counts = counts or {}
         m = _rms(h, p["mlp_norm"], c.rms_norm_eps)
-        if c.mlp_layer_types[i] == "dense":
+        if not c.sparse_mlp(i):
             return (h + _gated_mlp(p["mlp"], m.astype(self.dtype), self.dtype
-                                   ).astype(jnp.float32)), None
-        out, counts = self._experts(p, m)
-        return h + out, counts
+                                   ).astype(jnp.float32)), counts
+        out, routed = self._experts(p, m)
+        return h + out, {**counts, **routed}
 
     def _hidden(self, params, tokens):
         """One row: [T] ids -> ([T, d] float32 hidden state before the
-        final norm, the routing counts averaged over the expert layers)."""
+        final norm, each count averaged over the layers that have it)."""
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
         counts = []
         for i in range(self.cfg.num_hidden_layers):
@@ -429,12 +672,10 @@ class GatedMoEDecoder:
                 lambda p, h_, i=i: self._layer(p, h_, i),
                 policy=jax.checkpoint_policies.save_only_these_names(
                     *LAYER_KEEPS))(params[f"layer{i}"], h)
-            if c is not None:
-                counts.append(c)
-        if not counts:
-            return h, {k: jnp.zeros(()) for k in COUNTERS}
-        return h, {k: jnp.mean(jnp.stack([c[k] for c in counts]))
-                   for k in COUNTERS}
+            counts.append(c)
+        return h, {k: (jnp.mean(jnp.stack([c[k] for c in counts if k in c]))
+                       if any(k in c for c in counts) else jnp.zeros(()))
+                   for k in self.counters}
 
     def _logits(self, params, h):
         x = _rms(h, params["norm"], self.cfg.rms_norm_eps).astype(self.dtype)
@@ -454,7 +695,11 @@ class GatedMoEDecoder:
         [B, T] labels (negative = not counted), [B] 0/1 row weights ->
         (sum of the counted positions' negative log-likelihood over
         their count, {"acc": next-token accuracy over the counted
-        positions, **routing counts})."""
+        positions, **counts}).  Layers with an indexer add their
+        alignment terms, each a mean over the positions of the rows that
+        count: the indexer's leaves get that term's gradient alone and
+        every other leaf the cross-entropy's alone (the indexer's input
+        is detached and the selection is not differentiated)."""
         h, counts = jax.vmap(lambda row: self._hidden(params, row))(tokens)
         counted = (weights[:, None] * (labels >= 0)).astype(jnp.float32)
         n = counted.size
@@ -483,4 +728,11 @@ class GatedMoEDecoder:
             total = jnp.maximum(jnp.sum(counted), 1.0)
             aux = {"acc": jnp.sum(hit) / total,
                    **{k: jnp.mean(v) for k, v in counts.items()}}
-            return jnp.sum(nll) / total, aux
+            loss = jnp.sum(nll) / total
+        if self.cfg.indexed:
+            rows = weights.astype(jnp.float32)
+            aux["index_align_loss"] = (
+                jnp.sum(rows * counts["index_align_loss"])
+                / jnp.maximum(jnp.sum(rows), 1.0))
+            loss = loss + self.cfg.num_hidden_layers * aux["index_align_loss"]
+        return loss, aux

@@ -689,24 +689,26 @@ def build_model(
     ``dtype`` may be a string ("bfloat16" → MXU-native compute); params
     stay float32 (flax param_dtype default) — bf16 is compute-only.
     ``stage_sizes`` (resnet18 only) overrides the per-stage block counts
-    for shallow variants.  ``decoder`` (laguna only) is the
-    ``DecoderConfig``; ``num_classes`` is then the vocabulary rows held.
+    for shallow variants.  ``decoder`` (model ``decoder``, or ``laguna``
+    as the first one was named) is the ``DecoderConfig``, whose
+    ``model_type`` says which layer is built; ``num_classes`` is then
+    the vocabulary rows held.
     """
     if isinstance(dtype, str):
         dtype = jnp.dtype(dtype)
     key = name.lower()
-    if key == "laguna":
+    if key in ("decoder", "laguna"):     # "laguna": the first one's name
         from dopt.models.decoder import GatedMoEDecoder
 
         if decoder is None:
-            raise ValueError("model='laguna' needs ModelConfig.decoder "
+            raise ValueError(f"model={key!r} needs ModelConfig.decoder "
                              "(dopt.config.DecoderConfig)")
         return GatedMoEDecoder(decoder, vocab_rows=num_classes, dtype=dtype)
     if decoder is not None:
-        raise ValueError("ModelConfig.decoder applies to model='laguna' only")
+        raise ValueError("ModelConfig.decoder applies to model='decoder' only")
     if key not in _ZOO:
         raise ValueError(
-            f"unknown model {name!r}; one of {sorted([*_ZOO, 'laguna'])}")
+            f"unknown model {name!r}; one of {sorted([*_ZOO, 'decoder'])}")
     kwargs: dict[str, Any] = dict(num_classes=num_classes, dtype=dtype)
     if faithful is not None:
         kwargs["faithful"] = faithful

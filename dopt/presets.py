@@ -247,11 +247,20 @@ def laguna_localsgd2(toy: bool = False) -> ExperimentConfig:
     else:
         decoder = laguna_xs2_decoder(num_hidden_layers=5, experts_held=8)
         vocab, seq = 12544, 4096
+    return _localsgd2("laguna-localsgd2", decoder, model="laguna", seed=28,
+                      vocab=vocab, seq=seq, rows=16, toy=toy)
+
+
+def _localsgd2(name, decoder, *, model, seed, vocab, seq, rows, toy):
+    """Two workers of ``decoder`` on a complete graph with self weight
+    (parameters averaged every round, momentum not), ``rows`` token rows
+    of ``seq`` ids between them, one row a step, one local epoch a
+    round; a toy computes in float32."""
     return ExperimentConfig(
-        name="laguna-localsgd2-toy" if toy else "laguna-localsgd2", seed=28,
+        name=name + "-toy" if toy else name, seed=seed,
         data=DataConfig(dataset="synthetic_tokens", num_users=2, iid=True,
-                        synthetic_train_size=16, synthetic_test_size=2),
-        model=ModelConfig(model="laguna", faithful=False, num_classes=vocab,
+                        synthetic_train_size=rows, synthetic_test_size=2),
+        model=ModelConfig(model=model, faithful=False, num_classes=vocab,
                           input_shape=(seq,), decoder=decoder,
                           compute_dtype="float32" if toy else "bfloat16"),
         optim=OptimizerConfig(lr=0.01, momentum=0.9),
@@ -260,6 +269,63 @@ def laguna_localsgd2(toy: bool = False) -> ExperimentConfig:
                             rounds=10, local_ep=1, local_bs=1),
         mesh_devices=1,
     )
+
+
+def keye_vl2_decoder(**cut) -> DecoderConfig:
+    """The language model of Keye-VL-2.0-30B-A3B, its published
+    ``config.json``
+    (https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json,
+    ``model_type: KeyeVL2``, 30B-A3B) key for key, the vision tower left
+    out; ``cut`` replaces what a worker holds less of
+    (``num_hidden_layers``, ``experts_held``) or, for a toy, any width."""
+    published = dict(
+        model_type="KeyeVL2", vocab_size=151936, hidden_size=2048,
+        intermediate_size=6144, num_hidden_layers=48,
+        num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+        max_position_embeddings=262144, max_window_layers=48,
+        attention_bias=False, rms_norm_eps=1e-06, hidden_act="silu",
+        num_experts=128, num_local_experts=128, num_experts_per_tok=8,
+        moe_intermediate_size=768, norm_topk_prob=True,
+        decoder_sparse_step=1, mlp_only_layers=(),
+        tie_word_embeddings=False, sliding_window=None,
+        use_sliding_window=False, rope_theta=10000000,
+        rope_scaling={"mrope_section": [16, 24, 24],
+                      "rope_type": "default", "type": "default"},
+        sa_config={"indexer_head_dim": 64, "indexer_num_heads": 16,
+                   "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                   "q_chunk_size": 512, "topk": 2048})
+    return DecoderConfig(**{**published, **cut})
+
+
+def keye_localsgd2(toy: bool = False) -> ExperimentConfig:
+    """Two local-SGD workers, each one chip's share of Keye-VL-2.0's
+    language model (layers 0-3, experts 0-7 of 128 a layer, 18,992 of
+    151,936 vocabulary rows: 314.4 M parameters), averaging their
+    parameters every round of 2 steps x 1 row of 8,192 Zipf token ids
+    (the largest H of 1, 2, 4, 8 whose round stays under 3 s on the
+    v5e: benchmark/traffic/localsgd2-t8192.json): the benchmark cell
+    ``keye-vl2.localsgd2.t8192`` (needs a 16 GB chip).  ``toy=True``
+    keeps the form (an indexer of 4 heads that keeps 16 of up to 64
+    keys a query, per-head norms, 8 of 32 softmax-routed experts held)
+    at widths a CPU trains in seconds."""
+    if toy:
+        decoder = keye_vl2_decoder(
+            hidden_size=64, intermediate_size=128, head_dim=16,
+            num_key_value_heads=2, num_attention_heads=4,
+            num_hidden_layers=2, num_experts=32, num_local_experts=32,
+            num_experts_per_tok=4, moe_intermediate_size=32,
+            experts_held=8,
+            rope_scaling={"mrope_section": [2, 3, 3],
+                          "rope_type": "default", "type": "default"},
+            sa_config={"indexer_head_dim": 8, "indexer_num_heads": 4,
+                       "indexer_num_kv_heads": 1, "kv_chunk_size": 16,
+                       "q_chunk_size": 16, "topk": 16})
+        vocab, seq = 256, 64
+    else:
+        decoder = keye_vl2_decoder(num_hidden_layers=4, experts_held=8)
+        vocab, seq = 18992, 8192
+    return _localsgd2("keye-localsgd2", decoder, model="decoder", seed=32,
+                      vocab=vocab, seq=seq, rows=16 if toy else 4, toy=toy)
 
 
 PRESETS = {
@@ -297,6 +363,8 @@ PRESETS = {
     "seqlm": seqlm_ring,
     "laguna-localsgd2": laguna_localsgd2,
     "laguna-localsgd2-toy": lambda: laguna_localsgd2(toy=True),
+    "keye-localsgd2": keye_localsgd2,
+    "keye-localsgd2-toy": lambda: keye_localsgd2(toy=True),
     # Fault-injection variants (dopt.faults.FaultPlan): the same
     # workloads under a production-shaped failure regime — per-round
     # client crashes, a straggler deadline finishing half the local

@@ -34,7 +34,11 @@ and backward, nested in ``dopt_local``; ``dopt.models.zoo``),
 fleet with its bias add, forward and backward, nested in ``dopt_local``
 and in ``dopt_eval``; ``dopt.models.zoo``), and the decoder's, all
 nested in ``dopt_local`` (``dopt.models.decoder``):
-``dopt_attn`` (normed input to gated output projection), ``dopt_moe``
+``dopt_attn`` (normed input to gated output projection) and, in a layer
+with an indexer, inside it ``dopt_index`` (indexer projections, index
+scores, selection, alignment term) with ``dopt_select`` inside that
+(the k-th largest score and the mask) and ``dopt_attend`` (masked
+scores, softmax, values, the head-mean), ``dopt_moe``
 (router to combined output) with ``dopt_route`` inside it (scores,
 top-k, combine weights and their application, not the expert matmuls),
 ``dopt_head`` (final norm, logits, loss).

@@ -1,7 +1,10 @@
-"""The gated window/full-attention mixture-of-experts decoder
-(``dopt.models.decoder``, ``model="laguna"``) against its plain
-reference (``benchmark/reference_models/laguna_xs2.py``), at toy widths
-on the CPU in float32 with seeded random weights.
+"""The mixture-of-experts decoder (``dopt.models.decoder``,
+``model="decoder"``) against the plain references of its two layers
+(``benchmark/reference_models/laguna_xs2.py``: gated window/full
+attention, sigmoid-routed experts beside a shared one;
+``keye_vl2.py``: a learned sparse attention with its lightning indexer
+and alignment term, softmax-routed experts), at toy widths on the CPU in
+float32 with seeded random weights.
 
 Tolerances.  Both sides compute in float32 on the CPU (exact products,
 no reduced-precision matmul), so what separates them is the ORDER of the
@@ -26,11 +29,13 @@ import numpy as np
 import pytest
 
 from benchmark import adapter, reference
+from benchmark.reference_models import keye_vl2 as keye
 from benchmark.reference_models import laguna_xs2 as ref
 from dopt.config import (DataConfig, DecoderConfig, ExperimentConfig,
                          GossipConfig, ModelConfig, OptimizerConfig)
 from dopt.models.decoder import (GatedMoEDecoder, _gated_mlp,
-                                 blocked_causal_attention)
+                                 blocked_causal_attention,
+                                 indexed_causal_attention, select_top_keys)
 
 ROOT = Path(__file__).resolve().parents[1]
 RTOL = 2e-5
@@ -41,6 +46,14 @@ VOCAB, DIM, T = 40, 32, 21
 # band of 6, 16 published experts, 4 a token.
 TOY = dict(ref.PUBLISHED, head_dim=8, kv_heads=2, window=6, experts=16,
            top_k=4)
+# ... and the sparse-attention model's: rows of 48 positions of which a
+# query keeps 8 (so the selection drops keys from position 8 on), an
+# indexer of 4 heads of 8.
+KEYE = json.loads(
+    (ROOT / "benchmark/configs/keye-vl2-30b-a3b.json").read_text())
+KEYE_T = 48
+KEYE_TOY = dict(keye.PUBLISHED, head_dim=8, kv_heads=2, experts=16, top_k=4,
+                index_heads=4, index_dim=8, index_top=8)
 
 
 def toy_decoder(heads, *, full_every=4, dense_layers=1, held=4, offset=0,
@@ -74,9 +87,42 @@ def toy_model(heads, *, attn_block=8, head_block=16, **kw) -> GatedMoEDecoder:
                            attn_block=attn_block, head_block=head_block)
 
 
-def batch(seed=1, rows=3):
+def keye_decoder(layers=3, *, held=4, offset=0, topk=8, **kw) -> DecoderConfig:
+    """The published sparse-attention configuration with the toy's
+    sizes."""
+    sa = {**KEYE["sa_config"], "indexer_num_heads": KEYE_TOY["index_heads"],
+          "indexer_head_dim": KEYE_TOY["index_dim"], "topk": topk}
+    body = {**KEYE["model"]["decoder"],
+            "hidden_size": DIM, "intermediate_size": 64,
+            "num_hidden_layers": layers, "num_attention_heads": 4,
+            "num_key_value_heads": KEYE_TOY["kv_heads"],
+            "head_dim": KEYE_TOY["head_dim"],
+            "num_experts": KEYE_TOY["experts"],
+            "num_local_experts": KEYE_TOY["experts"],
+            "num_experts_per_tok": KEYE_TOY["top_k"],
+            "moe_intermediate_size": 16, "sa_config": sa,
+            "rope_scaling": {"mrope_section": [1, 1, 2],
+                             "rope_type": "default", "type": "default"},
+            "experts_held": held, "expert_offset": offset, **kw}
+    return DecoderConfig(**body)
+
+
+def keye_model(layers=3, *, attn_block=4, head_block=16, **kw):
+    """... as a worker whose blocks of 4 queries go 8 to a run (32
+    positions): a row of 48 takes two runs, with 32 and 48 keys."""
+    return GatedMoEDecoder(keye_decoder(layers, **kw), vocab_rows=VOCAB,
+                           attn_block=attn_block, head_block=head_block)
+
+
+def keye_params(seed=0, layers=3, held=4):
+    return jax.tree.map(jnp.asarray, keye.init(
+        seed, KEYE_TOY, vocab=VOCAB, dim=DIM, heads=4, layers=layers,
+        expert=16, held=held))
+
+
+def batch(seed=1, rows=3, t=T):
     rng = np.random.default_rng(seed)
-    x = rng.integers(0, VOCAB, (rows, T)).astype(np.int32)
+    x = rng.integers(0, VOCAB, (rows, t)).astype(np.int32)
     y = np.concatenate([x[:, 1:], np.full((rows, 1), -1, np.int32)], 1)
     w = np.ones(rows, np.float32)
     w[-1] = 0.0                    # a padding row: no position of it counts
@@ -98,25 +144,47 @@ PATTERNS = {
     "one-layer": ([4], {}),
     "sparse-first": ([4, 6], {"dense_layers": 0}),
 }
+# the sparse-attention layer: three layers whose every query keeps 8 keys
+# (both runs of blocks select); two that keep 40 (the first run, of 32
+# keys, keeps all it sees and the second selects) in blocks of 20
+# queries, so that the row ends in a shorter block of its own.
+KEYE_PATTERNS = {
+    "keye-top8": (3, {}),
+    "keye-top40-ragged": (2, {"topk": 40, "attn_block": 20}),
+}
 
 
-@pytest.fixture(scope="module", params=sorted(PATTERNS))
-def both(request):
-    """(program's logits, loss, gradients, aux), (reference's) for one
-    pattern of layers, from the same seeded parameters and batch."""
-    heads, kinds = PATTERNS[request.param]
+def case(name):
+    """(model, seeded parameters, (x, y, w), the reference's forward and
+    objective) for one pattern of either model type."""
+    if name in KEYE_PATTERNS:
+        layers, kw = KEYE_PATTERNS[name]
+        spec = {**KEYE_TOY, "index_top": kw.get("topk", 8)}
+        return (keye_model(layers, **kw), keye_params(0, layers),
+                batch(t=KEYE_T),
+                functools.partial(keye.forward, spec=spec),
+                functools.partial(keye.objective, spec=spec))
+    heads, kinds = PATTERNS[name]
     spec = {**TOY, **kinds}
     params = jax.tree.map(jnp.asarray, ref.init(
         0, spec, vocab=VOCAB, dim=DIM, heads=heads, dense=64, expert=16,
         held=4))
-    model = toy_model(heads, **kinds)
-    x, y, w = batch()
+    return (toy_model(heads, **kinds), params, batch(),
+            functools.partial(ref.forward, spec=spec),
+            functools.partial(ref.objective, spec=spec))
+
+
+@pytest.fixture(scope="module", params=sorted({**PATTERNS, **KEYE_PATTERNS}))
+def both(request):
+    """(program's logits, loss, gradients, aux), (reference's) for one
+    pattern of layers, from the same seeded parameters and batch."""
+    model, params, (x, y, w), forward, objective = case(request.param)
     (loss, aux), grads = jax.value_and_grad(
         lambda p: model.loss(p, x, y, w), has_aux=True)(params)
     want_loss, want_grads = jax.value_and_grad(
-        lambda p: ref.objective(p, x, y, w, spec))(params)
+        lambda p: objective(p, x, y, w))(params)
     return ((model.apply({"params": params}, x), loss, grads, aux),
-            (ref.forward(params, x, spec), want_loss, want_grads))
+            (forward(params, x), want_loss, want_grads))
 
 
 def test_logits_equal_the_reference(both):
@@ -131,8 +199,9 @@ def test_loss_equals_the_reference(both):
 
 
 def test_gradients_equal_the_reference(both):
-    """Leaf by leaf, each held to its own largest value: a router's or a
-    gate's gradient is orders of magnitude under the head's."""
+    """Leaf by leaf, each held to its own largest value: a router's, a
+    gate's or an indexer's gradient is orders of magnitude under the
+    head's."""
     got, want = both
     bad = {jax.tree_util.keystr(k) for (k, g), w in zip(
         jax.tree_util.tree_leaves_with_path(got[2]),
@@ -157,25 +226,47 @@ def test_init_builds_the_reference_tree_and_the_published_count():
         == PUBLISHED["parameters"] == 389_634_048
 
 
+def test_init_builds_the_sparse_attention_tree_and_its_count():
+    mine = keye_model().init(jax.random.key(0))["params"]
+    assert (jax.tree.map(lambda a: a.shape, mine)
+            == jax.tree.map(lambda a: a.shape, keye_params()))
+    layer = mine["layer0"]
+    assert "gate" not in layer and "shared" not in layer
+    assert float(jnp.abs(layer["indexer"]["k_bias"]).max()) == 0.0
+    body = ModelConfig(**KEYE["model"])
+    full = GatedMoEDecoder(body.decoder, vocab_rows=body.num_classes)
+    shapes = jax.eval_shape(full.init, jax.random.key(0))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)) \
+        == KEYE["parameters"] == 314_396_160
+
+
 # ------------------------------------------------------- the shares add up
 
 @pytest.mark.parametrize("held", [1, 4, 8, 16])
-def test_the_shares_add_up_to_the_uncut_layer(held):
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+def test_the_shares_add_up_to_the_uncut_layer(model_type, held):
     """For every share of the 16 published experts, the layer's routed
-    part, plus the shared expert counted once, is what the uncut
-    reference layer gives: nothing stands in for the absent chips, and
-    nothing of theirs is lost or doubled."""
-    spec = {**TOY, "dense_layers": 0}
-    layer = jax.tree.map(jnp.asarray, ref.init(
-        2, spec, vocab=VOCAB, dim=DIM, heads=[4], dense=64, expert=16,
-        held=TOY["experts"])["layer0"])
+    part, plus the shared expert (where the layer has one) counted once,
+    is what the uncut reference layer gives: nothing stands in for the
+    absent chips, and nothing of theirs is lost or doubled."""
     m = jnp.asarray(np.random.default_rng(3).standard_normal(
         (T, DIM)).astype(np.float32))
-    want = ref._experts(layer, m, spec)
-    shared = _gated_mlp(layer["shared"], m, jnp.float32)
+    if model_type == "laguna":
+        spec = {**TOY, "dense_layers": 0}
+        layer = jax.tree.map(jnp.asarray, ref.init(
+            2, spec, vocab=VOCAB, dim=DIM, heads=[4], dense=64, expert=16,
+            held=TOY["experts"])["layer0"])
+        want = ref._experts(layer, m, spec)
+        shared = _gated_mlp(layer["shared"], m, jnp.float32)
+    else:
+        layer = keye_params(2, 1, KEYE_TOY["experts"])["layer0"]
+        want = keye._experts(layer, m, KEYE_TOY)
+        shared = 0.0
     total, slots = shared, 0.0
     for offset in range(0, TOY["experts"], held):
-        model = toy_model([4], dense_layers=0, held=held, offset=offset)
+        model = (toy_model([4], dense_layers=0, held=held, offset=offset)
+                 if model_type == "laguna"
+                 else keye_model(1, held=held, offset=offset))
         share = {**layer, "experts": jax.tree.map(
             lambda a: a[offset:offset + held], layer["experts"])}
         out, counts = model._experts(share, m)
@@ -186,16 +277,100 @@ def test_the_shares_add_up_to_the_uncut_layer(held):
     assert abs(slots - 1.0) < 1e-6
 
 
-def test_routing_counts_are_counts():
-    model = toy_model([4, 6])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+def test_routing_counts_are_counts(model_type):
+    model = toy_model([4, 6]) if model_type == "laguna" else keye_model(2)
     params = model.init(jax.random.key(1))["params"]
-    x, y, w = batch()
+    x, y, w = batch(t=T if model_type == "laguna" else KEYE_T)
     _, aux = model.loss(params, x, y, w)
     assert set(aux) == {"acc", *model.counters}
     # 4 of 16 held: a quarter of the slots in expectation, never over
     # top_k * held / (top_k * 1) and the fullest expert at least the mean
     assert 0.0 < float(aux["moe_held_slot_share"]) < 1.0
     assert float(aux["moe_load_max_over_mean"]) >= 1.0
+    if model_type == "KeyeVL2":
+        # exactly min(t + 1, 8) of the t + 1 visible keys a query: a count
+        kept = sum(min(t + 1, 8) for t in range(KEYE_T))
+        assert float(aux["index_keys_kept_share"]) == pytest.approx(
+            kept / (KEYE_T * (KEYE_T + 1) / 2), rel=1e-6)
+        assert float(aux["index_align_loss"]) > 0.0     # a KL divergence
+
+
+# ------------------------------------------- the learned sparse attention
+
+def test_each_loss_term_trains_its_own_leaves():
+    """The indexer's leaves get the alignment term's gradient alone and
+    every other leaf the cross-entropy's alone: exact zeros, not small
+    numbers (the indexer's input is detached, the head-mean of the
+    probabilities is a constant of the alignment term and the selection
+    is not differentiated)."""
+    model, params = keye_model(), keye_params()
+    x, y, w = batch(t=KEYE_T)
+    layers = model.cfg.num_hidden_layers
+
+    def terms(p):
+        loss, aux = model.loss(p, x, y, w)
+        align = layers * aux["index_align_loss"]
+        return loss - align, align
+
+    by_ce = jax.grad(lambda p: terms(p)[0])(params)
+    by_align = jax.grad(lambda p: terms(p)[1])(params)
+    for path, g in jax.tree_util.tree_leaves_with_path(by_ce):
+        mine = "indexer" in jax.tree_util.keystr(path)
+        assert (float(jnp.abs(g).max()) == 0.0) == mine, path
+    for path, g in jax.tree_util.tree_leaves_with_path(by_align):
+        mine = "indexer" in jax.tree_util.keystr(path)
+        assert (float(jnp.abs(g).max()) > 0.0) == mine, path
+
+
+@pytest.mark.parametrize("tq, tk, top, scores", [
+    (8, 48, 8, "normal"),         # every row drops keys
+    (48, 48, 8, "normal"),        # the first rows see fewer than they keep
+    (40, 300, 130, "normal"),     # three chunks of the running count
+    (48, 48, 8, "levels"),        # many ties at the k-th place
+    (48, 48, 8, "zeros"),         # all tied, -0.0 among them: the first k
+    (16, 200, 1, "levels"),       # one key a query
+])
+def test_the_selection_keeps_exactly_k_keys_ties_to_the_lower_position(
+        tq, tk, top, scores):
+    """``select_top_keys`` (bisection on the score's bits, a running
+    count for the ties) against the reference's stable sort: the same
+    mask, with exactly min(t + 1, k) keys a query."""
+    rng = np.random.default_rng(tk + top)
+    index = {"normal": rng.standard_normal((tq, tk)),
+             "levels": rng.integers(-2, 3, (tq, tk)) * 0.5,
+             "zeros": np.where(rng.random((tq, tk)) < 0.5, 0.0, -0.0)
+             }[scores].astype(np.float32)
+    at = tk - tq + np.arange(tq)                  # the row's last queries
+    seen = jnp.asarray(np.arange(tk)[None, :] <= at[:, None])
+    count = jnp.minimum(jnp.asarray(at) + 1, top)
+    got = select_top_keys(jnp.asarray(index), seen, count)
+    np.testing.assert_array_equal(got.sum(-1), count)
+    assert not bool((got & ~seen).any())
+    np.testing.assert_array_equal(
+        got, keye.select(jnp.asarray(index), seen, top))
+    if scores == "zeros":         # the diagonal's rule: the lowest positions
+        np.testing.assert_array_equal(
+            got, np.arange(tk)[None, :] < np.asarray(count)[:, None])
+
+
+@pytest.mark.parametrize("t, block", [(48, 4), (21, 8), (37, 16)])
+def test_keeping_every_key_is_dense_causal_attention(t, block):
+    """With ``topk`` >= T the selection drops nothing: the indexed body
+    is causal attention whatever the indexer says, every visible key is
+    counted and T need not be a multiple of the block."""
+    rng = np.random.default_rng(t)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+    q, k, v = draw(2, 3, t, 8), draw(2, t, 8), draw(2, t, 8)
+    got, align, kept = indexed_causal_attention(
+        q, k, v, draw(4, t, 8), draw(t, 8), draw(t, 4), topk=t, block=block)
+    assert close(got, blocked_causal_attention(q, k, v, window=None,
+                                               block=block))
+    assert float(kept) == pytest.approx(1.0, rel=1e-6)
+    assert float(align) > 0.0
 
 
 # ----------------------------------------------------- banded attention
@@ -221,27 +396,36 @@ def test_blocked_attention_equals_masked_full_attention(t, window, block):
 
 # -------------------------------------------------- through the engine
 
-def gossip_config(**model_kw) -> ExperimentConfig:
+def gossip_config(model_type="laguna", **model_kw) -> ExperimentConfig:
+    """Two workers of the toy of either model type under the traffic of
+    its cell (``model="laguna"`` is the first one's name for
+    ``"decoder"``, and stays valid)."""
+    laguna = model_type == "laguna"
     traffic = json.loads(
-        (ROOT / "benchmark/traffic/localsgd2-t4096.json").read_text())
+        (ROOT / "benchmark/traffic"
+         / ("localsgd2-t4096.json" if laguna else "localsgd2-t8192.json")
+         ).read_text())
     return ExperimentConfig(
         name="decoder-toy", seed=3, mesh_devices=1,
         data=DataConfig(dataset="synthetic_tokens", num_users=2, iid=True,
                         synthetic_train_size=8, synthetic_test_size=2),
-        model=ModelConfig(model="laguna", faithful=False, num_classes=VOCAB,
-                          input_shape=(T,),
-                          decoder=toy_decoder([4, 6, 6], **model_kw)),
+        model=ModelConfig(
+            model="laguna" if laguna else "decoder", faithful=False,
+            num_classes=VOCAB, input_shape=(T if laguna else KEYE_T,),
+            decoder=(toy_decoder([4, 6, 6], **model_kw) if laguna
+                     else keye_decoder(2, **model_kw))),
         optim=OptimizerConfig(lr=0.05, momentum=0.9),
         gossip=GossipConfig(**{**traffic["gossip"], "local_bs": 2}))
 
 
-def test_two_gossip_rounds_equal_the_reference_loop():
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+def test_two_gossip_rounds_equal_the_reference_loop(model_type):
     """``GossipTrainer`` on token rows against ``benchmark.reference``'s
     gossip loop: same initial parameters, batches and mixing matrices,
     two rounds of two steps a worker."""
     from dopt.engine import GossipTrainer
 
-    cfg = gossip_config()
+    cfg = gossip_config(model_type)
     traffic = {"engine": "gossip", "eval": "none"}
     trainer = adapter.build_trainer(cfg, traffic)
     assert isinstance(trainer, GossipTrainer)
@@ -249,9 +433,12 @@ def test_two_gossip_rounds_equal_the_reference_loop():
     rounds = adapter.reference_rounds(trainer, cfg, traffic, 2)
     trainer.run(rounds=2)
     got = adapter.final_params(trainer, traffic)
+    objective = (functools.partial(ref.objective, spec=TOY)
+                 if model_type == "laguna"
+                 else functools.partial(keye.objective, spec=KEYE_TOY))
     want = reference.run_gossip(
-        functools.partial(ref.objective, spec=TOY), init, rounds,
-        lr=cfg.optim.lr, momentum=cfg.optim.momentum)
+        objective, init, rounds, lr=cfg.optim.lr,
+        momentum=cfg.optim.momentum)
     moved = reference.max_abs_error(want, [init] * 2)
     assert moved > 1e-3
     assert reference.max_abs_error(got, want) <= 1e-4 * moved
@@ -261,6 +448,9 @@ def test_two_gossip_rounds_equal_the_reference_loop():
         assert np.isfinite(r["avg_train_loss"])
         assert 0.0 < r["moe_held_slot_share"] < 1.0
         assert r["moe_load_max_over_mean"] >= 1.0
+        if model_type == "KeyeVL2":
+            assert r["index_align_loss"] > 0.0
+            assert 0.0 < r["index_keys_kept_share"] < 1.0
 
 
 def test_the_cell_averages_the_two_workers():
@@ -303,33 +493,119 @@ def test_refusals():
         ModelConfig(model="laguna", decoder={"no_such_key": 1})
     with pytest.raises(ValueError, match="not among"):
         toy_decoder([4], held=4, offset=14)
+    with pytest.raises(ValueError, match="needs ModelConfig.decoder"):
+        build_model("decoder")
+    with pytest.raises(ValueError, match="model='decoder' only"):
+        build_model("mlp", decoder=keye_decoder())
 
 
-def test_compiled_round_carries_the_decoder_scopes():
+@pytest.mark.parametrize("change, message", [
+    ({"model_type": "qwen3_moe"}, "one of laguna"),
+    ({"gating": False}, "a key of model_type 'laguna'"),
+    ({"shared_expert_intermediate_size": 16}, "a key of model_type 'laguna'"),
+    ({"sa_config": None}, "sa_config is required"),
+    ({"rope_theta": None}, "rope_theta is required"),
+    ({"num_attention_heads": None}, "num_attention_heads is required"),
+    ({"sa_config": {"topk": 8}}, "sa_config has the keys"),
+    ({"sa_config": {**KEYE["sa_config"], "indexer_num_kv_heads": 2}},
+     "ONE key head"),
+    ({"sa_config": {**KEYE["sa_config"], "topk": 0}}, "ONE key head"),
+    ({"norm_topk_prob": False}, "renormalises"),
+    ({"decoder_sparse_step": 0}, "renormalises"),
+    ({"sliding_window": 512}, "renormalises"),
+    ({"use_sliding_window": True}, "renormalises"),
+    ({"hidden_act": "gelu"}, "renormalises"),
+    ({"num_local_experts": 8}, "renormalises"),
+    ({"rope_scaling": {"rope_type": "yarn"}}, "plain rotary"),
+    ({"rope_scaling": {"mrope_section": [16, 24, 24]}}, "half a head"),
+    ({"attention_bias": True}, "no biases"),
+    ({"num_attention_heads": 3}, "multiple of"),
+])
+def test_the_sparse_attention_config_refuses(change, message):
+    """A key of the new ``config.json`` that the layer cannot honour, a
+    key of the other model type, and a missing one are refused."""
+    with pytest.raises(ValueError, match=message):
+        keye_decoder(**change)
+
+
+def test_a_laguna_config_refuses_the_other_type_s_keys():
+    with pytest.raises(ValueError, match="a key of model_type 'KeyeVL2'"):
+        toy_decoder([4], sa_config=KEYE["sa_config"])
+    with pytest.raises(ValueError, match="gating true"):
+        toy_decoder([4], gating=False)
+    with pytest.raises(ValueError, match="layer_types is required"):
+        toy_decoder([4], layer_types=None)
+    with pytest.raises(TypeError):       # a key no config.json here has
+        keye_decoder(index_n_heads=4)
+
+
+def test_which_mlp_a_sparse_attention_layer_has():
+    """``decoder_sparse_step`` and ``mlp_only_layers`` as Qwen3-MoE
+    reads them: the published 1 and [] make every layer sparse."""
+    assert all(keye_decoder(4).sparse_mlp(i) for i in range(4))
+    cfg = keye_decoder(4, decoder_sparse_step=2, mlp_only_layers=[3])
+    assert [cfg.sparse_mlp(i) for i in range(4)] == [False, True, False,
+                                                     False]
+    tree = GatedMoEDecoder(cfg, vocab_rows=VOCAB).init(
+        jax.random.key(0))["params"]
+    assert "mlp" in tree["layer0"] and "router" in tree["layer1"]
+
+
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+def test_compiled_round_carries_the_decoder_scopes(model_type):
     """``dopt_attn``, ``dopt_moe`` with ``dopt_route`` inside it and
     ``dopt_head`` mark the decoder's forward and backward inside the
-    local phase; the update and the mix keep their own scopes."""
+    local phase; the update and the mix keep their own scopes.  A layer
+    with an indexer also has ``dopt_index`` with ``dopt_select`` inside
+    it and ``dopt_attend``, all inside ``dopt_attn``; the selection has
+    no backward pass."""
     import re
 
     from jax._src.config import enable_compilation_cache
 
     from dopt.engine import GossipTrainer
 
-    _, lowered = GossipTrainer(gossip_config(),
+    _, lowered = GossipTrainer(gossip_config(model_type),
                                eval_every=10**9).lower_round(1)
     with enable_compilation_cache(False):   # the key ignores metadata
         text = lowered.compile().as_text()
     stacks = {s for s in re.findall(r'op_name="([^"]*)"', text)
               if s.startswith("jit(")}
-    for scope in ("dopt_attn", "dopt_moe", "dopt_route", "dopt_head"):
+    indexed = ("dopt_index", "dopt_attend")
+    for scope in ("dopt_attn", "dopt_moe", "dopt_route", "dopt_head",
+                  *(indexed if model_type == "KeyeVL2" else ())):
         mine = {s for s in stacks if scope in s}
         assert mine, scope
         assert all("dopt_local" in s for s in mine if "dopt_eval" not in s)
         assert any("transpose(jvp(" in s for s in mine), scope
+    for scope in (*indexed, "dopt_select"):
+        mine = {s for s in stacks if scope in s}
+        assert bool(mine) == (model_type == "KeyeVL2"), scope
+        assert all("dopt_attn" in s for s in mine), scope
+    assert all("dopt_index" in s for s in stacks if "dopt_select" in s)
+    # ... it runs again in a block's recompute and never as a transpose
+    assert all("rematted_computation" in s for s in stacks
+               if "dopt_select" in s and "transpose(jvp(" in s)
+    assert not [s for s in stacks if "dopt_index" in s and "dopt_attend" in s]
     assert all("dopt_moe" in s for s in stacks if "dopt_route" in s)
     assert not [s for s in stacks if "dopt_attn" in s and "dopt_moe" in s]
     for scope in ("dopt_update", "dopt_mix", "dopt_batch"):
         assert any(scope in s for s in stacks), scope
+
+
+def test_the_laguna_round_is_the_parent_s_program():
+    """PR 32 put a second layer into the decoder: the toy ``laguna``
+    round lowers to the text it lowered to before (its hash on commit
+    975eb78, jax 0.9.0 on the CPU), so what the ``laguna-xs2`` cell
+    compiles did not move."""
+    import hashlib
+
+    from dopt.engine import GossipTrainer
+
+    _, lowered = GossipTrainer(gossip_config(),
+                               eval_every=10**9).lower_round(1)
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == (
+        "c208f2b4259daf453581c77d95fde30e1846f74187b0e35fc9dfc7b7c8360f16")
 
 
 def test_the_federated_engine_refuses_the_decoder_with_a_pointer():
@@ -346,10 +622,8 @@ def test_the_federated_engine_refuses_the_decoder_with_a_pointer():
 
 def _grad_program(name):
     """(jitted gradient of the toy's loss, its parameters) for a pattern."""
-    heads, kinds = PATTERNS[name]
-    model = toy_model(heads, **kinds)
+    model, _, (x, y, w), *_ = case(name)
     params = model.init(jax.random.key(4))["params"]
-    x, y, w = batch()
     return jax.jit(jax.grad(lambda p: model.loss(p, x, y, w)[0])), params
 
 
@@ -357,9 +631,10 @@ def _recomputed_matmuls(grad, params):
     """Name stacks of the compiled ``dot`` / ``convolution`` instructions
     under ``rematted_computation``, jax's name for a checkpoint's
     recompute, but those of the head block and of the attention block's
-    OWN checkpoint (the scope then stands before the recompute's name:
-    the ``jax.numpy`` blocks never hold their scores, which is what the
-    fused kernel's backward does inside itself)."""
+    OWN checkpoint (the scope then stands before the recompute's name,
+    with the ``lax.map`` over a run of blocks between them in the indexed
+    body: the ``jax.numpy`` blocks never hold their scores, which is what
+    the fused kernel's backward does inside itself)."""
     import re
 
     from jax._src.config import enable_compilation_cache
@@ -368,12 +643,13 @@ def _recomputed_matmuls(grad, params):
         text = grad.lower(params).compile().as_text()
     found = re.findall(r'= \S+ (?:dot|convolution)\(.*op_name="([^"]*)"', text)
     assert any("rematted_computation" not in s for s in found)
+    own = re.compile(r"dopt_attn/(while/body/closed_call/)?checkpoint/"
+                     r"rematted_computation")
     return [s for s in found if "rematted_computation" in s
-            and "dopt_head" not in s
-            and "dopt_attn/checkpoint/rematted_computation" not in s]
+            and "dopt_head" not in s and not own.search(s)]
 
 
-@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+@pytest.mark.parametrize("pattern", sorted({**PATTERNS, **KEYE_PATTERNS}))
 def test_a_layer_recomputes_no_matmul(pattern):
     """A count, on the CPU: the backward pass computes none of a layer's
     matmuls again: q / k / v, the per-head gate, the output projection,
@@ -396,14 +672,18 @@ def test_the_count_sees_what_a_bare_policy_recomputes(monkeypatch):
         assert any(name in s for s in stacks), name
 
 
-@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+@pytest.mark.parametrize("pattern", sorted({**PATTERNS, **KEYE_PATTERNS}))
 def test_gradients_equal_those_without_any_checkpoint(monkeypatch, pattern):
     """The kept values are the values the recompute would produce: every
     gradient leaf equals that of the same layers with ``jax.checkpoint``
     the identity (layer, head block and attention block) to 1e-6 of the
-    leaf's largest value."""
+    leaf's largest value (5e-6 for the sparse attention: the alignment
+    term's gradient is the difference of two distributions, softmax of
+    the index scores less the attention's head-mean, and what XLA fuses
+    differently on the two sides is not cancelled; 1.07e-6 was read)."""
     from dopt.models import decoder
 
+    tol = 5e-6 if pattern in KEYE_PATTERNS else 1e-6
     grad, params = _grad_program(pattern)
     got = grad(params)
     assert "prevent_cse" in str(jax.make_jaxpr(grad)(params))
@@ -415,7 +695,7 @@ def test_gradients_equal_those_without_any_checkpoint(monkeypatch, pattern):
     bad = {jax.tree_util.keystr(k) for (k, g), w in zip(
         jax.tree_util.tree_leaves_with_path(got),
         jax.tree.leaves(plain(params)))
-        if not np.abs(g - w).max() <= 1e-6 * np.abs(w).max()}
+        if not np.abs(g - w).max() <= tol * np.abs(w).max()}
     assert not bad
 
 
