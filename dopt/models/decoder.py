@@ -31,7 +31,10 @@ file is the same mathematics arranged for the chip:
   the layer has an indexer the mask comes from the data and a third
   body takes it (``indexed_causal_attention``: index scores, selection,
   masked softmax, values and the alignment term a block of queries at a
-  time, so no T x T array is held for a row);
+  time, so no T x T array is held for a row; what follows the selection
+  runs as this repo's own Pallas kernels, ``dopt.ops.sparse_attention``,
+  wherever their shape limits allow, ``indexed_attention_path``, and in
+  ``jax.numpy`` elsewhere);
 * an expert layer that is TOLD which experts it holds
   (``expert_offset``, ``experts_held``), routes every token over all
   ``num_experts`` published ones and adds its own experts' part beside
@@ -51,12 +54,15 @@ file is the same mathematics arranged for the chip:
   pass recomputes elementwise work and the router's top-k, and no matmul.
 
 Scopes (inside the engines' ``dopt_local``): ``dopt_attn`` (normed input
-to gated output projection; the splash kernels carry no name stack and
-go by their own names, ``splash_mqa_*``) and, where the layer has an
+to gated output projection; a compiled kernel may carry no name stack
+and goes by its own name: ``splash_mqa_*``, and
+``dopt_attn_dopt_attend_fwd`` / ``_probs`` / ``_bwd``, which spell out
+the two scopes they stand in) and, where the layer has an
 indexer, inside it ``dopt_index`` (indexer projections, index scores,
 selection, alignment term) with ``dopt_select`` inside that (the k-th
 largest score and the mask, alone) and ``dopt_attend`` (masked scores,
-softmax, value product, the head-mean); ``dopt_moe`` (router to combined
+softmax, value product, the head-mean: the three kernels and the little
+that feeds them, or the ``jax.numpy`` body); ``dopt_moe`` (router to combined
 output) with ``dopt_route`` inside it (scores, top-k, combine weights
 and their application, not the expert matmuls), ``dopt_head`` (final
 norm, logits, loss).  ``loss`` also returns the step's counts
@@ -75,6 +81,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from dopt.config import DecoderConfig
+from dopt.ops import sparse_attention
 
 # What a layer's ``jax.checkpoint`` keeps of its forward pass
 # (``LAYER_KEEPS``).  ``ATTN_RESIDUALS``: the fused attention kernel's
@@ -96,7 +103,10 @@ from dopt.config import DecoderConfig
 # for them; norm, rotary and cast are recomputed), the indexer's three
 # products likewise, and under ``ATTN_RESIDUALS`` the indexed
 # attention's output, so that the blocks run forward once for the
-# layer and once inside their own checkpoints, not a third time.
+# layer and once inside their own checkpoints, not a third time; where
+# the blocks run the fused kernels, also their log-sum-exp, and the
+# recompute inside a block's checkpoint is then the index scores, the
+# selection and the head-mean, not the attention.
 ATTN_RESIDUALS = "attn_residuals"
 MATMUL_PRODUCTS = "matmul_products"
 LAYER_KEEPS = (ATTN_RESIDUALS, MATMUL_PRODUCTS)
@@ -112,12 +122,15 @@ INDEX_COUNTERS = ("index_align_loss", "index_keys_kept_share")
 # smaller ones to ``GatedMoEDecoder``.
 ATTN_BLOCK = 512
 HEAD_BLOCK = 1024
-# The indexed attention's blocks hold their float32 scores in HBM, for
-# every head and worker at once (0.5 GB an array at 256 queries, two
-# workers and 8,192 keys; PERF.md, PR 32).  ``INDEX_SPAN`` blocks in
-# a row share one piece of code (``jax.lax.map``) and one extent of keys,
-# the end of the last of them: 1 would compile every block apart, the
-# whole row would multiply every block with every key.
+# A block of the indexed attention holds its float32 INDEX scores in HBM
+# for every indexer head and worker at once (0.27 GB at 256 queries, two
+# workers, 16 heads and 8,192 keys), and so does the ``jax.numpy`` body
+# with its attention scores (0.5 GB an array; PERF.md, PR 32; the fused
+# kernels keep theirs in VMEM, PR 33).  ``INDEX_SPAN`` blocks in a row
+# share one piece of code (``jax.lax.map``) and one extent of keys, the
+# end of the last of them: 1 would compile every block apart, the whole
+# row would multiply every block with every key (the kernels visit no
+# tile of keys past a block's last query whatever the extent).
 INDEX_BLOCK = 256
 INDEX_SPAN = 4
 # Every matrix is normal(0, INITIALIZER_RANGE), every norm weight 1 (the
@@ -377,14 +390,49 @@ def _masked_softmax(x, keep, log: bool = False):
     return shifted - jnp.log(total) if log else jnp.exp(shifted) / total
 
 
-def _indexed_block(q, k, v, qi, ki, wi, first, *, topk: int):
+def _masked_attention(q, k, v, keep):
+    """The sparse attention's body in ``jax.numpy``, the definition the
+    fused kernels are held to: q [G, R, Tq, D], k and v [G, Tk, D], keep
+    [Tq, Tk] bool -> (output [G, R, Tq, D], the head-mean [Tq, Tk] of the
+    float32 probabilities, a constant under differentiation).  Each
+    float32 [G, R, Tq, Tk] array passes through HBM."""
+    scores = jnp.einsum("grqd,gkd->grqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(q.shape[-1])
+    probs = _masked_softmax(scores, keep)
+    out = jnp.einsum("grqk,gkd->grqd", probs.astype(v.dtype), v)
+    return out, jax.lax.stop_gradient(jnp.mean(probs, axis=(0, 1)))
+
+
+def indexed_attention_path(t: int, head_dim: int,
+                           block: int = INDEX_BLOCK) -> str:
+    """Which body the indexed attention's blocks run for a row of ``t``
+    positions: ``"indexed-fused"``, the Pallas kernels of
+    ``dopt.ops.sparse_attention``, wherever their shape limits allow it
+    -- the row whole blocks, ``block`` and the head multiples of the
+    chip's 128 lanes -- else ``"indexed"``, the ``jax.numpy`` body.
+    Nothing else decides it: no option, no platform (the kernels are
+    interpreted on the CPU)."""
+    fits = t % block == 0 and sparse_attention.fits(block, t, head_dim)
+    return "indexed-fused" if fits else "indexed"
+
+
+def _indexed_block(q, k, v, qi, ki, wi, first, *, topk: int, fused: bool):
     """One block of queries, at positions ``first ...``, of the indexed
     attention against the keys ``0 .. Tk-1`` (every key the block can
     see): q [G, R, Tq, D], k and v [G, Tk, D], and the indexer's qi
     [J, Tq, E], ki [Tk, E] in the compute dtype, wi [Tq, J] float32 ->
     (attention output [G, R, Tq, D], the block's alignment sum, its count
     of selected keys).  Scores, selection, softmax and the alignment
-    term in float32."""
+    term in float32.
+
+    ``fused`` (``indexed_attention_path``) says which body runs under
+    ``dopt_attend``.  The ``jax.numpy`` one (``_masked_attention``) is the
+    definition.  The kernels keep the scores in VMEM, visit no tile of
+    keys past the block's last query, hand back the head-mean from a
+    kernel of its own and put the output and the log-sum-exp under
+    ``ATTN_RESIDUALS``, so that a recompute runs the index scores, the
+    selection and the head-mean again and not the attention."""
     at = first + jnp.arange(q.shape[-2])
     seen = jnp.arange(k.shape[-2])[None, :] <= at[:, None]
     with jax.named_scope("dopt_index"):
@@ -398,12 +446,11 @@ def _indexed_block(q, k, v, qi, ki, wi, first, *, topk: int):
         else:                    # statically: every visible key is kept
             chosen = seen
     with jax.named_scope("dopt_attend"):
-        scores = jnp.einsum("grqd,gkd->grqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(q.shape[-1])
-        probs = _masked_softmax(scores, chosen)
-        out = jnp.einsum("grqk,gkd->grqd", probs.astype(v.dtype), v)
-        target = jax.lax.stop_gradient(jnp.mean(probs, axis=(0, 1)))
+        if fused:
+            out, target = sparse_attention.masked_attention(
+                q, k, v, chosen, first, residual_name=ATTN_RESIDUALS)
+        else:
+            out, target = _masked_attention(q, k, v, chosen)
     with jax.named_scope("dopt_index"):
         logp = _masked_softmax(index, chosen, log=True)
         align = jnp.where(
@@ -413,20 +460,36 @@ def _indexed_block(q, k, v, qi, ki, wi, first, *, topk: int):
 
 
 def indexed_causal_attention(q, k, v, qi, ki, wi, *, topk: int,
-                             block: int = INDEX_BLOCK):
+                             block: int = INDEX_BLOCK, project=None):
     """Causal attention of one row in which query t attends the
     ``min(t + 1, topk)`` keys its indexer scores highest (the third body
-    of the attention: the mask comes from the data, so no fused kernel
-    in the tree takes it): q [G, R, T, D], k and v [G, T, D], qi
-    [J, T, E], ki [T, E], wi [T, J] -> (output [G, R, T, D], the row's
-    alignment term, its share of selected among visible keys).
+    of the attention: the mask comes from the data, and the kernels that
+    take it are this repo's own, ``dopt.ops.sparse_attention``): q
+    [G, R, T, D], k and v [G, T, D], qi [J, T, E], ki [T, E], wi [T, J]
+    -> (output [G, R, T, D], the row's alignment term, its share of
+    selected among visible keys).  ``project`` (a run's outputs
+    [blocks, G, R, block, D] -> [blocks * block, d]) is applied before
+    the runs are joined and its rows [T, d] come back in the output's
+    place: a layer hands in its output projection, because the joined
+    attention outputs would stand beside the kept ones, a second copy of
+    all of them, in the layer's backward pass (0.29 GB of the benchmark
+    cell's round, compile only; PERF.md, PR 33).
 
     A block of ``block`` queries at a time, each ``jax.checkpoint``-ed,
     ``INDEX_SPAN`` blocks in a row through one ``jax.lax.map`` against
     the keys up to the end of the last of them; T need not be a multiple
-    of ``block`` (the remainder is a block of its own)."""
+    of ``block`` (the remainder is a block of its own).  Which body a
+    block's ``dopt_attend`` runs is ``indexed_attention_path``'s to say,
+    from the shapes alone; the two are held to each other, outputs,
+    head-means and gradients, in ``tests/test_decoder.py``, and a traced
+    run shows which ran by the kernels' names."""
     t = q.shape[-2]
-    body = jax.checkpoint(functools.partial(_indexed_block, topk=topk))
+    fused = indexed_attention_path(t, q.shape[-1], block) == "indexed-fused"
+    # A block's checkpoint keeps what the kernels put under the name (the
+    # jax.numpy body puts nothing there): the output and the log-sum-exp.
+    body = jax.checkpoint(
+        functools.partial(_indexed_block, topk=topk, fused=fused),
+        policy=jax.checkpoint_policies.save_only_these_names(ATTN_RESIDUALS))
     edges = list(range(0, t - t % block, block * INDEX_SPAN))
     runs = [(s, min(s + block * INDEX_SPAN, t - t % block), block)
             for s in edges]
@@ -447,10 +510,13 @@ def indexed_causal_attention(q, k, v, qi, ki, wi, *, topk: int,
                                 b[2], b[3]),
             (blocks(q, 2), blocks(qi, 1), blocks(wi, 0),
              s + size * jnp.arange(n)))
-        outs.append(jnp.moveaxis(out, 0, 2).reshape(*q.shape[:2], e - s, -1))
+        if not fused:      # (the kernels name theirs, a block at a time)
+            out = checkpoint_name(out, ATTN_RESIDUALS)
+        outs.append(project(out) if project else jnp.moveaxis(
+            out, 0, 2).reshape(*q.shape[:2], e - s, -1))
         aligns.append(jnp.sum(align))
         kepts.append(jnp.sum(kept))
-    out = checkpoint_name(jnp.concatenate(outs, axis=2), ATTN_RESIDUALS)
+    out = jnp.concatenate(outs, axis=0 if project else 2)
     return out, sum(aligns) / t, sum(kepts) / (t * (t + 1) / 2)
 
 
@@ -480,10 +546,12 @@ class GatedMoEDecoder:
         self.counters = COUNTERS + (INDEX_COUNTERS if cfg.indexed else ())
 
     def attention_path(self, t: int) -> str:
-        """``"indexed"``, ``"splash"`` or ``"blocked"`` for rows of ``t``
-        positions (``dopt.run`` prints it beside the device)."""
+        """``"indexed-fused"`` or ``"indexed"``, ``"splash"`` or
+        ``"blocked"`` for rows of ``t`` positions (``dopt.run`` prints it
+        beside the device)."""
         if self.cfg.indexed:
-            return "indexed"
+            return indexed_attention_path(t, self.cfg.head_dim,
+                                          self.attn_block)
         return attention_path(t, self.cfg.head_dim, self.attn_block)
 
     # ---------------------------------------------------------- params
@@ -584,12 +652,17 @@ class GatedMoEDecoder:
                 k = _rotary(_rms(_keep(heads_of("k", kv)), p["k_norm"],
                                  c.rms_norm_eps), rope).astype(dt)
                 v = _keep(heads_of("v", kv).astype(dt))
+                wo = p["o"].astype(dt).reshape(kv, heads // kv, hd, -1)
+                # (a run of blocks at a time: see ``project`` there)
                 out, align, kept = indexed_causal_attention(
                     q.reshape(kv, heads // kv, t, hd), k, v,
                     *self._index(p["indexer"], x, rope),
-                    topk=c.sa_config["topk"], block=self.attn_block)
+                    topk=c.sa_config["topk"], block=self.attn_block,
+                    project=lambda out: jnp.einsum(
+                        "bgrqe,gred->bqd", out, wo,
+                        preferred_element_type=jnp.float32
+                    ).reshape(-1, wo.shape[-1]))
                 counts = dict(zip(INDEX_COUNTERS, (align, kept)))
-                out = out.reshape(heads, t, hd)
             else:
                 q = _rotary(heads_of("q", heads), rope)
                 k = _keep(_rotary(heads_of("k", kv), rope).astype(dt))
@@ -600,10 +673,12 @@ class GatedMoEDecoder:
                     "td,dn->nt", x, p["gate"].astype(dt),
                     preferred_element_type=jnp.float32)))
                 out = out.reshape(heads, t, hd) * gate[..., None].astype(dt)
+                out = jnp.einsum(
+                    "nte,ned->td", out,
+                    p["o"].astype(dt).reshape(heads, hd, -1),
+                    preferred_element_type=jnp.float32)
                 counts = None
-            return _keep(h + jnp.einsum(
-                "nte,ned->td", out, p["o"].astype(dt).reshape(heads, hd, -1),
-                preferred_element_type=jnp.float32)), counts
+            return _keep(h + out), counts
 
     def _route(self, router, m):
         """[T, held] combine weights (0 where a token was not routed to

@@ -763,6 +763,131 @@ def test_decoder_takes_the_kernel_where_the_shapes_fit():
     assert all(jax.tree.leaves(jax.tree.map(close, got_g, want_g)))
 
 
+# ------------------------------------- the sparse attention's fused kernels
+
+# name: (first query's position, keys, keys kept a query, levels of the
+# index scores: few levels plant ties at the threshold)
+MASKS = {
+    "first-block-keeps-every-visible-key": (0, 128, 128, None),
+    "a-single-key-a-row": (256, 384, 1, None),
+    "ties-at-the-threshold": (128, 256, 40, 5),
+    "a-late-block-two-tiles-of-keys": (896, 1024, 100, None),
+}
+
+
+@pytest.mark.parametrize("mask", sorted(MASKS))
+def test_sparse_attention_kernels_equal_the_jax_numpy_body(mask):
+    """The three Pallas kernels (interpreted on the CPU) against the
+    ``jax.numpy`` body (``decoder._masked_attention``) for masks that come from the selection: output,
+    head-mean target, dq, dk and dv, 2 query heads on each of 2 key/value
+    heads, float32 (so only the order of the arithmetic differs)."""
+    from dopt.models.decoder import _masked_attention
+    from dopt.ops.sparse_attention import masked_attention
+
+    first, tk, top, levels = MASKS[mask]
+    rng = np.random.default_rng(11)
+    tq, d = 128, 128
+    q = jnp.asarray(rng.standard_normal((2, 2, tq, d)).astype(np.float32))
+    k, v = (jnp.asarray(rng.standard_normal((2, tk, d)).astype(np.float32))
+            for _ in range(2))
+    w = jnp.asarray(rng.standard_normal((2, 2, tq, d)).astype(np.float32))
+    index = rng.standard_normal((tq, tk)).astype(np.float32)
+    if levels:
+        index = np.round(index * levels / 4) * 4 / levels
+    at = first + np.arange(tq)
+    seen = jnp.asarray(np.arange(tk)[None, :] <= at[:, None])
+    keep = select_top_keys(jnp.asarray(index), seen,
+                           jnp.minimum(at + 1, top))
+    kept = np.asarray(keep).sum(-1)
+    assert (kept == np.minimum(at + 1, top)).all()
+    if levels:      # the threshold is tied in most rows: that is the case
+        edge = np.where(np.asarray(keep), index, np.inf).min(-1)
+        assert ((np.asarray(seen) & (index == edge[:, None])).sum(-1)
+                > 1).mean() > 0.5
+
+    def fused(q, k, v):
+        return masked_attention(q, k, v, keep, first)
+
+    def plain(q, k, v):
+        return _masked_attention(q, k, v, keep)
+
+    for got, want in zip(fused(q, k, v), plain(q, k, v)):
+        assert close(got, want)
+    got, want = (jax.grad(lambda *a: jnp.sum(f(*a)[0] * w),
+                          argnums=(0, 1, 2))(q, k, v)
+                 for f in (fused, plain))
+    # (held to the largest of the three: where a row keeps one key, dq
+    # and dk are zero and the kernel's are a float32 rounding of zero)
+    scale = max(float(jnp.abs(x).max()) for x in want)
+    assert all(float(jnp.abs(g - x).max()) <= RTOL * scale
+               for g, x in zip(got, want))
+
+
+def test_sparse_decoder_takes_the_kernels_where_the_shapes_fit():
+    """Only the kernels' shape limits choose the body of the indexed
+    attention: rows that are whole blocks, a block and a head that are
+    multiples of the chip's 128 lanes -- the benchmark's cell, the preset
+    and the configuration file take the kernels, the toys and the
+    rehearsal's 48 positions ``jax.numpy``.  At 256 positions and heads of
+    128 a worker with blocks of 128 takes them and one with blocks of 64
+    does not: the loss, both counters and every gradient leaf agree, and
+    the backward pass runs the forward kernel no second time (a block's
+    recompute is the index scores, the selection and the head-mean)."""
+    from dopt.models.decoder import indexed_attention_path
+    from dopt.ops.sparse_attention import KERNEL_NAMES
+    from dopt.presets import get_preset
+
+    assert indexed_attention_path(8192, 128) == "indexed-fused"
+    assert indexed_attention_path(256, 128, 128) == "indexed-fused"
+    for t, d, block in [(256, 128, 64), (384, 128, 256), (256, 8, 128),
+                        (48, 8, 4), (48, 128, 256), (8192 + 128, 128, 256)]:
+        assert indexed_attention_path(t, d, block) == "indexed"
+    for model in (get_preset("keye-localsgd2").model,
+                  ModelConfig(**KEYE["model"])):
+        worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
+        assert worker.attention_path(model.input_shape[0]) == "indexed-fused"
+    rehearsal = KEYE["rehearsal"]["model"]["input_shape"][0]
+    assert worker.attention_path(rehearsal) == "indexed"
+    assert keye_model().attention_path(KEYE_T) == "indexed"
+
+    kw = dict(head_dim=128, topk=100, rope_scaling={
+        "mrope_section": [16, 24, 24], "rope_type": "default",
+        "type": "default"})
+    x = np.random.default_rng(7).integers(0, VOCAB, (2, 256)).astype(np.int32)
+    y = np.concatenate([x[:, 1:], np.full((2, 1), -1, np.int32)], 1)
+    w = np.ones(2, np.float32)
+    plain, fused = (keye_model(2, attn_block=block, **kw)
+                    for block in (64, 128))
+    assert (plain.attention_path(256), fused.attention_path(256)) == (
+        "indexed", "indexed-fused")
+    params = plain.init(jax.random.key(2))["params"]
+    (want, want_aux), want_g = jax.value_and_grad(
+        lambda p: plain.loss(p, x, y, w), has_aux=True)(params)
+    (got, got_aux), got_g = jax.value_and_grad(
+        lambda p: fused.loss(p, x, y, w), has_aux=True)(params)
+    assert abs(float(got) - float(want)) <= RTOL * float(want)
+    for name in ("index_align_loss", "index_keys_kept_share"):
+        assert abs(float(got_aux[name]) - float(want_aux[name])) <= (
+            RTOL * float(want_aux[name]))
+    assert all(jax.tree.leaves(jax.tree.map(close, got_g, want_g)))
+    # per layer: forward, head-mean (and again in the recompute), backward
+    from jax._src.core import jaxprs_in_params
+
+    calls = {name: 0 for name in KERNEL_NAMES.values()}
+
+    def count(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] += 1
+            for inner in jaxprs_in_params(eqn.params):
+                count(inner)
+
+    count(jax.make_jaxpr(
+        jax.grad(lambda p: fused.loss(p, x, y, w)[0]))(params).jaxpr)
+    calls = {kind: calls[name] for kind, name in KERNEL_NAMES.items()}
+    assert calls == {"fwd": 2, "probs": 4, "bwd": 2}
+
+
 @pytest.fixture(scope="module")
 def v5e_chip():
     """One chip of a DESCRIBED v5e (nothing is attached: the TPU's
@@ -809,3 +934,46 @@ def test_splash_kernels_compile_for_the_chip_at_any_ambient_precision(
             shape(8, 4096, 128)).compile()
     text = compiled.as_text()
     assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_sparse_attention_kernels_compile_for_the_chip_at_any_ambient_precision(
+        v5e_chip, monkeypatch, precision):
+    """Forward and gradient of the three kernels at the benchmark cell's
+    sizes (4 x 8 heads of 128, a block of 256 queries against 8,192 keys,
+    two workers under a ``vmap`` as the engines stack them) through the
+    real XLA:TPU + Mosaic compile, also at the ``highest`` the parity
+    check sets around the whole program.  The compiled custom calls'
+    names carry both scopes they stand for: the benchmark's readers find
+    them by ``dopt_attn`` and ``dopt_attend``.  Compile only: nothing
+    runs."""
+    import re
+
+    from dopt.ops.sparse_attention import KERNEL_NAMES, masked_attention
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def loss(q, k, v, keep, first):
+        out, target = masked_attention(q, k, v, keep, first,
+                                       residual_name="attn_residuals")
+        return jnp.sum(out.astype(jnp.float32) ** 2) + jnp.sum(target)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e_chip)
+
+    step = jax.vmap(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    in_axes=(0, 0, 0, 0, None))
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(step).lower(
+            shape(2, 4, 8, 256, 128), shape(2, 4, 8192, 128),
+            shape(2, 4, 8192, 128), shape(2, 256, 8192, dtype=jnp.bool_),
+            shape(dtype=jnp.int32)).compile()
+    # the instructions of the Mosaic custom calls, by the name a trace shows
+    called = re.findall(
+        r'%(\S+) = [^\n]*custom-call\([^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())
+    assert len(called) == 3
+    for kernel in KERNEL_NAMES.values():
+        assert sum(kernel in name for name in called) == 1, kernel
+    assert all("dopt_attn" in name and "dopt_attend" in name
+               for name in called)
