@@ -38,20 +38,26 @@ file is the same mathematics arranged for the chip:
 * an expert layer that is TOLD which experts it holds
   (``expert_offset``, ``experts_held``), routes every token over all
   ``num_experts`` published ones and adds its own experts' part beside
-  the shared expert.  Dispatch is dense and dropless: every held expert
-  multiplies every token and a token's combine weight is zero where it
-  was not routed, so the result is exact for ANY routing at a fixed
-  cost of ``experts_held`` expert passes a token (a grouped matmul that
-  skips empty tiles would pay ``num_experts_per_tok * held /
-  num_experts`` of them: ROADMAP R3);
+  the shared expert.  Dispatch is DROPLESS and sparse: the routed
+  (token, held expert) slots are sorted by expert and only they are
+  multiplied, as a grouped matmul whose group sizes are data
+  (``dopt.ops.grouped_experts``: three Pallas kernels that gather a
+  tile's token rows, multiply them with their expert's matrices and add
+  the weighted result back onto the tokens, wherever their shape limits
+  allow, ``expert_path``; the same sorted slots in ``jax.numpy``
+  elsewhere).  No capacity and no dropped slot: the result is exact for
+  ANY routing, from no token at all to every token choosing every held
+  expert, at a cost that follows the slots (``num_experts_per_tok * held
+  / num_experts`` expert passes a token in expectation);
 * the output head and the loss a block of ``HEAD_BLOCK`` positions at a
   time;
 * a layer, an attention block and a head block are ``jax.checkpoint``-ed.
   A layer keeps its matmul products (``LAYER_KEEPS``): the fused attention
   kernel's output, q / k / v as the attention takes them, the per-head
   gate's and the router's products, the residual stream after the output
-  projection and every gated MLP's gate and up products, so its backward
-  pass recomputes elementwise work and the router's top-k, and no matmul.
+  projection, every gated MLP's gate and up products (the held experts'
+  over their routed slots) and the router's chosen experts, so its
+  backward pass recomputes elementwise work, and no matmul and no top-k.
 
 Scopes (inside the engines' ``dopt_local``): ``dopt_attn`` (normed input
 to gated output projection; a compiled kernel may carry no name stack
@@ -63,9 +69,11 @@ selection, alignment term) with ``dopt_select`` inside that (the k-th
 largest score and the mask, alone) and ``dopt_attend`` (masked scores,
 softmax, value product, the head-mean: the three kernels and the little
 that feeds them, or the ``jax.numpy`` body); ``dopt_moe`` (router to combined
-output) with ``dopt_route`` inside it (scores, top-k, combine weights
-and their application, not the expert matmuls), ``dopt_head`` (final
-norm, logits, loss).  ``loss`` also returns the step's counts
+output; the held experts' kernels go by their own names,
+``dopt_moe_experts_fwd`` / ``_dx`` / ``_dw``) with ``dopt_route`` inside
+it (scores, top-k, combine weights, and the dispatch: where each routed
+slot sits among the sorted slots and the combine weights' gradient, not
+the expert matmuls), ``dopt_head`` (final norm, logits, loss).  ``loss`` also returns the step's counts
 (``model.counters``: routing, and the indexer's two), which the gossip
 engine averages into each round's history row.
 """
@@ -81,7 +89,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from dopt.config import DecoderConfig
-from dopt.ops import sparse_attention
+from dopt.ops import grouped_experts, sparse_attention
+from dopt.ops.grouped_experts import running_count as _running_count
 
 # What a layer's ``jax.checkpoint`` keeps of its forward pass
 # (``LAYER_KEEPS``).  ``ATTN_RESIDUALS``: the fused attention kernel's
@@ -91,14 +100,17 @@ from dopt.ops import sparse_attention
 # the attention takes them, the per-head gate's and the router's
 # products before their sigmoids, the float32 residual stream after the
 # output projection, and the gate and up products of the dense MLP, the
-# shared expert and the held experts.  A tag sits on the product itself,
-# not past an activation: the backward pass of ``sigmoid`` or ``top_k``
-# asks for that primitive's own output, which no name reaches.  At the
+# shared expert and the held experts (these over the routed slots, with
+# the slots' layout: ``dopt.ops.grouped_experts``), and the experts the
+# router's ``top_k`` chose (the values are taken at them).  A tag sits
+# on the product itself, not past an activation: the backward pass of
+# ``sigmoid`` asks for that primitive's own output, which no name
+# reaches.  At the
 # benchmark's cell that is 181-236 MB a layer, worker and row beside the
 # kernel's 51-68 (1.01 GB over the five layers, PERF.md, PR 29), and it
 # grows with rows x positions x layers a worker.  The recompute keeps
-# norms, casts, activations, the gated attention output, the combine
-# weights and the router's top-k.  A layer with an indexer keeps q and k
+# norms, casts, activations, the gated attention output and the combine
+# weights.  A layer with an indexer keeps q and k
 # as float32 PRODUCTS, before their per-head norms (whose backward asks
 # for them; norm, rotary and cast are recomputed), the indexer's three
 # products likewise, and under ``ATTN_RESIDUALS`` the indexed
@@ -318,26 +330,6 @@ def splash_causal_attention(q, k, v, *, window: int | None, block: int):
 
     attend.defvjp(forward, backward)
     return attend(q, k, v)
-
-
-def _running_count(flags):
-    """[..., N] bool -> [..., N] float32, how many of a row's flags are
-    set up to and with each position: exact, by two small matmuls over
-    chunks of 128 (counts within a chunk, chunks before it) where a
-    running sum along 8,192 positions would be a long serial pass."""
-    *lead, n = flags.shape
-    chunk = 128
-    pad = -n % chunk
-    x = jnp.pad(flags, [(0, 0)] * len(lead) + [(0, pad)])
-    x = x.reshape(*lead, -1, chunk).astype(jnp.bfloat16)    # 0 / 1: exact
-    upto = jnp.triu(jnp.ones((chunk, chunk), jnp.bfloat16))
-    within = jnp.einsum("...cj,ji->...ci", x, upto,
-                        preferred_element_type=jnp.float32)
-    chunks = x.shape[-2]
-    before = jnp.triu(jnp.ones((chunks, chunks), jnp.float32), 1)
-    offset = jnp.einsum("...c,cd->...d", within[..., -1], before,
-                        precision=jax.lax.Precision.HIGHEST)
-    return (within + offset[..., None]).reshape(*lead, -1)[..., :n]
 
 
 def select_top_keys(index, seen, count):
@@ -681,20 +673,28 @@ class GatedMoEDecoder:
             return _keep(h + out), counts
 
     def _route(self, router, m):
-        """[T, held] combine weights (0 where a token was not routed to
-        that held expert) and the step's routing counts."""
+        """-> ([T, held] combine weights, 0 where a token was not routed
+        to that held expert; [T, held] bool, whether it was; the step's
+        routing counts).  The chosen experts are kept across a layer's
+        remat and the scores taken at them (``top_k``'s own values and
+        gradients, bit for bit), so the backward pass runs no second
+        ``top_k``."""
         c = self.cfg
         scores = _keep(jnp.dot(m, router,
                                precision=jax.lax.Precision.HIGHEST))
+        # sigmoid scores, or a softmax over the published experts
+        scores = (jax.nn.softmax(scores, axis=-1) if c.indexed
+                  else jax.nn.sigmoid(scores))
+        idx = _keep(jax.lax.top_k(jax.lax.stop_gradient(scores),
+                                  c.num_experts_per_tok)[1])
+        # (a masked sum of one term: a gather of single elements, and the
+        # scatter-add that is its gradient, cost this chip 6 ns each)
+        top = jnp.sum(jnp.where(
+            idx[..., None] == jnp.arange(scores.shape[-1]),
+            scores[:, None, :], 0.0), axis=-1)
+        top = top / jnp.sum(top, -1, keepdims=True)       # renormalised
         if not c.indexed:
-            scores = jax.nn.sigmoid(scores)
-            top, idx = jax.lax.top_k(scores, c.num_experts_per_tok)
-            top = (top / jnp.sum(top, -1, keepdims=True)
-                   * c.moe_routed_scaling_factor)
-        else:             # a softmax over the published experts, top-k
-            scores = jax.nn.softmax(scores, axis=-1)   # renormalised
-            top, idx = jax.lax.top_k(scores, c.num_experts_per_tok)
-            top = top / jnp.sum(top, -1, keepdims=True)
+            top = top * c.moe_routed_scaling_factor
         # [T, k, held]: slot j of token t reached held expert e
         hit = ((idx - c.expert_offset)[..., None]
                == jnp.arange(self.experts_held)).astype(jnp.float32)
@@ -704,25 +704,27 @@ class GatedMoEDecoder:
             "moe_load_max_over_mean":
                 load.max() / jnp.maximum(load.mean(), 1.0 / idx.size),
         }
-        return jnp.sum(hit * top[..., None], axis=1), counts
+        return (jnp.sum(hit * top[..., None], axis=1),
+                jnp.sum(hit, axis=1) > 0, counts)
+
+    def expert_path(self) -> str:
+        """``"grouped-fused"`` or ``"grouped"``: which body the held
+        experts run (``dopt.run`` prints it beside the device)."""
+        return grouped_experts.path(self.cfg.hidden_size,
+                                    self.cfg.moe_intermediate_size)
 
     def _experts(self, p, m):
         dt = self.dtype
         with jax.named_scope("dopt_moe"):
             with jax.named_scope("dopt_route"):
-                weight, counts = self._route(p["router"], m)
-            x = m.astype(dt)
-            if "shared" in p:
-                out = _gated_mlp(p["shared"], x, dt).astype(jnp.float32)
-            e = p["experts"]
-            g = _keep(jnp.einsum("td,edf->tef", x, e["gate"].astype(dt)))
-            u = _keep(jnp.einsum("td,edf->tef", x, e["up"].astype(dt)))
-            mid = jax.nn.silu(g) * u
-            with jax.named_scope("dopt_route"):
-                mid = mid * weight[..., None].astype(dt)
-            routed = jnp.einsum("tef,efd->td", mid, e["down"].astype(dt),
-                                preferred_element_type=jnp.float32)
-            return (out + routed if "shared" in p else routed), counts
+                weight, hit, counts = self._route(p["router"], m)
+            out = (_gated_mlp(p["shared"], m.astype(dt), dt
+                              ).astype(jnp.float32)
+                   if "shared" in p else jnp.zeros_like(m))
+            return grouped_experts.grouped_experts(
+                m, hit, weight, p["experts"], out,
+                k=self.cfg.num_experts_per_tok, dtype=dt,
+                keep_name=MATMUL_PRODUCTS), counts
 
     def _layer(self, p, h, i):
         """-> (the residual stream after layer i, its counts: the

@@ -1,6 +1,7 @@
-"""Pallas TPU kernels: the fused update and mix (``fused_update``) and the
-body of the learned sparse attention (``sparse_attention``, which
-``dopt.models.decoder`` imports as a module)."""
+"""Pallas TPU kernels: the fused update and mix (``fused_update``), the
+body of the learned sparse attention (``sparse_attention``) and the held
+experts' dropless grouped matmul (``grouped_experts``; both of which
+``dopt.models.decoder`` imports as modules)."""
 
 from dopt.ops.fused_update import (
     fused_mix_sgd,

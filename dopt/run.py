@@ -105,10 +105,13 @@ def device_details(trainer) -> str:
             f"device_kind={devs[0].device_kind!r} n_devices={len(devs)} "
             f"mesh={dict(trainer.mesh.shape)}")
     # A decoder says which body its attention runs at this row length
-    # (dopt.models.decoder.causal_attention): the fused kernel or jax.numpy.
-    path = getattr(getattr(trainer, "model", None), "attention_path", None)
+    # (dopt.models.decoder.causal_attention) and which its held experts
+    # (dopt.ops.grouped_experts.path): the fused kernels or jax.numpy.
+    model = getattr(trainer, "model", None)
+    path = getattr(model, "attention_path", None)
     if path is not None:
-        line += f" attention={path(trainer.cfg.model.input_shape[0])}"
+        line += (f" attention={path(trainer.cfg.model.input_shape[0])}"
+                 f" experts={model.expert_path()}")
     return line
 
 

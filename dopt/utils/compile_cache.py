@@ -25,7 +25,7 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 # ``dopt.utils.profiling``) bumps it and pays one cold compile.  (Not
 # ``jax_compilation_cache_include_metadata_in_key``: source lines are in
 # that metadata, so every shifted line would recompile every program.)
-PROGRAM_METADATA_VERSION = 5
+PROGRAM_METADATA_VERSION = 6
 
 
 def enable_compile_cache() -> str:

@@ -39,8 +39,11 @@ with an indexer, inside it ``dopt_index`` (indexer projections, index
 scores, selection, alignment term) with ``dopt_select`` inside that
 (the k-th largest score and the mask) and ``dopt_attend`` (masked
 scores, softmax, values, the head-mean), ``dopt_moe``
-(router to combined output) with ``dopt_route`` inside it (scores,
-top-k, combine weights and their application, not the expert matmuls),
+(router to combined output; the held experts' grouped-matmul kernels go
+by their own names, ``dopt_moe_experts_fwd`` / ``_dx`` / ``_dw``, which
+spell the scope out) with ``dopt_route`` inside it (scores, top-k,
+combine weights, and the dispatch around the kernels: the slots' layout
+and the combine weights' gradient, not the expert matmuls),
 ``dopt_head`` (final norm, logits, loss).
 
 The rule: no span or scope without a reader — each of these is read by

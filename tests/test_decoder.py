@@ -594,10 +594,13 @@ def test_compiled_round_carries_the_decoder_scopes(model_type):
 
 
 def test_the_laguna_round_is_the_parent_s_program():
-    """PR 32 put a second layer into the decoder: the toy ``laguna``
-    round lowers to the text it lowered to before (its hash on commit
-    975eb78, jax 0.9.0 on the CPU), so what the ``laguna-xs2`` cell
-    compiles did not move."""
+    """The toy ``laguna`` round lowers to a pinned text (jax 0.9.0 on the
+    CPU), so that a change which is not meant to move what the
+    ``laguna-xs2`` cell compiles is seen not to.  PR 32 put a second
+    layer into the decoder and left the hash of commit 975eb78 standing
+    (``c208f2b4...``); PR 34 moved it on purpose: the held experts' dense
+    dispatch became the sorted slots' grouped matmul, and the router
+    takes its values at kept indices."""
     import hashlib
 
     from dopt.engine import GossipTrainer
@@ -605,7 +608,7 @@ def test_the_laguna_round_is_the_parent_s_program():
     _, lowered = GossipTrainer(gossip_config(),
                                eval_every=10**9).lower_round(1)
     assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == (
-        "c208f2b4259daf453581c77d95fde30e1846f74187b0e35fc9dfc7b7c8360f16")
+        "478ec7f2cd3ed0b8d90bb6f81c756875bc9b9d1e211c8a33b7164836f84d1b8f")
 
 
 def test_the_federated_engine_refuses_the_decoder_with_a_pointer():
@@ -667,7 +670,7 @@ def test_the_count_sees_what_a_bare_policy_recomputes(monkeypatch):
 
     monkeypatch.setattr(decoder, "LAYER_KEEPS", (decoder.ATTN_RESIDUALS,))
     stacks = _recomputed_matmuls(*_grad_program("published-pattern"))
-    for name in ("td,dne->nte", "td,dn->nt", "nte,ned->td", "td,edf->tef",
+    for name in ("td,dne->nte", "td,dn->nt", "nte,ned->td", "epd,edf->pf",
                  "dopt_route"):
         assert any(name in s for s in stacks), name
 
@@ -977,3 +980,268 @@ def test_sparse_attention_kernels_compile_for_the_chip_at_any_ambient_precision(
         assert sum(kernel in name for name in called) == 1, kernel
     assert all("dopt_attn" in name and "dopt_attend" in name
                for name in called)
+
+
+# ------------------------------- the held experts: a dropless grouped matmul
+
+def dense_dispatch(model, p, m):
+    """The dense dropless dispatch the grouped matmul took the place of
+    (PR 34), the definition it is held to: every held expert multiplies
+    every token, and a token's combine weight is zero where it was not
+    routed.  Same router, same shared expert."""
+    dt = model.dtype
+    weight, _, counts = model._route(p["router"], m)
+    x = m.astype(dt)
+    e = p["experts"]
+    g = jnp.einsum("td,edf->tef", x, e["gate"].astype(dt))
+    u = jnp.einsum("td,edf->tef", x, e["up"].astype(dt))
+    mid = jax.nn.silu(g) * u * weight[..., None].astype(dt)
+    out = jnp.einsum("tef,efd->td", mid, e["down"].astype(dt),
+                     preferred_element_type=jnp.float32)
+    if "shared" in p:
+        out = out + _gated_mlp(p["shared"], x, dt).astype(jnp.float32)
+    return out, counts
+
+
+# (hidden size, expert width) of the two bodies: the toy's, which takes the
+# sorted slots in jax.numpy, and the smallest the kernels take (a float32
+# row of whole (8, 128) tiles), interpreted on the CPU.
+EXPERT_BODIES = {"grouped": (DIM, 16), "grouped-fused": (1024, 128)}
+EXPERT_TOKENS = 150            # 128 + 22: a full group is not whole tiles
+HELD, OFFSET = 4, 8
+
+
+def expert_layer(model_type, body, routing, workers=2):
+    """(model, the stacked parameters of ``workers`` expert layers, their
+    inputs [workers, tokens, hidden]) with the routing PLANTED through the
+    router: input feature 0 is a constant 5 and the router's row 0 holds
+    +8 for the held experts every token is to choose and -8 for those no
+    token is to choose (scores of +-40 before the sigmoid or softmax)."""
+    hidden, width = EXPERT_BODIES[body]
+    kw = dict(hidden_size=hidden, moe_intermediate_size=width, held=HELD,
+              offset=OFFSET)
+    model = (toy_model([4], dense_layers=0, **kw) if model_type == "laguna"
+             else keye_model(1, **kw))
+    assert model.expert_path() == body
+    rng = np.random.default_rng(11)
+
+    def mat(*shape):
+        return (0.05 * rng.standard_normal((workers, *shape))
+                ).astype(np.float32)
+
+    p = {"router": mat(hidden, TOY["experts"]),
+         "experts": {"gate": mat(HELD, hidden, width),
+                     "up": mat(HELD, hidden, width),
+                     "down": mat(HELD, width, hidden)}}
+    if model_type == "laguna":
+        p["shared"] = {"gate": mat(hidden, 16), "up": mat(hidden, 16),
+                       "down": mat(16, hidden)}
+    m = rng.standard_normal((workers, EXPERT_TOKENS, hidden)
+                            ).astype(np.float32)
+    held = slice(OFFSET, OFFSET + HELD)
+    if routing != "router":
+        m[:, :, 0] = 5.0
+        p["router"][:, 0, held] = {"all": 8.0, "none": -8.0, "one": -8.0}[
+            routing]
+        if routing == "one":
+            p["router"][:, 0, OFFSET + 1] = 8.0
+    return model, jax.tree.map(jnp.asarray, p), jnp.asarray(m)
+
+
+@pytest.mark.parametrize("routing", ["router", "all", "none", "one"])
+@pytest.mark.parametrize("body", sorted(EXPERT_BODIES))
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+def test_grouped_experts_equal_the_dense_dispatch(model_type, body, routing):
+    """The expert layer's output and EVERY gradient leaf (experts, router,
+    shared expert, input) against the dense dispatch, to 2e-5 of the
+    leaf's own largest value, under a ``vmap`` of two workers, for both
+    bodies of the sorted slots: ``jax.numpy``, and the three kernels
+    interpreted.  Routings: the router's own; every token to all held
+    experts (the worst case: every slot of the arrays is filled, and a
+    group of 150 is a whole tile and a part of one); no token to any
+    held expert (zero slots: the routed part and the experts' gradients
+    are exactly zero, nothing is NaN); all slots to one held expert."""
+    model, p, m = expert_layer(model_type, body, routing)
+    cot = jnp.asarray(np.random.default_rng(12).standard_normal(
+        m.shape).astype(np.float32))
+
+    def run(experts):
+        def worker(p, m, cot):
+            out, counts = experts(p, m)
+            return jnp.sum(out * cot), (out, counts)
+
+        return jax.jit(jax.vmap(jax.value_and_grad(
+            worker, argnums=(0, 1), has_aux=True)))(p, m, cot)
+
+    (_, (want, want_counts)), want_g = run(
+        functools.partial(dense_dispatch, model))
+    (_, (got, counts)), got_g = run(model._experts)
+    share = {"router": None, "all": 1.0, "none": 0.0, "one": 0.25}[routing]
+    if share is not None:
+        assert np.allclose(counts["moe_held_slot_share"], share)
+    for name in want_counts:
+        assert np.array_equal(counts[name], want_counts[name]), name
+    bad = {jax.tree_util.keystr(k) for (k, g), w in zip(
+        jax.tree_util.tree_leaves_with_path((got, got_g)),
+        jax.tree.leaves((want, want_g)))
+        if not np.abs(g - w).max() <= RTOL * np.abs(w).max()}
+    assert not bad
+    assert all(np.isfinite(g).all() for g in jax.tree.leaves((got, got_g)))
+    if routing == "none":
+        shared = (_gated_mlp(jax.tree.map(lambda a: a[0], p["shared"]), m[0],
+                             jnp.float32) if "shared" in p else 0.0)
+        assert np.abs(got[0] - shared).max() <= RTOL * np.abs(m).max()
+        assert all(not np.asarray(g).any()
+                   for g in jax.tree.leaves(got_g[0]["experts"]))
+
+
+@pytest.mark.parametrize("body", sorted(EXPERT_BODIES))
+def test_rows_under_one_set_of_experts_share_the_grouped_matmul(body):
+    """A ``vmap`` over rows whose experts are NOT batched (the model's own,
+    over a worker's rows) inside one over workers: the rows' tokens go
+    through one grouped matmul a worker and the experts' gradients come
+    back summed over the rows, as the dense dispatch's do."""
+    model, p, m = expert_layer("laguna", body, "router", workers=4)
+    m = m.reshape(2, 2, *m.shape[1:])          # 2 workers x 2 rows
+    p = jax.tree.map(lambda a: a[:2], p)
+
+    def run(experts):
+        def worker(p, m):
+            out, _ = jax.vmap(lambda row: experts(p, row))(m)
+            return jnp.sum(jnp.sin(out)), out
+
+        return jax.jit(jax.vmap(jax.value_and_grad(
+            worker, argnums=(0, 1), has_aux=True)))(p, m)
+
+    want = run(functools.partial(dense_dispatch, model))
+    got = run(model._experts)
+    assert all(jax.tree.leaves(jax.tree.map(close, got, want)))
+
+
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+def test_the_router_gives_top_k_s_values_and_gradients_bit_for_bit(
+        model_type):
+    """The router takes its values at the chosen experts
+    (``take_along_axis`` at ``top_k``'s indices, which a layer's
+    checkpoint keeps) where it took ``top_k``'s own: the combine weights
+    and their gradients to the router and the input are the same bits."""
+    model, p, m = expert_layer(model_type, "grouped", "router", workers=1)
+    p, m = jax.tree.map(lambda a: a[0], (p, m))
+    c = model.cfg
+
+    def before(router, m):
+        scores = jnp.dot(m, router, precision=jax.lax.Precision.HIGHEST)
+        scores = (jax.nn.softmax(scores, axis=-1) if c.indexed
+                  else jax.nn.sigmoid(scores))
+        top, idx = jax.lax.top_k(scores, c.num_experts_per_tok)
+        top = top / jnp.sum(top, -1, keepdims=True)
+        if not c.indexed:
+            top = top * c.moe_routed_scaling_factor
+        hit = ((idx - c.expert_offset)[..., None]
+               == jnp.arange(model.experts_held)).astype(jnp.float32)
+        return jnp.sum(hit * top[..., None], axis=1)
+
+    cot = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (EXPERT_TOKENS, HELD)).astype(np.float32))
+    want = jax.value_and_grad(
+        lambda r, m: jnp.sum(before(r, m) * cot), argnums=(0, 1))(
+            p["router"], m)
+    got = jax.value_and_grad(
+        lambda r, m: jnp.sum(model._route(r, m)[0] * cot), argnums=(0, 1))(
+            p["router"], m)
+    assert np.any(np.asarray(got[1][0]))
+    assert all(np.array_equal(g, w) for g, w in zip(
+        jax.tree.leaves(got), jax.tree.leaves(want)))
+
+
+@pytest.mark.parametrize("pattern", ["published-pattern", "keye-top8"])
+def test_the_compiled_toy_holds_one_top_k_a_layer(pattern):
+    """A count, on the CPU: the compiled gradient of the toy's loss holds
+    one ``top_k`` an expert layer (the forward's), where it held two (the
+    layer's recompute ran it again for its indices; PERF.md, PR 29)."""
+    grad, params = _grad_program(pattern)
+    text = grad.lower(params).compile().as_text()
+    layers = sum("router" in params[k] for k in params if "layer" in k)
+    assert layers >= 3
+    assert text.count('custom_call_target="TopK"') == layers
+
+
+def test_the_experts_body_goes_by_shapes_alone():
+    """Only the kernels' shape limits choose the held experts' body: a
+    token's float32 row whole (8, 128) tiles and the expert's width whole
+    lanes -- both benchmark cells, their presets and configuration files
+    take the kernels, the toys and the rehearsals ``jax.numpy``; and
+    ``python -m dopt.run`` prints which."""
+    from dopt.ops.grouped_experts import path, slot_capacity
+    from dopt.presets import get_preset
+    from dopt.run import device_details
+
+    assert path(2048, 512) == path(2048, 768) == "grouped-fused"
+    assert path(1024, 128) == "grouped-fused"
+    for hidden, width in [(DIM, 16), (1024 + 128, 128), (1024, 100),
+                          (512, 128), (64, 64)]:
+        assert path(hidden, width) == "grouped"
+    for preset, config in (("laguna-localsgd2", PUBLISHED),
+                           ("keye-localsgd2", KEYE)):
+        for model in (get_preset(preset).model,
+                      ModelConfig(**config["model"])):
+            worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
+            assert worker.expert_path() == "grouped-fused"
+        toy = ModelConfig(**{**config["model"],
+                             **config["rehearsal"]["model"]})
+        assert GatedMoEDecoder(toy.decoder, vocab_rows=VOCAB
+                               ).expert_path() == "grouped"
+        assert GatedMoEDecoder(get_preset(preset + "-toy").model.decoder,
+                               vocab_rows=VOCAB).expert_path() == "grouped"
+    # the worst case: every token chooses min(k, held) held experts, and
+    # every group's last tile may be a part of one
+    assert slot_capacity(4096, 8, 8) == 4096 * 8 // 128 + 8
+    assert slot_capacity(150, 4, 8) == 5 + 8
+    from dopt.engine import GossipTrainer
+
+    line = device_details(GossipTrainer(gossip_config(), eval_every=10**9))
+    assert line.endswith("attention=blocked experts=grouped")
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("tokens, width, published", [
+    (4096, 512, 256), (8192, 768, 128)])
+def test_grouped_expert_kernels_compile_for_the_chip_at_any_ambient_precision(
+        v5e_chip, monkeypatch, precision, tokens, width, published):
+    """Forward and gradient of the held experts at the two benchmark
+    cells' sizes (8 held experts of 2,048 x ``width``, rows of 4,096 and
+    8,192 tokens, two workers under the engines' ``vmap`` and a row under
+    the model's) through the real XLA:TPU + Mosaic compile, also at the
+    ``highest`` the parity check sets around the whole program: ONE call
+    of each of the three kernels serves both workers (``custom_vmap``
+    folds them into the groups), and the compiled custom calls' names
+    carry the scope they stand for, by which the benchmark's readers
+    find them.  Compile only: nothing runs."""
+    import re
+
+    from dopt.ops.grouped_experts import KERNEL_NAMES, grouped_experts
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def loss(m, hit, weight, experts):
+        out = jax.vmap(lambda m, hit, weight: grouped_experts(
+            m, hit, weight, experts, jnp.zeros_like(m), k=8,
+            dtype=jnp.bfloat16, keep_name="matmul_products"))(m, hit, weight)
+        return jnp.sum(out ** 2)
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct((2, *dims), dtype, sharding=v5e_chip)
+
+    experts = {"gate": shape(8, 2048, width), "up": shape(8, 2048, width),
+               "down": shape(8, width, 2048)}
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.vmap(jax.grad(loss, argnums=(0, 2, 3)))).lower(
+            shape(1, tokens, 2048), shape(1, tokens, 8, dtype=jnp.bool_),
+            shape(1, tokens, 8), experts).compile()
+    called = re.findall(
+        r'%(\S+) = [^\n]*custom-call\([^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())
+    assert len(called) == 3
+    for kernel in KERNEL_NAMES.values():
+        assert sum(kernel in name for name in called) == 1, kernel
+    assert all("dopt_moe" in name for name in called)
