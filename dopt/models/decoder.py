@@ -31,10 +31,12 @@ file is the same mathematics arranged for the chip:
   the layer has an indexer the mask comes from the data and a third
   body takes it (``indexed_causal_attention``: index scores, selection,
   masked softmax, values and the alignment term a block of queries at a
-  time, so no T x T array is held for a row; what follows the selection
-  runs as this repo's own Pallas kernels, ``dopt.ops.sparse_attention``,
-  wherever their shape limits allow, ``indexed_attention_path``, and in
-  ``jax.numpy`` elsewhere);
+  time, so no T x T array is held for a row; the index scores and what
+  follows the selection run as this repo's own Pallas kernels,
+  ``dopt.ops.sparse_attention``, wherever their shape limits allow,
+  ``indexed_attention_path``, and in ``jax.numpy`` elsewhere: with the
+  kernels neither the indexer's [heads, queries, keys] products nor the
+  attention's scores ever leave VMEM, forward or backward);
 * an expert layer that is TOLD which experts it holds
   (``expert_offset``, ``experts_held``), routes every token over all
   ``num_experts`` published ones and adds its own experts' part beside
@@ -62,10 +64,12 @@ file is the same mathematics arranged for the chip:
 Scopes (inside the engines' ``dopt_local``): ``dopt_attn`` (normed input
 to gated output projection; a compiled kernel may carry no name stack
 and goes by its own name: ``splash_mqa_*``, and
-``dopt_attn_dopt_attend_fwd`` / ``_probs`` / ``_bwd``, which spell out
-the two scopes they stand in) and, where the layer has an
-indexer, inside it ``dopt_index`` (indexer projections, index scores,
-selection, alignment term) with ``dopt_select`` inside that (the k-th
+``dopt_attn_dopt_attend_fwd`` / ``_probs`` / ``_bwd`` and
+``dopt_attn_dopt_index_fwd`` / ``_bwd``, which spell out the two scopes
+they stand in) and, where the layer has an
+indexer, inside it ``dopt_index`` (indexer projections, index scores --
+the two index kernels or the ``jax.numpy`` definition --, selection,
+alignment term) with ``dopt_select`` inside that (the k-th
 largest score and the mask, alone) and ``dopt_attend`` (masked scores,
 softmax, value product, the head-mean: the three kernels and the little
 that feeds them, or the ``jax.numpy`` body); ``dopt_moe`` (router to combined
@@ -134,15 +138,17 @@ INDEX_COUNTERS = ("index_align_loss", "index_keys_kept_share")
 # smaller ones to ``GatedMoEDecoder``.
 ATTN_BLOCK = 512
 HEAD_BLOCK = 1024
-# A block of the indexed attention holds its float32 INDEX scores in HBM
-# for every indexer head and worker at once (0.27 GB at 256 queries, two
-# workers, 16 heads and 8,192 keys), and so does the ``jax.numpy`` body
-# with its attention scores (0.5 GB an array; PERF.md, PR 32; the fused
-# kernels keep theirs in VMEM, PR 33).  ``INDEX_SPAN`` blocks in a row
-# share one piece of code (``jax.lax.map``) and one extent of keys, the
-# end of the last of them: 1 would compile every block apart, the whole
-# row would multiply every block with every key (the kernels visit no
-# tile of keys past a block's last query whatever the extent).
+# Queries a block of the indexed attention, and blocks a run.  The
+# ``jax.numpy`` bodies hold a block's float32 products in HBM for every
+# head and worker at once (the indexer's 0.27 GB at 256 queries, two
+# workers, 16 heads and 8,192 keys, the attention's 0.5 GB an array;
+# PERF.md, PR 32); the fused kernels keep both in VMEM (PR 33, PR 35) and
+# visit no tile of keys past a block's last query whatever the extent.
+# ``INDEX_SPAN`` blocks in a row share one piece of code
+# (``jax.lax.map``) and one extent of keys, the end of the last of them,
+# which the selection, the alignment term and (in ``jax.numpy``) every
+# product run over: 1 would compile every block apart, the whole row
+# would make each block's [queries, keys] passes as long as the row.
 INDEX_BLOCK = 256
 INDEX_SPAN = 4
 # Every matrix is normal(0, INITIALIZER_RANGE), every norm weight 1 (the
@@ -396,16 +402,30 @@ def _masked_attention(q, k, v, keep):
     return out, jax.lax.stop_gradient(jnp.mean(probs, axis=(0, 1)))
 
 
-def indexed_attention_path(t: int, head_dim: int,
+def _index_scores(qi, ki, wi):
+    """The lightning indexer's scores in ``jax.numpy``, the definition the
+    fused kernels are held to: qi [J, Tq, E], ki [Tk, E], wi [Tq, J]
+    float32 -> float32 [Tq, Tk], ``sum_j wi[q, j] * relu(qi[j, q] .
+    ki[k])``.  The float32 [J, Tq, Tk] products pass through HBM, forward
+    and backward."""
+    dots = jnp.einsum("jqe,ke->jqk", qi, ki,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots) * wi.T[:, :, None], axis=0)
+
+
+def indexed_attention_path(t: int, head_dim: int, index_heads: int,
                            block: int = INDEX_BLOCK) -> str:
-    """Which body the indexed attention's blocks run for a row of ``t``
+    """Which bodies the indexed attention's blocks run for a row of ``t``
     positions: ``"indexed-fused"``, the Pallas kernels of
-    ``dopt.ops.sparse_attention``, wherever their shape limits allow it
-    -- the row whole blocks, ``block`` and the head multiples of the
-    chip's 128 lanes -- else ``"indexed"``, the ``jax.numpy`` body.
-    Nothing else decides it: no option, no platform (the kernels are
-    interpreted on the CPU)."""
-    fits = t % block == 0 and sparse_attention.fits(block, t, head_dim)
+    ``dopt.ops.sparse_attention`` for the index scores and for what
+    follows the selection, wherever their shape limits allow it -- the
+    row whole blocks, ``block`` and the head multiples of the chip's 128
+    lanes, the ``index_heads`` indexer heads' queries of a block within
+    the 4,096 lanes the index kernels hold side by side -- else
+    ``"indexed"``, the ``jax.numpy`` bodies.  Nothing else decides it: no
+    option, no platform (the kernels are interpreted on the CPU)."""
+    fits = (t % block == 0 and sparse_attention.fits(block, t, head_dim)
+            and sparse_attention.index_fits(block, t, index_heads))
     return "indexed-fused" if fits else "indexed"
 
 
@@ -418,19 +438,23 @@ def _indexed_block(q, k, v, qi, ki, wi, first, *, topk: int, fused: bool):
     of selected keys).  Scores, selection, softmax and the alignment
     term in float32.
 
-    ``fused`` (``indexed_attention_path``) says which body runs under
-    ``dopt_attend``.  The ``jax.numpy`` one (``_masked_attention``) is the
-    definition.  The kernels keep the scores in VMEM, visit no tile of
-    keys past the block's last query, hand back the head-mean from a
-    kernel of its own and put the output and the log-sum-exp under
-    ``ATTN_RESIDUALS``, so that a recompute runs the index scores, the
-    selection and the head-mean again and not the attention."""
+    ``fused`` (``indexed_attention_path``) says which bodies run, the
+    index scores' under ``dopt_index`` and the attention's under
+    ``dopt_attend``.  The ``jax.numpy`` ones (``_index_scores``,
+    ``_masked_attention``) are the definitions.  The kernels keep the
+    [J, Tq, Tk] products and the attention's scores in VMEM, forward and
+    backward, and visit no tile of keys past the block's last query (the
+    index scores are exact zeros there; ``seen`` and ``chosen`` mask them
+    here as they mask the definition's); the attention's hand back the
+    head-mean from a kernel of its own and put the output and the
+    log-sum-exp under ``ATTN_RESIDUALS``, so that a recompute runs the
+    index scores, the selection and the head-mean again and not the
+    attention."""
     at = first + jnp.arange(q.shape[-2])
     seen = jnp.arange(k.shape[-2])[None, :] <= at[:, None]
     with jax.named_scope("dopt_index"):
-        dots = jnp.einsum("jqe,ke->jqk", qi, ki,
-                          preferred_element_type=jnp.float32)
-        index = jnp.sum(jax.nn.relu(dots) * wi.T[:, :, None], axis=0)
+        index = (sparse_attention.index_scores(qi, ki, wi, first) if fused
+                 else _index_scores(qi, ki, wi))
         if k.shape[-2] > topk:
             with jax.named_scope("dopt_select"):
                 chosen = select_top_keys(jax.lax.stop_gradient(index), seen,
@@ -470,13 +494,16 @@ def indexed_causal_attention(q, k, v, qi, ki, wi, *, topk: int,
     A block of ``block`` queries at a time, each ``jax.checkpoint``-ed,
     ``INDEX_SPAN`` blocks in a row through one ``jax.lax.map`` against
     the keys up to the end of the last of them; T need not be a multiple
-    of ``block`` (the remainder is a block of its own).  Which body a
-    block's ``dopt_attend`` runs is ``indexed_attention_path``'s to say,
-    from the shapes alone; the two are held to each other, outputs,
-    head-means and gradients, in ``tests/test_decoder.py``, and a traced
-    run shows which ran by the kernels' names."""
+    of ``block`` (the remainder is a block of its own).  Which bodies a
+    block runs, the index scores' and the attention's, is
+    ``indexed_attention_path``'s to say, from the shapes alone; the
+    kernels and the ``jax.numpy`` definitions are held to each other,
+    scores, outputs, head-means and gradients, in
+    ``tests/test_decoder.py``, and a traced run shows which ran by the
+    kernels' names."""
     t = q.shape[-2]
-    fused = indexed_attention_path(t, q.shape[-1], block) == "indexed-fused"
+    fused = indexed_attention_path(t, q.shape[-1], qi.shape[0],
+                                   block) == "indexed-fused"
     # A block's checkpoint keeps what the kernels put under the name (the
     # jax.numpy body puts nothing there): the output and the log-sum-exp.
     body = jax.checkpoint(
@@ -542,8 +569,9 @@ class GatedMoEDecoder:
         ``"blocked"`` for rows of ``t`` positions (``dopt.run`` prints it
         beside the device)."""
         if self.cfg.indexed:
-            return indexed_attention_path(t, self.cfg.head_dim,
-                                          self.attn_block)
+            return indexed_attention_path(
+                t, self.cfg.head_dim, self.cfg.sa_config["indexer_num_heads"],
+                self.attn_block)
         return attention_path(t, self.cfg.head_dim, self.attn_block)
 
     # ---------------------------------------------------------- params

@@ -1,7 +1,7 @@
 """Pallas TPU kernels: the fused update and mix (``fused_update``), the
-body of the learned sparse attention (``sparse_attention``) and the held
-experts' dropless grouped matmul (``grouped_experts``; both of which
-``dopt.models.decoder`` imports as modules)."""
+learned sparse attention's index scores and body (``sparse_attention``)
+and the held experts' dropless grouped matmul (``grouped_experts``;
+both of which ``dopt.models.decoder`` imports as modules)."""
 
 from dopt.ops.fused_update import (
     fused_mix_sgd,

@@ -1,9 +1,11 @@
-"""Pallas TPU kernels for the body of a learned sparse attention: one
-block of queries against the keys it can see under a mask that comes
-from the DATA (``dopt.models.decoder._indexed_block`` is the definition
-they are held to, ``tests/test_decoder.py``).
+"""Pallas TPU kernels for a learned sparse attention: one block of
+queries against the keys it can see, the lightning indexer's scores of
+those keys and, under the mask the selection makes of them (it comes
+from the DATA), the attention's body (``dopt.models.decoder``'s
+``_index_scores`` and ``_masked_attention`` are the definitions they are
+held to, ``tests/test_decoder.py``).
 
-Three kernels, and no score ever leaves VMEM:
+Three kernels of the body, and no score ever leaves VMEM:
 
 * ``..._fwd``: q [G, R, Tq, D], k and v [G, Tk, D] and ONE mask for all
   G * R heads -> the attention output and the rows' log-sum-exp, by an
@@ -33,6 +35,23 @@ round that calls them a hundred times traces each shape once; a kernel's
 body is two dozen operations because each one is traced, batched and
 lowered again at every call that is left (``setup_s``; PERF.md, PR 33).
 
+Two kernels of the index scores, ``index[q, k] = sum_j wi[q, j] *
+relu(qi[j, q] . ki[k])``, in the same arrangement (all J indexer heads'
+queries side by side, qi read as [J * Tq, E], the products transposed
+[keys, J * Tq], ``wi`` a [1, J * Tq] row, the head sum an add of J lane
+slices), so that no [J, Tq, keys] array ever leaves VMEM:
+
+* ``dopt_attn_dopt_index_fwd``: the float32 [Tq, Tk] scores, zeros in
+  the tiles past the block's last query (forward, and again in a
+  block's ``jax.checkpoint`` recompute);
+* ``dopt_attn_dopt_index_bwd``: from the scores' cotangent (the
+  alignment term's, zero off the selected keys) the products again in
+  VMEM, ``g = dindex * wi * (dots > 0)`` rounded to the compute dtype
+  for its two products (what a float32 cotangent into a default-
+  precision product with a bfloat16 operand is), ``dqi = g . ki`` held
+  per query across the tiles, ``dki = g^T . qi`` a tile's rows, and
+  ``dwi = sum_k relu(dots) * dindex`` in float32.
+
 A masked position contributes exactly 0; every row keeps at least one
 key (a query attends itself), so no row is empty.  Matmul inputs in the
 compute dtype with float32 accumulation; the probabilities are rounded
@@ -44,8 +63,10 @@ parity check sets around the whole program (PERF.md, PR 28).
 A trace's event carries its instruction's name alone, and the name stack
 the benchmark's readers join to it comes from the compiled HLO's
 metadata, which a custom call may lack: so the kernels' own names spell
-out both scopes they stand in, ``dopt_attn`` and ``dopt_attend``.  Compiled on ``tpu``, interpreted on
-``cpu`` (``dopt.ops.pallas_interpret``).
+out both scopes they stand in, ``dopt_attn`` and ``dopt_attend`` or
+``dopt_index`` (and not the other's: each scope's readers divide by the
+time under it).  Compiled on ``tpu``, interpreted on ``cpu``
+(``dopt.ops.pallas_interpret``).
 """
 
 from __future__ import annotations
@@ -70,6 +91,13 @@ KERNEL_NAMES = {kind: f"dopt_attn_dopt_attend_{kind}"
 # which XLA reserves 60 MB more of HBM for the round, and are 29 MB more
 # of code, which the chip holds in HBM too (PERF.md, PR 33).
 KEY_TILE = 256
+INDEX_KERNEL_NAMES = {kind: f"dopt_attn_dopt_index_{kind}"
+                      for kind in ("fwd", "bwd")}
+# The most lanes (indexer heads side by side x queries) the index
+# kernels take: a step's float32 [KEY_TILE, lanes] arrays, 4 MB each at
+# the benchmark's cell, are what their VMEM holds (tiles of 512 keys run
+# them 1-3% faster alone, 128 8-12% slower; PERF.md, PR 35).
+INDEX_LANES = 4096
 _LANES = 128
 # Stands in for -inf under the online maximum: exp(_MASKED - m) is exactly
 # 0 for any real m, and _MASKED - _MASKED is 0, not nan.
@@ -324,6 +352,148 @@ def _backward(q, k, v, keep_t, last, do, lse, delta, *, interpret: bool):
         name=KERNEL_NAMES["bwd"],
     )(last, q, do, lse.reshape(g, 1, r * tq), delta.reshape(g, 1, r * tq),
       k, v, keep_t)
+
+
+# ------------------------------------------------- the indexer's scores
+
+def index_fits(block: int, keys: int, heads: int) -> bool:
+    """Whether the index kernels take a block of ``block`` queries of
+    ``heads`` indexer heads against ``keys`` keys: block and keys whole
+    lane tiles, and all the heads' queries side by side within
+    ``INDEX_LANES`` (a step's float32 [tile, heads * block] arrays are
+    what their VMEM holds).  Any head size: it is the contraction."""
+    return (block % _LANES == 0 and keys % _LANES == 0 and keys >= block
+            and heads * block <= INDEX_LANES)
+
+
+def _index_fwd_kernel(last_ref, q_ref, k_ref, w_ref, out_ref):
+    tq = out_ref.shape[0]
+    j = pl.program_id(0)
+
+    @pl.when(j <= last_ref[0])
+    def _():
+        s = jnp.maximum(_dot(k_ref[...], q_ref[...], _NT), 0.0) * w_ref[...]
+        out_ref[...] = sum(s[:, h * tq:(h + 1) * tq]
+                           for h in range(s.shape[1] // tq)).T
+
+    @pl.when(j > last_ref[0])
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _index_forward(qi, ki, wi, last, *, interpret: bool):
+    j, tq, e = qi.shape
+    keys = ki.shape[0]
+    tk = _key_tile(keys)
+    return pl.pallas_call(
+        _index_fwd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(keys // tk,),
+            in_specs=[
+                pl.BlockSpec((j * tq, e), lambda j, f: (0, 0)),
+                pl.BlockSpec((tk, e), lambda j, f: (_visited(j, f), 0)),
+                pl.BlockSpec((1, j * tq), lambda j, f: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((tq, tk), lambda j, f: (0, j))),
+        out_shape=jax.ShapeDtypeStruct((tq, keys), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=INDEX_KERNEL_NAMES["fwd"],
+    )(last, qi.reshape(j * tq, e), ki, wi.T.reshape(1, j * tq))
+
+
+def _index_bwd_kernel(last_ref, q_ref, k_ref, w_ref, d_ref, dq_ref, dk_ref,
+                      dw_ref, dq_acc):
+    tq = d_ref.shape[0]
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(j <= last_ref[0])
+    def _():
+        k, q = k_ref[...], q_ref[...]
+        s = _dot(k, q, _NT)                                  # [tk, J * Tq]
+        d = jnp.concatenate([d_ref[...].T] * (s.shape[1] // tq), axis=1)
+        dw_ref[...] += jnp.sum(jnp.maximum(s, 0.0) * d, axis=0,
+                               keepdims=True)
+        g = jnp.where(s > 0, d * w_ref[...], 0.0).astype(q.dtype)
+        dk_ref[...] = _dot(g, q).astype(dk_ref.dtype)
+        dq_acc[...] += _dot(k.T, g)                          # [E, J * Tq]
+
+    @pl.when(j > last_ref[0])
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+
+    @pl.when(j == pl.num_programs(0) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].T.astype(dq_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _index_backward(qi, ki, wi, last, dindex, *, interpret: bool):
+    j, tq, e = qi.shape
+    keys = ki.shape[0]
+    tk = _key_tile(keys)
+    heads = pl.BlockSpec((j * tq, e), lambda j, f: (0, 0))
+    row = pl.BlockSpec((1, j * tq), lambda j, f: (0, 0))
+    dq, dk, dw = pl.pallas_call(
+        _index_bwd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(keys // tk,),
+            in_specs=[
+                heads,
+                pl.BlockSpec((tk, e), lambda j, f: (_visited(j, f), 0)),
+                row,
+                pl.BlockSpec((tq, tk), lambda j, f: (0, _visited(j, f))),
+            ],
+            out_specs=[heads, pl.BlockSpec((tk, e), lambda j, f: (j, 0)), row],
+            scratch_shapes=[pltpu.VMEM((e, j * tq), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((j * tq, e), qi.dtype),
+                   jax.ShapeDtypeStruct(ki.shape, ki.dtype),
+                   jax.ShapeDtypeStruct((1, j * tq), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=INDEX_KERNEL_NAMES["bwd"],
+    )(last, qi.reshape(j * tq, e), ki, wi.T.reshape(1, j * tq), dindex)
+    return dq.reshape(qi.shape), dk, dw.reshape(j, tq).T
+
+
+@jax.custom_vjp
+def _index(qi, ki, wi, last):
+    return _index_forward(qi, ki, wi, last, interpret=_interpret())
+
+
+def _index_fwd(qi, ki, wi, last):
+    return _index(qi, ki, wi, last), (qi, ki, wi, last)
+
+
+def _index_bwd(residuals, dindex):
+    return *_index_backward(*residuals, dindex, interpret=_interpret()), None
+
+
+_index.defvjp(_index_fwd, _index_bwd)
+
+
+def index_scores(qi, ki, wi, first):
+    """The lightning indexer's scores of one block of queries, at
+    positions ``first ...``, against the keys ``0 .. Tk-1``: ``index[q, k]
+    = sum_j wi[q, j] * relu(qi[j, q] . ki[k])``, float32 [Tq, Tk], from qi
+    [J, Tq, E] and ki [Tk, E] in the compute dtype and wi [Tq, J] float32;
+    differentiable in all three.  Exact zeros in the tiles of keys past
+    the block's last query, which are not visited (forward or backward:
+    no gradient comes from there or goes there); inside the last visited
+    tile the scores of keys a query cannot see are computed like any
+    other and are the caller's to mask."""
+    last = last_tile(first, qi.shape[1], ki.shape[0])
+    return _index(qi, ki, wi, last)
 
 
 # ------------------------------------------------------------------ surface

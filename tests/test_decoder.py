@@ -837,14 +837,19 @@ def test_sparse_decoder_takes_the_kernels_where_the_shapes_fit():
     the backward pass runs the forward kernel no second time (a block's
     recompute is the index scores, the selection and the head-mean)."""
     from dopt.models.decoder import indexed_attention_path
-    from dopt.ops.sparse_attention import KERNEL_NAMES
+    from dopt.ops.sparse_attention import INDEX_KERNEL_NAMES, KERNEL_NAMES
     from dopt.presets import get_preset
 
-    assert indexed_attention_path(8192, 128) == "indexed-fused"
-    assert indexed_attention_path(256, 128, 128) == "indexed-fused"
-    for t, d, block in [(256, 128, 64), (384, 128, 256), (256, 8, 128),
-                        (48, 8, 4), (48, 128, 256), (8192 + 128, 128, 256)]:
-        assert indexed_attention_path(t, d, block) == "indexed"
+    assert indexed_attention_path(8192, 128, 16) == "indexed-fused"
+    assert indexed_attention_path(256, 128, 4, 128) == "indexed-fused"
+    assert indexed_attention_path(256, 128, 32, 128) == "indexed-fused"
+    for t, d, j, block in [(256, 128, 4, 64), (384, 128, 4, 256),
+                           (256, 8, 4, 128), (48, 8, 4, 4), (48, 128, 16, 256),
+                           (8192 + 128, 128, 16, 256),
+                           # the index kernels hold every indexer head's
+                           # queries of a block side by side: 4,096 lanes
+                           (8192, 128, 17, 256), (256, 128, 33, 128)]:
+        assert indexed_attention_path(t, d, j, block) == "indexed"
     for model in (get_preset("keye-localsgd2").model,
                   ModelConfig(**KEYE["model"])):
         worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
@@ -876,7 +881,8 @@ def test_sparse_decoder_takes_the_kernels_where_the_shapes_fit():
     # per layer: forward, head-mean (and again in the recompute), backward
     from jax._src.core import jaxprs_in_params
 
-    calls = {name: 0 for name in KERNEL_NAMES.values()}
+    calls = {name: 0 for name in (*KERNEL_NAMES.values(),
+                                  *INDEX_KERNEL_NAMES.values())}
 
     def count(jaxpr):
         for eqn in jaxpr.eqns:
@@ -887,8 +893,184 @@ def test_sparse_decoder_takes_the_kernels_where_the_shapes_fit():
 
     count(jax.make_jaxpr(
         jax.grad(lambda p: fused.loss(p, x, y, w)[0]))(params).jaxpr)
-    calls = {kind: calls[name] for kind, name in KERNEL_NAMES.items()}
-    assert calls == {"fwd": 2, "probs": 4, "bwd": 2}
+    # ... and the index scores: forward (and again in the recompute),
+    # backward
+    assert calls == {**dict(zip(KERNEL_NAMES.values(), (2, 4, 2))),
+                     **dict(zip(INDEX_KERNEL_NAMES.values(), (4, 2)))}
+
+
+# --------------------------------------------- the index scores' two kernels
+
+def _index_inputs(seed, t, heads=4, dim=8, lead=()):
+    """(qi [*lead, J, T, E], ki [*lead, T, E], wi [*lead, T, J], a
+    cotangent [*lead, T, T]) for a row of ``t`` positions."""
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((*lead, *shape)).astype(
+        np.float32)) for shape in ((heads, t, dim), (t, dim), (t, heads),
+                                   (t, t)))
+
+
+def _block_scores(body, qi, ki, wi, weight, first, tq, keys):
+    """sum(index * weight) over the keys the block's queries see, and the
+    masked scores: what the decoder takes of a block's index scores."""
+    at = first + jnp.arange(tq)
+    seen = jnp.arange(keys)[None, :] <= at[:, None]
+    index = jnp.where(seen, body(
+        jax.lax.dynamic_slice_in_dim(qi, first, tq, 1), ki[:keys],
+        jax.lax.dynamic_slice_in_dim(wi, first, tq, 0)), 0.0)
+    cut = jax.lax.dynamic_slice_in_dim(weight, first, tq, 0)[:, :keys]
+    return jnp.sum(index * cut), index
+
+
+INDEX_BLOCKS = {"the-first-block": (0, 128), "the-first-block-of-a-run": (0, 512),
+                "mid-row": (384, 512), "mid-row-shared-extent": (256, 1024)}
+
+
+@pytest.mark.parametrize("how", ["alone", "two-vmaps", "map-checkpoint"])
+@pytest.mark.parametrize("where", sorted(INDEX_BLOCKS))
+def test_index_score_kernels_equal_the_jax_numpy_definition(where, how):
+    """The two Pallas kernels of the index scores (interpreted on the
+    CPU) against ``decoder._index_scores``: the scores a block's queries
+    see and all three gradients, float32 (so only the order of the
+    arithmetic differs), 4 indexer heads of 8, blocks of 128 queries
+    against an extent of keys that ends with the block or runs past it
+    (a run's blocks share the extent of its last one): alone, under the
+    engines' two ``vmap``s (workers, rows), and a run of blocks through
+    ``lax.map`` + ``jax.checkpoint`` as ``indexed_causal_attention``
+    calls them."""
+    from dopt.models.decoder import _index_scores
+    from dopt.ops.sparse_attention import index_scores
+
+    first, keys = INDEX_BLOCKS[where]
+    tq, lead = 128, (2, 2) if how == "two-vmaps" else ()
+    inputs = _index_inputs(13, 1024, lead=lead)
+
+    def program(fused):
+        def body(qi, ki, wi, first):
+            return (index_scores(qi, ki, wi, first) if fused
+                    else _index_scores(qi, ki, wi))
+
+        def block(qi, ki, wi, weight, first):
+            return _block_scores(
+                lambda q, k, w: body(q, k, w, first), qi, ki, wi, weight,
+                first, tq, keys)
+
+        def run(qi, ki, wi, weight):
+            if how != "map-checkpoint":
+                return block(qi, ki, wi, weight, first)
+            # every block of the run that ends at ``keys``
+            total, index = jax.lax.map(
+                lambda at: jax.checkpoint(block)(qi, ki, wi, weight, at),
+                jnp.arange(keys - 4 * tq if keys > 4 * tq else 0, keys, tq))
+            return jnp.sum(total), index
+
+        for _ in lead:
+            run = jax.vmap(run)
+        return jax.value_and_grad(
+            lambda *a: (lambda out: (jnp.sum(out[0]), out[1]))(run(*a)),
+            argnums=(0, 1, 2), has_aux=True)
+
+    # (the summed loss cancels to a thousandth of its terms: not compared)
+    ((_, got_index), got_g), ((_, want_index), want_g) = (
+        program(fused)(*inputs) for fused in (True, False))
+    assert close(got_index, want_index)
+    assert float(jnp.abs(want_index).max()) > 1.0
+    for g, w in zip(got_g, want_g):
+        assert float(jnp.abs(w).max()) > 0 and close(g, w)
+
+
+def _one_indexed_block(seed, first, keys, tq=128, topk=40):
+    """Inputs of ``decoder._indexed_block`` at the kernels' smallest
+    shapes: 2 query heads on each of 2 key/value heads of 128, 4 indexer
+    heads of 8."""
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+    return (normal(2, 2, tq, 128), normal(2, keys, 128), normal(2, keys, 128),
+            normal(4, tq, 8), normal(keys, 8), normal(tq, 4))
+
+
+@pytest.mark.parametrize("poison", [float("nan"), 1e30])
+def test_keys_past_a_block_s_last_tile_reach_nothing(poison):
+    """What the fused block holds for the tiles of keys past its last
+    query (a run's blocks share the extent of its last one) reaches no
+    result: with NaN or 1e30 in every row of ``ki``, ``k`` and ``v`` past
+    the block's last tile of 256 keys, the output, the alignment sum,
+    the count of kept keys and all six gradients are those of clean
+    rows, bit for bit, and the poisoned rows' own gradients are exact
+    zeros.  (The index kernels write zeros there and take no gradient
+    from there; the attention's visit no such tile either.)"""
+    from dopt.models.decoder import _indexed_block
+
+    first, keys, seen_to = 128, 768, 256
+    q, k, v, qi, ki, wi = _one_indexed_block(17, first, keys)
+    weight = jnp.asarray(np.random.default_rng(3).standard_normal(
+        q.shape).astype(np.float32))
+
+    def loss(*a):
+        out, align, kept = _indexed_block(*a, first, topk=40, fused=True)
+        return jnp.sum(out * weight) + align, (out, align, kept)
+
+    grad = jax.value_and_grad(loss, argnums=tuple(range(6)), has_aux=True)
+    (_, want), want_g = grad(q, k, v, qi, ki, wi)
+    k, v, ki = (x.at[..., seen_to:, :].set(poison) for x in (k, v, ki))
+    (_, got), got_g = grad(q, k, v, qi, ki, wi)
+    assert float(want[2]) == sum(min(first + i + 1, 40) for i in range(128))
+    for g, w in zip((*got, *got_g), (*want, *want_g)):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    for i in (1, 2, 4):
+        assert not np.asarray(got_g[i][..., seen_to:, :]).any()
+        assert np.asarray(got_g[i][..., :seen_to, :]).any()
+
+
+def test_the_fused_row_is_the_jax_numpy_row(monkeypatch):
+    """``indexed_causal_attention`` over a row of 512 positions in blocks
+    of 128 (one run: 40 keys a query, so every block but the first rows
+    of the first selects), the kernels against the ``jax.numpy`` bodies
+    at the SAME blocks: the output, the alignment term, the kept share,
+    and the gradients of a loss with both terms on all six inputs (the
+    indexer's three get the alignment term's alone).  The two selections
+    are the same masks: where a block's two index scores differ it is by
+    float32 rounding (under 1e-5 of the largest score), and a key chosen
+    by one body alone would sit that close to its query's cut."""
+    from dopt.models import decoder
+    from dopt.ops.sparse_attention import index_scores
+
+    t, tq, topk = 512, 128, 40
+    q, k, v, qi, ki, wi = _one_indexed_block(19, 0, t, tq=t)
+    wi = wi / 8
+    weight = jnp.asarray(np.random.default_rng(5).standard_normal(
+        q.shape).astype(np.float32))
+
+    def loss(*a):
+        out, align, kept = indexed_causal_attention(*a, topk=topk, block=tq)
+        return jnp.sum(out * weight) + 100.0 * align, (out, align, kept)
+
+    grad = jax.value_and_grad(loss, argnums=tuple(range(6)), has_aux=True)
+    assert decoder.indexed_attention_path(t, 128, 4, tq) == "indexed-fused"
+    (_, got), got_g = grad(q, k, v, qi, ki, wi)
+    monkeypatch.setattr(decoder, "indexed_attention_path",
+                        lambda *a: "indexed")
+    (_, want), want_g = grad(q, k, v, qi, ki, wi)
+    assert float(want[1]) > 0 and float(want[2]) < 0.2
+    for g, w in zip((*got, *got_g), (*want, *want_g)):
+        assert close(g, w)
+    for first in range(0, t, tq):
+        at = first + jnp.arange(tq)
+        seen = jnp.arange(t)[None, :] <= at[:, None]
+        block = (qi[:, first:first + tq], ki, wi[first:first + tq])
+        fused, plain = index_scores(*block, first), decoder._index_scores(
+            *block)
+        tol = 1e-5 * float(jnp.abs(plain).max())
+        assert float(jnp.abs(jnp.where(seen, fused - plain, 0)).max()) <= tol
+        count = jnp.minimum(at + 1, topk)
+        a, b = (np.asarray(select_top_keys(x, seen, count))
+                for x in (fused, plain))
+        cut = np.where(b, np.asarray(plain), np.inf).min(-1, keepdims=True)
+        assert (np.abs(np.asarray(plain) - cut)[a != b] <= tol).all()
+        assert (a.sum(-1) == np.asarray(count)).all()
 
 
 @pytest.fixture(scope="module")
@@ -980,6 +1162,59 @@ def test_sparse_attention_kernels_compile_for_the_chip_at_any_ambient_precision(
         assert sum(kernel in name for name in called) == 1, kernel
     assert all("dopt_attn" in name and "dopt_attend" in name
                for name in called)
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_a_fused_run_compiles_for_the_chip_and_holds_no_index_product_in_hbm(
+        v5e_chip, monkeypatch, precision):
+    """One run of the indexed attention at the benchmark cell's sizes (4
+    blocks of 256 queries against 1,024 keys of which a query keeps 512,
+    4 x 8 heads of 128, 16 indexer heads of 64, two workers under a
+    ``vmap``), forward and gradient through the real XLA:TPU + Mosaic
+    compile, also at the ``highest`` the parity check sets around the
+    whole program.  The index kernels go by names that carry
+    ``dopt_attn`` and ``dopt_index`` and neither ``dopt_attend`` nor
+    ``dopt_select`` (whose readers divide by the time under them), and so
+    does their name stack; forward, recompute and backward are one call
+    each; and no float32 array of [16, 256, keys] is left anywhere in
+    the compiled program: the products exist in VMEM alone.  Compile
+    only: nothing runs."""
+    import re
+
+    from dopt.ops.sparse_attention import INDEX_KERNEL_NAMES
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def loss(*a):
+        out, align, _ = indexed_causal_attention(*a, topk=512)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + align
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((2, *dims), dtype, sharding=v5e_chip)
+
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(jax.vmap(jax.grad(loss, argnums=tuple(range(6))))).lower(
+            shape(4, 8, 1024, 128), shape(4, 1024, 128), shape(4, 1024, 128),
+            shape(16, 1024, 64), shape(1024, 64),
+            shape(1024, 16, dtype=jnp.float32)).compile().as_text()
+    called = dict(re.findall(
+        r'%(\S+) = [^\n]*custom-call\([^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', text))
+    mine = {name: stack for name, stack in called.items()
+            if "dopt_index" in name}
+    fwd, bwd = (sorted(n for n in mine if kernel in n)
+                for kernel in INDEX_KERNEL_NAMES.values())
+    # (beside the attention's three: a gradient alone takes its head-mean
+    # in the recompute only)
+    assert (len(fwd), len(bwd)) == (2, 1) and len(called) == 3 + 3
+    for name, stack in mine.items():
+        for text_ in (name, stack):
+            assert "dopt_attn" in text_ and "dopt_index" in text_
+            assert "dopt_attend" not in text_ and "dopt_select" not in text_
+    assert sum("rematted_computation" in mine[n] for n in fwd) == 1
+    assert "transpose(jvp(" in mine[bwd[0]]
+    assert not re.findall(r"f32\[(?:2,)?16,256,\d+\]", text)
+    assert re.findall(r"f32\[2,256,1024\]", text)      # the scores' sum is
 
 
 # ------------------------------- the held experts: a dropless grouped matmul
