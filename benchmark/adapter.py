@@ -2,13 +2,15 @@
 
 It uses only the program's public surface: the ``dopt.config``
 dataclasses, ``GossipTrainer(cfg, eval_every=)`` / ``FederatedTrainer(cfg)``,
-``trainer.run(rounds=)``, ``.params``, ``.theta``, ``.history.rows``,
-``.timers``, ``.mesh``, ``.num_workers``, ``.mixing.for_round(t)``,
-``.index_matrix``, ``.dataset``, ``.lower_round()``, ``dopt.data``'s
-``holdout_split`` / ``make_batch_plan`` / ``gather_batches``, and
-``enable_compile_cache()``.  One seam is not public yet (README, "Seams"):
-FedAvg's client sample is re-drawn here from ``dopt.utils.prng.host_rng``
-with the engine's salt, as ``scripts/oracle_trajectory.py`` does.
+``trainer.run(rounds=)``, ``.params`` (replaced once where the
+configuration pins the initial model), ``.model``, ``.theta``,
+``.history.rows``, ``.timers``, ``.mesh``, ``.num_workers``, ``.faults``,
+``.mixing.for_round(t)``, ``.index_matrix``, ``.dataset``,
+``.lower_round()``, ``dopt.data``'s ``holdout_split`` /
+``make_batch_plan`` / ``gather_batches``, and ``enable_compile_cache()``.
+One seam is not public yet (README, "Seams"): FedAvg's client sample is
+re-drawn here from ``dopt.utils.prng.host_rng`` with the engine's salt, as
+``scripts/oracle_trajectory.py`` does.
 
 Every switch a cell depends on is set explicitly from the cell's two data
 files, so a changed default in the program changes no cell.
@@ -19,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from dopt.config import (DataConfig, ExperimentConfig, FederatedConfig,
@@ -66,6 +69,9 @@ def _section(cls, *sources: dict):
 
 def build_config(name: str, config: dict, traffic: dict, *, seed: int,
                  chips: int) -> ExperimentConfig:
+    """``seed`` is ``--seed``, unless the configuration pins its own
+    (``seed``): then ``--seed`` varies nothing."""
+    seed = config.get("seed", seed)
     engine = traffic["engine"]
     guard = config["guarantees"]
     sections = {engine: _section(ENGINE_SECTIONS[engine], guard,
@@ -138,11 +144,55 @@ def rehearsal_config(cfg: ExperimentConfig, traffic: dict,
                                        local_ep=min(sec.local_ep, 2))})
 
 
-def build_trainer(cfg: ExperimentConfig, traffic: dict):
+def build_trainer(cfg: ExperimentConfig, traffic: dict,
+                  config: dict | None = None):
+    """The cell's trainer.  Where the configuration carries ``init_seed``
+    its workers start from the model drawn from that seed, and ``cfg.seed``
+    (``--seed``) draws everything else: the rows, their shards, the batch
+    plans and the mixing matrices.  Without a configuration (tier-1's
+    callers), the program's own draw."""
+    init_seed = (config or {}).get("init_seed")
     if traffic["engine"] == "gossip":
         every = {"none": NEVER, "every_round": 1}[traffic["eval"]]
-        return GossipTrainer(cfg, eval_every=every)
+        trainer = GossipTrainer(cfg, eval_every=every)
+        if init_seed is None:
+            return trainer
+        if cfg.gossip.mixing != "sync" or trainer.faults.delay_max > 0:
+            # the async mix's previous round and the delayed links'
+            # history start as copies of the initial model
+            raise ValueError(
+                "init_seed replaces only the workers' parameters; this mix "
+                "keeps another copy of the initial model (mixing "
+                f"{cfg.gossip.mixing!r}, link delay up to "
+                f"{trainer.faults.delay_max} rounds)")
+        trainer.params = pinned_params(trainer, cfg, init_seed)
+        return trainer
+    if init_seed is not None:
+        raise ValueError(
+            f"init_seed is for the gossip engine's stacked workers; the "
+            f"{traffic['engine']!r} engine's global model is not pinned")
     return FederatedTrainer(cfg, eval_train=traffic["eval"] == "test+train")
+
+
+def pinned_params(trainer, cfg: ExperimentConfig, init_seed: int):
+    """The fleet's initial parameters drawn from ``init_seed`` as
+    ``GossipTrainer.__init__`` draws them from ``cfg.seed``: one worker's
+    ``model.init`` and its cast to ``param_dtype``, op by op as the
+    program runs them (a jitted draw fuses them and differs in the last
+    bit), then every worker the same, placed as the trainer placed its
+    own draw, which they replace."""
+    # the program's own draw goes first: set-up holds one fleet, as it does
+    # without a pinned model
+    shardings = jax.tree.map(lambda x: x.sharding, trainer.params)
+    jax.tree.map(lambda x: x.delete(), trainer.params)
+    dummy = jnp.zeros((1, *cfg.model.input_shape))
+    dtype = jnp.dtype(cfg.model.param_dtype)
+    one = jax.tree.map(lambda x: x.astype(dtype), trainer.model.init(
+        jax.random.key(init_seed), dummy)["params"])
+    workers = trainer.num_workers
+    fleet = jax.jit(lambda tree: jax.tree.map(lambda x: jnp.broadcast_to(
+        x[None], (workers, *x.shape)), tree), out_shardings=shardings)
+    return fleet(one)
 
 
 def initial_params(trainer, traffic: dict):

@@ -77,7 +77,7 @@ def run(cfg, config: dict, traffic: dict) -> dict:
     pcfg = adapter.parity_config(cfg, traffic)
     objective = reference.load_objective(config["reference"])
     with jax.default_matmul_precision("highest"):
-        trainer = adapter.build_trainer(pcfg, traffic)
+        trainer = adapter.build_trainer(pcfg, traffic, config)
         init = adapter.initial_params(trainer, traffic)
         rounds = adapter.reference_rounds(trainer, pcfg, traffic, n_rounds)
         trainer.run(rounds=n_rounds)

@@ -153,11 +153,14 @@ def _mean_leaf(xs):
     return [_to_host(sum(xs[1:], xs[0]) / len(xs))]
 
 
-def run_gossip(objective, init_params, rounds, *, lr, momentum):
+def run_gossip(objective, init_params, rounds, *, lr, momentum,
+               precision="highest"):
     """``rounds`` is a list of ``{"w": [n, n], "bx": [n, S, B, ...],
     "by", "bw"}``.  Every worker starts from ``init_params``.  Returns
-    the list of the n workers' final parameter trees (numpy)."""
-    with jax.default_matmul_precision("highest"):
+    the list of the n workers' final parameter trees (numpy).
+    ``precision`` is the ambient matmul precision: ``highest`` is the
+    reference; another is a witness (``tests/chip_parity_leaves.py``)."""
+    with jax.default_matmul_precision(precision):
         step = make_step(objective, lr=lr, momentum=momentum)
         n = rounds[0]["w"].shape[0]
         params = [_f32(init_params)] * n

@@ -133,7 +133,7 @@ def set_up_and_measure(args, cell, cfg, meter) -> Window:
     from benchmark import adapter, flops, trace_reduce
 
     config, traffic = cell["config"], cell["traffic"]
-    trainer = adapter.build_trainer(cfg, traffic)
+    trainer = adapter.build_trainer(cfg, traffic, config)
     have = adapter.param_count(trainer)
     listed = flops.param_count(config["layers"])
     # A rehearsal that overrides the model holds a smaller one than listed.

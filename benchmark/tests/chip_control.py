@@ -43,7 +43,7 @@ def main(argv=None) -> int:
                 continue
             adapter.build_trainer = (
                 build if wrap is None
-                else lambda c, t, wrap=wrap: wrap(build(c, t)))
+                else lambda c, t, f, wrap=wrap: wrap(build(c, t, f)))
             try:
                 got = parity.run(cfg, config, tr)
             finally:

@@ -55,7 +55,8 @@ def run_cell(monkeypatch, capsys, fault):
 
     build = adapter.build_trainer
     monkeypatch.setattr(adapter, "build_trainer",
-                        lambda cfg, traffic: FAULTS[fault](build(cfg, traffic)))
+                        lambda cfg, traffic, config: FAULTS[fault](
+                            build(cfg, traffic, config)))
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     rc = run.main(["--workload", CELL, "--seed", "2700000003", "--seconds",
                    "1", "--trace", "0", "--rehearse"])

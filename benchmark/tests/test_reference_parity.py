@@ -42,7 +42,7 @@ def test_a_wrong_neighbour_fails_the_tolerance():
     cfg = adapter.rehearsal_config(adapter.build_config(
         "x", config, traffic, seed=3, chips=1), traffic)
     pcfg = adapter.parity_config(cfg, traffic)
-    trainer = adapter.build_trainer(pcfg, traffic)
+    trainer = adapter.build_trainer(pcfg, traffic, config)
     init = adapter.initial_params(trainer, traffic)
     rounds = adapter.reference_rounds(trainer, pcfg, traffic, 2)
     trainer.run(rounds=2)

@@ -271,7 +271,12 @@ def old_parity_config(cfg, traffic):
                                        local_bs=bs)})
 
 
-@pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
+# The four cells PR 27's seams were written for; the decoder cells bring
+# their own cut (the stated precision) and their own tolerance.
+@pytest.mark.parametrize("name", ["resnet18.gossip32.chip1",
+                                  "resnet18.gossip32.chip4",
+                                  "model1.gossip160-ring",
+                                  "model1.fedavg128"])
 def test_the_shipped_cut_is_the_old_parity_job(name):
     from benchmark import adapter
 
@@ -417,4 +422,4 @@ def test_the_toy_becomes_a_cell_by_files_and_entries_alone(tmp_path,
     assert adapter.parity_config(cfg, traffic) \
         == cfg.replace(name=cfg.name + ".parity")
     with pytest.raises(ValueError, match="sequence model"):
-        adapter.build_trainer(small, traffic)
+        adapter.build_trainer(small, traffic, config)
