@@ -66,8 +66,16 @@ to gated output projection; a compiled kernel may carry no name stack
 and goes by its own name: ``splash_mqa_*``, and
 ``dopt_attn_dopt_attend_fwd`` / ``_probs`` / ``_bwd`` and
 ``dopt_attn_dopt_index_fwd`` / ``_bwd``, which spell out the two scopes
-they stand in) and, where the layer has an
-indexer, inside it ``dopt_index`` (indexer projections, index scores --
+they stand in) with, inside it, ``dopt_qkv`` (the q / k / v projections
+and the per-head gate's), ``dopt_rope`` (what lies between them and the
+attention: per-head norms, rotary, the queries' scale for the kernel,
+the casts to the compute dtype), ``dopt_splash`` (on the kernel's path,
+the kernel's call: what XLA does around it to its operands and
+outputs), ``dopt_oproj``
+(gate, output projection; where the layer has an indexer, a run of
+blocks at a time) -- the layer-input norm and the residual add stay
+under ``dopt_attn`` alone -- and, where the layer has an
+indexer, ``dopt_index`` (indexer projections, index scores --
 the two index kernels or the ``jax.numpy`` definition --, selection,
 alignment term) with ``dopt_select`` inside that (the k-th
 largest score and the mask, alone) and ``dopt_attend`` (masked scores,
@@ -77,7 +85,9 @@ output; the held experts' kernels go by their own names,
 ``dopt_moe_experts_fwd`` / ``_dx`` / ``_dw``) with ``dopt_route`` inside
 it (scores, top-k, combine weights, and the dispatch: where each routed
 slot sits among the sorted slots and the combine weights' gradient, not
-the expert matmuls), ``dopt_head`` (final norm, logits, loss).  ``loss`` also returns the step's counts
+the expert matmuls) and ``dopt_shared`` (the shared expert's MLP);
+``dopt_mlp`` (a dense layer's MLP, without its norm and residual add);
+``dopt_head`` (final norm, logits, loss).  ``loss`` also returns the step's counts
 (``model.counters``: routing, and the indexer's two), which the gossip
 engine averages into each round's history row.
 """
@@ -271,13 +281,18 @@ def causal_attention(q, k, v, *, window: int | None, block: int = ATTN_BLOCK):
     reads the ``splash_mqa_*`` kernels and is absent without them."""
     heads, t, d = q.shape
     grouped = (k.shape[0], heads // k.shape[0], t, d)
-    if attention_path(t, d, block) == "splash":
-        attend, q = splash_causal_attention, q / math.sqrt(d)
-    else:
-        attend = blocked_causal_attention
-    # kept across a layer's remat as the body takes it: each body's own cast
-    q = _keep(q.astype(k.dtype))
-    return attend(q.reshape(grouped), k, v, window=window, block=block)
+    splash = attention_path(t, d, block) == "splash"
+    # kept across a layer's remat as the body takes it: each body's own
+    # cast, the queries' last float32 pass (XLA fuses it with the rotary
+    # in front of it, and a fusion goes by its root)
+    with jax.named_scope("dopt_rope"):
+        q = _keep((q / math.sqrt(d) if splash else q).astype(k.dtype))
+    if splash:
+        with jax.named_scope("dopt_splash"):
+            return splash_causal_attention(q.reshape(grouped), k, v,
+                                           window=window, block=block)
+    return blocked_causal_attention(q.reshape(grouped), k, v, window=window,
+                                    block=block)
 
 
 def splash_causal_attention(q, k, v, *, window: int | None, block: int):
@@ -665,38 +680,64 @@ class GatedMoEDecoder:
                     "td,dne->nte", x, p[name].astype(dt).reshape(-1, n, hd),
                     preferred_element_type=jnp.float32)
 
+            # (each part's scope opens around its ops where the layer runs
+            # them: a scope names ops and moves none)
             if c.indexed:
                 # per-head RMS norms, whose backward asks for the products
-                q = _rotary(_rms(_keep(heads_of("q", heads)), p["q_norm"],
-                                 c.rms_norm_eps), rope).astype(dt)
-                k = _rotary(_rms(_keep(heads_of("k", kv)), p["k_norm"],
-                                 c.rms_norm_eps), rope).astype(dt)
-                v = _keep(heads_of("v", kv).astype(dt))
-                wo = p["o"].astype(dt).reshape(kv, heads // kv, hd, -1)
-                # (a run of blocks at a time: see ``project`` there)
+                with jax.named_scope("dopt_qkv"):
+                    q = _keep(heads_of("q", heads))
+                with jax.named_scope("dopt_rope"):
+                    q = _rotary(_rms(q, p["q_norm"], c.rms_norm_eps),
+                                rope).astype(dt)
+                with jax.named_scope("dopt_qkv"):
+                    k = _keep(heads_of("k", kv))
+                with jax.named_scope("dopt_rope"):
+                    k = _rotary(_rms(k, p["k_norm"], c.rms_norm_eps),
+                                rope).astype(dt)
+                with jax.named_scope("dopt_qkv"):
+                    v = _keep(heads_of("v", kv).astype(dt))
+                with jax.named_scope("dopt_oproj"):
+                    wo = p["o"].astype(dt).reshape(kv, heads // kv, hd, -1)
+
+                def project(out):
+                    """A run of blocks at a time: see ``project`` there."""
+                    with jax.named_scope("dopt_oproj"):
+                        return jnp.einsum(
+                            "bgrqe,gred->bqd", out, wo,
+                            preferred_element_type=jnp.float32
+                        ).reshape(-1, wo.shape[-1])
+
                 out, align, kept = indexed_causal_attention(
                     q.reshape(kv, heads // kv, t, hd), k, v,
                     *self._index(p["indexer"], x, rope),
                     topk=c.sa_config["topk"], block=self.attn_block,
-                    project=lambda out: jnp.einsum(
-                        "bgrqe,gred->bqd", out, wo,
-                        preferred_element_type=jnp.float32
-                    ).reshape(-1, wo.shape[-1]))
+                    project=project)
                 counts = dict(zip(INDEX_COUNTERS, (align, kept)))
             else:
-                q = _rotary(heads_of("q", heads), rope)
-                k = _keep(_rotary(heads_of("k", kv), rope).astype(dt))
-                v = _keep(heads_of("v", kv).astype(dt))
+                with jax.named_scope("dopt_qkv"):
+                    q = heads_of("q", heads)
+                with jax.named_scope("dopt_rope"):
+                    q = _rotary(q, rope)
+                with jax.named_scope("dopt_qkv"):
+                    k = heads_of("k", kv)
+                with jax.named_scope("dopt_rope"):
+                    k = _keep(_rotary(k, rope).astype(dt))
+                with jax.named_scope("dopt_qkv"):
+                    v = _keep(heads_of("v", kv).astype(dt))
                 out = causal_attention(q, k, v, window=c.window(i),
                                        block=self.attn_block)
-                gate = jax.nn.sigmoid(_keep(jnp.einsum(
-                    "td,dn->nt", x, p["gate"].astype(dt),
-                    preferred_element_type=jnp.float32)))
-                out = out.reshape(heads, t, hd) * gate[..., None].astype(dt)
-                out = jnp.einsum(
-                    "nte,ned->td", out,
-                    p["o"].astype(dt).reshape(heads, hd, -1),
-                    preferred_element_type=jnp.float32)
+                with jax.named_scope("dopt_qkv"):
+                    gate = _keep(jnp.einsum(
+                        "td,dn->nt", x, p["gate"].astype(dt),
+                        preferred_element_type=jnp.float32))
+                with jax.named_scope("dopt_oproj"):
+                    gate = jax.nn.sigmoid(gate)
+                    out = (out.reshape(heads, t, hd)
+                           * gate[..., None].astype(dt))
+                    out = jnp.einsum(
+                        "nte,ned->td", out,
+                        p["o"].astype(dt).reshape(heads, hd, -1),
+                        preferred_element_type=jnp.float32)
                 counts = None
             return _keep(h + out), counts
 
@@ -746,9 +787,12 @@ class GatedMoEDecoder:
         with jax.named_scope("dopt_moe"):
             with jax.named_scope("dopt_route"):
                 weight, hit, counts = self._route(p["router"], m)
-            out = (_gated_mlp(p["shared"], m.astype(dt), dt
-                              ).astype(jnp.float32)
-                   if "shared" in p else jnp.zeros_like(m))
+            if "shared" in p:
+                with jax.named_scope("dopt_shared"):
+                    out = _gated_mlp(p["shared"], m.astype(dt), dt
+                                     ).astype(jnp.float32)
+            else:
+                out = jnp.zeros_like(m)
             return grouped_experts.grouped_experts(
                 m, hit, weight, p["experts"], out,
                 k=self.cfg.num_experts_per_tok, dtype=dt,
@@ -762,8 +806,10 @@ class GatedMoEDecoder:
         counts = counts or {}
         m = _rms(h, p["mlp_norm"], c.rms_norm_eps)
         if not c.sparse_mlp(i):
-            return (h + _gated_mlp(p["mlp"], m.astype(self.dtype), self.dtype
-                                   ).astype(jnp.float32)), counts
+            with jax.named_scope("dopt_mlp"):
+                out = _gated_mlp(p["mlp"], m.astype(self.dtype), self.dtype
+                                 ).astype(jnp.float32)
+            return h + out, counts
         out, routed = self._experts(p, m)
         return h + out, {**counts, **routed}
 

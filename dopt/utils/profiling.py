@@ -34,8 +34,12 @@ and backward, nested in ``dopt_local``; ``dopt.models.zoo``),
 fleet with its bias add, forward and backward, nested in ``dopt_local``
 and in ``dopt_eval``; ``dopt.models.zoo``), and the decoder's, all
 nested in ``dopt_local`` (``dopt.models.decoder``):
-``dopt_attn`` (normed input to gated output projection) and, in a layer
-with an indexer, inside it ``dopt_index`` (indexer projections, index
+``dopt_attn`` (normed input to gated output projection) with, inside
+it, ``dopt_qkv`` (the q / k / v and gate projections), ``dopt_rope``
+(per-head norms, rotary and casts between them and the attention),
+``dopt_splash`` (the fused kernel's call, where it runs),
+``dopt_oproj`` (gate and output projection) and, in a layer with an
+indexer, ``dopt_index`` (indexer projections, index
 scores, selection, alignment term) with ``dopt_select`` inside that
 (the k-th largest score and the mask) and ``dopt_attend`` (masked
 scores, softmax, values, the head-mean), ``dopt_moe``
@@ -43,8 +47,9 @@ scores, softmax, values, the head-mean), ``dopt_moe``
 by their own names, ``dopt_moe_experts_fwd`` / ``_dx`` / ``_dw``, which
 spell the scope out) with ``dopt_route`` inside it (scores, top-k,
 combine weights, and the dispatch around the kernels: the slots' layout
-and the combine weights' gradient, not the expert matmuls),
-``dopt_head`` (final norm, logits, loss).
+and the combine weights' gradient, not the expert matmuls) and
+``dopt_shared`` (the shared expert), ``dopt_mlp`` (a dense layer's
+MLP), ``dopt_head`` (final norm, logits, loss).
 
 The rule: no span or scope without a reader — each of these is read by
 a per-layer metric of ``BENCHMARK.json`` (table in PERF.md §3).  A PR

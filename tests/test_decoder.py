@@ -611,6 +611,148 @@ def test_the_laguna_round_is_the_parent_s_program():
         "478ec7f2cd3ed0b8d90bb6f81c756875bc9b9d1e211c8a33b7164836f84d1b8f")
 
 
+ANATOMY = ("dopt_qkv", "dopt_rope", "dopt_oproj")
+# name: (model type, toy keys, has a dense layer, has a shared expert)
+LAYER_KINDS = {
+    "laguna": ("laguna", {}, True, True),
+    "laguna-no-dense-layer": ("laguna", {"dense_layers": 0}, False, True),
+    "keye": ("KeyeVL2", {}, False, False),
+    "keye-a-dense-layer": ("KeyeVL2", {"mlp_only_layers": [1]}, True, False),
+}
+
+
+def _stacks(text):
+    import re
+
+    return {s for s in re.findall(r'op_name="([^"]*)"', text)
+            if s.startswith("jit(")}
+
+
+def _check_scope(stacks, scope, parent):
+    """``scope`` marks forward and backward ops, all under ``parent`` (but
+    in the round's own evaluation, which is not in the local phase)."""
+    mine = {s for s in stacks if scope in s}
+    assert mine, scope
+    assert all(parent in s for s in mine if "dopt_eval" not in s), scope
+    assert any("transpose(jvp(" not in s for s in mine), scope
+    assert any("transpose(jvp(" in s for s in mine), scope
+
+
+@pytest.mark.parametrize("kind", sorted(LAYER_KINDS))
+def test_compiled_round_names_every_part_of_the_layer(kind):
+    """``dopt_qkv``, ``dopt_rope`` and ``dopt_oproj`` inside ``dopt_attn``,
+    ``dopt_shared`` inside ``dopt_moe`` where the layer has a shared
+    expert, ``dopt_mlp`` inside ``dopt_local`` (and outside the attention
+    and the experts) where a layer is dense, each forward and backward;
+    the indexer keeps its own scope; ``dopt_splash`` only on the kernel's
+    path, which no toy round takes."""
+    from jax._src.config import enable_compilation_cache
+
+    from dopt.engine import GossipTrainer
+
+    model_type, kw, dense, shared = LAYER_KINDS[kind]
+    _, lowered = GossipTrainer(gossip_config(model_type, **kw),
+                               eval_every=10**9).lower_round(1)
+    with enable_compilation_cache(False):   # the key ignores metadata
+        stacks = _stacks(lowered.compile().as_text())
+    for scope in ANATOMY:
+        _check_scope(stacks, scope, "dopt_attn")
+        assert not [s for s in stacks if scope in s and (
+            "dopt_index" in s or "dopt_attend" in s)], scope
+    for scope, present, parent in [("dopt_mlp", dense, "dopt_local"),
+                                   ("dopt_shared", shared, "dopt_moe")]:
+        if present:
+            _check_scope(stacks, scope, parent)
+        else:
+            assert not [s for s in stacks if scope in s], scope
+    assert not [s for s in stacks if "dopt_mlp" in s
+                and ("dopt_attn" in s or "dopt_moe" in s)]
+    assert not [s for s in stacks if "dopt_shared" in s and "dopt_route" in s]
+    assert not [s for s in stacks if "dopt_splash" in s]
+
+
+def test_the_kernel_s_operands_carry_their_scope():
+    """On the splash path (the kernel interpreted on the CPU) the kernel's
+    call sits under ``dopt_splash`` inside ``dopt_attn``, forward and
+    backward, beside the other three parts; the queries' scale and cast
+    in front of it under ``dopt_rope``."""
+    from jax._src.config import enable_compilation_cache
+
+    model = toy_model([2, 1], attn_block=128, head_dim=128,
+                      num_key_value_heads=1, sliding_window=100)
+    assert model.attention_path(256) == "splash"
+    x = np.random.default_rng(7).integers(0, VOCAB, (2, 256)).astype(np.int32)
+    y = np.concatenate([x[:, 1:], np.full((2, 1), -1, np.int32)], 1)
+    w = np.ones(2, np.float32)
+    params = model.init(jax.random.key(2))["params"]
+    grad = jax.jit(jax.grad(lambda p: model.loss(p, x, y, w)[0]))
+    with enable_compilation_cache(False):
+        stacks = _stacks(grad.lower(params).compile().as_text())
+    for scope in (*ANATOMY, "dopt_splash"):
+        _check_scope(stacks, scope, "dopt_attn")
+
+
+def _scope_free(monkeypatch):
+    """Every ``jax.named_scope`` (a context or a decorator) names nothing
+    from here on."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def bare(name):
+        yield
+
+    monkeypatch.setattr(jax, "named_scope", bare)
+
+
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+def test_the_scopes_are_metadata_only(monkeypatch, model_type):
+    """The round program lowers to the same text without debug info with
+    every scope and without any: they name ops and change none."""
+    from dopt.engine import GossipTrainer
+
+    def lowered():
+        return GossipTrainer(gossip_config(model_type),
+                             eval_every=10**9).lower_round(1)[1]
+
+    scoped = lowered()
+    assert "dopt_qkv" in scoped.as_text(debug_info=True)
+    _scope_free(monkeypatch)
+    bare = lowered()
+    assert "dopt_qkv" not in bare.as_text(debug_info=True)
+    assert bare.as_text() == scoped.as_text()
+
+
+@pytest.mark.parametrize("path", ["splash", "indexed-fused"])
+def test_the_kernel_paths_scopes_are_metadata_only(monkeypatch, path):
+    """... and so on the two kernels' paths, whose toys lower the
+    interpreted kernels: the gradient program's text is the same with and
+    without every scope."""
+    if path == "splash":
+        model = toy_model([2, 1], attn_block=128, head_dim=128,
+                          num_key_value_heads=1, sliding_window=100)
+    else:
+        model = keye_model(2, attn_block=128, head_dim=128, topk=100,
+                           rope_scaling={"mrope_section": [16, 24, 24],
+                                         "rope_type": "default",
+                                         "type": "default"})
+    assert model.attention_path(256) == path
+    x = np.random.default_rng(7).integers(0, VOCAB, (2, 256)).astype(np.int32)
+    y = np.concatenate([x[:, 1:], np.full((2, 1), -1, np.int32)], 1)
+    w = np.ones(2, np.float32)
+    params = model.init(jax.random.key(2))["params"]
+
+    def lowered():
+        return jax.jit(jax.grad(lambda p: model.loss(p, x, y, w)[0])
+                       ).lower(params)
+
+    scoped = lowered()
+    assert "dopt_oproj" in scoped.as_text(debug_info=True)
+    _scope_free(monkeypatch)
+    bare = lowered()
+    assert "dopt_oproj" not in bare.as_text(debug_info=True)
+    assert bare.as_text() == scoped.as_text()
+
+
 def test_the_federated_engine_refuses_the_decoder_with_a_pointer():
     from dopt.config import FederatedConfig
     from dopt.engine import FederatedTrainer
