@@ -60,6 +60,10 @@ file is the same mathematics arranged for the chip:
   projection, every gated MLP's gate and up products (the held experts'
   over their routed slots) and the router's chosen experts, so its
   backward pass recomputes elementwise work, and no matmul and no top-k.
+  The layers of one kind (``GatedMoEDecoder.layer_kinds``: query heads,
+  rotary, window, MLP) share ONE ``jax.jit`` of that checkpoint, so a
+  program traces, differentiates, batches and lowers each kind once, not
+  each layer; XLA inlines the calls.
 
 Scopes (inside the engines' ``dopt_local``): ``dopt_attn`` (normed input
 to gated output projection; a compiled kernel may carry no name stack
@@ -94,7 +98,9 @@ engine averages into each round's history row.
 
 from __future__ import annotations
 
+import collections
 import functools
+import json
 import math
 
 import jax
@@ -578,6 +584,8 @@ class GatedMoEDecoder:
         self.experts_held = (cfg.num_experts if cfg.experts_held is None
                              else cfg.experts_held)
         self.counters = COUNTERS + (INDEX_COUNTERS if cfg.indexed else ())
+        self._bodies = {}
+        self.kind_traces = collections.Counter()
 
     def attention_path(self, t: int) -> str:
         """``"indexed-fused"`` or ``"indexed"``, ``"splash"`` or
@@ -813,16 +821,48 @@ class GatedMoEDecoder:
         out, routed = self._experts(p, m)
         return h + out, {**counts, **routed}
 
+    def _kind(self, i: int) -> tuple:
+        """What ``_layer`` reads of ``i``: query heads, rotary, window,
+        sparse or dense MLP, indexer or not.  Layers of one kind compute
+        the same function of (their parameters, the stream)."""
+        c = self.cfg
+        return (c.query_heads(i), json.dumps(c.rope(i), sort_keys=True),
+                c.window(i), c.sparse_mlp(i), c.indexed)
+
+    def layer_kinds(self) -> dict:
+        """{kind: [its layers]}, in the order of their first layers
+        (``dopt.run`` prints how many of each there are)."""
+        kinds = {}
+        for i in range(self.cfg.num_hidden_layers):
+            kinds.setdefault(self._kind(i), []).append(i)
+        return kinds
+
+    def _body(self, i: int):
+        """Layer ``i``'s kind's one ``jax.jit(jax.checkpoint(...))``, made
+        once per model: every layer of the kind calls the same object, so
+        jax traces, differentiates, batches and lowers the layer once per
+        kind and shape, not once per layer (XLA inlines the calls).
+        ``kind_traces`` counts the traces by kind."""
+        kind = self._kind(i)
+        if kind not in self._bodies:
+            first = self.layer_kinds()[kind][0]
+
+            def layer(p, h):
+                self.kind_traces[kind] += 1
+                return self._layer(p, h, first)
+
+            self._bodies[kind] = jax.jit(jax.checkpoint(
+                layer, policy=jax.checkpoint_policies.save_only_these_names(
+                    *LAYER_KEEPS)))
+        return self._bodies[kind]
+
     def _hidden(self, params, tokens):
         """One row: [T] ids -> ([T, d] float32 hidden state before the
         final norm, each count averaged over the layers that have it)."""
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
         counts = []
         for i in range(self.cfg.num_hidden_layers):
-            h, c = jax.checkpoint(
-                lambda p, h_, i=i: self._layer(p, h_, i),
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *LAYER_KEEPS))(params[f"layer{i}"], h)
+            h, c = self._body(i)(params[f"layer{i}"], h)
             counts.append(c)
         return h, {k: (jnp.mean(jnp.stack([c[k] for c in counts if k in c]))
                        if any(k in c for c in counts) else jnp.zeros(()))

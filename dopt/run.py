@@ -106,12 +106,15 @@ def device_details(trainer) -> str:
             f"mesh={dict(trainer.mesh.shape)}")
     # A decoder says which body its attention runs at this row length
     # (dopt.models.decoder.causal_attention) and which its held experts
-    # (dopt.ops.grouped_experts.path): the fused kernels or jax.numpy.
+    # (dopt.ops.grouped_experts.path): the fused kernels or jax.numpy;
+    # and how many layer kinds its layers share (one body traced a kind).
     model = getattr(trainer, "model", None)
     path = getattr(model, "attention_path", None)
     if path is not None:
         line += (f" attention={path(trainer.cfg.model.input_shape[0])}"
-                 f" experts={model.expert_path()}")
+                 f" experts={model.expert_path()}"
+                 f" layers={model.cfg.num_hidden_layers}"
+                 f" kinds={len(model.layer_kinds())}")
     return line
 
 

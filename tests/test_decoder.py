@@ -600,7 +600,10 @@ def test_the_laguna_round_is_the_parent_s_program():
     layer into the decoder and left the hash of commit 975eb78 standing
     (``c208f2b4...``); PR 34 moved it on purpose: the held experts' dense
     dispatch became the sorted slots' grouped matmul, and the router
-    takes its values at kept indices."""
+    takes its values at kept indices.  Then it moved on purpose again:
+    one function per layer kind (``GatedMoEDecoder._body``), called by
+    each layer of the kind, where every layer was written out (on
+    XLA:CPU the compiled toy holds the same instructions)."""
     import hashlib
 
     from dopt.engine import GossipTrainer
@@ -608,7 +611,7 @@ def test_the_laguna_round_is_the_parent_s_program():
     _, lowered = GossipTrainer(gossip_config(),
                                eval_every=10**9).lower_round(1)
     assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == (
-        "478ec7f2cd3ed0b8d90bb6f81c756875bc9b9d1e211c8a33b7164836f84d1b8f")
+        "5ff897a5812409fe12eaab55cdea0abd5273dd24b69cbdf4c2d27a118c72855e")
 
 
 ANATOMY = ("dopt_qkv", "dopt_rope", "dopt_oproj")
@@ -671,6 +674,123 @@ def test_compiled_round_names_every_part_of_the_layer(kind):
     assert not [s for s in stacks if "dopt_splash" in s]
 
 
+# The layers of each kind, in order, of each LAYER_KINDS toy at its cell's
+# depth: five laguna layers (full / sliding x 3 / full, the first dense
+# where the toy has a dense layer), four keye layers.
+KIND_LAYERS = {
+    "laguna": [[0], [1, 2, 3], [4]],
+    "laguna-no-dense-layer": [[0, 4], [1, 2, 3]],
+    "keye": [[0, 1, 2, 3]],
+    "keye-a-dense-layer": [[0, 2, 3], [1]],
+}
+
+
+def kinds_toy(kind):
+    """(the LAYER_KINDS toy at KIND_LAYERS' depth, a batch of its rows)."""
+    model_type, kw, *_ = LAYER_KINDS[kind]
+    if model_type == "laguna":
+        return toy_model([4, 6, 6, 6, 4], **kw), batch()
+    return keye_model(4, **kw), batch(t=KEYE_T)
+
+
+def _layer_calls(text):
+    """{function: calls} of the layer bodies in a lowered module's text."""
+    import re
+
+    return {f: text.count(f"call @{f}(")
+            for f in re.findall(r"func\.func private @(layer(?:_\d+)?)\(",
+                                text)}
+
+
+@pytest.mark.parametrize("kind", sorted(LAYER_KINDS))
+def test_each_layer_kind_is_traced_once_a_branch(kind):
+    """One ``jax.jit(jax.checkpoint(...))`` a layer kind: the gradient
+    program traces each kind's body once, however many layers it has,
+    and lowers it to one function a branch, the forward with its kept
+    residuals and the backward, that every layer of the kind calls; the
+    round adds its evaluation's forward, at the training rows' shapes in
+    these toys, so no second trace."""
+    from dopt.engine import GossipTrainer
+
+    model, (x, y, w) = kinds_toy(kind)
+    kinds = model.layer_kinds()
+    assert list(kinds.values()) == KIND_LAYERS[kind]
+    params = model.init(jax.random.key(0))["params"]
+    text = jax.jit(jax.grad(lambda p: model.loss(p, x, y, w)[0])).lower(
+        params).as_text()
+    assert dict(model.kind_traces) == dict.fromkeys(kinds, 1)
+    # each kind's two functions are called once by each of its layers
+    assert sorted(_layer_calls(text).values()) == sorted(
+        len(layers) for layers in KIND_LAYERS[kind] * 2)
+
+    model_type, kw, *_ = LAYER_KINDS[kind]
+    trainer = GossipTrainer(gossip_config(model_type, **kw),
+                            eval_every=10**9)
+    round_text = trainer.lower_round(1)[1].as_text()
+    kinds = trainer.model.layer_kinds()
+    assert dict(trainer.model.kind_traces) == dict.fromkeys(kinds, 1)
+    calls = _layer_calls(round_text)
+    assert len(calls) == 3 * len(kinds)
+    assert sum(calls.values()) == 3 * sum(map(len, kinds.values()))
+
+
+def _per_layer_hidden(self, params, tokens):
+    """``GatedMoEDecoder._hidden`` as it was before one body a kind: a new
+    ``jax.checkpoint`` around a new ``lambda`` for every layer."""
+    from dopt.models import decoder
+
+    h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
+    counts = []
+    for i in range(self.cfg.num_hidden_layers):
+        h, c = jax.checkpoint(
+            lambda p, h_, i=i: self._layer(p, h_, i),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *decoder.LAYER_KEEPS))(params[f"layer{i}"], h)
+        counts.append(c)
+    return h, {k: (jnp.mean(jnp.stack([c[k] for c in counts if k in c]))
+                   if any(k in c for c in counts) else jnp.zeros(()))
+               for k in self.counters}
+
+
+@pytest.mark.parametrize("kind", sorted(LAYER_KINDS))
+def test_one_body_a_kind_equals_a_checkpoint_a_layer(monkeypatch, kind):
+    """The loss, every count and every gradient leaf equal those of the
+    per-layer ``jax.checkpoint`` form BIT FOR BIT on XLA:CPU (the
+    compiled programs hold the same instructions: XLA inlines the
+    calls)."""
+    model, (x, y, w) = kinds_toy(kind)
+    params = model.init(jax.random.key(5))["params"]
+
+    def run():
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.loss(p, x, y, w), has_aux=True))(params)
+
+    (loss, aux), grads = run()
+    traced = dict(model.kind_traces)
+    monkeypatch.setattr(GatedMoEDecoder, "_hidden", _per_layer_hidden)
+    (want_loss, want_aux), want_grads = run()
+    assert dict(model.kind_traces) == traced    # no body in the old form
+    assert np.asarray(loss).tobytes() == np.asarray(want_loss).tobytes()
+    for a, b in zip(jax.tree.leaves((aux, grads)),
+                    jax.tree.leaves((want_aux, want_grads))):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_the_cells_layers_fall_into_their_kinds():
+    """Laguna's five layers are three kinds (full attention with 48 heads
+    and a dense MLP; sliding, 64 heads, experts; full, 48, experts),
+    keye's four one; from the configuration alone."""
+    from dopt.presets import get_preset
+
+    for preset, config, want in (
+            ("laguna-localsgd2", PUBLISHED, [[0], [1, 2, 3], [4]]),
+            ("keye-localsgd2", KEYE, [[0, 1, 2, 3]])):
+        for model in (get_preset(preset).model,
+                      ModelConfig(**config["model"])):
+            worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
+            assert list(worker.layer_kinds().values()) == want
+
+
 def test_the_kernel_s_operands_carry_their_scope():
     """On the splash path (the kernel interpreted on the CPU) the kernel's
     call sits under ``dopt_splash`` inside ``dopt_attn``, forward and
@@ -726,22 +846,25 @@ def test_the_scopes_are_metadata_only(monkeypatch, model_type):
 def test_the_kernel_paths_scopes_are_metadata_only(monkeypatch, path):
     """... and so on the two kernels' paths, whose toys lower the
     interpreted kernels: the gradient program's text is the same with and
-    without every scope."""
-    if path == "splash":
-        model = toy_model([2, 1], attn_block=128, head_dim=128,
-                          num_key_value_heads=1, sliding_window=100)
-    else:
-        model = keye_model(2, attn_block=128, head_dim=128, topk=100,
-                           rope_scaling={"mrope_section": [16, 24, 24],
-                                         "rope_type": "default",
-                                         "type": "default"})
-    assert model.attention_path(256) == path
+    without every scope.  (A model is built for each: it traces each
+    layer kind's body once and keeps it.)"""
+    def toy():
+        if path == "splash":
+            return toy_model([2, 1], attn_block=128, head_dim=128,
+                             num_key_value_heads=1, sliding_window=100)
+        return keye_model(2, attn_block=128, head_dim=128, topk=100,
+                          rope_scaling={"mrope_section": [16, 24, 24],
+                                        "rope_type": "default",
+                                        "type": "default"})
+
+    assert toy().attention_path(256) == path
     x = np.random.default_rng(7).integers(0, VOCAB, (2, 256)).astype(np.int32)
     y = np.concatenate([x[:, 1:], np.full((2, 1), -1, np.int32)], 1)
     w = np.ones(2, np.float32)
-    params = model.init(jax.random.key(2))["params"]
+    params = toy().init(jax.random.key(2))["params"]
 
     def lowered():
+        model = toy()
         return jax.jit(jax.grad(lambda p: model.loss(p, x, y, w)[0])
                        ).lower(params)
 
@@ -1577,7 +1700,7 @@ def test_the_experts_body_goes_by_shapes_alone():
     from dopt.engine import GossipTrainer
 
     line = device_details(GossipTrainer(gossip_config(), eval_every=10**9))
-    assert line.endswith("attention=blocked experts=grouped")
+    assert line.endswith("attention=blocked experts=grouped layers=3 kinds=2")
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
