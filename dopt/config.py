@@ -59,17 +59,26 @@ class DataConfig:
 # the source and held to one value or not read).  A key of one type alone
 # is refused under the other.
 _DECODER_KEYS = {
-    "laguna": (("num_attention_heads_per_layer", "layer_types",
-                "mlp_layer_types", "sliding_window", "rope_parameters",
-                "shared_expert_intermediate_size",
+    "laguna": (("num_experts", "num_attention_heads_per_layer",
+                "layer_types", "mlp_layer_types", "sliding_window",
+                "rope_parameters", "shared_expert_intermediate_size",
                 "moe_routed_scaling_factor", "gating"),
                ("moe_apply_router_weight_on_input",
                 "partial_rotary_factor")),
-    "KeyeVL2": (("sa_config", "norm_topk_prob", "decoder_sparse_step",
-                 "rope_theta", "rope_scaling"),
+    "KeyeVL2": (("num_experts", "sa_config", "norm_topk_prob",
+                 "decoder_sparse_step", "rope_theta", "rope_scaling"),
                 ("mlp_only_layers", "hidden_act", "max_window_layers",
                  "num_local_experts", "use_sliding_window",
                  "sliding_window")),
+    "joyai_llm_flash": (("n_routed_experts", "n_shared_experts",
+                         "routed_scaling_factor",
+                         "norm_topk_prob", "scoring_func", "topk_method",
+                         "n_group", "topk_group", "first_k_dense_replace",
+                         "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                         "qk_rope_head_dim", "v_head_dim", "rope_theta",
+                         "rope_interleave", "num_nextn_predict_layers"),
+                        ("hidden_act", "rope_scaling", "qk_head_dim",
+                         "moe_layer_freq", "ep_size")),
 }
 _SA_KEYS = {"indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
             "kv_chunk_size", "q_chunk_size", "topk"}
@@ -80,7 +89,7 @@ class DecoderConfig:
     """A mixture-of-experts decoder (``dopt.models.decoder``) under the
     keys of its published ``config.json``; a key the file has and this
     class lacks is refused, not ignored, and so is a key of the other
-    ``model_type``.  Two are known, and the type alone says which layer
+    ``model_type``.  Three are known, and the type alone says which layer
     is built:
 
     ``laguna``: gated window/full attention with query heads, attention
@@ -97,6 +106,20 @@ class DecoderConfig:
     top-k weights and no shared one; layer i is sparse unless it is in
     ``mlp_only_layers`` or ``(i + 1) % decoder_sparse_step`` is not 0.
 
+    ``joyai_llm_flash`` (DeepSeek-V3's keys, arXiv:2412.19437): every
+    layer's attention is multi-head LATENT attention (queries through a
+    ``q_lora_rank`` bottleneck, keys and values through a
+    ``kv_lora_rank`` one, each with an RMS norm in the middle; query and
+    key heads of ``qk_nope_head_dim + qk_rope_head_dim`` over value heads
+    of ``v_head_dim``; ONE rotary key of ``qk_rope_head_dim`` shared by
+    every head, interleaved pairs), the first ``first_k_dense_replace``
+    layers dense and the rest sigmoid-routed experts chosen by score plus
+    a correction bias (``topk_method: noaux_tc``) beside
+    ``n_shared_experts`` shared ones, and ``num_nextn_predict_layers``
+    (0 or 1) multi-token-prediction modules in the training loss.
+    ``ep_size``, ``moe_layer_freq`` (1) and ``qk_head_dim`` (the sum) are
+    stated and not read.
+
     A worker builds layers ``0 .. num_hidden_layers-1``, so a cut in
     depth changes one number.  ``experts_held`` / ``expert_offset`` say
     which of the ``num_experts`` published experts of a layer this
@@ -112,10 +135,10 @@ class DecoderConfig:
     num_hidden_layers: int
     num_key_value_heads: int
     head_dim: int
-    num_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
     model_type: str = "laguna"
+    num_experts: int | None = None        # n_routed_experts for joyai
     rms_norm_eps: float = 1e-6
     attention_bias: bool = False
     tie_word_embeddings: bool = False
@@ -144,6 +167,25 @@ class DecoderConfig:
     # the plain rotary.
     hidden_act: str | None = None
     use_sliding_window: bool | None = None
+    # model_type joyai_llm_flash:
+    n_routed_experts: int | None = None
+    n_shared_experts: int | None = None
+    routed_scaling_factor: float | None = None
+    scoring_func: str | None = None
+    topk_method: str | None = None
+    n_group: int | None = None
+    topk_group: int | None = None
+    first_k_dense_replace: int | None = None
+    q_lora_rank: int | None = None
+    kv_lora_rank: int | None = None
+    qk_nope_head_dim: int | None = None
+    qk_rope_head_dim: int | None = None
+    v_head_dim: int | None = None
+    rope_interleave: bool | None = None
+    num_nextn_predict_layers: int | None = None
+    qk_head_dim: int | None = None
+    moe_layer_freq: int | None = None
+    ep_size: int | None = None
     # Stated by the source and not read by the model (they describe the
     # whole checkpoint, or repeat another key):
     vocab_size: int | None = None
@@ -181,19 +223,21 @@ class DecoderConfig:
         n = self.num_hidden_layers
         if self.model_type == "laguna":
             self._check_laguna(n)
-        else:
+        elif self.model_type == "KeyeVL2":
             self._check_keye()
+        else:
+            self._check_joyai()
         if any(self.query_heads(i) % self.num_key_value_heads
                for i in range(n)):
             raise ValueError(
                 "every layer's query heads must be a multiple of "
                 f"num_key_value_heads={self.num_key_value_heads}")
-        held = self.num_experts if self.experts_held is None else self.experts_held
+        held = self.experts if self.experts_held is None else self.experts_held
         if not (0 < held and 0 <= self.expert_offset
-                and self.expert_offset + held <= self.num_experts):
+                and self.expert_offset + held <= self.experts):
             raise ValueError(
                 f"experts {self.expert_offset} .. {self.expert_offset + held - 1} "
-                f"are not among the {self.num_experts} published")
+                f"are not among the {self.experts} published")
 
     def _check_laguna(self, n: int) -> None:
         for name in ("num_attention_heads_per_layer", "layer_types",
@@ -251,7 +295,78 @@ class DecoderConfig:
                 "null, use_sliding_window false, hidden_act 'silu' and "
                 "num_local_experts equal to num_experts")
 
+    def _check_joyai(self) -> None:
+        if self.num_attention_heads is None:
+            raise ValueError("decoder.num_attention_heads is required for "
+                             "model_type 'joyai_llm_flash'")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError(
+                "group-limited routing is not built: n_group and topk_group "
+                "must be 1")
+        if self.scoring_func != "sigmoid" or self.topk_method != "noaux_tc":
+            raise ValueError(
+                "the router scores by sigmoid and chooses by score plus its "
+                "correction bias: scoring_func must be 'sigmoid' and "
+                "topk_method 'noaux_tc'")
+        if self.rope_scaling is not None:
+            raise ValueError("the latent attention's rotary is unscaled: "
+                             "rope_scaling must be null")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("num_nextn_predict_layers must be 0 or 1")
+        if self.moe_layer_freq not in (None, 1):
+            raise ValueError(
+                "every layer past first_k_dense_replace has experts: "
+                "moe_layer_freq must be 1")
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError(
+                "latent attention has a key and a value head a query head: "
+                "num_key_value_heads must equal num_attention_heads")
+        rope, nope = self.qk_rope_head_dim, self.qk_nope_head_dim
+        if self.head_dim != rope or self.qk_head_dim not in (None,
+                                                             nope + rope):
+            raise ValueError(
+                f"head_dim is the rotary key's width ({rope}) and "
+                f"qk_head_dim the query and key heads' ({nope} + {rope})")
+        if (rope % 2 or not self.norm_topk_prob
+                or (self.hidden_act or "silu") != "silu"):
+            raise ValueError(
+                "qk_rope_head_dim must be even, norm_topk_prob true and "
+                "hidden_act 'silu'")
+
     # What layer i is, whichever model_type's keys say it.
+    @property
+    def experts(self) -> int:
+        """The routed experts a layer publishes (the router's width)."""
+        return self.n_routed_experts if self.latent else self.num_experts
+
+    @property
+    def latent(self) -> bool:
+        """The ``joyai_llm_flash`` layer: multi-head latent attention, a
+        bias-corrected sigmoid router beside shared experts, and a
+        multi-token-prediction module in the loss."""
+        return self.model_type == "joyai_llm_flash"
+
+    @property
+    def mtp(self) -> bool:
+        """Whether the loss has a multi-token-prediction module."""
+        return bool(self.latent and self.num_nextn_predict_layers)
+
+    @property
+    def routed_scale(self) -> float | None:
+        """What the renormalised top-k weights are multiplied by (None:
+        the ``KeyeVL2`` router does not scale them)."""
+        return (self.routed_scaling_factor if self.latent
+                else self.moe_routed_scaling_factor)
+
+    @property
+    def shared_width(self) -> int | None:
+        """The shared expert's width, None where a layer has none."""
+        if self.indexed:
+            return None
+        if self.latent:
+            return self.n_shared_experts * self.moe_intermediate_size or None
+        return self.shared_expert_intermediate_size
+
     @property
     def indexed(self) -> bool:
         """The ``KeyeVL2`` layer: an indexer picks the keys a query
@@ -260,22 +375,31 @@ class DecoderConfig:
         return self.model_type == "KeyeVL2"
 
     def query_heads(self, i: int) -> int:
-        return (self.num_attention_heads if self.indexed
+        return (self.num_attention_heads if self.indexed or self.latent
                 else self.num_attention_heads_per_layer[i])
 
     def window(self, i: int) -> int | None:
         """Positions back a query sees through a band; None = no band."""
-        if self.indexed or self.layer_types[i] != "sliding_attention":
+        if (self.indexed or self.latent
+                or self.layer_types[i] != "sliding_attention"):
             return None
         return self.sliding_window
 
     def rope(self, i: int) -> Mapping[str, Any]:
-        """Layer i's ``rope_parameters`` entry."""
+        """Layer i's ``rope_parameters`` entry (the latent attention's
+        interleaves its pairs)."""
+        if self.latent:
+            return {"rope_type": "default", "rope_theta": self.rope_theta,
+                    "interleave": bool(self.rope_interleave)}
         if self.indexed:
             return {"rope_type": "default", "rope_theta": self.rope_theta}
         return self.rope_parameters[self.layer_types[i]]
 
     def sparse_mlp(self, i: int) -> bool:
+        """Whether layer i has experts (i = ``num_hidden_layers`` of the
+        published depth is a multi-token-prediction module's)."""
+        if self.latent:
+            return i >= self.first_k_dense_replace
         if self.indexed:
             return (i not in self.mlp_only_layers
                     and (i + 1) % self.decoder_sparse_step == 0)

@@ -1,24 +1,30 @@
 """A mixture-of-experts decoder as ONE worker of the gossip engine
 (``model="decoder"``, configured by ``dopt.config.DecoderConfig`` under
 the keys of the published ``config.json``, whose ``model_type`` says
-which layer is built): token ids in, next-token loss out.  Two layers
+which layer is built): token ids in, next-token loss out.  Three layers
 are known: ``laguna``'s gated window/full attention beside
-sigmoid-routed experts and a shared one, and ``KeyeVL2``'s learned
-sparse attention (a lightning indexer picks the ``topk`` keys a query
-attends and an alignment term trains it) beside softmax-routed experts.
+sigmoid-routed experts and a shared one, ``KeyeVL2``'s learned sparse
+attention (a lightning indexer picks the ``topk`` keys a query attends
+and an alignment term trains it) beside softmax-routed experts, and
+``joyai_llm_flash``'s multi-head latent attention beside sigmoid-routed
+experts chosen by score plus a correction bias and a shared one, with a
+multi-token-prediction module in the loss.
 
 Not a flax module: the parameters are a plain dict tree (``embed``,
 ``layer<i>`` with ``attn_norm q k v o mlp_norm``, ``gate`` or ``q_norm
-k_norm indexer``, and ``mlp`` or ``router experts`` with or without
-``shared``, ``norm``, ``head``; the experts' leaves carry a leading
-EXPERT axis) and the surface the engines use is ``init(key, dummy)``,
+k_norm indexer`` -- or, latent, ``attn_norm q_a q_a_norm q_b kv_a
+kv_a_norm kv_b o mlp_norm`` --, and ``mlp`` or ``router experts`` with
+or without ``router_bias`` and ``shared``; ``mtp`` with ``enorm hnorm
+eh_proj layer norm`` where the model has the module; ``norm``,
+``head``; the experts' leaves carry a leading EXPERT axis) and the surface the engines use is ``init(key, dummy)``,
 ``apply({"params": p}, tokens)`` and ``loss(p, tokens, labels,
 weights)``.  The worker axis is the engines' ``vmap`` over the stacked
 fleet state.
 
 What a layer computes is written out in the docstrings of
-``benchmark/reference_models/laguna_xs2.py`` and ``keye_vl2.py``; this
-file is the same mathematics arranged for the chip:
+``benchmark/reference_models/laguna_xs2.py``, ``keye_vl2.py`` and
+``joyai_llm_flash.py``; this file is the same mathematics arranged for
+the chip:
 
 * matmul inputs in the compute dtype (bfloat16 on the chip) with float32
   accumulation; residual stream, norms, router scores, attention
@@ -71,7 +77,9 @@ and goes by its own name: ``splash_mqa_*``, and
 ``dopt_attn_dopt_attend_fwd`` / ``_probs`` / ``_bwd`` and
 ``dopt_attn_dopt_index_fwd`` / ``_bwd``, which spell out the two scopes
 they stand in) with, inside it, ``dopt_qkv`` (the q / k / v projections
-and the per-head gate's), ``dopt_rope`` (what lies between them and the
+and the per-head gate's; in a latent layer the two up-projections, and
+``dopt_latent`` inside it: the two down-projections and their norms),
+``dopt_rope`` (what lies between them and the
 attention: per-head norms, rotary, the queries' scale for the kernel,
 the casts to the compute dtype), ``dopt_splash`` (on the kernel's path,
 the kernel's call: what XLA does around it to its operands and
@@ -91,8 +99,11 @@ it (scores, top-k, combine weights, and the dispatch: where each routed
 slot sits among the sorted slots and the combine weights' gradient, not
 the expert matmuls) and ``dopt_shared`` (the shared expert's MLP);
 ``dopt_mlp`` (a dense layer's MLP, without its norm and residual add);
-``dopt_head`` (final norm, logits, loss).  ``loss`` also returns the step's counts
-(``model.counters``: routing, and the indexer's two), which the gossip
+``dopt_head`` (final norm, logits, loss); ``dopt_mtp`` (the
+multi-token-prediction module: its norms and projection, its layer, whose
+scopes nest inside, and its pass through the head under ``dopt_head``).
+``loss`` also returns the step's counts (``model.counters``: routing,
+the indexer's two, the module's loss and accuracy), which the gossip
 engine averages into each round's history row.
 """
 
@@ -138,7 +149,11 @@ from dopt.ops.grouped_experts import running_count as _running_count
 # layer and once inside their own checkpoints, not a third time; where
 # the blocks run the fused kernels, also their log-sum-exp, and the
 # recompute inside a block's checkpoint is then the index scores, the
-# selection and the head-mean, not the attention.
+# selection and the head-mean, not the attention.  A latent layer keeps
+# its two down-projections' float32 products (their norms' backward asks
+# for them; the rotary key is a slice of one) and q, k, v as the
+# attention takes them; the up-projections' products feed only linear
+# passes and are not needed.
 ATTN_RESIDUALS = "attn_residuals"
 MATMUL_PRODUCTS = "matmul_products"
 LAYER_KEEPS = (ATTN_RESIDUALS, MATMUL_PRODUCTS)
@@ -148,6 +163,12 @@ LAYER_KEEPS = (ATTN_RESIDUALS, MATMUL_PRODUCTS)
 # visible keys (0.4375 at 8,192 positions and 2,048 keys a query).
 COUNTERS = ("moe_held_slot_share", "moe_load_max_over_mean")
 INDEX_COUNTERS = ("index_align_loss", "index_keys_kept_share")
+# A model with a multi-token-prediction module: its cross-entropy against
+# the token after next and its accuracy there.
+MTP_COUNTERS = ("mtp_loss", "mtp_acc")
+# lambda of ``loss = CE_main + lambda * CE_mtp``: DeepSeek-V3's value for its
+# first 10 T tokens (arXiv:2412.19437 section 4.2); no config carries one.
+MTP_LOSS_WEIGHT = 0.3
 # Queries a block of attention, and positions a block of the output head
 # (logits and loss are never held for the whole batch).  Not
 # configuration: only tests, whose rows are shorter than a block, pass
@@ -212,7 +233,12 @@ def _inverse_frequencies(rope, head_dim: int):
 
 def _rotary(x, rope):
     """x: [H, T, head_dim]; ``rotate_half`` over the first rotary_dim
-    dimensions of each head, float32."""
+    dimensions of each head, float32.  Where ``rope["interleave"]`` the
+    pairs are (2i, 2i + 1) instead of (i, i + rotary_dim / 2): they are
+    taken apart into the two halves first, and stay apart in the result.
+    That is the interleaved rotation with its dimensions in another
+    order, the same order for every query and key, so every score is the
+    interleaved one."""
     inv, scale = _inverse_frequencies(rope, x.shape[-1])
     angles = (jnp.arange(x.shape[-2], dtype=jnp.float32)[:, None]
               * jnp.asarray(inv, jnp.float32)[None, :])
@@ -220,6 +246,9 @@ def _rotary(x, rope):
     cos, sin = jnp.cos(angles) * scale, jnp.sin(angles) * scale
     rd = angles.shape[-1]
     rot, rest = x[..., :rd], x[..., rd:]
+    if rope.get("interleave"):
+        rot = jnp.swapaxes(rot.reshape(*rot.shape[:-1], rd // 2, 2), -1,
+                           -2).reshape(rot.shape)
     half = jnp.concatenate([-rot[..., rd // 2:], rot[..., :rd // 2]], -1)
     return jnp.concatenate([rot * cos + half * sin, rest], -1)
 
@@ -227,9 +256,9 @@ def _rotary(x, rope):
 @jax.checkpoint
 def _attend_block(q, k, v, first_q, first_k, window):
     """Queries at positions ``first_q ...`` against keys at ``first_k
-    ...``: q [G, R, Tq, D], k and v [G, Tk, D] -> [G, R, Tq, D].  Scores
-    and softmax in float32.  Every query sees at least itself, so no row
-    of the mask is empty."""
+    ...``: q [G, R, Tq, D], k [G, Tk, D] and v [G, Tk, E] -> [G, R, Tq,
+    E].  Scores and softmax in float32.  Every query sees at least
+    itself, so no row of the mask is empty."""
     scores = jnp.einsum("grqd,gkd->grqk", q, k,
                         preferred_element_type=jnp.float32)
     scores = scores / math.sqrt(q.shape[-1])
@@ -237,13 +266,13 @@ def _attend_block(q, k, v, first_q, first_k, window):
             - (first_k + jnp.arange(k.shape[-2]))[None, :])
     seen = (back >= 0) & (back < window)
     probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("grqk,gkd->grqd", probs.astype(v.dtype), v)
+    return jnp.einsum("grqk,gke->grqe", probs.astype(v.dtype), v)
 
 
 def blocked_causal_attention(q, k, v, *, window: int | None, block: int):
     """Causal attention of one row, a block of ``block`` queries at a
     time: q [G, R, T, D] (R query heads share each of the G key/value
-    heads), k and v [G, T, D].  ``window`` (None = full) is how many
+    heads), k [G, T, D], v [G, T, E].  ``window`` (None = full) is how many
     positions back a query sees, itself included.  Block b multiplies
     its queries with the keys ``lo .. end of b`` only, ``lo`` the
     128-aligned start of the band for a window and 0 without one; T need
@@ -258,22 +287,28 @@ def blocked_causal_attention(q, k, v, *, window: int | None, block: int):
     return jnp.concatenate(out, axis=-2)
 
 
-def attention_path(t: int, head_dim: int, block: int = ATTN_BLOCK) -> str:
+def attention_path(t: int, head_dim: int, block: int = ATTN_BLOCK,
+                   value_dim: int | None = None) -> str:
     """Which body ``causal_attention`` runs for a row of ``t`` positions:
     ``"splash"``, the fused kernel, wherever the kernel's own shape
     limits allow it — the row a multiple of twice ``block`` (keys go two
-    blocks at a time), ``block`` and the head multiples of the chip's 128
-    lanes — else ``"blocked"``.  Nothing else decides it: no option, no
-    platform (the kernel is interpreted on the CPU)."""
-    fits = t % (2 * block) == 0 and block % 128 == 0 and head_dim % 128 == 0
+    blocks at a time), ``block`` and the value head (``value_dim``, the
+    query and key head where None) multiples of the chip's 128 lanes, the
+    query and key head a multiple of 64 (the latent attention's 192 next
+    to values of 128 compiles and runs on the v5e; PERF.md) —
+    else ``"blocked"``.  Nothing else decides it: no option, no platform
+    (the kernel is interpreted on the CPU)."""
+    value_dim = head_dim if value_dim is None else value_dim
+    fits = (t % (2 * block) == 0 and block % 128 == 0
+            and head_dim % 64 == 0 and value_dim % 128 == 0)
     return "splash" if fits else "blocked"
 
 
 def causal_attention(q, k, v, *, window: int | None, block: int = ATTN_BLOCK):
     """Causal (``window`` None) or banded attention of one row: q
     [G * R, T, D] float32 and unscaled (R query heads share each of the
-    G key/value heads), k and v [G, T, D] in the compute dtype ->
-    [G, R, T, D].
+    G key/value heads), k [G, T, D] and v [G, T, E] in the compute dtype
+    -> [G, R, T, E].
 
     The ONE fork of the attention, and it exists only because the fused
     kernel cannot take every shape (``attention_path``).  Published
@@ -287,7 +322,7 @@ def causal_attention(q, k, v, *, window: int | None, block: int = ATTN_BLOCK):
     reads the ``splash_mqa_*`` kernels and is absent without them."""
     heads, t, d = q.shape
     grouped = (k.shape[0], heads // k.shape[0], t, d)
-    splash = attention_path(t, d, block) == "splash"
+    splash = attention_path(t, d, block, v.shape[-1]) == "splash"
     # kept across a layer's remat as the body takes it: each body's own
     # cast, the queries' last float32 pass (XLA fuses it with the rotary
     # in front of it, and a fusion goes by its root)
@@ -311,6 +346,8 @@ def splash_causal_attention(q, k, v, *, window: int | None, block: int):
     ``ATTN_RESIDUALS``, which a layer's ``jax.checkpoint`` keeps so that
     the backward pass does not run the forward kernel again.  Compiled
     on tpu, interpreted on cpu (``dopt.ops.pallas_interpret``).  The
+    values' head may be narrower than the queries' and keys' (jax's
+    kernels read each from its operand).  The
     compiled kernels carry no jax name stack: a trace finds them by
     their own names, ``splash_mqa_fwd`` / ``_dq`` / ``_dkv``."""
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
@@ -581,9 +618,10 @@ class GatedMoEDecoder:
         self.attn_block = attn_block or (INDEX_BLOCK if cfg.indexed
                                          else ATTN_BLOCK)
         self.head_block = head_block
-        self.experts_held = (cfg.num_experts if cfg.experts_held is None
+        self.experts_held = (cfg.experts if cfg.experts_held is None
                              else cfg.experts_held)
-        self.counters = COUNTERS + (INDEX_COUNTERS if cfg.indexed else ())
+        self.counters = (COUNTERS + (INDEX_COUNTERS if cfg.indexed else ())
+                         + (MTP_COUNTERS if cfg.mtp else ()))
         self._bodies = {}
         self.kind_traces = collections.Counter()
 
@@ -591,11 +629,22 @@ class GatedMoEDecoder:
         """``"indexed-fused"`` or ``"indexed"``, ``"splash"`` or
         ``"blocked"`` for rows of ``t`` positions (``dopt.run`` prints it
         beside the device)."""
-        if self.cfg.indexed:
+        c = self.cfg
+        if c.indexed:
             return indexed_attention_path(
-                t, self.cfg.head_dim, self.cfg.sa_config["indexer_num_heads"],
+                t, c.head_dim, c.sa_config["indexer_num_heads"],
                 self.attn_block)
-        return attention_path(t, self.cfg.head_dim, self.attn_block)
+        if c.latent:
+            return attention_path(t, c.qk_nope_head_dim + c.qk_rope_head_dim,
+                                  self.attn_block, c.v_head_dim)
+        return attention_path(t, c.head_dim, self.attn_block)
+
+    @property
+    def mtp_layer(self) -> int:
+        """The index the multi-token-prediction module's block has among
+        the layers (after every layer: one with experts)."""
+        return max(self.cfg.num_hidden_layers,
+                   self.cfg.first_k_dense_replace or 0)
 
     # ---------------------------------------------------------- params
     def init(self, key, dummy=None):
@@ -604,7 +653,8 @@ class GatedMoEDecoder:
         pass a sample for flax models)."""
         c = self.cfg
         d, hd, kv = c.hidden_size, c.head_dim, c.num_key_value_heads
-        keys = iter(jax.random.split(key, 16 * c.num_hidden_layers + 2))
+        blocks = c.num_hidden_layers + c.mtp
+        keys = iter(jax.random.split(key, 16 * blocks + 2))
 
         def mat(*shape):
             return INITIALIZER_RANGE * jax.random.normal(
@@ -614,14 +664,33 @@ class GatedMoEDecoder:
             return {"gate": mat(*lead, d, width), "up": mat(*lead, d, width),
                     "down": mat(*lead, width, d)}
 
+        def latent(h):
+            """A multi-head latent attention's leaves: two down-projections
+            with their norms, two up-projections, the output projection."""
+            qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+            return {"attn_norm": jnp.ones(d), "q_a": mat(d, c.q_lora_rank),
+                    "q_a_norm": jnp.ones(c.q_lora_rank),
+                    "q_b": mat(c.q_lora_rank, h * qk),
+                    "kv_a": mat(d, c.kv_lora_rank + c.qk_rope_head_dim),
+                    "kv_a_norm": jnp.ones(c.kv_lora_rank),
+                    "kv_b": mat(c.kv_lora_rank,
+                                h * (c.qk_nope_head_dim + c.v_head_dim)),
+                    "o": mat(h * c.v_head_dim, d), "mlp_norm": jnp.ones(d)}
+
         params = {"embed": mat(self.vocab_rows, d)}
-        for i in range(c.num_hidden_layers):
+        for i in range(blocks):
+            if i == c.num_hidden_layers:      # the MTP module's block
+                i = self.mtp_layer
             h = c.query_heads(i)
-            layer = {"attn_norm": jnp.ones(d), "q": mat(d, h * hd),
-                     "k": mat(d, kv * hd), "v": mat(d, kv * hd)}
-            if not c.indexed:
+            if c.latent:
+                layer = latent(h)
+            else:
+                layer = {"attn_norm": jnp.ones(d), "q": mat(d, h * hd),
+                         "k": mat(d, kv * hd), "v": mat(d, kv * hd)}
+            if not (c.indexed or c.latent):
                 layer["gate"] = mat(d, h)
-            layer.update(o=mat(h * hd, d), mlp_norm=jnp.ones(d))
+            if not c.latent:
+                layer.update(o=mat(h * hd, d), mlp_norm=jnp.ones(d))
             if c.indexed:
                 j, e = (c.sa_config[n] for n in ("indexer_num_heads",
                                                  "indexer_head_dim"))
@@ -633,12 +702,19 @@ class GatedMoEDecoder:
             if not c.sparse_mlp(i):
                 layer["mlp"] = mlp(c.intermediate_size)
             else:
-                layer["router"] = mat(d, c.num_experts)
-                if not c.indexed:
-                    layer["shared"] = mlp(c.shared_expert_intermediate_size)
+                layer["router"] = mat(d, c.experts)
+                if c.latent:      # chooses; held at 0 (no gradient)
+                    layer["router_bias"] = jnp.zeros(c.experts)
+                if c.shared_width:
+                    layer["shared"] = mlp(c.shared_width)
                 layer["experts"] = mlp(c.moe_intermediate_size,
                                        self.experts_held)
-            params[f"layer{i}"] = layer
+            if i == self.mtp_layer:
+                params["mtp"] = {"enorm": jnp.ones(d), "hnorm": jnp.ones(d),
+                                 "eh_proj": mat(2 * d, d), "layer": layer,
+                                 "norm": jnp.ones(d)}
+            else:
+                params[f"layer{i}"] = layer
         params["norm"] = jnp.ones(d)
         params["head"] = mat(d, self.vocab_rows)
         return {"params": params}
@@ -669,6 +745,51 @@ class GatedMoEDecoder:
                     _rotary(ki[None], rope)[0].astype(dt),
                     wi * (j ** -0.5 * e ** -0.5))
 
+    def _latent_attention(self, p, x, rope):
+        """Multi-head latent attention (DeepSeek-V3, arXiv:2412.19437
+        section 2.1.1) of the normed layer input x [T, d] in the compute
+        dtype -> [T, d] float32 before the residual add.  Queries through
+        the ``q_lora_rank`` bottleneck, keys and values through the
+        ``kv_lora_rank`` one, each normed in the middle; a query and a key
+        head are ``qk_nope_head_dim`` dimensions without rotary and
+        ``qk_rope_head_dim`` with it, and the rotary part of the key is
+        ONE vector a position that every head shares; values are
+        ``v_head_dim`` wide."""
+        c, dt = self.cfg, self.dtype
+        t = x.shape[0]
+        heads, nope = c.num_attention_heads, c.qk_nope_head_dim
+        rank, vd = c.kv_lora_rank, c.v_head_dim
+        with jax.named_scope("dopt_qkv"):
+            with jax.named_scope("dopt_latent"):
+                # the norms' backward asks for the float32 products
+                cq = _keep(jnp.dot(x, p["q_a"].astype(dt),
+                                   preferred_element_type=jnp.float32))
+                cq = _rms(cq, p["q_a_norm"], c.rms_norm_eps).astype(dt)
+                ckv = _keep(jnp.dot(x, p["kv_a"].astype(dt),
+                                    preferred_element_type=jnp.float32))
+                c_kv = _rms(ckv[:, :rank], p["kv_a_norm"],
+                            c.rms_norm_eps).astype(dt)
+            q = jnp.einsum("tr,rne->nte", cq,
+                           p["q_b"].astype(dt).reshape(-1, heads,
+                                                       nope + c.head_dim),
+                           preferred_element_type=jnp.float32)
+            kv = jnp.einsum("tr,rne->nte", c_kv,
+                            p["kv_b"].astype(dt).reshape(-1, heads, nope + vd),
+                            preferred_element_type=jnp.float32)
+        with jax.named_scope("dopt_rope"):
+            q = jnp.concatenate([q[..., :nope],
+                                 _rotary(q[..., nope:], rope)], -1)
+            shared = _rotary(ckv[None, :, rank:], rope)          # [1, T, rd]
+            k = _keep(jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(
+                    shared, (heads, t, c.head_dim))], -1).astype(dt))
+            v = _keep(kv[..., nope:].astype(dt))
+        out = causal_attention(q, k, v, window=None, block=self.attn_block)
+        with jax.named_scope("dopt_oproj"):
+            return jnp.einsum("nte,ned->td", out.reshape(heads, t, vd),
+                              p["o"].astype(dt).reshape(heads, vd, -1),
+                              preferred_element_type=jnp.float32)
+
     def _attention(self, p, h, i):
         """-> (the residual stream after the layer's attention, None or
         the indexer's counts)."""
@@ -690,7 +811,9 @@ class GatedMoEDecoder:
 
             # (each part's scope opens around its ops where the layer runs
             # them: a scope names ops and moves none)
-            if c.indexed:
+            if c.latent:
+                out, counts = self._latent_attention(p, x, rope), None
+            elif c.indexed:
                 # per-head RMS norms, whose backward asks for the products
                 with jax.named_scope("dopt_qkv"):
                     q = _keep(heads_of("q", heads))
@@ -749,20 +872,24 @@ class GatedMoEDecoder:
                 counts = None
             return _keep(h + out), counts
 
-    def _route(self, router, m):
+    def _route(self, router, m, bias=None):
         """-> ([T, held] combine weights, 0 where a token was not routed
         to that held expert; [T, held] bool, whether it was; the step's
         routing counts).  The chosen experts are kept across a layer's
         remat and the scores taken at them (``top_k``'s own values and
         gradients, bit for bit), so the backward pass runs no second
-        ``top_k``."""
+        ``top_k``.  Where the layer has a correction ``bias`` it chooses by
+        score plus bias and weighs by the score alone (DeepSeek-V3's
+        auxiliary-loss-free balancing, ``topk_method: noaux_tc``); the
+        bias gets no gradient."""
         c = self.cfg
         scores = _keep(jnp.dot(m, router,
                                precision=jax.lax.Precision.HIGHEST))
         # sigmoid scores, or a softmax over the published experts
         scores = (jax.nn.softmax(scores, axis=-1) if c.indexed
                   else jax.nn.sigmoid(scores))
-        idx = _keep(jax.lax.top_k(jax.lax.stop_gradient(scores),
+        choose = scores if bias is None else scores + bias
+        idx = _keep(jax.lax.top_k(jax.lax.stop_gradient(choose),
                                   c.num_experts_per_tok)[1])
         # (a masked sum of one term: a gather of single elements, and the
         # scatter-add that is its gradient, cost this chip 6 ns each)
@@ -771,7 +898,7 @@ class GatedMoEDecoder:
             scores[:, None, :], 0.0), axis=-1)
         top = top / jnp.sum(top, -1, keepdims=True)       # renormalised
         if not c.indexed:
-            top = top * c.moe_routed_scaling_factor
+            top = top * c.routed_scale
         # [T, k, held]: slot j of token t reached held expert e
         hit = ((idx - c.expert_offset)[..., None]
                == jnp.arange(self.experts_held)).astype(jnp.float32)
@@ -794,7 +921,8 @@ class GatedMoEDecoder:
         dt = self.dtype
         with jax.named_scope("dopt_moe"):
             with jax.named_scope("dopt_route"):
-                weight, hit, counts = self._route(p["router"], m)
+                weight, hit, counts = self._route(p["router"], m,
+                                                  p.get("router_bias"))
             if "shared" in p:
                 with jax.named_scope("dopt_shared"):
                     out = _gated_mlp(p["shared"], m.astype(dt), dt
@@ -839,13 +967,14 @@ class GatedMoEDecoder:
 
     def _body(self, i: int):
         """Layer ``i``'s kind's one ``jax.jit(jax.checkpoint(...))``, made
-        once per model: every layer of the kind calls the same object, so
+        once per model: every layer of the kind (the multi-token-prediction
+        module's block among them, ``mtp_layer``) calls the same object, so
         jax traces, differentiates, batches and lowers the layer once per
         kind and shape, not once per layer (XLA inlines the calls).
         ``kind_traces`` counts the traces by kind."""
         kind = self._kind(i)
         if kind not in self._bodies:
-            first = self.layer_kinds()[kind][0]
+            first = self.layer_kinds().get(kind, [i])[0]
 
             def layer(p, h):
                 self.kind_traces[kind] += 1
@@ -858,7 +987,8 @@ class GatedMoEDecoder:
 
     def _hidden(self, params, tokens):
         """One row: [T] ids -> ([T, d] float32 hidden state before the
-        final norm, each count averaged over the layers that have it)."""
+        final norm, each layer count averaged over the layers that have
+        it)."""
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
         counts = []
         for i in range(self.cfg.num_hidden_layers):
@@ -866,12 +996,56 @@ class GatedMoEDecoder:
             counts.append(c)
         return h, {k: (jnp.mean(jnp.stack([c[k] for c in counts if k in c]))
                        if any(k in c for c in counts) else jnp.zeros(()))
-                   for k in self.counters}
+                   for k in self.counters if k not in MTP_COUNTERS}
 
     def _logits(self, params, h):
         x = _rms(h, params["norm"], self.cfg.rms_norm_eps).astype(self.dtype)
         return jnp.dot(x, params["head"].astype(self.dtype),
                        preferred_element_type=jnp.float32)
+
+    def _mtp(self, params, h, nxt):
+        """The multi-token-prediction module (DeepSeek-V3 section 2.2,
+        depth 1) on one row: the main model's hidden state h [T, d]
+        before its final norm and the next tokens' ids ``nxt`` [T] (the
+        labels; negative ones are taken as id 0 and not counted) -> the
+        module's hidden state [T, d] before its own final norm, and its
+        block's routing counts.  The two streams are normed and joined,
+        the embedding's first as the published checkpoints have them
+        (``[enorm(Emb(t_{i+1})) ; hnorm(h_i)] eh_proj``), and go through
+        one more layer."""
+        c, dt, p = self.cfg, self.dtype, params["mtp"]
+        e = jnp.take(params["embed"], jnp.maximum(nxt, 0), axis=0)
+        x = jnp.concatenate([_rms(e, p["enorm"], c.rms_norm_eps),
+                             _rms(h, p["hnorm"], c.rms_norm_eps)], -1)
+        x = jnp.dot(x.astype(dt), p["eh_proj"].astype(dt),
+                    preferred_element_type=jnp.float32)
+        return self._body(self.mtp_layer)(p["layer"], x)
+
+    def _head_loss(self, out, h, labels, counted):
+        """The head and the loss a block of ``head_block`` positions at a
+        time: ``out`` {"norm", "head"}, h [B, T, d], labels [B, T] (ids,
+        negative where ``counted`` is 0), counted [B, T] float32 -> (each
+        block's summed negative log-likelihood, each block's hits)."""
+        n = counted.size
+        blk = min(self.head_block, n)
+        pad = -n % blk
+
+        def blocks(x):
+            x = x.reshape(n, *x.shape[2:])
+            return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)
+                           ).reshape((n + pad) // blk, blk, *x.shape[1:])
+
+        @jax.checkpoint
+        def block(out, hb, yb, cb):
+            logp = jax.nn.log_softmax(self._logits(out, hb), axis=-1)
+            yb = jnp.maximum(yb, 0)
+            nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
+            hit = (jnp.argmax(logp, axis=-1) == yb).astype(jnp.float32)
+            return jnp.sum(nll * cb), jnp.sum(hit * cb)
+
+        return jax.lax.map(
+            lambda b: block(out, *b),
+            (blocks(h), blocks(labels.astype(jnp.int32)), blocks(counted)))
 
     # --------------------------------------------------------- surface
     def apply(self, variables, tokens):
@@ -890,36 +1064,40 @@ class GatedMoEDecoder:
         alignment terms, each a mean over the positions of the rows that
         count: the indexer's leaves get that term's gradient alone and
         every other leaf the cross-entropy's alone (the indexer's input
-        is detached and the selection is not differentiated)."""
+        is detached and the selection is not differentiated).  A
+        multi-token-prediction module adds ``MTP_LOSS_WEIGHT`` times its
+        cross-entropy against the token after next (``labels`` one
+        position on), over the positions whose label and next label both
+        count; it reaches the shared embedding and head."""
         h, counts = jax.vmap(lambda row: self._hidden(params, row))(tokens)
         counted = (weights[:, None] * (labels >= 0)).astype(jnp.float32)
-        n = counted.size
-        blk = min(self.head_block, n)
-        pad = -n % blk
-
-        def blocks(x):
-            x = x.reshape(n, *x.shape[2:])
-            return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)
-                           ).reshape((n + pad) // blk, blk, *x.shape[1:])
-
-        @jax.checkpoint
-        def block(out, hb, yb, cb):
-            logp = jax.nn.log_softmax(self._logits(out, hb), axis=-1)
-            yb = jnp.maximum(yb, 0)
-            nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
-            hit = (jnp.argmax(logp, axis=-1) == yb).astype(jnp.float32)
-            return jnp.sum(nll * cb), jnp.sum(hit * cb)
-
         with jax.named_scope("dopt_head"):
-            out = {k: params[k] for k in ("norm", "head")}
-            nll, hit = jax.lax.map(
-                lambda b: block(out, *b),
-                (blocks(h), blocks(labels.astype(jnp.int32)),
-                 blocks(counted)))
+            nll, hit = self._head_loss(
+                {k: params[k] for k in ("norm", "head")}, h, labels, counted)
             total = jnp.maximum(jnp.sum(counted), 1.0)
             aux = {"acc": jnp.sum(hit) / total,
                    **{k: jnp.mean(v) for k, v in counts.items()}}
             loss = jnp.sum(nll) / total
+        if self.cfg.mtp:
+            with jax.named_scope("dopt_mtp"):
+                g, block = jax.vmap(lambda hr, yr: self._mtp(params, hr,
+                                                             yr))(h, labels)
+                after = jnp.concatenate(
+                    [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], 1)
+                both = counted * (after >= 0)
+                with jax.named_scope("dopt_head"):
+                    nll, hit = self._head_loss(
+                        {"norm": params["mtp"]["norm"],
+                         "head": params["head"]}, g, after, both)
+                total = jnp.maximum(jnp.sum(both), 1.0)
+            # the block's routing counts join the layers' means
+            n = sum(map(self.cfg.sparse_mlp,
+                        range(self.cfg.num_hidden_layers)))
+            aux.update({k: (aux[k] * n + jnp.mean(block[k])) / (n + 1)
+                        for k in COUNTERS},
+                       mtp_loss=jnp.sum(nll) / total,
+                       mtp_acc=jnp.sum(hit) / total)
+            loss = loss + MTP_LOSS_WEIGHT * aux["mtp_loss"]
         if self.cfg.indexed:
             rows = weights.astype(jnp.float32)
             aux["index_align_loss"] = (

@@ -328,6 +328,55 @@ def keye_localsgd2(toy: bool = False) -> ExperimentConfig:
                       vocab=vocab, seq=seq, rows=16 if toy else 4, toy=toy)
 
 
+def joyai_llm_flash_decoder(**cut) -> DecoderConfig:
+    """JoyAI-LLM-Flash's published ``config.json``
+    (https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json,
+    ``model_type: joyai_llm_flash``, 48B-A2.7B) key for key; ``cut``
+    replaces what a worker holds less of (``num_hidden_layers``,
+    ``experts_held``) or, for a toy, any width."""
+    published = dict(
+        model_type="joyai_llm_flash", vocab_size=129280, hidden_size=2048,
+        intermediate_size=7168, moe_intermediate_size=768,
+        num_hidden_layers=40, num_attention_heads=32,
+        num_key_value_heads=32, head_dim=64, n_routed_experts=256,
+        n_shared_experts=1, num_experts_per_tok=8, routed_scaling_factor=2.5,
+        norm_topk_prob=True, scoring_func="sigmoid", topk_method="noaux_tc",
+        n_group=1, topk_group=1, first_k_dense_replace=1, moe_layer_freq=1,
+        ep_size=1, q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, qk_head_dim=192, v_head_dim=128,
+        rope_theta=32000000, rope_interleave=True, rope_scaling=None,
+        max_position_embeddings=131072, num_nextn_predict_layers=1,
+        hidden_act="silu", attention_bias=False, rms_norm_eps=1e-06,
+        tie_word_embeddings=False)
+    return DecoderConfig(**{**published, **cut})
+
+
+def joyai_localsgd2(toy: bool = False) -> ExperimentConfig:
+    """Two local-SGD workers, each one chip's share of JoyAI-LLM-Flash
+    (layers 0-4 and the multi-token-prediction module, experts 0-7 of 256
+    a layer, 16,160 of 129,280 vocabulary rows: 491.7 M parameters),
+    averaging their parameters every round of 4 steps x 1 row of 4,096
+    Zipf token ids: the benchmark cell ``joyai-flash.localsgd2.t4096``
+    (needs a 16 GB chip).  ``toy=True`` keeps the form (latent attention
+    with 4 heads of 16 + 8 over values of 16, a dense layer then experts,
+    8 of 32 held, the module) at widths a CPU trains in seconds."""
+    if toy:
+        decoder = joyai_llm_flash_decoder(
+            hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            num_hidden_layers=3, num_attention_heads=4,
+            num_key_value_heads=4, head_dim=8, qk_rope_head_dim=8,
+            qk_nope_head_dim=16, qk_head_dim=24, v_head_dim=16,
+            q_lora_rank=48, kv_lora_rank=32, n_routed_experts=32,
+            num_experts_per_tok=4, experts_held=8)
+        vocab, seq, rows = 256, 64, 16
+    else:
+        decoder = joyai_llm_flash_decoder(num_hidden_layers=5,
+                                          experts_held=8)
+        vocab, seq, rows = 16160, 4096, 8
+    return _localsgd2("joyai-localsgd2", decoder, model="decoder", seed=40,
+                      vocab=vocab, seq=seq, rows=rows, toy=toy)
+
+
 PRESETS = {
     "reference-fedavg": lambda: reference_federated("fedavg"),
     "reference-fedprox": lambda: reference_federated("fedprox"),
@@ -365,6 +414,8 @@ PRESETS = {
     "laguna-localsgd2-toy": lambda: laguna_localsgd2(toy=True),
     "keye-localsgd2": keye_localsgd2,
     "keye-localsgd2-toy": lambda: keye_localsgd2(toy=True),
+    "joyai-localsgd2": joyai_localsgd2,
+    "joyai-localsgd2-toy": lambda: joyai_localsgd2(toy=True),
     # Fault-injection variants (dopt.faults.FaultPlan): the same
     # workloads under a production-shaped failure regime — per-round
     # client crashes, a straggler deadline finishing half the local

@@ -1,10 +1,12 @@
 """The mixture-of-experts decoder (``dopt.models.decoder``,
-``model="decoder"``) against the plain references of its two layers
+``model="decoder"``) against the plain references of its three layers
 (``benchmark/reference_models/laguna_xs2.py``: gated window/full
 attention, sigmoid-routed experts beside a shared one;
 ``keye_vl2.py``: a learned sparse attention with its lightning indexer
-and alignment term, softmax-routed experts), at toy widths on the CPU in
-float32 with seeded random weights.
+and alignment term, softmax-routed experts; ``joyai_llm_flash.py``:
+multi-head latent attention, experts chosen by score plus a correction
+bias, a multi-token-prediction module in the loss), at toy widths on the
+CPU in float32 with seeded random weights.
 
 Tolerances.  Both sides compute in float32 on the CPU (exact products,
 no reduced-precision matmul), so what separates them is the ORDER of the
@@ -29,6 +31,7 @@ import numpy as np
 import pytest
 
 from benchmark import adapter, reference
+from benchmark.reference_models import joyai_llm_flash as joy
 from benchmark.reference_models import keye_vl2 as keye
 from benchmark.reference_models import laguna_xs2 as ref
 from dopt.config import (DataConfig, DecoderConfig, ExperimentConfig,
@@ -54,6 +57,11 @@ KEYE = json.loads(
 KEYE_T = 48
 KEYE_TOY = dict(keye.PUBLISHED, head_dim=8, kv_heads=2, experts=16, top_k=4,
                 index_heads=4, index_dim=8, index_top=8)
+# ... and the latent-attention model's: 4 heads of 8 + 8 over values of 8,
+# ranks 24 and 16, 16 published experts, 4 a token.
+JOYAI = json.loads(
+    (ROOT / "benchmark/configs/joyai-llm-flash.json").read_text())
+JOYAI_TOY = dict(joy.PUBLISHED, nope=8, rope=8, v=8, experts=16, top_k=4)
 
 
 def toy_decoder(heads, *, full_every=4, dense_layers=1, held=4, offset=0,
@@ -114,6 +122,37 @@ def keye_model(layers=3, *, attn_block=4, head_block=16, **kw):
                            attn_block=attn_block, head_block=head_block)
 
 
+def joyai_decoder(layers=3, *, held=4, offset=0, **kw) -> DecoderConfig:
+    """The published latent-attention configuration with the toy's
+    sizes."""
+    body = {**JOYAI["model"]["decoder"],
+            "hidden_size": DIM, "intermediate_size": 64,
+            "moe_intermediate_size": 16, "num_hidden_layers": layers,
+            "num_attention_heads": 4, "num_key_value_heads": 4,
+            "head_dim": JOYAI_TOY["rope"],
+            "qk_rope_head_dim": JOYAI_TOY["rope"],
+            "qk_nope_head_dim": JOYAI_TOY["nope"],
+            "qk_head_dim": JOYAI_TOY["nope"] + JOYAI_TOY["rope"],
+            "v_head_dim": JOYAI_TOY["v"], "q_lora_rank": 24,
+            "kv_lora_rank": 16, "n_routed_experts": JOYAI_TOY["experts"],
+            "num_experts_per_tok": JOYAI_TOY["top_k"],
+            "experts_held": held, "expert_offset": offset, **kw}
+    return DecoderConfig(**body)
+
+
+def joyai_model(layers=3, *, attn_block=8, head_block=16, **kw):
+    """... as a worker whose blocks are shorter than the toy's rows."""
+    return GatedMoEDecoder(joyai_decoder(layers, **kw), vocab_rows=VOCAB,
+                           attn_block=attn_block, head_block=head_block)
+
+
+def joyai_params(seed=0, layers=3, held=4, bias_scale=0.0):
+    return jax.tree.map(jnp.asarray, joy.init(
+        seed, JOYAI_TOY, vocab=VOCAB, dim=DIM, heads=4, layers=layers,
+        dense=64, expert=16, held=held, q_rank=24, kv_rank=16,
+        bias_scale=bias_scale))
+
+
 def keye_params(seed=0, layers=3, held=4):
     return jax.tree.map(jnp.asarray, keye.init(
         seed, KEYE_TOY, vocab=VOCAB, dim=DIM, heads=4, layers=layers,
@@ -152,11 +191,26 @@ KEYE_PATTERNS = {
     "keye-top8": (3, {}),
     "keye-top40-ragged": (2, {"topk": 40, "attn_block": 20}),
 }
+# the latent-attention layer: a dense layer, two with experts and the
+# multi-token-prediction module; the same with a correction bias that
+# moves the choice; a lone dense layer (the module's block is then the
+# only layer with experts).
+JOYAI_PATTERNS = {
+    "joyai-mtp": (3, 0.0),
+    "joyai-biased-router": (3, 0.5),
+    "joyai-one-dense-layer": (1, 0.0),
+}
+ALL_PATTERNS = sorted({**PATTERNS, **KEYE_PATTERNS, **JOYAI_PATTERNS})
 
 
 def case(name):
     """(model, seeded parameters, (x, y, w), the reference's forward and
-    objective) for one pattern of either model type."""
+    objective) for one pattern of any model type."""
+    if name in JOYAI_PATTERNS:
+        layers, bias = JOYAI_PATTERNS[name]
+        return (joyai_model(layers), joyai_params(0, layers, bias_scale=bias),
+                batch(), functools.partial(joy.forward, spec=JOYAI_TOY),
+                functools.partial(joy.objective, spec=JOYAI_TOY))
     if name in KEYE_PATTERNS:
         layers, kw = KEYE_PATTERNS[name]
         spec = {**KEYE_TOY, "index_top": kw.get("topk", 8)}
@@ -174,7 +228,7 @@ def case(name):
             functools.partial(ref.objective, spec=spec))
 
 
-@pytest.fixture(scope="module", params=sorted({**PATTERNS, **KEYE_PATTERNS}))
+@pytest.fixture(scope="module", params=ALL_PATTERNS)
 def both(request):
     """(program's logits, loss, gradients, aux), (reference's) for one
     pattern of layers, from the same seeded parameters and batch."""
@@ -226,6 +280,23 @@ def test_init_builds_the_reference_tree_and_the_published_count():
         == PUBLISHED["parameters"] == 389_634_048
 
 
+def test_init_builds_the_latent_attention_tree_and_the_published_count():
+    """The toy's tree is the reference's (a multi-token-prediction module
+    beside the layers, a correction bias of zeros in each layer with
+    experts), and the configuration file's worker holds its
+    491,697,408 parameters, from shapes alone."""
+    mine = joyai_model().init(jax.random.key(0))["params"]
+    assert (jax.tree.map(lambda a: a.shape, mine)
+            == jax.tree.map(lambda a: a.shape, joyai_params()))
+    assert "mlp" in mine["layer0"] and "router_bias" in mine["layer1"]
+    assert float(jnp.abs(mine["mtp"]["layer"]["router_bias"]).max()) == 0.0
+    body = ModelConfig(**JOYAI["model"])
+    full = GatedMoEDecoder(body.decoder, vocab_rows=body.num_classes)
+    shapes = jax.eval_shape(full.init, jax.random.key(0))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes)) \
+        == JOYAI["parameters"] == 491_697_408
+
+
 def test_init_builds_the_sparse_attention_tree_and_its_count():
     mine = keye_model().init(jax.random.key(0))["params"]
     assert (jax.tree.map(lambda a: a.shape, mine)
@@ -243,7 +314,7 @@ def test_init_builds_the_sparse_attention_tree_and_its_count():
 # ------------------------------------------------------- the shares add up
 
 @pytest.mark.parametrize("held", [1, 4, 8, 16])
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
 def test_the_shares_add_up_to_the_uncut_layer(model_type, held):
     """For every share of the 16 published experts, the layer's routed
     part, plus the shared expert (where the layer has one) counted once,
@@ -258,15 +329,22 @@ def test_the_shares_add_up_to_the_uncut_layer(model_type, held):
             held=TOY["experts"])["layer0"])
         want = ref._experts(layer, m, spec)
         shared = _gated_mlp(layer["shared"], m, jnp.float32)
-    else:
+    elif model_type == "KeyeVL2":
         layer = keye_params(2, 1, KEYE_TOY["experts"])["layer0"]
         want = keye._experts(layer, m, KEYE_TOY)
         shared = 0.0
+    else:      # with a correction bias that moves the choice
+        layer = joyai_params(2, 2, JOYAI_TOY["experts"],
+                             bias_scale=0.5)["layer1"]
+        want = joy._experts(layer, m, JOYAI_TOY)
+        shared = _gated_mlp(layer["shared"], m, jnp.float32)
     total, slots = shared, 0.0
     for offset in range(0, TOY["experts"], held):
-        model = (toy_model([4], dense_layers=0, held=held, offset=offset)
-                 if model_type == "laguna"
-                 else keye_model(1, held=held, offset=offset))
+        model = {"laguna": lambda: toy_model([4], dense_layers=0, held=held,
+                                             offset=offset),
+                 "KeyeVL2": lambda: keye_model(1, held=held, offset=offset),
+                 "joyai_llm_flash": lambda: joyai_model(
+                     2, held=held, offset=offset)}[model_type]()
         share = {**layer, "experts": jax.tree.map(
             lambda a: a[offset:offset + held], layer["experts"])}
         out, counts = model._experts(share, m)
@@ -277,9 +355,11 @@ def test_the_shares_add_up_to_the_uncut_layer(model_type, held):
     assert abs(slots - 1.0) < 1e-6
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
 def test_routing_counts_are_counts(model_type):
-    model = toy_model([4, 6]) if model_type == "laguna" else keye_model(2)
+    model = {"laguna": lambda: toy_model([4, 6]),
+             "KeyeVL2": lambda: keye_model(2),
+             "joyai_llm_flash": lambda: joyai_model(2)}[model_type]()
     params = model.init(jax.random.key(1))["params"]
     x, y, w = batch(t=T if model_type == "laguna" else KEYE_T)
     _, aux = model.loss(params, x, y, w)
@@ -294,6 +374,9 @@ def test_routing_counts_are_counts(model_type):
         assert float(aux["index_keys_kept_share"]) == pytest.approx(
             kept / (KEYE_T * (KEYE_T + 1) / 2), rel=1e-6)
         assert float(aux["index_align_loss"]) > 0.0     # a KL divergence
+    if model_type == "joyai_llm_flash":
+        assert float(aux["mtp_loss"]) > 0.0
+        assert 0.0 <= float(aux["mtp_acc"]) <= 1.0
 
 
 # ------------------------------------------- the learned sparse attention
@@ -397,28 +480,32 @@ def test_blocked_attention_equals_masked_full_attention(t, window, block):
 # -------------------------------------------------- through the engine
 
 def gossip_config(model_type="laguna", **model_kw) -> ExperimentConfig:
-    """Two workers of the toy of either model type under the traffic of
+    """Two workers of the toy of any model type under the traffic of
     its cell (``model="laguna"`` is the first one's name for
     ``"decoder"``, and stays valid)."""
     laguna = model_type == "laguna"
+    mix, decoder, t = {
+        "laguna": ("localsgd2-t4096", lambda: toy_decoder([4, 6, 6],
+                                                          **model_kw), T),
+        "KeyeVL2": ("localsgd2-t8192", lambda: keye_decoder(2, **model_kw),
+                    KEYE_T),
+        "joyai_llm_flash": ("localsgd2-t4096-h4",
+                            lambda: joyai_decoder(2, **model_kw), T),
+    }[model_type]
     traffic = json.loads(
-        (ROOT / "benchmark/traffic"
-         / ("localsgd2-t4096.json" if laguna else "localsgd2-t8192.json")
-         ).read_text())
+        (ROOT / "benchmark/traffic" / f"{mix}.json").read_text())
     return ExperimentConfig(
         name="decoder-toy", seed=3, mesh_devices=1,
         data=DataConfig(dataset="synthetic_tokens", num_users=2, iid=True,
                         synthetic_train_size=8, synthetic_test_size=2),
         model=ModelConfig(
             model="laguna" if laguna else "decoder", faithful=False,
-            num_classes=VOCAB, input_shape=(T if laguna else KEYE_T,),
-            decoder=(toy_decoder([4, 6, 6], **model_kw) if laguna
-                     else keye_decoder(2, **model_kw))),
+            num_classes=VOCAB, input_shape=(t,), decoder=decoder()),
         optim=OptimizerConfig(lr=0.05, momentum=0.9),
         gossip=GossipConfig(**{**traffic["gossip"], "local_bs": 2}))
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
 def test_two_gossip_rounds_equal_the_reference_loop(model_type):
     """``GossipTrainer`` on token rows against ``benchmark.reference``'s
     gossip loop: same initial parameters, batches and mixing matrices,
@@ -433,9 +520,11 @@ def test_two_gossip_rounds_equal_the_reference_loop(model_type):
     rounds = adapter.reference_rounds(trainer, cfg, traffic, 2)
     trainer.run(rounds=2)
     got = adapter.final_params(trainer, traffic)
-    objective = (functools.partial(ref.objective, spec=TOY)
-                 if model_type == "laguna"
-                 else functools.partial(keye.objective, spec=KEYE_TOY))
+    objective = {
+        "laguna": functools.partial(ref.objective, spec=TOY),
+        "KeyeVL2": functools.partial(keye.objective, spec=KEYE_TOY),
+        "joyai_llm_flash": functools.partial(joy.objective, spec=JOYAI_TOY),
+    }[model_type]
     want = reference.run_gossip(
         objective, init, rounds, lr=cfg.optim.lr,
         momentum=cfg.optim.momentum)
@@ -451,6 +540,8 @@ def test_two_gossip_rounds_equal_the_reference_loop(model_type):
         if model_type == "KeyeVL2":
             assert r["index_align_loss"] > 0.0
             assert 0.0 < r["index_keys_kept_share"] < 1.0
+        if model_type == "joyai_llm_flash":
+            assert r["mtp_loss"] > 0.0 and 0.0 <= r["mtp_acc"] <= 1.0
 
 
 def test_the_cell_averages_the_two_workers():
@@ -551,7 +642,7 @@ def test_which_mlp_a_sparse_attention_layer_has():
     assert "mlp" in tree["layer0"] and "router" in tree["layer1"]
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
 def test_compiled_round_carries_the_decoder_scopes(model_type):
     """``dopt_attn``, ``dopt_moe`` with ``dopt_route`` inside it and
     ``dopt_head`` mark the decoder's forward and backward inside the
@@ -621,6 +712,7 @@ LAYER_KINDS = {
     "laguna-no-dense-layer": ("laguna", {"dense_layers": 0}, False, True),
     "keye": ("KeyeVL2", {}, False, False),
     "keye-a-dense-layer": ("KeyeVL2", {"mlp_only_layers": [1]}, True, False),
+    "joyai": ("joyai_llm_flash", {}, True, True),
 }
 
 
@@ -682,6 +774,7 @@ KIND_LAYERS = {
     "laguna-no-dense-layer": [[0, 4], [1, 2, 3]],
     "keye": [[0, 1, 2, 3]],
     "keye-a-dense-layer": [[0, 2, 3], [1]],
+    "joyai": [[0], [1, 2, 3, 4]],
 }
 
 
@@ -690,6 +783,8 @@ def kinds_toy(kind):
     model_type, kw, *_ = LAYER_KINDS[kind]
     if model_type == "laguna":
         return toy_model([4, 6, 6, 6, 4], **kw), batch()
+    if model_type == "joyai_llm_flash":
+        return joyai_model(5, **kw), batch()
     return keye_model(4, **kw), batch(t=KEYE_T)
 
 
@@ -709,7 +804,8 @@ def test_each_layer_kind_is_traced_once_a_branch(kind):
     and lowers it to one function a branch, the forward with its kept
     residuals and the backward, that every layer of the kind calls; the
     round adds its evaluation's forward, at the training rows' shapes in
-    these toys, so no second trace."""
+    these toys, so no second trace.  A multi-token-prediction module's
+    block is one more call of its kind's body, not a trace."""
     from dopt.engine import GossipTrainer
 
     model, (x, y, w) = kinds_toy(kind)
@@ -720,8 +816,10 @@ def test_each_layer_kind_is_traced_once_a_branch(kind):
         params).as_text()
     assert dict(model.kind_traces) == dict.fromkeys(kinds, 1)
     # each kind's two functions are called once by each of its layers
-    assert sorted(_layer_calls(text).values()) == sorted(
-        len(layers) for layers in KIND_LAYERS[kind] * 2)
+    # (and by the module's block)
+    mtp = model.cfg.mtp and model._kind(model.mtp_layer)
+    calls = [len(layers) + (k == mtp) for k, layers in kinds.items()]
+    assert sorted(_layer_calls(text).values()) == sorted(calls * 2)
 
     model_type, kw, *_ = LAYER_KINDS[kind]
     trainer = GossipTrainer(gossip_config(model_type, **kw),
@@ -731,7 +829,8 @@ def test_each_layer_kind_is_traced_once_a_branch(kind):
     assert dict(trainer.model.kind_traces) == dict.fromkeys(kinds, 1)
     calls = _layer_calls(round_text)
     assert len(calls) == 3 * len(kinds)
-    assert sum(calls.values()) == 3 * sum(map(len, kinds.values()))
+    assert sum(calls.values()) == 3 * (sum(map(len, kinds.values()))
+                                       + trainer.model.cfg.mtp)
 
 
 def _per_layer_hidden(self, params, tokens):
@@ -779,12 +878,14 @@ def test_one_body_a_kind_equals_a_checkpoint_a_layer(monkeypatch, kind):
 def test_the_cells_layers_fall_into_their_kinds():
     """Laguna's five layers are three kinds (full attention with 48 heads
     and a dense MLP; sliding, 64 heads, experts; full, 48, experts),
-    keye's four one; from the configuration alone."""
+    keye's four one, joyai's five two (dense, experts); from the
+    configuration alone."""
     from dopt.presets import get_preset
 
     for preset, config, want in (
             ("laguna-localsgd2", PUBLISHED, [[0], [1, 2, 3], [4]]),
-            ("keye-localsgd2", KEYE, [[0, 1, 2, 3]])):
+            ("keye-localsgd2", KEYE, [[0, 1, 2, 3]]),
+            ("joyai-localsgd2", JOYAI, [[0], [1, 2, 3, 4]])):
         for model in (get_preset(preset).model,
                       ModelConfig(**config["model"])):
             worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
@@ -824,7 +925,7 @@ def _scope_free(monkeypatch):
     monkeypatch.setattr(jax, "named_scope", bare)
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
 def test_the_scopes_are_metadata_only(monkeypatch, model_type):
     """The round program lowers to the same text without debug info with
     every scope and without any: they name ops and change none."""
@@ -917,7 +1018,7 @@ def _recomputed_matmuls(grad, params):
             and "dopt_head" not in s and not own.search(s)]
 
 
-@pytest.mark.parametrize("pattern", sorted({**PATTERNS, **KEYE_PATTERNS}))
+@pytest.mark.parametrize("pattern", ALL_PATTERNS)
 def test_a_layer_recomputes_no_matmul(pattern):
     """A count, on the CPU: the backward pass computes none of a layer's
     matmuls again: q / k / v, the per-head gate, the output projection,
@@ -940,7 +1041,7 @@ def test_the_count_sees_what_a_bare_policy_recomputes(monkeypatch):
         assert any(name in s for s in stacks), name
 
 
-@pytest.mark.parametrize("pattern", sorted({**PATTERNS, **KEYE_PATTERNS}))
+@pytest.mark.parametrize("pattern", ALL_PATTERNS)
 def test_gradients_equal_those_without_any_checkpoint(monkeypatch, pattern):
     """The kept values are the values the recompute would produce: every
     gradient leaf equals that of the same layers with ``jax.checkpoint``
@@ -1745,3 +1846,242 @@ def test_grouped_expert_kernels_compile_for_the_chip_at_any_ambient_precision(
     for kernel in KERNEL_NAMES.values():
         assert sum(kernel in name for name in called) == 1, kernel
     assert all("dopt_moe" in name for name in called)
+
+
+# ------------- latent attention, the corrected router and the MTP module
+
+@pytest.mark.parametrize("dims", [(192, 128), (64, 128)])
+def test_splash_attention_takes_values_narrower_than_its_keys(dims):
+    """The fused kernel (interpreted on the CPU) with query and key heads
+    wider or narrower than the value heads, as latent attention has them
+    (192 over 128), against the ``jax.numpy`` blocks: outputs and
+    gradients, one query head on each of 2 key/value heads."""
+    from dopt.models.decoder import attention_path, splash_causal_attention
+
+    qk, vd = dims
+    rng = np.random.default_rng(11)
+    t = 256
+    q = jnp.asarray(rng.standard_normal((2, 1, t, qk)).astype(np.float32))
+    k = jnp.asarray(rng.standard_normal((2, t, qk)).astype(np.float32))
+    v = jnp.asarray(rng.standard_normal((2, t, vd)).astype(np.float32))
+    w = jnp.asarray(rng.standard_normal((2, 1, t, vd)).astype(np.float32))
+    assert attention_path(t, qk, 128, vd) == "splash"
+
+    def blocked(q, k, v):
+        return blocked_causal_attention(q, k, v, window=None, block=128)
+
+    def splash(q, k, v):
+        return splash_causal_attention(q / np.sqrt(qk), k, v, window=None,
+                                       block=128)
+
+    assert splash(q, k, v).shape == (2, 1, t, vd)
+    assert close(splash(q, k, v), blocked(q, k, v))
+    got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2))(
+        q, k, v) for f in (splash, blocked))
+    assert all(close(g, x) for g, x in zip(got, want))
+
+
+def test_the_latent_attention_takes_the_kernel_at_the_cell_s_shapes():
+    """The cell's shapes (4,096 positions, heads of 192 over 128) are the
+    kernel's, in the preset and in the configuration file alike; a toy
+    worker with rows of 256, blocks of 128 and the published heads takes
+    it too, and its loss and every gradient leaf agree with the same
+    worker in ``jax.numpy`` blocks of 64."""
+    from dopt.models.decoder import attention_path
+    from dopt.presets import get_preset
+
+    assert attention_path(4096, 192, 512, 128) == "splash"
+    assert attention_path(4096, 192, 512, 96) == "blocked"
+    assert attention_path(4096, 160, 512, 128) == "blocked"
+    for model in (get_preset("joyai-localsgd2").model,
+                  ModelConfig(**JOYAI["model"])):
+        worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
+        assert worker.attention_path(model.input_shape[0]) == "splash"
+    kw = dict(num_attention_heads=1, num_key_value_heads=1, head_dim=64,
+              qk_rope_head_dim=64, qk_nope_head_dim=128, qk_head_dim=192,
+              v_head_dim=128)
+    blocked, splash = (joyai_model(2, attn_block=block, **kw)
+                       for block in (64, 128))
+    assert (blocked.attention_path(256), splash.attention_path(256)) == (
+        "blocked", "splash")
+    x = np.random.default_rng(7).integers(0, VOCAB, (2, 256)).astype(np.int32)
+    y = np.concatenate([x[:, 1:], np.full((2, 1), -1, np.int32)], 1)
+    w = np.ones(2, np.float32)
+    params = blocked.init(jax.random.key(2))["params"]
+    (want, _), want_g = jax.value_and_grad(
+        lambda p: blocked.loss(p, x, y, w), has_aux=True)(params)
+    (got, _), got_g = jax.value_and_grad(
+        lambda p: splash.loss(p, x, y, w), has_aux=True)(params)
+    assert abs(float(got) - float(want)) <= RTOL * float(want)
+    assert all(jax.tree.leaves(jax.tree.map(close, got_g, want_g)))
+
+
+def test_the_rotary_interleaves_as_the_reference_does():
+    """``_rotary`` with ``interleave`` is the pair rotation (2i, 2i + 1)
+    in another order of the dimensions, the same for every vector: every
+    dot product of a rotated query and a rotated key is the reference's;
+    ``rotate_half`` in its place gives other scores."""
+    from dopt.models.decoder import _rotary
+
+    rng = np.random.default_rng(3)
+    q = rng.standard_normal((2, 17, 8)).astype(np.float32)
+    k = rng.standard_normal((1, 17, 8)).astype(np.float32)
+    spec = {"theta": 100.0}
+    rope = {"rope_type": "default", "rope_theta": 100.0, "interleave": True}
+
+    def scores(q, k):
+        return np.einsum("hqd,hkd->hqk", np.asarray(q), np.asarray(k))
+
+    want = scores(np.moveaxis(joy._rotary(np.moveaxis(q, 0, 1), spec), 1, 0),
+                  np.moveaxis(joy._rotary(np.moveaxis(k, 0, 1), spec), 1, 0))
+    got = scores(_rotary(q, rope), _rotary(k, rope))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    half = scores(_rotary(q, {**rope, "interleave": False}),
+                  _rotary(k, {**rope, "interleave": False}))
+    assert np.abs(half - want).max() > 0.1
+
+
+def test_the_correction_bias_chooses_and_does_not_weigh():
+    """A nonzero ``router_bias`` changes which experts a token reaches
+    but not the weights' formula (the sigmoid scores at the chosen
+    experts, renormalised, times 2.5), and its gradient is exactly 0."""
+    model = joyai_model(2, held=16)
+    layer = joyai_params(2, 2, 16)["layer1"]
+    m = jnp.asarray(np.random.default_rng(4).standard_normal(
+        (T, DIM)).astype(np.float32))
+    bias = jnp.asarray(np.random.default_rng(5).standard_normal(16),
+                       jnp.float32)
+    plain, _, _ = model._route(layer["router"], m, layer["router_bias"])
+    moved, hit, _ = model._route(layer["router"], m, bias)
+    assert (np.asarray(plain > 0) != np.asarray(hit)).any()
+    scores = jax.nn.sigmoid(m @ layer["router"])
+    top = np.where(np.asarray(hit), np.asarray(scores), 0.0)
+    want = top / top.sum(-1, keepdims=True) * 2.5
+    np.testing.assert_allclose(np.asarray(moved), want, rtol=1e-5, atol=1e-6)
+    x, y, w = batch()
+    params = joyai_params(0, 3, bias_scale=0.5)
+    grads = jax.grad(lambda p: model_loss(joyai_model(3), p, x, y, w))(params)
+    for name in ("layer1", "layer2"):
+        assert float(jnp.abs(grads[name]["router_bias"]).max()) == 0.0
+    assert float(jnp.abs(grads["mtp"]["layer"]["router_bias"]).max()) == 0.0
+
+
+def model_loss(model, params, x, y, w):
+    return model.loss(params, x, y, w)[0]
+
+
+def test_the_mtp_term_counts_where_both_labels_count(monkeypatch):
+    """The module's cross-entropy is taken where a position's label and
+    the next label both count: a row's last two positions and the two
+    around an uncounted label add nothing, nor does a padding row; it is
+    the reference's second term alone, and a weight of 0 leaves the main
+    cross-entropy, with no gradient on the module's own leaves."""
+    from dopt.models import decoder
+
+    x, y, w = batch()
+    y[0, 5] = -1                   # positions 4 and 5 of row 0 drop out
+    params = joyai_params(1, 2)
+    _, aux = joyai_model(2).loss(params, x, y, w)
+    spec = JOYAI_TOY
+    after = np.concatenate([y[:, 1:], np.full_like(y[:, :1], -1)], 1)
+    after = np.where(y >= 0, after, -1)
+    assert (after[:, -2:] == -1).all() and (after[0, 4:6] == -1).all()
+    mtp = jnp.stack([joy._mtp_logits(params, joy._hidden(params, r, spec),
+                                     yr, spec) for r, yr in zip(x, y)])
+    want = reference.token_cross_entropy(mtp, after, w)
+    assert abs(float(aux["mtp_loss"]) - float(want)) <= RTOL * float(want)
+    main = reference.token_cross_entropy(joy.forward(params, x, spec), y, w)
+    monkeypatch.setattr(decoder, "MTP_LOSS_WEIGHT", 0.0)
+    loss, grads = jax.value_and_grad(
+        lambda p: model_loss(joyai_model(2), p, x, y, w))(params)
+    assert abs(float(loss) - float(main)) <= RTOL * float(main)
+    assert all(float(jnp.abs(g).max()) == 0.0
+               for g in jax.tree.leaves(grads["mtp"]))
+
+
+@pytest.mark.parametrize("change, message", [
+    ({"n_group": 8, "topk_group": 4}, "group-limited"),
+    ({"q_lora_rank": None}, "q_lora_rank is required"),
+    ({"sa_config": KEYE["sa_config"]}, "a key of model_type 'KeyeVL2'"),
+    ({"gating": True}, "a key of model_type 'laguna'"),
+    ({"num_experts": 16}, "a key of model_type"),
+    ({"scoring_func": "softmax"}, "sigmoid"),
+    ({"topk_method": "greedy"}, "noaux_tc"),
+    ({"rope_scaling": {"type": "yarn", "factor": 40}}, "unscaled"),
+    ({"num_nextn_predict_layers": 2}, "0 or 1"),
+    ({"moe_layer_freq": 2}, "moe_layer_freq"),
+    ({"num_key_value_heads": 2}, "num_key_value_heads"),
+    ({"qk_head_dim": 12}, "qk_head_dim"),
+    ({"head_dim": 16}, "rotary key"),
+    ({"norm_topk_prob": False}, "norm_topk_prob"),
+    ({"attention_bias": True}, "no biases"),
+])
+def test_the_latent_attention_config_refuses(change, message):
+    """What the layer cannot honour, a missing rank and a key of another
+    model type are refused."""
+    with pytest.raises(ValueError, match=message):
+        joyai_decoder(**change)
+
+
+def test_the_latent_attention_config_takes_every_published_key():
+    """Every key of the published ``config.json`` (the configuration
+    file's top level, at the published depth) builds the configuration,
+    and what it says is read: MLA widths, one dense layer, the module."""
+    from dopt.presets import joyai_llm_flash_decoder
+
+    published = {k: v for k, v in JOYAI["model"]["decoder"].items()
+                 if k not in ("experts_held", "expert_offset")}
+    assert set(published) <= set(JOYAI)
+    cfg = DecoderConfig(**{**published, "num_hidden_layers": 40})
+    assert cfg == joyai_llm_flash_decoder()
+    assert cfg.latent and cfg.mtp and cfg.experts == 256
+    assert [cfg.sparse_mlp(i) for i in range(3)] == [False, True, True]
+    assert cfg.routed_scale == 2.5 and cfg.shared_width == 768
+    assert not joyai_decoder(num_nextn_predict_layers=0).mtp
+
+
+def test_compiled_round_names_the_latent_and_mtp_parts():
+    """``dopt_latent`` (the two down-projections and their norms) inside
+    ``dopt_qkv``, forward and backward; ``dopt_mtp`` over the module: its
+    projection, its block's attention and experts and its head pass, so
+    ``head_ms`` holds both head passes."""
+    from jax._src.config import enable_compilation_cache
+
+    from dopt.engine import GossipTrainer
+
+    _, lowered = GossipTrainer(gossip_config("joyai_llm_flash"),
+                               eval_every=10**9).lower_round(1)
+    with enable_compilation_cache(False):   # the key ignores metadata
+        stacks = _stacks(lowered.compile().as_text())
+    _check_scope(stacks, "dopt_latent", "dopt_qkv")
+    _check_scope(stacks, "dopt_mtp", "dopt_local")
+    mtp = {s for s in stacks if "dopt_mtp" in s}
+    for part in ("dopt_attn", "dopt_moe", "dopt_head", "dopt_latent"):
+        assert any(part in s for s in mtp), part
+    assert any("dopt_attn" in s for s in stacks - mtp)
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_latent_attention_kernels_compile_for_the_chip(
+        v5e_chip, monkeypatch, precision):
+    """The forward and backward splash kernels at the cell's sizes: 4,096
+    positions, 32 heads with queries and keys of 192 and values of 128,
+    through the real XLA:TPU + Mosaic compile, also at the parity
+    check's ``highest``.  Compile only: nothing runs."""
+    from dopt.models.decoder import splash_causal_attention
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def loss(q, k, v):
+        out = splash_causal_attention(q, k, v, window=None, block=512)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=v5e_chip)
+
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape(32, 1, 4096, 192), shape(32, 4096, 192),
+            shape(32, 4096, 128)).compile()
+    text = compiled.as_text()
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text

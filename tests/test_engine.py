@@ -763,3 +763,58 @@ def test_sharded_eval_composes_with_dropout_and_choco(devices):
 def test_eval_mode_validation():
     with pytest.raises(ValueError, match="eval_mode"):
         GossipTrainer(_gossip_cfg(gossip={"eval_mode": "bogus"}))
+
+
+def _plain_eval(apply, params, x, y, batch):
+    """One worker on the whole test split, by hand: the share of rows it
+    labels right, and the reference notebooks' loss (the mean over test
+    batches of each batch's mean cross-entropy; the last batch holds only
+    the rows that are left)."""
+    logits = np.asarray(apply(params, x), np.float64)
+    logp = logits - logits.max(-1, keepdims=True)
+    logp = logp - np.log(np.exp(logp).sum(-1, keepdims=True))
+    nll = -logp[np.arange(len(y)), y]
+    losses = [nll[s:s + batch].mean() for s in range(0, len(y), batch)]
+    return float((logits.argmax(-1) == y).mean()), float(np.mean(losses))
+
+
+@pytest.mark.parametrize("model", ["mlp", "resnet18"])
+def test_round_eval_is_every_worker_on_the_whole_test_split(devices, model):
+    """A round's ``avg_test_acc`` / ``avg_test_loss`` (``eval_mode``
+    full, every round) are each worker's own parameters after the round's
+    mix, on every row of the test split and on nothing else (300 rows:
+    the second batch of 256 is mostly padding), averaged over the workers.
+    ``resnet18`` takes the grouped stacked forward, as the benchmark's
+    ResNet cells do; ``mlp`` the vmapped one."""
+    import jax
+
+    cfg = _gossip_cfg(num_users=4, iid=False)
+    if model == "resnet18":
+        cfg = dataclasses.replace(cfg, model=ModelConfig(
+            model="resnet18", stage_sizes=(1, 1), faithful=False,
+            input_shape=(8, 8, 3)))
+    cfg = dataclasses.replace(
+        cfg, mesh_devices=1,
+        data=dataclasses.replace(cfg.data, synthetic_train_size=64,
+                                 synthetic_test_size=300),
+        gossip=dataclasses.replace(cfg.gossip, local_bs=8))
+    tr = GossipTrainer(cfg, eval_every=1)
+    tr.run(rounds=1)
+    before = jax.device_get(tr.params)
+    w = np.asarray(tr.mixing.for_round(1), np.float64)
+    row = [r for r in tr.run(rounds=1) if r["round"] == 1][0]
+
+    x, y = tr.dataset.test_x, np.asarray(tr.dataset.test_y)
+    assert len(y) == 300
+    apply = jax.jit(lambda p, x: tr.model.apply({"params": p}, x))
+    per_worker = []
+    for i in range(cfg.data.num_users):
+        mixed = jax.tree.map(
+            lambda leaf: np.tensordot(w[i], np.asarray(leaf, np.float64),
+                                      axes=1).astype(np.float32), before)
+        per_worker.append(_plain_eval(apply, mixed, x, y, batch=256))
+    acc, loss = np.mean(per_worker, axis=0)
+    # Workers that differ, so that one of them evaluated for all would show.
+    assert len({round(a, 6) for a, _ in per_worker}) > 1, per_worker
+    assert row["avg_test_acc"] == pytest.approx(acc, abs=1 / (4 * len(y)))
+    assert row["avg_test_loss"] == pytest.approx(loss, rel=1e-4)
