@@ -27,8 +27,13 @@ What a layer computes is written out in the docstrings of
 the chip:
 
 * matmul inputs in the compute dtype (bfloat16 on the chip) with float32
-  accumulation; residual stream, norms, router scores, attention
-  softmax and the loss in float32;
+  accumulation; residual stream, norms, router scores, rotary,
+  attention softmax and the loss in float32; where a layer's queries and
+  keys are projections' products with no per-head norm between, rotary,
+  the queries' scale and the cast to the compute dtype are one pass a
+  tensor of this repo's own Pallas kernels, ``dopt.ops.rotary``,
+  wherever their shape limits allow (``rotary_path``), and ``_rotary``
+  elsewhere;
 * attention a block of ``ATTN_BLOCK`` queries at a time against the keys
   that block can see — everything up to its end in a full layer, the
   band of ``sliding_window`` in front of it in a sliding one — so a
@@ -74,14 +79,16 @@ the chip:
 Scopes (inside the engines' ``dopt_local``): ``dopt_attn`` (normed input
 to gated output projection; a compiled kernel may carry no name stack
 and goes by its own name: ``splash_mqa_*``, and
-``dopt_attn_dopt_attend_fwd`` / ``_probs`` / ``_bwd`` and
-``dopt_attn_dopt_index_fwd`` / ``_bwd``, which spell out the two scopes
-they stand in) with, inside it, ``dopt_qkv`` (the q / k / v projections
-and the per-head gate's; in a latent layer the two up-projections, and
-``dopt_latent`` inside it: the two down-projections and their norms),
-``dopt_rope`` (what lies between them and the
-attention: per-head norms, rotary, the queries' scale for the kernel,
-the casts to the compute dtype), ``dopt_splash`` (on the kernel's path,
+``dopt_attn_dopt_attend_fwd`` / ``_probs`` / ``_bwd``,
+``dopt_attn_dopt_index_fwd`` / ``_bwd`` and ``dopt_attn_dopt_rope_fwd``
+/ ``_bwd``, which spell out the two scopes they stand in) with, inside
+it, ``dopt_qkv`` (the q / k / v projections and the per-head gate's; in
+a latent layer the two up-projections, and ``dopt_latent`` inside it:
+the two down-projections and their norms), ``dopt_rope`` (what lies
+between them and the attention: per-head norms, rotary, the queries'
+scale for the kernel, the casts to the compute dtype; where
+``rotary_path`` takes the rotary kernels, those and the small table and
+slice passes XLA leaves around them), ``dopt_splash`` (on the kernel's path,
 the kernel's call: what XLA does around it to its operands and
 outputs), ``dopt_oproj``
 (gate, output projection; where the layer has an indexer, a run of
@@ -121,6 +128,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dopt.config import DecoderConfig
 from dopt.ops import grouped_experts, sparse_attention
+from dopt.ops import rotary as rotary_kernels
 from dopt.ops.grouped_experts import running_count as _running_count
 
 # What a layer's ``jax.checkpoint`` keeps of its forward pass
@@ -231,6 +239,15 @@ def _inverse_frequencies(rope, head_dim: int):
     return inv, float(rope["attention_factor"])
 
 
+def _angles(rope, width: int, t: int):
+    """([t, rotary_dim / 2] float32 angles of positions ``0 .. t-1`` for
+    a ``width``-lane head, the attention factor their cos and sin take):
+    what every rotary body of one layer kind reads."""
+    inv, scale = _inverse_frequencies(rope, width)
+    return (jnp.arange(t, dtype=jnp.float32)[:, None]
+            * jnp.asarray(inv, jnp.float32)[None, :]), scale
+
+
 def _rotary(x, rope):
     """x: [H, T, head_dim]; ``rotate_half`` over the first rotary_dim
     dimensions of each head, float32.  Where ``rope["interleave"]`` the
@@ -239,9 +256,7 @@ def _rotary(x, rope):
     That is the interleaved rotation with its dimensions in another
     order, the same order for every query and key, so every score is the
     interleaved one."""
-    inv, scale = _inverse_frequencies(rope, x.shape[-1])
-    angles = (jnp.arange(x.shape[-2], dtype=jnp.float32)[:, None]
-              * jnp.asarray(inv, jnp.float32)[None, :])
+    angles, scale = _angles(rope, x.shape[-1], x.shape[-2])
     angles = jnp.concatenate([angles, angles], axis=-1)
     cos, sin = jnp.cos(angles) * scale, jnp.sin(angles) * scale
     rd = angles.shape[-1]
@@ -251,6 +266,54 @@ def _rotary(x, rope):
                            -2).reshape(rot.shape)
     half = jnp.concatenate([-rot[..., rd // 2:], rot[..., :rd // 2]], -1)
     return jnp.concatenate([rot * cos + half * sin, rest], -1)
+
+
+def _tables(rope, width: int, t: int):
+    """(cos, sin) [t, rotary_dim / 2] float32 of those angles, times the
+    attention factor: what the rotary kernels take."""
+    angles, factor = _angles(rope, width, t)
+    return jnp.cos(angles) * factor, jnp.sin(angles) * factor
+
+
+def rotary_dim(rope, width: int) -> int:
+    """Lanes of a ``width``-lane head (or part of one) that rotary turns:
+    the first ``partial_rotary_factor`` of them."""
+    return int(width * rope.get("partial_rotary_factor", 1.0))
+
+
+def rotary_path(t: int, head_dim: int, rotary: int, *, nope: int = 0,
+                normed: bool = False) -> str:
+    """Which body turns the queries and keys of a layer, rows of ``t``
+    positions and heads of ``head_dim`` lanes whose ``rotary`` rotary
+    lanes follow ``nope`` lanes without rotary: ``"fused"``, the Pallas
+    kernels of ``dopt.ops.rotary`` (rotary, the queries' scale and the
+    cast to the compute dtype in one pass a tensor, forward and
+    backward), where the rotary's input is a projection's product with no
+    per-head norm in between (``normed``: the norm's backward asks for
+    its own input, so norm and rotary would have to be one kernel) and
+    the kernel's shape limits allow it (``rotary.fits``: the row whole
+    tiles of 128 positions, the head at least a lane tile of 128, rotary
+    lanes a multiple of 64 that start on a lane tile) -- else ``"jnp"``,
+    ``_rotary`` and the scale and cast after it.  Nothing else decides
+    it: no option, no platform (the kernels are interpreted on the
+    CPU)."""
+    fits = not normed and rotary_kernels.fits(t, head_dim, rotary, nope)
+    return "fused" if fits else "jnp"
+
+
+def _rope(x, rope, dtype, *, nope: int = 0, scale: float = 1.0):
+    """``rotary_path``'s ``"fused"`` body: x [H, T, D] float32, a
+    projection's product -> [H, T, D] in ``dtype`` as the attention takes
+    it, under the name a layer keeps, in ONE kernel pass: rotary over the
+    lanes from ``nope`` on (``_rotary``'s rotation; interleaved pairs keep
+    the product's lane order, which ``_rotary`` takes apart: the same
+    order for every query and key of a layer either way), the first
+    ``nope`` lanes passed through, every lane times ``scale``."""
+    _, t, d = x.shape
+    return _keep(rotary_kernels.rotate(
+        x, *_tables(rope, d - nope, t), rotary_dim=rotary_dim(rope, d - nope),
+        interleave=bool(rope.get("interleave")), scale=scale, dtype=dtype,
+        nope=nope))
 
 
 @jax.checkpoint
@@ -304,11 +367,22 @@ def attention_path(t: int, head_dim: int, block: int = ATTN_BLOCK,
     return "splash" if fits else "blocked"
 
 
-def causal_attention(q, k, v, *, window: int | None, block: int = ATTN_BLOCK):
+def query_scale(t: int, head_dim: int, block: int = ATTN_BLOCK,
+                value_dim: int | None = None) -> float:
+    """What the rotary kernel scales a query by for ``causal_attention``:
+    1/sqrt(head_dim) where the fused attention takes pre-scaled queries
+    (``attention_path``), 1 where the blocked body scales its scores."""
+    splash = attention_path(t, head_dim, block, value_dim) == "splash"
+    return 1.0 / math.sqrt(head_dim) if splash else 1.0
+
+
+def causal_attention(q, k, v, *, window: int | None, block: int = ATTN_BLOCK,
+                     scaled: bool = False):
     """Causal (``window`` None) or banded attention of one row: q
     [G * R, T, D] float32 and unscaled (R query heads share each of the
-    G key/value heads), k [G, T, D] and v [G, T, E] in the compute dtype
-    -> [G, R, T, E].
+    G key/value heads) -- or, ``scaled``, as the rotary kernel leaves it:
+    in the compute dtype, scaled by ``query_scale`` and kept --, k
+    [G, T, D] and v [G, T, E] in the compute dtype -> [G, R, T, E].
 
     The ONE fork of the attention, and it exists only because the fused
     kernel cannot take every shape (``attention_path``).  Published
@@ -323,11 +397,12 @@ def causal_attention(q, k, v, *, window: int | None, block: int = ATTN_BLOCK):
     heads, t, d = q.shape
     grouped = (k.shape[0], heads // k.shape[0], t, d)
     splash = attention_path(t, d, block, v.shape[-1]) == "splash"
-    # kept across a layer's remat as the body takes it: each body's own
-    # cast, the queries' last float32 pass (XLA fuses it with the rotary
-    # in front of it, and a fusion goes by its root)
-    with jax.named_scope("dopt_rope"):
-        q = _keep((q / math.sqrt(d) if splash else q).astype(k.dtype))
+    if not scaled:
+        # kept across a layer's remat as the body takes it: each body's
+        # own cast, the queries' last float32 pass (XLA fuses it with the
+        # rotary in front of it, and a fusion goes by its root)
+        with jax.named_scope("dopt_rope"):
+            q = _keep((q / math.sqrt(d) if splash else q).astype(k.dtype))
     if splash:
         with jax.named_scope("dopt_splash"):
             return splash_causal_attention(q.reshape(grouped), k, v,
@@ -639,6 +714,20 @@ class GatedMoEDecoder:
                                   self.attn_block, c.v_head_dim)
         return attention_path(t, c.head_dim, self.attn_block)
 
+    def rotary_path(self, t: int) -> str:
+        """``"fused(k/n kinds)"`` where k of the n layer kinds turn their
+        queries and keys with the rotary kernels at rows of ``t``
+        positions, ``"jnp"`` where none does (``dopt.run`` prints it
+        beside the device; ``rotary_path`` is the rule)."""
+        c = self.cfg
+        nope = c.qk_nope_head_dim if c.latent else 0
+        d = nope + c.head_dim
+        paths = [rotary_path(t, d, rotary_dim(c.rope(i[0]), d - nope),
+                             nope=nope, normed=c.indexed)
+                 for i in self.layer_kinds().values()]
+        fused = paths.count("fused")
+        return f"fused({fused}/{len(paths)} kinds)" if fused else "jnp"
+
     @property
     def mtp_layer(self) -> int:
         """The index the multi-token-prediction module's block has among
@@ -776,15 +865,27 @@ class GatedMoEDecoder:
             kv = jnp.einsum("tr,rne->nte", c_kv,
                             p["kv_b"].astype(dt).reshape(-1, heads, nope + vd),
                             preferred_element_type=jnp.float32)
+        qk = nope + c.head_dim
+        fused = rotary_path(t, qk, rotary_dim(rope, c.head_dim),
+                            nope=nope) == "fused"
         with jax.named_scope("dopt_rope"):
-            q = jnp.concatenate([q[..., :nope],
-                                 _rotary(q[..., nope:], rope)], -1)
-            shared = _rotary(ckv[None, :, rank:], rope)          # [1, T, rd]
-            k = _keep(jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(
-                    shared, (heads, t, c.head_dim))], -1).astype(dt))
-            v = _keep(kv[..., nope:].astype(dt))
-        out = causal_attention(q, k, v, window=None, block=self.attn_block)
+            if fused:
+                q = _rope(q, rope, dt, nope=nope, scale=query_scale(
+                    t, qk, self.attn_block, vd))
+                k, v = map(_keep, rotary_kernels.latent_keys(
+                    kv, ckv[:, rank:], *_tables(rope, c.head_dim, t),
+                    nope=nope, interleave=bool(rope.get("interleave")),
+                    dtype=dt))
+            else:
+                q = jnp.concatenate([q[..., :nope],
+                                     _rotary(q[..., nope:], rope)], -1)
+                shared = _rotary(ckv[None, :, rank:], rope)      # [1, T, rd]
+                k = _keep(jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(
+                        shared, (heads, t, c.head_dim))], -1).astype(dt))
+                v = _keep(kv[..., nope:].astype(dt))
+        out = causal_attention(q, k, v, window=None, block=self.attn_block,
+                               scaled=fused)
         with jax.named_scope("dopt_oproj"):
             return jnp.einsum("nte,ned->td", out.reshape(heads, t, vd),
                               p["o"].astype(dt).reshape(heads, vd, -1),
@@ -845,18 +946,22 @@ class GatedMoEDecoder:
                     project=project)
                 counts = dict(zip(INDEX_COUNTERS, (align, kept)))
             else:
+                fused = rotary_path(t, hd, rotary_dim(rope, hd)) == "fused"
                 with jax.named_scope("dopt_qkv"):
                     q = heads_of("q", heads)
                 with jax.named_scope("dopt_rope"):
-                    q = _rotary(q, rope)
+                    q = (_rope(q, rope, dt, scale=query_scale(
+                        t, hd, self.attn_block)) if fused
+                         else _rotary(q, rope))
                 with jax.named_scope("dopt_qkv"):
                     k = heads_of("k", kv)
                 with jax.named_scope("dopt_rope"):
-                    k = _keep(_rotary(k, rope).astype(dt))
+                    k = (_rope(k, rope, dt) if fused
+                         else _keep(_rotary(k, rope).astype(dt)))
                 with jax.named_scope("dopt_qkv"):
                     v = _keep(heads_of("v", kv).astype(dt))
                 out = causal_attention(q, k, v, window=c.window(i),
-                                       block=self.attn_block)
+                                       block=self.attn_block, scaled=fused)
                 with jax.named_scope("dopt_qkv"):
                     gate = _keep(jnp.einsum(
                         "td,dn->nt", x, p["gate"].astype(dt),

@@ -1,7 +1,9 @@
 """Pallas TPU kernels: the fused update and mix (``fused_update``), the
-learned sparse attention's index scores and body (``sparse_attention``)
-and the held experts' dropless grouped matmul (``grouped_experts``;
-both of which ``dopt.models.decoder`` imports as modules)."""
+learned sparse attention's index scores and body (``sparse_attention``),
+the held experts' dropless grouped matmul (``grouped_experts``) and the
+rotary pass between a decoder attention's projections and the attention
+(``rotary``; the last three of which ``dopt.models.decoder`` imports as
+modules)."""
 
 from dopt.ops.fused_update import (
     fused_mix_sgd,

@@ -36,7 +36,9 @@ and in ``dopt_eval``; ``dopt.models.zoo``), and the decoder's, all
 nested in ``dopt_local`` (``dopt.models.decoder``):
 ``dopt_attn`` (normed input to gated output projection) with, inside
 it, ``dopt_qkv`` (the q / k / v and gate projections), ``dopt_rope``
-(per-head norms, rotary and casts between them and the attention),
+(per-head norms, rotary and casts between them and the attention; the
+rotary kernels go by names that spell out both scopes,
+``dopt_attn_dopt_rope_fwd`` / ``_bwd``),
 ``dopt_splash`` (the fused kernel's call, where it runs),
 ``dopt_oproj`` (gate and output projection) and, in a layer with an
 indexer, ``dopt_index`` (indexer projections, index

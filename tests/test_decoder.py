@@ -1801,7 +1801,8 @@ def test_the_experts_body_goes_by_shapes_alone():
     from dopt.engine import GossipTrainer
 
     line = device_details(GossipTrainer(gossip_config(), eval_every=10**9))
-    assert line.endswith("attention=blocked experts=grouped layers=3 kinds=2")
+    assert line.endswith(
+        "attention=blocked rope=jnp experts=grouped layers=3 kinds=2")
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
@@ -1939,6 +1940,65 @@ def test_the_rotary_interleaves_as_the_reference_does():
     half = scores(_rotary(q, {**rope, "interleave": False}),
                   _rotary(k, {**rope, "interleave": False}))
     assert np.abs(half - want).max() > 0.1
+
+
+# Workers at published head widths and rows of 256 (two layers of each
+# kind the cell has), whose queries and keys take the rotary kernels:
+# model type -> (model, its keys).
+ROPE_WIDTHS = {
+    "laguna": lambda block: toy_model(
+        [2, 2], attn_block=block, head_dim=128, num_key_value_heads=1,
+        sliding_window=100, full_every=2),
+    "joyai_llm_flash": lambda block: joyai_model(
+        2, attn_block=block, num_attention_heads=2, num_key_value_heads=2,
+        head_dim=64, qk_rope_head_dim=64, qk_nope_head_dim=128,
+        qk_head_dim=192, v_head_dim=128),
+}
+
+
+@pytest.mark.parametrize("block", [128, 64])
+@pytest.mark.parametrize("model_type", sorted(ROPE_WIDTHS))
+def test_the_rotary_kernels_leave_the_loss_and_gradients_as_rotary_does(
+        monkeypatch, model_type, block):
+    """One laguna worker (a full layer with YaRN over half its heads of
+    128, a sliding one with the default rotary over the whole head) and
+    one joyai worker (queries and keys of 128 + 64 interleaved rotary
+    lanes over values of 128, two layers and the MTP block) at rows of
+    256: every layer kind takes the rotary kernels (``rotary_path``), the
+    traced program holds both, and the hidden state, the loss and every
+    gradient leaf equal those of the same worker with ``_rotary`` forced.
+    Blocks of 128 take the splash kernel, whose queries the rotary kernel
+    scales; blocks of 64 the ``jax.numpy`` attention, which scales its
+    scores.  The toys' 8-wide heads never reach the kernels."""
+    from dopt.models import decoder
+
+    x = np.random.default_rng(7).integers(0, VOCAB, (2, 256)).astype(np.int32)
+    y = np.concatenate([x[:, 1:], np.full((2, 1), -1, np.int32)], 1)
+    w = np.ones(2, np.float32)
+
+    def run(model):
+        def loss(p):
+            return model.loss(p, x, y, w)
+
+        (value, _), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        hidden = jax.vmap(lambda t: model._hidden(params, t)[0])(x)
+        return value, grads, hidden, str(jax.make_jaxpr(
+            jax.grad(lambda p: loss(p)[0]))(params))
+
+    fused = ROPE_WIDTHS[model_type](block)
+    assert fused.rotary_path(256) == "fused(2/2 kinds)"
+    params = fused.init(jax.random.key(2))["params"]
+    got, got_g, got_h, program = run(fused)
+    assert "dopt_attn_dopt_rope_fwd" in program
+    assert "dopt_attn_dopt_rope_bwd" in program
+    monkeypatch.setattr(decoder, "rotary_path", lambda *a, **k: "jnp")
+    plain = ROPE_WIDTHS[model_type](block)
+    assert plain.rotary_path(256) == "jnp"
+    want, want_g, want_h, program = run(plain)
+    assert "dopt_attn_dopt_rope" not in program
+    assert abs(float(got) - float(want)) <= RTOL * float(want)
+    assert close(got_h, want_h)
+    assert all(jax.tree.leaves(jax.tree.map(close, got_g, want_g)))
 
 
 def test_the_correction_bias_chooses_and_does_not_weigh():
@@ -2085,3 +2145,73 @@ def test_latent_attention_kernels_compile_for_the_chip(
             shape(32, 4096, 128)).compile()
     text = compiled.as_text()
     assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+
+
+# name: (heads, head lanes, rope parameters, lanes without rotary); the
+# cells' shapes at 4,096 positions.
+ROTARY_CELLS = {
+    "laguna-full-queries": (48, 128, "full_attention", 0),
+    "laguna-sliding-queries": (64, 128, "sliding_attention", 0),
+    "laguna-keys": (8, 128, "full_attention", 0),
+    "joyai-queries": (32, 192, None, 128),
+}
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("name", [*sorted(ROTARY_CELLS), "joyai-keys"])
+def test_rotary_kernels_compile_for_the_chip(v5e_chip, monkeypatch, name,
+                                             precision):
+    """Forward and backward of both forms of the rotary kernel at the
+    cells' sizes (4,096 positions; laguna's 48 / 64 query heads and 8 key
+    heads of 128, YaRN over half of them or the default over the whole;
+    joyai's 32 heads of 128 + 64 interleaved rotary lanes, and its keys
+    and values of 32 heads from the kv product beside the shared key),
+    two workers under a ``vmap`` as the engines stack them, through the
+    real XLA:TPU + Mosaic compile, also at the parity check's
+    ``highest``.  The compiled custom calls' names carry both scopes they
+    stand for, ``dopt_attn`` and ``dopt_rope``.  Compile only: nothing
+    runs."""
+    from dopt.models.decoder import _tables, rotary_dim
+    from dopt.ops import rotary
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct((2, *dims), jnp.float32,
+                                    sharding=v5e_chip)
+
+    def tables(rope, width):
+        return _tables(rope, width, 4096)
+
+    joyai = {"rope_type": "default", "rope_theta": JOYAI["rope_theta"],
+             "interleave": True}
+    if name == "joyai-keys":
+        cos, sin = tables(joyai, 64)
+
+        def loss(kv, shared):
+            k, v = jax.vmap(lambda kv, r: rotary.latent_keys(
+                kv, r, cos, sin, nope=128, interleave=True,
+                dtype=jnp.bfloat16))(kv, shared)
+            return jnp.sum(k.astype(jnp.float32) ** 2) + jnp.sum(
+                v.astype(jnp.float32) ** 2)
+
+        args = (shape(32, 4096, 256), shape(4096, 64))
+    else:
+        heads, d, kind, nope = ROTARY_CELLS[name]
+        rope = (joyai if kind is None
+                else PUBLISHED["model"]["decoder"]["rope_parameters"][kind])
+        cos, sin = tables(rope, d - nope)
+
+        def loss(x):
+            y = jax.vmap(lambda x: rotary.rotate(
+                x, cos, sin, rotary_dim=rotary_dim(rope, d - nope),
+                interleave=kind is None, scale=d ** -0.5,
+                dtype=jnp.bfloat16, nope=nope))(x)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        args = (shape(heads, 4096, d),)
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(
+            *args).compile().as_text()
+    assert "dopt_attn_dopt_rope_fwd" in text
+    assert "dopt_attn_dopt_rope_bwd" in text
