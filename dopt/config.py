@@ -57,20 +57,30 @@ class DataConfig:
 # What each published ``config.json`` brings beyond the keys every decoder
 # has, by ``model_type``: (keys it must give, keys it may give: stated by
 # the source and held to one value or not read).  A key of one type alone
-# is refused under the other.
+# is refused under the other.  Every type but ``smallthinker`` states its
+# dense and expert widths and its experts a token as ``intermediate_size``,
+# ``moe_intermediate_size`` and ``num_experts_per_tok`` (SmallThinker's
+# are ``moe_ffn_hidden_size`` and ``moe_num_active_primary_experts``, and
+# it has no dense layer).
 _DECODER_KEYS = {
-    "laguna": (("num_experts", "num_attention_heads_per_layer",
+    "laguna": (("intermediate_size", "moe_intermediate_size",
+                "num_experts_per_tok",
+                "num_experts", "num_attention_heads_per_layer",
                 "layer_types", "mlp_layer_types", "sliding_window",
                 "rope_parameters", "shared_expert_intermediate_size",
                 "moe_routed_scaling_factor", "gating"),
                ("moe_apply_router_weight_on_input",
                 "partial_rotary_factor")),
-    "KeyeVL2": (("num_experts", "sa_config", "norm_topk_prob",
+    "KeyeVL2": (("intermediate_size", "moe_intermediate_size",
+                 "num_experts_per_tok",
+                 "num_experts", "sa_config", "norm_topk_prob",
                  "decoder_sparse_step", "rope_theta", "rope_scaling"),
                 ("mlp_only_layers", "hidden_act", "max_window_layers",
                  "num_local_experts", "use_sliding_window",
                  "sliding_window")),
-    "joyai_llm_flash": (("n_routed_experts", "n_shared_experts",
+    "joyai_llm_flash": (("intermediate_size", "moe_intermediate_size",
+                         "num_experts_per_tok",
+                         "n_routed_experts", "n_shared_experts",
                          "routed_scaling_factor",
                          "norm_topk_prob", "scoring_func", "topk_method",
                          "n_group", "topk_group", "first_k_dense_replace",
@@ -79,6 +89,12 @@ _DECODER_KEYS = {
                          "rope_interleave", "num_nextn_predict_layers"),
                         ("hidden_act", "rope_scaling", "qk_head_dim",
                          "moe_layer_freq", "ep_size")),
+    "smallthinker": (("moe_num_primary_experts",
+                      "moe_num_active_primary_experts", "moe_ffn_hidden_size",
+                      "moe_primary_router_apply_softmax", "norm_topk_prob",
+                      "rope_layout", "sliding_window_layout",
+                      "sliding_window_size", "rope_theta"),
+                     ("model_name", "rope_scaling")),
 }
 _SA_KEYS = {"indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
             "kv_chunk_size", "q_chunk_size", "topk"}
@@ -89,7 +105,7 @@ class DecoderConfig:
     """A mixture-of-experts decoder (``dopt.models.decoder``) under the
     keys of its published ``config.json``; a key the file has and this
     class lacks is refused, not ignored, and so is a key of the other
-    ``model_type``.  Three are known, and the type alone says which layer
+    ``model_type``.  Four are known, and the type alone says which layer
     is built:
 
     ``laguna``: gated window/full attention with query heads, attention
@@ -120,6 +136,20 @@ class DecoderConfig:
     ``ep_size``, ``moe_layer_freq`` (1) and ``qk_head_dim`` (the sum) are
     stated and not read.
 
+    ``smallthinker`` (the published file carries ``model_name`` and no
+    ``model_type``; this is the repo's name for it): layer i's attention
+    is banded by ``sliding_window_size`` where ``sliding_window_layout[i]``
+    is 1 and full where it is 0, and turns q and k by rotary where
+    ``rope_layout[i]`` is 1 and not at all (NoPE) where it is 0 (both
+    lists over the PUBLISHED depth); no output gate; every layer routes to
+    ``moe_num_active_primary_experts`` of ``moe_num_primary_experts``
+    experts of ``moe_ffn_hidden_size`` by a softmax over the router's
+    logits, renormalised over the chosen (``norm_topk_prob``), the router
+    reading the layer's input BEFORE the attention; ReGLU experts
+    (``relu`` gate) and no shared one.  ``intermediate_size``,
+    ``num_experts_per_tok`` and ``moe_intermediate_size`` are the other
+    types' keys (``expert_width``, ``experts_per_tok`` read either).
+
     A worker builds layers ``0 .. num_hidden_layers-1``, so a cut in
     depth changes one number.  ``experts_held`` / ``expert_offset`` say
     which of the ``num_experts`` published experts of a layer this
@@ -131,12 +161,12 @@ class DecoderConfig:
     ``ModelConfig.input_shape[0]``."""
 
     hidden_size: int
-    intermediate_size: int
+    intermediate_size: int | None = field(default=None, kw_only=True)
     num_hidden_layers: int
     num_key_value_heads: int
     head_dim: int
-    num_experts_per_tok: int
-    moe_intermediate_size: int
+    num_experts_per_tok: int | None = field(default=None, kw_only=True)
+    moe_intermediate_size: int | None = field(default=None, kw_only=True)
     model_type: str = "laguna"
     num_experts: int | None = None        # n_routed_experts for joyai
     rms_norm_eps: float = 1e-6
@@ -186,6 +216,15 @@ class DecoderConfig:
     qk_head_dim: int | None = None
     moe_layer_freq: int | None = None
     ep_size: int | None = None
+    # model_type smallthinker:
+    moe_num_primary_experts: int | None = None
+    moe_num_active_primary_experts: int | None = None
+    moe_ffn_hidden_size: int | None = None
+    moe_primary_router_apply_softmax: bool | None = None
+    rope_layout: tuple[int, ...] | None = None            # 1: rotary, 0: none
+    sliding_window_layout: tuple[int, ...] | None = None  # 1: band, 0: full
+    sliding_window_size: int | None = None
+    model_name: str | None = None
     # Stated by the source and not read by the model (they describe the
     # whole checkpoint, or repeat another key):
     vocab_size: int | None = None
@@ -225,8 +264,10 @@ class DecoderConfig:
             self._check_laguna(n)
         elif self.model_type == "KeyeVL2":
             self._check_keye()
-        else:
+        elif self.latent:
             self._check_joyai()
+        else:
+            self._check_smallthinker(n)
         if any(self.query_heads(i) % self.num_key_value_heads
                for i in range(n)):
             raise ValueError(
@@ -333,11 +374,80 @@ class DecoderConfig:
                 "qk_rope_head_dim must be even, norm_topk_prob true and "
                 "hidden_act 'silu'")
 
+    def _check_smallthinker(self, n: int) -> None:
+        if self.num_attention_heads is None:
+            raise ValueError("decoder.num_attention_heads is required for "
+                             "model_type 'smallthinker'")
+        for name in ("rope_layout", "sliding_window_layout"):
+            per_layer = tuple(getattr(self, name))
+            object.__setattr__(self, name, per_layer)
+            if len(per_layer) < n:
+                raise ValueError(
+                    f"decoder.{name} lists {len(per_layer)} layers, "
+                    f"num_hidden_layers is {n}")
+            if not set(per_layer) <= {0, 1}:
+                raise ValueError(f"decoder.{name} holds 0 or 1 a layer")
+        if not self.moe_primary_router_apply_softmax or not self.norm_topk_prob:
+            raise ValueError(
+                "a smallthinker router takes a softmax over its experts and "
+                "renormalises the top-k: moe_primary_router_apply_softmax "
+                "and norm_topk_prob must be true")
+        if self.rope_scaling is not None:
+            raise ValueError("a smallthinker layer's rotary is unscaled: "
+                             "rope_scaling must be null")
+        if self.sliding_window_size < 1:
+            raise ValueError("sliding_window_size must be positive")
+
     # What layer i is, whichever model_type's keys say it.
     @property
     def experts(self) -> int:
         """The routed experts a layer publishes (the router's width)."""
+        if self.smallthinker:
+            return self.moe_num_primary_experts
         return self.n_routed_experts if self.latent else self.num_experts
+
+    @property
+    def experts_per_tok(self) -> int:
+        """The experts the router sends a token to."""
+        return (self.moe_num_active_primary_experts if self.smallthinker
+                else self.num_experts_per_tok)
+
+    @property
+    def expert_width(self) -> int:
+        """A routed expert's width."""
+        return (self.moe_ffn_hidden_size if self.smallthinker
+                else self.moe_intermediate_size)
+
+    @property
+    def smallthinker(self) -> bool:
+        """The ``smallthinker`` layer: NoPE full and rotary banded
+        attention by layer with no output gate, a softmax router that
+        reads the layer's input BEFORE the attention, ReGLU experts and
+        no shared one."""
+        return self.model_type == "smallthinker"
+
+    @property
+    def softmax_router(self) -> bool:
+        """Whether the router scores by a softmax over the published
+        experts (else by a sigmoid each)."""
+        return self.indexed or self.smallthinker
+
+    @property
+    def router_first(self) -> bool:
+        """Whether the router reads the attention's normed input (before
+        the attention) instead of the MLP's (after it)."""
+        return self.smallthinker
+
+    @property
+    def output_gate(self) -> bool:
+        """Whether the attention's output is gated, one sigmoid a head."""
+        return self.model_type == "laguna"
+
+    @property
+    def expert_act(self) -> str:
+        """The routed experts' gate activation: ``silu`` (SwiGLU) or
+        ``relu`` (ReGLU)."""
+        return "relu" if self.smallthinker else "silu"
 
     @property
     def latent(self) -> bool:
@@ -361,7 +471,7 @@ class DecoderConfig:
     @property
     def shared_width(self) -> int | None:
         """The shared expert's width, None where a layer has none."""
-        if self.indexed:
+        if self.indexed or self.smallthinker:
             return None
         if self.latent:
             return self.n_shared_experts * self.moe_intermediate_size or None
@@ -375,19 +485,26 @@ class DecoderConfig:
         return self.model_type == "KeyeVL2"
 
     def query_heads(self, i: int) -> int:
-        return (self.num_attention_heads if self.indexed or self.latent
-                else self.num_attention_heads_per_layer[i])
+        return (self.num_attention_heads_per_layer[i]
+                if self.model_type == "laguna" else self.num_attention_heads)
 
     def window(self, i: int) -> int | None:
         """Positions back a query sees through a band; None = no band."""
+        if self.smallthinker:
+            return (self.sliding_window_size if self.sliding_window_layout[i]
+                    else None)
         if (self.indexed or self.latent
                 or self.layer_types[i] != "sliding_attention"):
             return None
         return self.sliding_window
 
-    def rope(self, i: int) -> Mapping[str, Any]:
+    def rope(self, i: int) -> Mapping[str, Any] | None:
         """Layer i's ``rope_parameters`` entry (the latent attention's
-        interleaves its pairs)."""
+        interleaves its pairs); None where the layer has no rotary (a
+        smallthinker layer whose ``rope_layout`` entry is 0: NoPE)."""
+        if self.smallthinker:
+            return ({"rope_type": "default", "rope_theta": self.rope_theta}
+                    if self.rope_layout[i] else None)
         if self.latent:
             return {"rope_type": "default", "rope_theta": self.rope_theta,
                     "interleave": bool(self.rope_interleave)}
@@ -398,6 +515,8 @@ class DecoderConfig:
     def sparse_mlp(self, i: int) -> bool:
         """Whether layer i has experts (i = ``num_hidden_layers`` of the
         published depth is a multi-token-prediction module's)."""
+        if self.smallthinker:
+            return True
         if self.latent:
             return i >= self.first_k_dense_replace
         if self.indexed:

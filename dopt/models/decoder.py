@@ -1,18 +1,21 @@
 """A mixture-of-experts decoder as ONE worker of the gossip engine
 (``model="decoder"``, configured by ``dopt.config.DecoderConfig`` under
 the keys of the published ``config.json``, whose ``model_type`` says
-which layer is built): token ids in, next-token loss out.  Three layers
+which layer is built): token ids in, next-token loss out.  Four layers
 are known: ``laguna``'s gated window/full attention beside
 sigmoid-routed experts and a shared one, ``KeyeVL2``'s learned sparse
 attention (a lightning indexer picks the ``topk`` keys a query attends
-and an alignment term trains it) beside softmax-routed experts, and
+and an alignment term trains it) beside softmax-routed experts,
 ``joyai_llm_flash``'s multi-head latent attention beside sigmoid-routed
 experts chosen by score plus a correction bias and a shared one, with a
-multi-token-prediction module in the loss.
+multi-token-prediction module in the loss, and ``smallthinker``'s full
+attention without rotary (NoPE) or banded attention with it, by layer,
+beside ReGLU experts whose softmax router reads the layer's input before
+the attention.
 
 Not a flax module: the parameters are a plain dict tree (``embed``,
 ``layer<i>`` with ``attn_norm q k v o mlp_norm``, ``gate`` or ``q_norm
-k_norm indexer`` -- or, latent, ``attn_norm q_a q_a_norm q_b kv_a
+k_norm indexer`` or neither -- or, latent, ``attn_norm q_a q_a_norm q_b kv_a
 kv_a_norm kv_b o mlp_norm`` --, and ``mlp`` or ``router experts`` with
 or without ``router_bias`` and ``shared``; ``mtp`` with ``enorm hnorm
 eh_proj layer norm`` where the model has the module; ``norm``,
@@ -22,9 +25,9 @@ weights)``.  The worker axis is the engines' ``vmap`` over the stacked
 fleet state.
 
 What a layer computes is written out in the docstrings of
-``benchmark/reference_models/laguna_xs2.py``, ``keye_vl2.py`` and
-``joyai_llm_flash.py``; this file is the same mathematics arranged for
-the chip:
+``benchmark/reference_models/laguna_xs2.py``, ``keye_vl2.py``,
+``joyai_llm_flash.py`` and ``smallthinker_21b.py``; this file is the
+same mathematics arranged for the chip:
 
 * matmul inputs in the compute dtype (bfloat16 on the chip) with float32
   accumulation; residual stream, norms, router scores, rotary,
@@ -104,14 +107,19 @@ output; the held experts' kernels go by their own names,
 ``dopt_moe_experts_fwd`` / ``_dx`` / ``_dw``) with ``dopt_route`` inside
 it (scores, top-k, combine weights, and the dispatch: where each routed
 slot sits among the sorted slots and the combine weights' gradient, not
-the expert matmuls) and ``dopt_shared`` (the shared expert's MLP);
+the expert matmuls; where the router reads the layer's input, its
+scores, softmax, top-k, combine weights and hits run before the
+attention, under ``dopt_preroute`` inside ``dopt_route``, and the slots'
+layout stays with the experts' call) and ``dopt_shared`` (the shared
+expert's MLP);
 ``dopt_mlp`` (a dense layer's MLP, without its norm and residual add);
 ``dopt_head`` (final norm, logits, loss); ``dopt_mtp`` (the
 multi-token-prediction module: its norms and projection, its layer, whose
 scopes nest inside, and its pass through the head under ``dopt_head``).
 ``loss`` also returns the step's counts (``model.counters``: routing,
-the indexer's two, the module's loss and accuracy), which the gossip
-engine averages into each round's history row.
+the indexer's two, the module's loss and accuracy, ReGLU's share of
+zeros), which the gossip engine averages into each round's history
+row.
 """
 
 from __future__ import annotations
@@ -174,6 +182,10 @@ INDEX_COUNTERS = ("index_align_loss", "index_keys_kept_share")
 # A model with a multi-token-prediction module: its cross-entropy against
 # the token after next and its accuracy there.
 MTP_COUNTERS = ("mtp_loss", "mtp_acc")
+# A model with ReGLU experts: the share of relu(x W_gate) entries that are
+# 0 over the routed slots (mean over layers): how sparse the experts'
+# activations are.
+ACT_COUNTERS = ("moe_act_zero_share",)
 # lambda of ``loss = CE_main + lambda * CE_mtp``: DeepSeek-V3's value for its
 # first 10 T tokens (arXiv:2412.19437 section 4.2); no config carries one.
 MTP_LOSS_WEIGHT = 0.3
@@ -696,7 +708,8 @@ class GatedMoEDecoder:
         self.experts_held = (cfg.experts if cfg.experts_held is None
                              else cfg.experts_held)
         self.counters = (COUNTERS + (INDEX_COUNTERS if cfg.indexed else ())
-                         + (MTP_COUNTERS if cfg.mtp else ()))
+                         + (MTP_COUNTERS if cfg.mtp else ())
+                         + (ACT_COUNTERS if cfg.expert_act == "relu" else ()))
         self._bodies = {}
         self.kind_traces = collections.Counter()
 
@@ -718,15 +731,18 @@ class GatedMoEDecoder:
         """``"fused(k/n kinds)"`` where k of the n layer kinds turn their
         queries and keys with the rotary kernels at rows of ``t``
         positions, ``"jnp"`` where none does (``dopt.run`` prints it
-        beside the device; ``rotary_path`` is the rule)."""
+        beside the device; ``rotary_path`` is the rule; a kind without
+        rotary counts among the n)."""
         c = self.cfg
         nope = c.qk_nope_head_dim if c.latent else 0
         d = nope + c.head_dim
         paths = [rotary_path(t, d, rotary_dim(c.rope(i[0]), d - nope),
                              nope=nope, normed=c.indexed)
-                 for i in self.layer_kinds().values()]
+                 for i in self.layer_kinds().values()
+                 if c.rope(i[0]) is not None]
         fused = paths.count("fused")
-        return f"fused({fused}/{len(paths)} kinds)" if fused else "jnp"
+        kinds = len(self.layer_kinds())
+        return f"fused({fused}/{kinds} kinds)" if fused else "jnp"
 
     @property
     def mtp_layer(self) -> int:
@@ -776,7 +792,7 @@ class GatedMoEDecoder:
             else:
                 layer = {"attn_norm": jnp.ones(d), "q": mat(d, h * hd),
                          "k": mat(d, kv * hd), "v": mat(d, kv * hd)}
-            if not (c.indexed or c.latent):
+            if c.output_gate:
                 layer["gate"] = mat(d, h)
             if not c.latent:
                 layer.update(o=mat(h * hd, d), mlp_norm=jnp.ones(d))
@@ -796,8 +812,7 @@ class GatedMoEDecoder:
                     layer["router_bias"] = jnp.zeros(c.experts)
                 if c.shared_width:
                     layer["shared"] = mlp(c.shared_width)
-                layer["experts"] = mlp(c.moe_intermediate_size,
-                                       self.experts_held)
+                layer["experts"] = mlp(c.expert_width, self.experts_held)
             if i == self.mtp_layer:
                 params["mtp"] = {"enorm": jnp.ones(d), "hnorm": jnp.ones(d),
                                  "eh_proj": mat(2 * d, d), "layer": layer,
@@ -891,16 +906,18 @@ class GatedMoEDecoder:
                               p["o"].astype(dt).reshape(heads, vd, -1),
                               preferred_element_type=jnp.float32)
 
-    def _attention(self, p, h, i):
+    def _attention(self, p, h, i, a=None):
         """-> (the residual stream after the layer's attention, None or
-        the indexer's counts)."""
+        the indexer's counts); ``a`` the layer input's norm where the
+        caller has taken it."""
         c, dt = self.cfg, self.dtype
         t = h.shape[0]
         hd, kv = c.head_dim, c.num_key_value_heads
         heads = c.query_heads(i)
         rope = c.rope(i)
         with jax.named_scope("dopt_attn"):
-            a = _rms(h, p["attn_norm"], c.rms_norm_eps)
+            if a is None:
+                a = _rms(h, p["attn_norm"], c.rms_norm_eps)
             x = a.astype(dt)
 
             def heads_of(name, n):
@@ -946,30 +963,39 @@ class GatedMoEDecoder:
                     project=project)
                 counts = dict(zip(INDEX_COUNTERS, (align, kept)))
             else:
-                fused = rotary_path(t, hd, rotary_dim(rope, hd)) == "fused"
+                # (a layer without rotary, NoPE: q and k as projected; the
+                # queries' scale and cast are then causal_attention's)
+                fused = (rope is not None and rotary_path(
+                    t, hd, rotary_dim(rope, hd)) == "fused")
                 with jax.named_scope("dopt_qkv"):
                     q = heads_of("q", heads)
                 with jax.named_scope("dopt_rope"):
-                    q = (_rope(q, rope, dt, scale=query_scale(
-                        t, hd, self.attn_block)) if fused
-                         else _rotary(q, rope))
+                    if fused:
+                        q = _rope(q, rope, dt, scale=query_scale(
+                            t, hd, self.attn_block))
+                    elif rope is not None:
+                        q = _rotary(q, rope)
                 with jax.named_scope("dopt_qkv"):
                     k = heads_of("k", kv)
                 with jax.named_scope("dopt_rope"):
-                    k = (_rope(k, rope, dt) if fused
-                         else _keep(_rotary(k, rope).astype(dt)))
+                    k = (_rope(k, rope, dt) if fused else _keep(
+                        (k if rope is None else _rotary(k, rope)).astype(dt)))
                 with jax.named_scope("dopt_qkv"):
                     v = _keep(heads_of("v", kv).astype(dt))
                 out = causal_attention(q, k, v, window=c.window(i),
                                        block=self.attn_block, scaled=fused)
-                with jax.named_scope("dopt_qkv"):
-                    gate = _keep(jnp.einsum(
-                        "td,dn->nt", x, p["gate"].astype(dt),
-                        preferred_element_type=jnp.float32))
+                if c.output_gate:
+                    with jax.named_scope("dopt_qkv"):
+                        gate = _keep(jnp.einsum(
+                            "td,dn->nt", x, p["gate"].astype(dt),
+                            preferred_element_type=jnp.float32))
                 with jax.named_scope("dopt_oproj"):
-                    gate = jax.nn.sigmoid(gate)
-                    out = (out.reshape(heads, t, hd)
-                           * gate[..., None].astype(dt))
+                    if c.output_gate:
+                        gate = jax.nn.sigmoid(gate)
+                        out = (out.reshape(heads, t, hd)
+                               * gate[..., None].astype(dt))
+                    else:
+                        out = out.reshape(heads, t, hd)
                     out = jnp.einsum(
                         "nte,ned->td", out,
                         p["o"].astype(dt).reshape(heads, hd, -1),
@@ -991,18 +1017,18 @@ class GatedMoEDecoder:
         scores = _keep(jnp.dot(m, router,
                                precision=jax.lax.Precision.HIGHEST))
         # sigmoid scores, or a softmax over the published experts
-        scores = (jax.nn.softmax(scores, axis=-1) if c.indexed
+        scores = (jax.nn.softmax(scores, axis=-1) if c.softmax_router
                   else jax.nn.sigmoid(scores))
         choose = scores if bias is None else scores + bias
         idx = _keep(jax.lax.top_k(jax.lax.stop_gradient(choose),
-                                  c.num_experts_per_tok)[1])
+                                  c.experts_per_tok)[1])
         # (a masked sum of one term: a gather of single elements, and the
         # scatter-add that is its gradient, cost this chip 6 ns each)
         top = jnp.sum(jnp.where(
             idx[..., None] == jnp.arange(scores.shape[-1]),
             scores[:, None, :], 0.0), axis=-1)
         top = top / jnp.sum(top, -1, keepdims=True)       # renormalised
-        if not c.indexed:
+        if c.routed_scale is not None:
             top = top * c.routed_scale
         # [T, k, held]: slot j of token t reached held expert e
         hit = ((idx - c.expert_offset)[..., None]
@@ -1020,30 +1046,53 @@ class GatedMoEDecoder:
         """``"grouped-fused"`` or ``"grouped"``: which body the held
         experts run (``dopt.run`` prints it beside the device)."""
         return grouped_experts.path(self.cfg.hidden_size,
-                                    self.cfg.moe_intermediate_size)
+                                    self.cfg.expert_width)
 
-    def _experts(self, p, m):
+    def _preroute(self, p, a):
+        """``_route`` of the layer's normed input ``a`` [T, d] float32,
+        before the attention (``router_first``), under ``dopt_preroute``
+        inside ``dopt_moe`` / ``dopt_route``."""
+        with jax.named_scope("dopt_moe"), jax.named_scope("dopt_route"):
+            with jax.named_scope("dopt_preroute"):
+                return self._route(p["router"], a, p.get("router_bias"))
+
+    def _experts(self, p, m, routed=None):
+        """-> (the layer's expert part of the MLP's normed input m, the
+        routing counts); ``routed`` is ``_route``'s result where the
+        router ran before the attention, else the router reads m."""
         dt = self.dtype
         with jax.named_scope("dopt_moe"):
-            with jax.named_scope("dopt_route"):
-                weight, hit, counts = self._route(p["router"], m,
-                                                  p.get("router_bias"))
+            if routed is None:
+                with jax.named_scope("dopt_route"):
+                    routed = self._route(p["router"], m, p.get("router_bias"))
+            weight, hit, counts = routed
             if "shared" in p:
                 with jax.named_scope("dopt_shared"):
                     out = _gated_mlp(p["shared"], m.astype(dt), dt
                                      ).astype(jnp.float32)
             else:
                 out = jnp.zeros_like(m)
-            return grouped_experts.grouped_experts(
+            out, zeros = grouped_experts.grouped_experts(
                 m, hit, weight, p["experts"], out,
-                k=self.cfg.num_experts_per_tok, dtype=dt,
-                keep_name=MATMUL_PRODUCTS), counts
+                k=self.cfg.experts_per_tok, dtype=dt,
+                keep_name=MATMUL_PRODUCTS, act=self.cfg.expert_act)
+            if zeros is not None:
+                counts = {**counts, ACT_COUNTERS[0]: zeros}
+            return out, counts
 
     def _layer(self, p, h, i):
         """-> (the residual stream after layer i, its counts: the
-        indexer's and the router's, {} where it has neither)."""
+        indexer's and the router's, {} where it has neither).  Where the
+        router reads the layer's input (``router_first``) it runs on the
+        attention's normed input before the attention, and the experts
+        on the MLP's norm of the stream after it."""
         c = self.cfg
-        h, counts = self._attention(p, h, i)
+        routed = a = None
+        if c.router_first:
+            with jax.named_scope("dopt_attn"):
+                a = _rms(h, p["attn_norm"], c.rms_norm_eps)
+            routed = self._preroute(p, a)
+        h, counts = self._attention(p, h, i, a)
         counts = counts or {}
         m = _rms(h, p["mlp_norm"], c.rms_norm_eps)
         if not c.sparse_mlp(i):
@@ -1051,8 +1100,8 @@ class GatedMoEDecoder:
                 out = _gated_mlp(p["mlp"], m.astype(self.dtype), self.dtype
                                  ).astype(jnp.float32)
             return h + out, counts
-        out, routed = self._experts(p, m)
-        return h + out, {**counts, **routed}
+        out, routing = self._experts(p, m, routed)
+        return h + out, {**counts, **routing}
 
     def _kind(self, i: int) -> tuple:
         """What ``_layer`` reads of ``i``: query heads, rotary, window,

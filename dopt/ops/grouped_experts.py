@@ -23,7 +23,7 @@ Two bodies, one arrangement (``path`` says which, from shapes alone):
   expert's width at a time (the float32 leaves are rounded in VMEM too:
   a cast outside would be a pass over 100 MB of its own), writes the two
   products (what a layer's ``jax.checkpoint`` keeps), and adds
-  ``silu(g) * u @ down`` times the slot's combine weight back onto the
+  ``act(g) * u @ down`` times the slot's combine weight back onto the
   tokens' rows of the float32 output (rows read, added to and written
   back by DMA: a token is in a tile at most once, and tiles run one
   after another).  ``..._dx`` does the same walk for the gradients of
@@ -45,9 +45,12 @@ Matmul inputs in the compute dtype with float32 accumulation, pinned to
 ``DEFAULT`` precision (Mosaic refuses bfloat16 operands at the
 ``highest`` a parity check sets around the program, PERF.md, PR 28); a
 slot's output is weighted and added to its token in float32; the
-experts' gradients leave in the compute dtype, as the cotangent of a
-leaf cast to it does.  The kernels' names spell out the scope they stand
-in, ``dopt_moe``: a trace's readers find a scope as a substring of an
+experts' gradients are summed in float32, leave the kernel in the
+compute dtype, as the cotangent of a leaf cast to it does, and come back
+in the parameters' dtype.  ``act`` (static: SwiGLU's ``silu``, ReGLU's
+``relu``) is the gate's activation in both bodies and all three
+kernels.  The kernels' names spell out the scope they stand in,
+``dopt_moe``: a trace's readers find a scope as a substring of an
 instruction's name and name stack, and a custom call may carry no stack.
 Compiled on ``tpu``, interpreted on ``cpu`` (``dopt.ops.pallas_interpret``).
 """
@@ -77,18 +80,30 @@ _LANES = 128
 # the 16 MiB of VMEM a kernel gets beside a tile's rows.
 WIDTH_SLICE = _LANES
 _NT = (((1,), (1,)), ((), ()))     # a @ b.T
+# The experts' gate activations: SwiGLU's ``silu`` and ReGLU's ``relu``
+# (whose derivative is the step ``g > 0``, 0 at 0, as ``jax.nn.relu``'s).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def path(hidden: int, width: int) -> str:
     """Which body the held experts run: ``"grouped-fused"``, the Pallas
     kernels, wherever their shape limits allow it -- a token's float32 row
-    whole (8, 128) tiles, so that it can be copied alone, and the expert's
-    width whole lanes -- else ``"grouped"``,
-    the same sorted slots in ``jax.numpy``.  Nothing else decides it: no
-    option, no platform (the kernels are interpreted on the CPU).  No
-    shape keeps the dense dispatch: it is the tests' definition only."""
-    fits = hidden % (8 * _LANES) == 0 and width % _LANES == 0
+    whole lanes, so that as [hidden / 128, 128] it is a run of (8, 128)
+    tiles that can be copied alone (the last one padded where hidden /
+    128 is no multiple of 8: SmallThinker's 2,560 is 20 rows of lanes),
+    and the expert's width whole lanes -- else ``"grouped"``, the same
+    sorted slots in ``jax.numpy``.  Nothing else decides it: no option, no
+    platform (the kernels are interpreted on the CPU).  No shape keeps the
+    dense dispatch: it is the tests' definition only."""
+    fits = hidden % _LANES == 0 and width % _LANES == 0
     return "grouped-fused" if fits else "grouped"
+
+
+def _glu(act, g, u):
+    """``act(g) * u`` in float32, as the kernels compute it."""
+    if act == "silu":
+        return g * jax.nn.sigmoid(g) * u
+    return jnp.maximum(g, 0.0) * u
 
 
 def _interpret() -> bool:
@@ -287,7 +302,7 @@ def _row(column):
 
 def _fwd_kernel(group_ref, rows_ref, tok_ref, x_hbm, wg_ref, wu_ref, wd_ref,
                 scale_ref, init_hbm, g_ref, u_ref, out_hbm, xbuf, xin, acc,
-                sem):
+                sem, *, act):
     del group_ref, init_hbm           # (the index maps'; aliased to out_hbm)
     i, j = pl.program_id(0), pl.program_id(1)
     n = rows_ref[i]
@@ -303,7 +318,7 @@ def _fwd_kernel(group_ref, rows_ref, tok_ref, x_hbm, wg_ref, wu_ref, wd_ref,
     g_ref[...] = g
     u_ref[...] = u
     g, u = g.astype(jnp.float32), u.astype(jnp.float32)
-    mid = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+    mid = _glu(act, g, u).astype(x.dtype)
     acc[...] += _dot(mid, wd_ref[...].astype(x.dtype))
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -314,7 +329,7 @@ def _fwd_kernel(group_ref, rows_ref, tok_ref, x_hbm, wg_ref, wu_ref, wd_ref,
 
 def _dx_kernel(group_ref, rows_ref, tok_ref, dout_hbm, g_ref, u_ref, wg_ref,
                wu_ref, wd_ref, scale_ref, zero_hbm, dg_ref, du_ref,
-               dscale_ref, dx_hbm, dbuf, din, acc, sem):
+               dscale_ref, dx_hbm, dbuf, din, acc, sem, *, act):
     del group_ref, zero_hbm
     i, j = pl.program_id(0), pl.program_id(1)
     n = rows_ref[i]
@@ -326,14 +341,22 @@ def _dx_kernel(group_ref, rows_ref, tok_ref, dout_hbm, g_ref, u_ref, wg_ref,
         dscale_ref[...] = jnp.zeros_like(dscale_ref)
 
     g, u = g_ref[...].astype(jnp.float32), u_ref[...].astype(jnp.float32)
-    gate = jax.nn.sigmoid(g)
-    mid = (g * gate * u).astype(din.dtype).astype(jnp.float32)
+    if act == "silu":
+        gate = jax.nn.sigmoid(g)
+        mid = g * gate * u
+    else:
+        mid = jnp.maximum(g, 0.0) * u
+    mid = mid.astype(din.dtype).astype(jnp.float32)
     dt = din.dtype
     dmid = _dot(din[...], wd_ref[...].astype(dt), _NT)   # [tile, slice]
     dscale_ref[...] += _row(jnp.sum(dmid * mid, axis=1, keepdims=True))
     dmid = dmid * _column(scale_ref[...])
-    dg = (dmid * u * gate * (1.0 + g * (1.0 - gate))).astype(dg_ref.dtype)
-    du = (dmid * g * gate).astype(du_ref.dtype)
+    if act == "silu":
+        dg = (dmid * u * gate * (1.0 + g * (1.0 - gate))).astype(dg_ref.dtype)
+        du = (dmid * g * gate).astype(du_ref.dtype)
+    else:
+        dg = jnp.where(g > 0.0, dmid * u, 0.0).astype(dg_ref.dtype)
+        du = (dmid * jnp.maximum(g, 0.0)).astype(du_ref.dtype)
     dg_ref[...] = dg
     du_ref[...] = du
     acc[...] += (_dot(dg, wg_ref[...].astype(dt), _NT)
@@ -346,7 +369,7 @@ def _dx_kernel(group_ref, rows_ref, tok_ref, dout_hbm, g_ref, u_ref, wg_ref,
 
 def _dw_kernel(group_ref, rows_ref, tok_ref, x_hbm, dout_hbm, g_ref, u_ref,
                dg_ref, du_ref, scale_ref, dwg_ref, dwu_ref, dwd_ref, buf,
-               xin, din, dwg, dwu, dwd, sem):
+               xin, din, dwg, dwu, dwd, sem, *, act):
     i = pl.program_id(0)
     n = rows_ref[i]
     last = group_ref.shape[0] - 1
@@ -370,7 +393,7 @@ def _dw_kernel(group_ref, rows_ref, tok_ref, x_hbm, dout_hbm, g_ref, u_ref,
         dwg[...] += _dot(xt, dg_ref[...])
         dwu[...] += _dot(xt, du_ref[...])
         g, u = g_ref[...].astype(jnp.float32), u_ref[...].astype(jnp.float32)
-        mid = (g * jax.nn.sigmoid(g) * u).astype(dt).astype(jnp.float32)
+        mid = _glu(act, g, u).astype(dt).astype(jnp.float32)
         mid = (mid * _column(scale_ref[...])).astype(dt)
         dwd[...] += _dot(mid.T, din[...])                    # [width, hidden]
 
@@ -404,18 +427,19 @@ def _tokens(index_map):
     return pl.BlockSpec((None, 1, TILE), index_map, memory_space=pltpu.SMEM)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+@functools.partial(jax.jit, static_argnames=("dtype", "act", "interpret"))
 def _forward(x, wg, wu, wd, init, token, scale, group, rows, used, *,
-             dtype, interpret: bool):
-    """x and init [M, d / 128, 128], wg and wu [G, d, F], wd [G, F, d],
-    all float32 (the leaves are rounded to the compute ``dtype`` in VMEM:
-    a cast outside would be a pass over them of its own) -> (init + the
-    slots' weighted outputs, as init, the slots' gate and up products
-    [P, F] in ``dtype``)."""
+             dtype, act: str, interpret: bool):
+    """x and init [M, d / 128, 128] float32, wg and wu [G, d, F], wd
+    [G, F, d] in the parameters' dtype (the leaves are rounded to the
+    compute ``dtype`` in VMEM: a cast outside would be a pass over them of
+    its own) -> (init + the slots' weighted outputs, as init, the slots'
+    gate and up products [P, F] in ``dtype``); ``act`` the gate's
+    activation (``ACTIVATIONS``)."""
     d, f = wg.shape[1:]
     s, dt = WIDTH_SLICE, dtype
     g, u, out = pl.pallas_call(
-        _fwd_kernel,
+        functools.partial(_fwd_kernel, act=act),
         grid_spec=_grid_spec(
             used, lambda t: (t, f // s),
             [_tokens(lambda i, j, *_: (i, 0, 0)), _ANY, *_leaves(d, s),
@@ -438,9 +462,9 @@ def _forward(x, wg, wu, wd, init, token, scale, group, rows, used, *,
     return out, g, u
 
 
-@functools.partial(jax.jit, static_argnames="interpret")
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
 def _backward_dx(dout, g, u, wg, wu, wd, token, scale, group, rows, used, *,
-                 interpret: bool):
+                 act: str, interpret: bool):
     """-> (dx [M, d / 128, 128] float32, dscale [tiles, 1, tile], dg and
     du [P, F] in g's dtype)."""
     d, f = wg.shape[1:]
@@ -448,7 +472,7 @@ def _backward_dx(dout, g, u, wg, wu, wd, token, scale, group, rows, used, *,
     slots = pl.BlockSpec((TILE, s), lambda i, j, *_: (i, j))
     per_tile = pl.BlockSpec((None, 1, TILE), lambda i, j, *_: (i, 0, 0))
     dg, du, dscale, dx = pl.pallas_call(
-        _dx_kernel,
+        functools.partial(_dx_kernel, act=act),
         grid_spec=_grid_spec(
             used, lambda t: (t, f // s),
             [_tokens(lambda i, j, *_: (i, 0, 0)), _ANY, slots, slots,
@@ -470,9 +494,9 @@ def _backward_dx(dout, g, u, wg, wu, wd, token, scale, group, rows, used, *,
     return dx, dscale, dg, du
 
 
-@functools.partial(jax.jit, static_argnames=("groups", "interpret"))
+@functools.partial(jax.jit, static_argnames=("groups", "act", "interpret"))
 def _backward_dw(x, dout, g, u, dg, du, token, scale, group, rows, used, *,
-                 groups: int, interpret: bool):
+                 groups: int, act: str, interpret: bool):
     """-> (dwg and dwu [G, d, F], dwd [G, F, d]) in g's dtype, the compute
     dtype, as the cotangent of a leaf cast to it is (summed in float32)."""
     d, f = x.shape[1] * x.shape[2], g.shape[1]
@@ -487,7 +511,7 @@ def _backward_dw(x, dout, g, u, dg, du, token, scale, group, rows, used, *,
     per_tile = lambda i, *_: (i, 0, 0)
     mine = lambda i, grp, *_: (grp[i], 0, 0)
     return pl.pallas_call(
-        _dw_kernel,
+        functools.partial(_dw_kernel, act=act),
         grid_spec=_grid_spec(
             used, lambda t: (t,),
             [_tokens(per_tile), _ANY, _ANY, slots, slots, slots, slots,
@@ -572,87 +596,120 @@ def _flat(a):
     return a.reshape(-1, *a.shape[2:])
 
 
+def zero_share(g, rows, worker, workers: int):
+    """[workers] float32: each worker's share of the entries of
+    ``relu(g)`` that are 0 over its real slots.  g [P, F] the gate
+    products of ``tiles`` tiles of ``TILE`` slots, rows [tiles] how many
+    of a tile's slots are real and worker [tiles] whose they are
+    (``slot_layout``: a fleet's tiles in use lie one worker's after
+    another's, packed, so the worker of a tile is read from its group)."""
+    real = jnp.arange(TILE)[None, :] < rows[:, None]             # [tiles, T]
+    zeros = jnp.sum((g.reshape(-1, TILE, g.shape[-1]) <= 0)
+                    & real[..., None], axis=(1, 2))
+    mine = worker[:, None] == jnp.arange(workers)                # [tiles, W]
+
+    def by_worker(count):
+        return jnp.sum(jnp.where(mine, count[:, None], 0),
+                       axis=0).astype(jnp.float32)
+
+    return by_worker(zeros) / jnp.maximum(
+        by_worker(jnp.sum(real, axis=1)) * g.shape[-1], 1.0)
+
+
 # (jitted: the four layers of a round lower ONE trace of it)
-@functools.partial(jax.jit, static_argnames=("k", "dtype"))
-def _fwd_fleet(x, hit, weight, wg, wu, wd, init, *, k, dtype):
+@functools.partial(jax.jit, static_argnames=("k", "dtype", "act"))
+def _fwd_fleet(x, hit, weight, wg, wu, wd, init, *, k, dtype, act):
     """x and init [W, N, d] float32, hit and weight [W, N, E], the
-    experts' float32 leaves [W, E, ...] -> (init + the held experts'
-    part, then what the backward pass takes beside the inputs: the gate
-    and up products and the layout, every array [W, ...])."""
+    experts' leaves [W, E, ...] -> (init + the held experts' part, then
+    what the backward pass takes beside the inputs: the gate and up
+    products and the layout, every array [W, ...]; for ``relu``, last,
+    each worker's ``zero_share``)."""
     w = hit.shape[0]
     with jax.named_scope("dopt_route"):
         pos, *layout = slot_layout(hit, weight, k=k)
     out, g, u = _forward(
         _rows_of(x), _flat(wg), _flat(wu), _flat(wd), _rows_of(init),
-        *layout, dtype=dtype, interpret=_interpret())
+        *layout, dtype=dtype, act=act, interpret=_interpret())
     token, scale, group, rows, used = layout
+    counted = ()
+    if act == "relu":
+        counted = (zero_share(g, rows, group // hit.shape[2], w),)
     return (out.reshape(x.shape), pos, *(
         a.reshape(w, -1, *a.shape[1:])
         for a in (g, u, token, scale, group, rows)),
-        jnp.broadcast_to(used, (w, 1)))
+        jnp.broadcast_to(used, (w, 1)), *counted)
 
 
-def _fwd_shapes(x, hit, weight, wg, wu, wd, init, *, k, dtype):
+def _fwd_shapes(x, hit, weight, wg, wu, wd, init, *, k, dtype, act):
     (w, n, e), tiles = hit.shape, slot_capacity(hit.shape[1], k, hit.shape[2])
     products = ((w, tiles * TILE, wg.shape[-1]), dtype)
+    counted = [((w,), jnp.float32)] if act == "relu" else []
     return [(x.shape, x.dtype), ((w, n, e), jnp.int32), products, products,
             ((w, tiles, 1, TILE), jnp.int32),
             ((w, tiles, 1, TILE), jnp.float32), ((w, tiles), jnp.int32),
-            ((w, tiles), jnp.int32), ((w, 1), jnp.int32)]
+            ((w, tiles), jnp.int32), ((w, 1), jnp.int32), *counted]
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames="act")
 def _bwd_fleet(x, wg, wu, wd, pos, g, u, token, scale, group, rows, used,
-               dout):
-    """-> the cotangents of x [W, N, d], weight [W, N, E] and the
-    experts' leaves [W, E, ...], all float32."""
+               dout, *, act):
+    """-> the cotangents of x [W, N, d] and weight [W, N, E], float32,
+    and of the experts' leaves [W, E, ...] in the leaves' dtype."""
     w, e = wg.shape[:2]
     layout = (*(_flat(a) for a in (token, scale, group, rows)), used[0])
     g, u = _flat(g), _flat(u)
     dx, dscale, dg, du = _backward_dx(
         _rows_of(dout), g, u, _flat(wg), _flat(wu), _flat(wd), *layout,
-        interpret=_interpret())
+        act=act, interpret=_interpret())
     dwg, dwu, dwd = _backward_dw(
         _rows_of(x), _rows_of(dout), g, u, dg, du, *layout, groups=w * e,
-        interpret=_interpret())
+        act=act, interpret=_interpret())
     with jax.named_scope("dopt_route"):
         dweight = slot_values(dscale, pos)
     return (dx.reshape(x.shape), dweight, *(
-        a.reshape(w, e, *a.shape[1:]).astype(jnp.float32)
-        for a in (dwg, dwu, dwd)))
+        a.reshape(w, e, *a.shape[1:]).astype(leaf.dtype)
+        for a, leaf in ((dwg, wg), (dwu, wu), (dwd, wd))))
 
 
 def _bwd_shapes(x, wg, wu, wd, pos, *_):
     return [(x.shape, jnp.float32), (pos.shape, jnp.float32),
-            *((a.shape, jnp.float32) for a in (wg, wu, wd))]
+            *((a.shape, a.dtype) for a in (wg, wu, wd))]
 
 
 @functools.lru_cache(maxsize=None)
-def _fused(k: int, dtype, keep_name):
-    """(x, hit, weight, wg, wu, wd, init) -> init + the held experts'
+def _fused(k: int, dtype, keep_name, act: str = "silu"):
+    """(x, hit, weight, wg, wu, wd, init) -> (init + the held experts'
     part, over arrays that carry their worker axis (``_fwd_fleet``),
-    differentiable in all but ``hit``; the gate and up products and the
-    layout's small arrays under ``keep_name`` for a ``jax.checkpoint`` to
-    keep, so that its backward pass runs neither the forward kernel nor
-    the layout again."""
-    config = dict(k=k, dtype=dtype)
+    differentiable in all but ``hit``; for ``relu`` each worker's
+    ``zero_share``, else None); the gate and up products and the layout's
+    small arrays under ``keep_name`` for a ``jax.checkpoint`` to keep, so
+    that its backward pass runs neither the forward kernel nor the layout
+    again."""
+    config = dict(k=k, dtype=dtype, act=act)
+    counted = act == "relu"
     fwd = _fleet("dopt_experts_fwd", functools.partial(_fwd_fleet, **config),
-                 "tttwwwt", "ttwwwwwww",
+                 "tttwwwt", "ttwwwwwww" + "w" * counted,
                  functools.partial(_fwd_shapes, **config))
-    bwd = _fleet("dopt_experts_bwd", _bwd_fleet, "twwwtwwwwwwwt", "ttwww",
-                 _bwd_shapes)
+    bwd = _fleet("dopt_experts_bwd", functools.partial(_bwd_fleet, act=act),
+                 "twwwtwwwwwwwt", "ttwww", _bwd_shapes)
+
+    def run(x, hit, weight, wg, wu, wd, init):
+        out, *kept = fwd(x, hit, weight, wg, wu, wd, init)
+        share = kept.pop() if counted else None
+        return out, share, kept
 
     @jax.custom_vjp
     def experts(x, hit, weight, wg, wu, wd, init):
-        return fwd(x, hit, weight, wg, wu, wd, init)[0]
+        return run(x, hit, weight, wg, wu, wd, init)[:2]
 
     def forward(x, hit, weight, wg, wu, wd, init):
-        out, *kept = fwd(x, hit, weight, wg, wu, wd, init)
+        out, share, kept = run(x, hit, weight, wg, wu, wd, init)
         if keep_name is not None:
             kept = [checkpoint_name(a, keep_name) for a in kept]
-        return out, (x, wg, wu, wd, *kept)
+        return (out, share), (x, wg, wu, wd, *kept)
 
-    def backward(residuals, dout):
+    def backward(residuals, cotangents):
+        dout = cotangents[0]
         dx, dweight, dwg, dwu, dwd = bwd(*residuals, dout)
         return dx, None, dweight, dwg, dwu, dwd, dout
 
@@ -660,11 +717,14 @@ def _fused(k: int, dtype, keep_name):
     return experts
 
 
-def _grouped(x, hit, weight, wg, wu, wd, init, *, k, dtype, keep_name):
+def _grouped(x, hit, weight, wg, wu, wd, init, *, k, dtype, keep_name,
+             act="silu"):
     """The sorted slots in ``jax.numpy``, one worker's row (no worker
     axis: ``vmap`` batches it as it is): x and init [N, d], hit and
-    weight [N, E], the experts' leaves [E, ...].  The same values under
-    ``keep_name`` as the kernels': the layout and the two products."""
+    weight [N, E], the experts' leaves [E, ...] -> (init + the held
+    experts' part, for ``relu`` the row's ``zero_share`` else None).  The
+    same values under ``keep_name`` as the kernels': the layout and the
+    two products."""
     keep = ((lambda a: checkpoint_name(a, keep_name)) if keep_name
             else (lambda a: a))
     e = hit.shape[1]
@@ -681,27 +741,35 @@ def _grouped(x, hit, weight, wg, wu, wd, init, *, k, dtype, keep_name):
     # (the weight goes in BEFORE the down product: its gradient then asks
     # for the activation, and not for a product the recompute would run)
     with jax.named_scope("dopt_route"):
-        mid = jax.nn.silu(g) * u * scale.reshape(-1, 1).astype(dtype)
+        mid = ACTIVATIONS[act](g) * u * scale.reshape(-1, 1).astype(dtype)
     mid = jnp.where(mine[:, :, None], mid[None], 0)
     y = jnp.einsum("epf,efd->pd", mid, wd.astype(dtype),
                    preferred_element_type=jnp.float32)
+    share = (zero_share(g, rows, jnp.zeros_like(rows), 1)[0]
+             if act == "relu" else None)
     with jax.named_scope("dopt_route"):
-        return init.at[token].add(y)
+        return init.at[token].add(y), share
 
 
 def grouped_experts(x, hit, weight, experts, init, *, k: int, dtype,
-                    keep_name=None):
-    """``init`` + the held experts' part of one row of tokens: x and init
-    [N, d] float32, hit [N, E] bool (the token was routed to that held
-    expert) and weight [N, E] float32 (its combine weight there, 0
-    elsewhere), ``experts`` the float32 leaves ``gate``, ``up`` [E, d, F]
-    and ``down`` [E, F, d], ``k`` the experts a token is routed to (the
-    most of ``hit`` a row), ``dtype`` the matmuls' input dtype.  Exact for
-    any routing.  ``path`` says which body runs."""
+                    keep_name=None, act: str = "silu"):
+    """(``init`` + the held experts' part of one row of tokens, the share
+    of ``zero_share``'s zeros for ``act`` ``relu`` and None for ``silu``):
+    x and init [N, d] float32, hit [N, E] bool (the token was routed to
+    that held expert) and weight [N, E] float32 (its combine weight there,
+    0 elsewhere), ``experts`` the leaves ``gate``, ``up`` [E, d, F] and
+    ``down`` [E, F, d] (float32, or the parameters' dtype: their
+    gradients come back in it), ``k`` the experts a token is routed to
+    (the most of ``hit`` a row), ``dtype`` the matmuls' input dtype,
+    ``act`` the gate's activation (``ACTIVATIONS``: ``down(act(x gate) *
+    (x up))``).  Exact for any routing.  ``path`` says which body runs;
+    under the engines' ``vmap`` over rows the kernels' share is of the
+    worker's rows together, the ``jax.numpy`` body's of each row."""
     leaves = tuple(experts[name] for name in ("gate", "up", "down"))
     if path(x.shape[-1], leaves[0].shape[-1]) == "grouped":
         return _grouped(x, hit, weight, *leaves, init, k=k, dtype=dtype,
-                        keep_name=keep_name)
+                        keep_name=keep_name, act=act)
     lead = lambda a: a[None]
-    return _fused(k, jnp.dtype(dtype), keep_name)(
-        *map(lead, (x, hit, weight, *leaves, init)))[0]
+    out, share = _fused(k, jnp.dtype(dtype), keep_name, act)(
+        *map(lead, (x, hit, weight, *leaves, init)))
+    return out[0], None if share is None else share[0]

@@ -377,6 +377,52 @@ def joyai_localsgd2(toy: bool = False) -> ExperimentConfig:
                       vocab=vocab, seq=seq, rows=rows, toy=toy)
 
 
+def smallthinker_21b_decoder(**cut) -> DecoderConfig:
+    """SmallThinker-21BA3B's published ``config.json``
+    (https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct/blob/main/config.json,
+    21B-A3B) key for key, under this repo's ``model_type`` for it;
+    ``cut`` replaces what a worker holds less of (``num_hidden_layers``,
+    ``experts_held``) or, for a toy, any width."""
+    period = (0, 1, 1, 1)
+    published = dict(
+        model_type="smallthinker", model_name="smallthinker_21b_instruct",
+        vocab_size=151936, hidden_size=2560, num_hidden_layers=52,
+        num_attention_heads=28, num_key_value_heads=4, head_dim=128,
+        max_position_embeddings=16384, rms_norm_eps=1e-06,
+        moe_num_primary_experts=64, moe_num_active_primary_experts=6,
+        moe_ffn_hidden_size=768, moe_primary_router_apply_softmax=True,
+        norm_topk_prob=True, rope_layout=period * 13,
+        sliding_window_layout=period * 13, sliding_window_size=4096,
+        rope_theta=1500000, rope_scaling=None, tie_word_embeddings=False)
+    return DecoderConfig(**{**published, **cut})
+
+
+def smallthinker_localsgd2(toy: bool = False) -> ExperimentConfig:
+    """Two local-SGD workers, each one chip's share of SmallThinker-21BA3B
+    (layers 0-3: a NoPE full layer and three rotary layers banded to
+    4,096 positions; experts 0-7 of 64 a layer; 18,992 of 151,936
+    vocabulary rows: 370.5 M parameters), averaging their parameters
+    every round of 4 steps x 1 row of 8,192 Zipf token ids: the benchmark
+    cell ``smallthinker-21b.localsgd2.t8192`` (needs a 16 GB chip).
+    ``toy=True`` keeps the form (the same four kinds of layer with a band
+    of 16, 8 of 32 ReGLU experts held, routed before the attention) at
+    widths a CPU trains in seconds."""
+    if toy:
+        decoder = smallthinker_21b_decoder(
+            hidden_size=64, head_dim=16, num_key_value_heads=2,
+            num_attention_heads=4, num_hidden_layers=4,
+            sliding_window_size=16, moe_num_primary_experts=32,
+            moe_num_active_primary_experts=4, moe_ffn_hidden_size=32,
+            experts_held=8)
+        vocab, seq, rows = 256, 64, 16
+    else:
+        decoder = smallthinker_21b_decoder(num_hidden_layers=4,
+                                           experts_held=8)
+        vocab, seq, rows = 18992, 8192, 8
+    return _localsgd2("smallthinker-localsgd2", decoder, model="decoder",
+                      seed=42, vocab=vocab, seq=seq, rows=rows, toy=toy)
+
+
 PRESETS = {
     "reference-fedavg": lambda: reference_federated("fedavg"),
     "reference-fedprox": lambda: reference_federated("fedprox"),
@@ -416,6 +462,8 @@ PRESETS = {
     "keye-localsgd2-toy": lambda: keye_localsgd2(toy=True),
     "joyai-localsgd2": joyai_localsgd2,
     "joyai-localsgd2-toy": lambda: joyai_localsgd2(toy=True),
+    "smallthinker-localsgd2": smallthinker_localsgd2,
+    "smallthinker-localsgd2-toy": lambda: smallthinker_localsgd2(toy=True),
     # Fault-injection variants (dopt.faults.FaultPlan): the same
     # workloads under a production-shaped failure regime — per-round
     # client crashes, a straggler deadline finishing half the local

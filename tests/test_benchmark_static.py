@@ -6,7 +6,7 @@ its tests and not its chip check.  The functions are
 
 Then the statics of the ``laguna-xs2`` configuration: its file against
 the catalog row it was drawn from, against the program's preset, and
-against its own layer list.
+against its own layer list; and of ``smallthinker-21b-a3b`` likewise.
 """
 
 import json
@@ -24,6 +24,13 @@ from benchmark.tests.test_flops import (  # noqa: F401
     test_model1_forward_by_hand, test_peaks_table_has_no_default,
     test_resnet18_forward_by_hand,
     test_round_flops_counts_training_three_times)
+from benchmark.tests.test_preroute_metrics import (  # noqa: F401
+    test_preroute_is_none_without_its_scope,
+    test_preroute_reads_its_scope_inside_the_route_s,
+    test_the_configuration_counts_its_worker,
+    test_the_metric_is_listed_for_its_cell_alone,
+    test_the_traffic_is_four_steps_of_one_row_a_worker,
+    test_the_window_binds_on_three_of_four_layers)
 from benchmark.tests.test_ops import (  # noqa: F401
     test_every_op_file_has_both_functions_and_its_formula, test_op_by_hand,
     test_seq_toy_by_hand,
@@ -125,3 +132,57 @@ def test_laguna_traffic_is_the_named_one():
         == (1, 1)
     assert TRAFFIC["parity"] == {"rounds": 2, "steps_per_epoch": 2}
     assert TRAFFIC["optim"] == {"lr": 0.01, "momentum": 0.9}
+
+
+SMALL = json.loads(
+    (ROOT / "benchmark/configs/smallthinker-21b-a3b.json").read_text())
+SMALL_TRAFFIC = json.loads(
+    (ROOT / "benchmark/traffic/localsgd2-t8192-h4.json").read_text())
+SMALL_FLOORS = {"num_hidden_layers": 4, "experts_held": 8,
+                "vocab_rows": 18992}
+
+
+def test_smallthinker_file_holds_every_published_key():
+    """Every number of the catalog row's ``config`` stands at the top level
+    of the file under the same key (the layouts whole), but for the depth,
+    listed in ``reduced`` with the experts held and the vocabulary rows,
+    none of them a width; what runs is what the top level states."""
+    if not CATALOG.exists():
+        pytest.skip("no catalog on this machine")
+    row = next(r for r in map(json.loads, CATALOG.read_text().splitlines())
+               if r["name"] == "SmallThinker-21BA3B-Instruct")
+    assert SMALL["source"].startswith(row["source_url"])
+    differ = {k for k, v in row["config"].items() if SMALL.get(k) != v}
+    assert differ == {"num_hidden_layers"}
+    assert SMALL["reduced"] == list(SMALL_FLOORS)
+    assert {k: SMALL[k] for k in SMALL_FLOORS} == SMALL_FLOORS
+    assert 8 * SMALL["vocab_rows"] == SMALL["vocab_size"]
+    assert "8 chips" in SMALL["deployment"]
+    assert SMALL["published"]["num_hidden_layers"] == 52
+    decoder = SMALL["model"]["decoder"]
+    for key, value in decoder.items():
+        if key not in ("expert_offset", "model_type"):
+            assert SMALL[key] == value, key
+    assert decoder["model_type"] == "smallthinker"
+    assert SMALL["model"]["num_classes"] == SMALL["vocab_rows"]
+    # one whole period: NoPE full, then rotary banded x 3
+    n = decoder["num_hidden_layers"]
+    assert decoder["rope_layout"][:n] == decoder["sliding_window_layout"][:n] \
+        == [0, 1, 1, 1]
+
+
+def test_smallthinker_preset_is_the_benchmark_configuration():
+    """``python -m dopt.run --preset smallthinker-localsgd2`` trains what
+    the cell measures: the same decoder, data, optimizer and gossip job."""
+    from benchmark import adapter
+    from dopt.presets import get_preset
+
+    cell = adapter.build_config("cell", SMALL, SMALL_TRAFFIC, seed=42,
+                                chips=1)
+    preset = get_preset("smallthinker-localsgd2")
+    assert preset.model == cell.model
+    assert preset.optim == cell.optim
+    assert preset.data == cell.data
+    for field in ("algorithm", "topology", "mode", "self_weight", "local_ep",
+                  "local_bs"):
+        assert getattr(preset.gossip, field) == getattr(cell.gossip, field)

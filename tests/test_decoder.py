@@ -1,12 +1,14 @@
 """The mixture-of-experts decoder (``dopt.models.decoder``,
-``model="decoder"``) against the plain references of its three layers
+``model="decoder"``) against the plain references of its four layers
 (``benchmark/reference_models/laguna_xs2.py``: gated window/full
 attention, sigmoid-routed experts beside a shared one;
 ``keye_vl2.py``: a learned sparse attention with its lightning indexer
 and alignment term, softmax-routed experts; ``joyai_llm_flash.py``:
 multi-head latent attention, experts chosen by score plus a correction
-bias, a multi-token-prediction module in the loss), at toy widths on the
-CPU in float32 with seeded random weights.
+bias, a multi-token-prediction module in the loss; ``smallthinker_21b.py``:
+NoPE full and rotary banded attention, a softmax router before the
+attention, ReGLU experts), at toy widths on the CPU in float32 with
+seeded random weights.
 
 Tolerances.  Both sides compute in float32 on the CPU (exact products,
 no reduced-precision matmul), so what separates them is the ORDER of the
@@ -34,6 +36,7 @@ from benchmark import adapter, reference
 from benchmark.reference_models import joyai_llm_flash as joy
 from benchmark.reference_models import keye_vl2 as keye
 from benchmark.reference_models import laguna_xs2 as ref
+from benchmark.reference_models import smallthinker_21b as small
 from dopt.config import (DataConfig, DecoderConfig, ExperimentConfig,
                          GossipConfig, ModelConfig, OptimizerConfig)
 from dopt.models.decoder import (GatedMoEDecoder, _gated_mlp,
@@ -62,6 +65,12 @@ KEYE_TOY = dict(keye.PUBLISHED, head_dim=8, kv_heads=2, experts=16, top_k=4,
 JOYAI = json.loads(
     (ROOT / "benchmark/configs/joyai-llm-flash.json").read_text())
 JOYAI_TOY = dict(joy.PUBLISHED, nope=8, rope=8, v=8, experts=16, top_k=4)
+# ... and SmallThinker's: 4 query heads of 8 over 2 key/value heads, a band
+# of 6 (rows of 21 are longer), 16 published experts, 4 a token.
+SMALL = json.loads(
+    (ROOT / "benchmark/configs/smallthinker-21b-a3b.json").read_text())
+SMALL_TOY = dict(small.PUBLISHED, head_dim=8, kv_heads=2, window=6,
+                 experts=16, top_k=4)
 
 
 def toy_decoder(heads, *, full_every=4, dense_layers=1, held=4, offset=0,
@@ -146,6 +155,34 @@ def joyai_model(layers=3, *, attn_block=8, head_block=16, **kw):
                            attn_block=attn_block, head_block=head_block)
 
 
+def small_decoder(layers=4, *, held=4, offset=0, **kw) -> DecoderConfig:
+    """The published SmallThinker configuration with the toy's sizes
+    (its layouts the published 52 entries: NoPE full where i % 4 == 0)."""
+    body = {**SMALL["model"]["decoder"],
+            "hidden_size": DIM, "num_hidden_layers": layers,
+            "num_attention_heads": 4,
+            "num_key_value_heads": SMALL_TOY["kv_heads"],
+            "head_dim": SMALL_TOY["head_dim"],
+            "sliding_window_size": SMALL_TOY["window"],
+            "moe_num_primary_experts": SMALL_TOY["experts"],
+            "moe_num_active_primary_experts": SMALL_TOY["top_k"],
+            "moe_ffn_hidden_size": 16,
+            "experts_held": held, "expert_offset": offset, **kw}
+    return DecoderConfig(**body)
+
+
+def small_model(layers=4, *, attn_block=8, head_block=16, **kw):
+    """... as a worker whose blocks are shorter than the toy's rows."""
+    return GatedMoEDecoder(small_decoder(layers, **kw), vocab_rows=VOCAB,
+                           attn_block=attn_block, head_block=head_block)
+
+
+def small_params(seed=0, layers=4, held=4):
+    return jax.tree.map(jnp.asarray, small.init(
+        seed, SMALL_TOY, vocab=VOCAB, dim=DIM, heads=4, layers=layers,
+        expert=16, held=held))
+
+
 def joyai_params(seed=0, layers=3, held=4, bias_scale=0.0):
     return jax.tree.map(jnp.asarray, joy.init(
         seed, JOYAI_TOY, vocab=VOCAB, dim=DIM, heads=4, layers=layers,
@@ -200,12 +237,24 @@ JOYAI_PATTERNS = {
     "joyai-biased-router": (3, 0.5),
     "joyai-one-dense-layer": (1, 0.0),
 }
-ALL_PATTERNS = sorted({**PATTERNS, **KEYE_PATTERNS, **JOYAI_PATTERNS})
+# SmallThinker's layer: one whole period (NoPE full, rotary banded x 3),
+# and five layers (the next period's NoPE layer after it).
+SMALL_PATTERNS = {
+    "smallthinker-period": 4,
+    "smallthinker-five-layers": 5,
+}
+ALL_PATTERNS = sorted({**PATTERNS, **KEYE_PATTERNS, **JOYAI_PATTERNS,
+                       **SMALL_PATTERNS})
 
 
 def case(name):
     """(model, seeded parameters, (x, y, w), the reference's forward and
     objective) for one pattern of any model type."""
+    if name in SMALL_PATTERNS:
+        layers = SMALL_PATTERNS[name]
+        return (small_model(layers), small_params(0, layers), batch(),
+                functools.partial(small.forward, spec=SMALL_TOY),
+                functools.partial(small.objective, spec=SMALL_TOY))
     if name in JOYAI_PATTERNS:
         layers, bias = JOYAI_PATTERNS[name]
         return (joyai_model(layers), joyai_params(0, layers, bias_scale=bias),
@@ -314,15 +363,25 @@ def test_init_builds_the_sparse_attention_tree_and_its_count():
 # ------------------------------------------------------- the shares add up
 
 @pytest.mark.parametrize("held", [1, 4, 8, 16])
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash",
+                                        "smallthinker"])
 def test_the_shares_add_up_to_the_uncut_layer(model_type, held):
     """For every share of the 16 published experts, the layer's routed
     part, plus the shared expert (where the layer has one) counted once,
     is what the uncut reference layer gives: nothing stands in for the
-    absent chips, and nothing of theirs is lost or doubled."""
+    absent chips, and nothing of theirs is lost or doubled.  (SmallThinker's
+    router reads another input than its experts: the layer's, before the
+    attention.)"""
     m = jnp.asarray(np.random.default_rng(3).standard_normal(
         (T, DIM)).astype(np.float32))
-    if model_type == "laguna":
+    a = jnp.asarray(np.random.default_rng(4).standard_normal(
+        (T, DIM)).astype(np.float32))
+    routed = None
+    if model_type == "smallthinker":
+        layer = small_params(2, 1, SMALL_TOY["experts"])["layer0"]
+        want = small._experts(layer, m, small._route(layer, a, SMALL_TOY))
+        shared = 0.0
+    elif model_type == "laguna":
         spec = {**TOY, "dense_layers": 0}
         layer = jax.tree.map(jnp.asarray, ref.init(
             2, spec, vocab=VOCAB, dim=DIM, heads=[4], dense=64, expert=16,
@@ -344,10 +403,14 @@ def test_the_shares_add_up_to_the_uncut_layer(model_type, held):
                                              offset=offset),
                  "KeyeVL2": lambda: keye_model(1, held=held, offset=offset),
                  "joyai_llm_flash": lambda: joyai_model(
-                     2, held=held, offset=offset)}[model_type]()
+                     2, held=held, offset=offset),
+                 "smallthinker": lambda: small_model(
+                     1, held=held, offset=offset)}[model_type]()
         share = {**layer, "experts": jax.tree.map(
-            lambda a: a[offset:offset + held], layer["experts"])}
-        out, counts = model._experts(share, m)
+            lambda x: x[offset:offset + held], layer["experts"])}
+        if model_type == "smallthinker":
+            routed = model._preroute(share, a)
+        out, counts = model._experts(share, m, routed)
         total = total + (out - shared)
         slots += float(counts["moe_held_slot_share"])
     assert close(total, want)
@@ -355,13 +418,15 @@ def test_the_shares_add_up_to_the_uncut_layer(model_type, held):
     assert abs(slots - 1.0) < 1e-6
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash",
+                                        "smallthinker"])
 def test_routing_counts_are_counts(model_type):
     model = {"laguna": lambda: toy_model([4, 6]),
              "KeyeVL2": lambda: keye_model(2),
-             "joyai_llm_flash": lambda: joyai_model(2)}[model_type]()
+             "joyai_llm_flash": lambda: joyai_model(2),
+             "smallthinker": lambda: small_model(2)}[model_type]()
     params = model.init(jax.random.key(1))["params"]
-    x, y, w = batch(t=T if model_type == "laguna" else KEYE_T)
+    x, y, w = batch(t=KEYE_T if model_type == "KeyeVL2" else T)
     _, aux = model.loss(params, x, y, w)
     assert set(aux) == {"acc", *model.counters}
     # 4 of 16 held: a quarter of the slots in expectation, never over
@@ -377,6 +442,10 @@ def test_routing_counts_are_counts(model_type):
     if model_type == "joyai_llm_flash":
         assert float(aux["mtp_loss"]) > 0.0
         assert 0.0 <= float(aux["mtp_acc"]) <= 1.0
+    # a share of relu's zeros: about half of a random gate's products
+    assert ("moe_act_zero_share" in aux) == (model_type == "smallthinker")
+    if model_type == "smallthinker":
+        assert 0.2 < float(aux["moe_act_zero_share"]) < 0.8
 
 
 # ------------------------------------------- the learned sparse attention
@@ -491,6 +560,8 @@ def gossip_config(model_type="laguna", **model_kw) -> ExperimentConfig:
                     KEYE_T),
         "joyai_llm_flash": ("localsgd2-t4096-h4",
                             lambda: joyai_decoder(2, **model_kw), T),
+        "smallthinker": ("localsgd2-t8192-h4",
+                         lambda: small_decoder(4, **model_kw), T),
     }[model_type]
     traffic = json.loads(
         (ROOT / "benchmark/traffic" / f"{mix}.json").read_text())
@@ -505,7 +576,8 @@ def gossip_config(model_type="laguna", **model_kw) -> ExperimentConfig:
         gossip=GossipConfig(**{**traffic["gossip"], "local_bs": 2}))
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash",
+                                        "smallthinker"])
 def test_two_gossip_rounds_equal_the_reference_loop(model_type):
     """``GossipTrainer`` on token rows against ``benchmark.reference``'s
     gossip loop: same initial parameters, batches and mixing matrices,
@@ -524,6 +596,7 @@ def test_two_gossip_rounds_equal_the_reference_loop(model_type):
         "laguna": functools.partial(ref.objective, spec=TOY),
         "KeyeVL2": functools.partial(keye.objective, spec=KEYE_TOY),
         "joyai_llm_flash": functools.partial(joy.objective, spec=JOYAI_TOY),
+        "smallthinker": functools.partial(small.objective, spec=SMALL_TOY),
     }[model_type]
     want = reference.run_gossip(
         objective, init, rounds, lr=cfg.optim.lr,
@@ -542,6 +615,8 @@ def test_two_gossip_rounds_equal_the_reference_loop(model_type):
             assert 0.0 < r["index_keys_kept_share"] < 1.0
         if model_type == "joyai_llm_flash":
             assert r["mtp_loss"] > 0.0 and 0.0 <= r["mtp_acc"] <= 1.0
+        if model_type == "smallthinker":
+            assert 0.0 < r["moe_act_zero_share"] < 1.0
 
 
 def test_the_cell_averages_the_two_workers():
@@ -642,14 +717,16 @@ def test_which_mlp_a_sparse_attention_layer_has():
     assert "mlp" in tree["layer0"] and "router" in tree["layer1"]
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash",
+                                        "smallthinker"])
 def test_compiled_round_carries_the_decoder_scopes(model_type):
     """``dopt_attn``, ``dopt_moe`` with ``dopt_route`` inside it and
     ``dopt_head`` mark the decoder's forward and backward inside the
     local phase; the update and the mix keep their own scopes.  A layer
     with an indexer also has ``dopt_index`` with ``dopt_select`` inside
     it and ``dopt_attend``, all inside ``dopt_attn``; the selection has
-    no backward pass."""
+    no backward pass.  A router that reads the layer's input runs under
+    ``dopt_preroute`` inside ``dopt_route``, and only there."""
     import re
 
     from jax._src.config import enable_compilation_cache
@@ -680,6 +757,11 @@ def test_compiled_round_carries_the_decoder_scopes(model_type):
     assert not [s for s in stacks if "dopt_index" in s and "dopt_attend" in s]
     assert all("dopt_moe" in s for s in stacks if "dopt_route" in s)
     assert not [s for s in stacks if "dopt_attn" in s and "dopt_moe" in s]
+    pre = {s for s in stacks if "dopt_preroute" in s}
+    assert bool(pre) == (model_type == "smallthinker")
+    assert all("dopt_moe/dopt_route/dopt_preroute" in s for s in pre)
+    if pre:
+        _check_scope(stacks, "dopt_preroute", "dopt_route")
     for scope in ("dopt_update", "dopt_mix", "dopt_batch"):
         assert any(scope in s for s in stacks), scope
 
@@ -713,6 +795,7 @@ LAYER_KINDS = {
     "keye": ("KeyeVL2", {}, False, False),
     "keye-a-dense-layer": ("KeyeVL2", {"mlp_only_layers": [1]}, True, False),
     "joyai": ("joyai_llm_flash", {}, True, True),
+    "smallthinker": ("smallthinker", {}, False, False),
 }
 
 
@@ -775,6 +858,7 @@ KIND_LAYERS = {
     "keye": [[0, 1, 2, 3]],
     "keye-a-dense-layer": [[0, 2, 3], [1]],
     "joyai": [[0], [1, 2, 3, 4]],
+    "smallthinker": [[0, 4], [1, 2, 3]],
 }
 
 
@@ -785,6 +869,8 @@ def kinds_toy(kind):
         return toy_model([4, 6, 6, 6, 4], **kw), batch()
     if model_type == "joyai_llm_flash":
         return joyai_model(5, **kw), batch()
+    if model_type == "smallthinker":
+        return small_model(5, **kw), batch()
     return keye_model(4, **kw), batch(t=KEYE_T)
 
 
@@ -878,14 +964,15 @@ def test_one_body_a_kind_equals_a_checkpoint_a_layer(monkeypatch, kind):
 def test_the_cells_layers_fall_into_their_kinds():
     """Laguna's five layers are three kinds (full attention with 48 heads
     and a dense MLP; sliding, 64 heads, experts; full, 48, experts),
-    keye's four one, joyai's five two (dense, experts); from the
-    configuration alone."""
+    keye's four one, joyai's five two (dense, experts), SmallThinker's
+    four two (NoPE full; rotary banded); from the configuration alone."""
     from dopt.presets import get_preset
 
     for preset, config, want in (
             ("laguna-localsgd2", PUBLISHED, [[0], [1, 2, 3], [4]]),
             ("keye-localsgd2", KEYE, [[0, 1, 2, 3]]),
-            ("joyai-localsgd2", JOYAI, [[0], [1, 2, 3, 4]])):
+            ("joyai-localsgd2", JOYAI, [[0], [1, 2, 3, 4]]),
+            ("smallthinker-localsgd2", SMALL, [[0], [1, 2, 3]])):
         for model in (get_preset(preset).model,
                       ModelConfig(**config["model"])):
             worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
@@ -925,7 +1012,8 @@ def _scope_free(monkeypatch):
     monkeypatch.setattr(jax, "named_scope", bare)
 
 
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "joyai_llm_flash",
+                                        "smallthinker"])
 def test_the_scopes_are_metadata_only(monkeypatch, model_type):
     """The round program lowers to the same text without debug info with
     every scope and without any: they name ops and change none."""
@@ -1018,7 +1106,8 @@ def _recomputed_matmuls(grad, params):
             and "dopt_head" not in s and not own.search(s)]
 
 
-@pytest.mark.parametrize("pattern", ALL_PATTERNS)
+@pytest.mark.parametrize("pattern", [p for p in ALL_PATTERNS
+                                     if p not in SMALL_PATTERNS])
 def test_a_layer_recomputes_no_matmul(pattern):
     """A count, on the CPU: the backward pass computes none of a layer's
     matmuls again: q / k / v, the per-head gate, the output projection,
@@ -1026,6 +1115,22 @@ def test_a_layer_recomputes_no_matmul(pattern):
     up products are all kept (the down projections feed nothing the
     backward pass needs)."""
     assert _recomputed_matmuls(*_grad_program(pattern)) == []
+
+
+@pytest.mark.parametrize("pattern", sorted(SMALL_PATTERNS))
+def test_a_smallthinker_layer_recomputes_no_projection(pattern):
+    """The same count for SmallThinker's toy: no projection, no router
+    (before the attention) and no expert product is computed again.  One
+    matmul is: the NoPE layer's value product of the ``jax.numpy``
+    attention blocks, which XLA:CPU keeps in the layer's recompute where
+    q and k are the projections' own products (with rotary between, as in
+    every other layer, it does not).  The chip's path keeps the fused
+    kernel's output (``ATTN_RESIDUALS``) and recomputes no attention."""
+    stacks = _recomputed_matmuls(*_grad_program(pattern))
+    nope = (SMALL_PATTERNS[pattern] + 3) // 4          # layers 0, 4, ...
+    assert 1 <= len(stacks) <= nope
+    assert {s.split("/dopt_attn/")[1] for s in stacks} == {
+        "grqk,gke->grqe/dot_general"}
 
 
 def test_the_count_sees_what_a_bare_policy_recomputes(monkeypatch):
@@ -1589,14 +1694,23 @@ def dense_dispatch(model, p, m):
     """The dense dropless dispatch the grouped matmul took the place of
     (PR 34), the definition it is held to: every held expert multiplies
     every token, and a token's combine weight is zero where it was not
-    routed.  Same router, same shared expert."""
+    routed.  Same router, same shared expert, the model's gate activation
+    (``silu`` or ReGLU's ``relu``, whose zeros over the routed slots it
+    counts directly)."""
+    from dopt.ops.grouped_experts import ACTIVATIONS
+
     dt = model.dtype
-    weight, _, counts = model._route(p["router"], m)
+    weight, hit, counts = model._route(p["router"], m)
     x = m.astype(dt)
     e = p["experts"]
     g = jnp.einsum("td,edf->tef", x, e["gate"].astype(dt))
     u = jnp.einsum("td,edf->tef", x, e["up"].astype(dt))
-    mid = jax.nn.silu(g) * u * weight[..., None].astype(dt)
+    if model.cfg.expert_act == "relu":
+        zeros = jnp.sum((g <= 0) & hit[..., None])
+        counts = {**counts, "moe_act_zero_share": zeros / jnp.maximum(
+            jnp.sum(hit) * g.shape[-1], 1)}
+    mid = (ACTIVATIONS[model.cfg.expert_act](g) * u
+           * weight[..., None].astype(dt))
     out = jnp.einsum("tef,efd->td", mid, e["down"].astype(dt),
                      preferred_element_type=jnp.float32)
     if "shared" in p:
@@ -1619,10 +1733,14 @@ def expert_layer(model_type, body, routing, workers=2):
     +8 for the held experts every token is to choose and -8 for those no
     token is to choose (scores of +-40 before the sigmoid or softmax)."""
     hidden, width = EXPERT_BODIES[body]
-    kw = dict(hidden_size=hidden, moe_intermediate_size=width, held=HELD,
-              offset=OFFSET)
-    model = (toy_model([4], dense_layers=0, **kw) if model_type == "laguna"
-             else keye_model(1, **kw))
+    kw = dict(hidden_size=hidden, held=HELD, offset=OFFSET)
+    if model_type == "smallthinker":
+        model = small_model(1, moe_ffn_hidden_size=width, **kw)
+    elif model_type == "laguna":
+        model = toy_model([4], dense_layers=0, moe_intermediate_size=width,
+                          **kw)
+    else:
+        model = keye_model(1, moe_intermediate_size=width, **kw)
     assert model.expert_path() == body
     rng = np.random.default_rng(11)
 
@@ -1651,17 +1769,20 @@ def expert_layer(model_type, body, routing, workers=2):
 
 @pytest.mark.parametrize("routing", ["router", "all", "none", "one"])
 @pytest.mark.parametrize("body", sorted(EXPERT_BODIES))
-@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2"])
+@pytest.mark.parametrize("model_type", ["laguna", "KeyeVL2", "smallthinker"])
 def test_grouped_experts_equal_the_dense_dispatch(model_type, body, routing):
     """The expert layer's output and EVERY gradient leaf (experts, router,
     shared expert, input) against the dense dispatch, to 2e-5 of the
     leaf's own largest value, under a ``vmap`` of two workers, for both
     bodies of the sorted slots: ``jax.numpy``, and the three kernels
-    interpreted.  Routings: the router's own; every token to all held
-    experts (the worst case: every slot of the arrays is filled, and a
-    group of 150 is a whole tile and a part of one); no token to any
-    held expert (zero slots: the routed part and the experts' gradients
-    are exactly zero, nothing is NaN); all slots to one held expert."""
+    interpreted; SwiGLU experts (laguna, keye) and ReGLU ones
+    (SmallThinker: relu's step derivative in the ``dx`` and ``dw``
+    kernels, and the share of its zeros over the routed slots).
+    Routings: the router's own; every token to all held experts (the
+    worst case: every slot of the arrays is filled, and a group of 150 is
+    a whole tile and a part of one); no token to any held expert (zero
+    slots: the routed part and the experts' gradients are exactly zero,
+    nothing is NaN); all slots to one held expert."""
     model, p, m = expert_layer(model_type, body, routing)
     cot = jnp.asarray(np.random.default_rng(12).standard_normal(
         m.shape).astype(np.float32))
@@ -1680,6 +1801,12 @@ def test_grouped_experts_equal_the_dense_dispatch(model_type, body, routing):
     share = {"router": None, "all": 1.0, "none": 0.0, "one": 0.25}[routing]
     if share is not None:
         assert np.allclose(counts["moe_held_slot_share"], share)
+    if model_type == "smallthinker":
+        zeros = want_counts.pop("moe_act_zero_share")
+        # a count: a product within rounding of 0 may fall on either side
+        np.testing.assert_allclose(counts["moe_act_zero_share"], zeros,
+                                   atol=1e-3)
+        assert routing == "none" or 0.3 < float(np.min(zeros)) < 0.7
     for name in want_counts:
         assert np.array_equal(counts[name], want_counts[name]), name
     bad = {jax.tree_util.keystr(k) for (k, g), w in zip(
@@ -1769,21 +1896,26 @@ def test_the_compiled_toy_holds_one_top_k_a_layer(pattern):
 
 def test_the_experts_body_goes_by_shapes_alone():
     """Only the kernels' shape limits choose the held experts' body: a
-    token's float32 row whole (8, 128) tiles and the expert's width whole
-    lanes -- both benchmark cells, their presets and configuration files
-    take the kernels, the toys and the rehearsals ``jax.numpy``; and
-    ``python -m dopt.run`` prints which."""
+    token's float32 row whole lanes (a run of (8, 128) tiles, the last
+    padded where the lanes' rows are no multiple of 8: SmallThinker's
+    2,560) and the expert's width whole lanes -- every benchmark cell,
+    their presets and configuration files take the kernels, the toys and
+    the rehearsals ``jax.numpy``; and ``python -m dopt.run`` prints
+    which."""
     from dopt.ops.grouped_experts import path, slot_capacity
     from dopt.presets import get_preset
     from dopt.run import device_details
 
     assert path(2048, 512) == path(2048, 768) == "grouped-fused"
-    assert path(1024, 128) == "grouped-fused"
-    for hidden, width in [(DIM, 16), (1024 + 128, 128), (1024, 100),
-                          (512, 128), (64, 64)]:
+    assert path(1024, 128) == path(2560, 768) == "grouped-fused"
+    assert path(1024 + 128, 128) == path(512, 128) == "grouped-fused"
+    for hidden, width in [(DIM, 16), (1024 + 64, 128), (1024, 100),
+                          (64, 64)]:
         assert path(hidden, width) == "grouped"
     for preset, config in (("laguna-localsgd2", PUBLISHED),
-                           ("keye-localsgd2", KEYE)):
+                           ("keye-localsgd2", KEYE),
+                           ("joyai-localsgd2", JOYAI),
+                           ("smallthinker-localsgd2", SMALL)):
         for model in (get_preset(preset).model,
                       ModelConfig(**config["model"])):
             worker = GatedMoEDecoder(model.decoder, vocab_rows=VOCAB)
@@ -1826,7 +1958,7 @@ def test_grouped_expert_kernels_compile_for_the_chip_at_any_ambient_precision(
     monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
 
     def loss(m, hit, weight, experts):
-        out = jax.vmap(lambda m, hit, weight: grouped_experts(
+        out, _ = jax.vmap(lambda m, hit, weight: grouped_experts(
             m, hit, weight, experts, jnp.zeros_like(m), k=8,
             dtype=jnp.bfloat16, keep_name="matmul_products"))(m, hit, weight)
         return jnp.sum(out ** 2)
@@ -2215,3 +2347,267 @@ def test_rotary_kernels_compile_for_the_chip(v5e_chip, monkeypatch, name,
             *args).compile().as_text()
     assert "dopt_attn_dopt_rope_fwd" in text
     assert "dopt_attn_dopt_rope_bwd" in text
+
+
+# --------- SmallThinker: NoPE among rotary layers, a router before the
+# --------- attention, ReGLU experts
+
+def test_the_smallthinker_config_takes_every_published_key():
+    """Every key of the published ``config.json`` (the configuration
+    file's top level, at the published depth) builds the configuration,
+    and what it says is read: a NoPE full layer and three rotary layers
+    banded to 4,096 positions a period, 6 of 64 experts a token by a
+    softmax, ReGLU, no shared expert and no dense layer."""
+    from dopt.presets import smallthinker_21b_decoder
+
+    published = {k: v for k, v in SMALL["model"]["decoder"].items()
+                 if k not in ("experts_held", "expert_offset")}
+    assert set(published) - {"model_type"} <= set(SMALL)
+    cfg = DecoderConfig(**{**published, "num_hidden_layers": 52})
+    assert cfg == smallthinker_21b_decoder()
+    assert cfg.smallthinker and cfg.softmax_router and cfg.router_first
+    assert (cfg.experts, cfg.experts_per_tok, cfg.expert_width) == (64, 6, 768)
+    assert cfg.expert_act == "relu" and cfg.shared_width is None
+    assert cfg.routed_scale is None and not cfg.output_gate
+    assert [cfg.window(i) for i in range(5)] == [None, 4096, 4096, 4096, None]
+    assert [cfg.rope(i) is None for i in range(5)] == [True, False, False,
+                                                       False, True]
+    assert cfg.rope(1) == {"rope_type": "default", "rope_theta": 1500000}
+    assert all(cfg.sparse_mlp(i) for i in range(52))
+
+
+def test_the_published_smallthinker_counts_21b_and_about_3b_active():
+    """The published configuration, uncut (52 layers, every one of the 64
+    experts, the whole vocabulary), from shapes alone: 21.51 B parameters,
+    the published 21B; 6 of 64 experts a token leave 2.94 B active in its
+    layers (3.33 B with the output head, 3.72 B with the embedding too),
+    the published A3B.  The cut worker holds 370,547,200."""
+    from dopt.presets import smallthinker_21b_decoder
+
+    full = GatedMoEDecoder(smallthinker_21b_decoder(), vocab_rows=151936)
+    shapes = jax.eval_shape(full.init, jax.random.key(0))["params"]
+    total = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    assert total == 21_506_562_560
+    experts = sum(int(np.prod(s.shape)) for k, layer in shapes.items()
+                  if k.startswith("layer")
+                  for s in jax.tree.leaves(layer["experts"]))
+    layers = sum(int(np.prod(s.shape)) for k, layer in shapes.items()
+                 if k.startswith("layer") for s in jax.tree.leaves(layer))
+    active = layers - experts + experts * 6 // 64
+    assert round(active / 1e9, 2) == 2.94
+    assert round((active + shapes["head"].size) / 1e9, 2) == 3.33
+    body = ModelConfig(**SMALL["model"])
+    cut = GatedMoEDecoder(body.decoder, vocab_rows=body.num_classes)
+    assert sum(int(np.prod(s.shape)) for s in jax.tree.leaves(
+        jax.eval_shape(cut.init, jax.random.key(0)))) == SMALL["parameters"] \
+        == 370_547_200
+
+
+@pytest.mark.parametrize("change, message", [
+    ({"moe_primary_router_apply_softmax": False}, "takes a softmax"),
+    ({"norm_topk_prob": False}, "takes a softmax"),
+    ({"rope_scaling": {"rope_type": "yarn", "factor": 4.0}}, "unscaled"),
+    ({"rope_layout": [0, 1, 1]}, "rope_layout lists 3 layers"),
+    ({"sliding_window_layout": [0, 1]}, "sliding_window_layout lists 2"),
+    ({"rope_layout": [0, 2, 1, 1]}, "0 or 1 a layer"),
+    ({"sliding_window_size": 0}, "must be positive"),
+    ({"num_experts_per_tok": 6}, "a key of model_type 'laguna'"),
+    ({"moe_intermediate_size": 768}, "a key of model_type 'laguna'"),
+    ({"gating": True}, "a key of model_type 'laguna'"),
+    ({"sa_config": KEYE["sa_config"]}, "a key of model_type 'KeyeVL2'"),
+    ({"q_lora_rank": 16}, "a key of model_type 'joyai_llm_flash'"),
+    ({"moe_ffn_hidden_size": None}, "moe_ffn_hidden_size is required"),
+    ({"rope_layout": None}, "rope_layout is required"),
+    ({"num_attention_heads": None}, "num_attention_heads is required"),
+    ({"num_attention_heads": 3}, "multiple of"),
+    ({"experts_held": 8, "expert_offset": 12}, "not among"),
+])
+def test_the_smallthinker_config_refuses(change, message):
+    """What the layer cannot honour, a key of another model type and a
+    missing one are refused; so is a key of the new type under another."""
+    with pytest.raises(ValueError, match=message):
+        small_decoder(**change)
+
+
+def test_another_type_refuses_the_smallthinker_keys():
+    with pytest.raises(ValueError, match="a key of model_type 'smallthinker'"):
+        toy_decoder([4], rope_layout=[0, 1, 1, 1])
+    with pytest.raises(ValueError, match="a key of model_type 'smallthinker'"):
+        keye_decoder(moe_ffn_hidden_size=16)
+
+
+def test_the_nope_layer_is_attention_without_rotary():
+    """Layer 0 (full attention, ``rope_layout`` 0) against the reference's
+    attention with NO rotary, and layer 1 (banded, rotary) against its
+    rotary one; a rotary planted in the NoPE layer's reference is far
+    from the program, as the band left out of layer 1's is."""
+    model, params = small_model(4), small_params(0, 4)
+    h = jnp.asarray(np.random.default_rng(8).standard_normal(
+        (T, DIM)).astype(np.float32))
+
+    def reference(i, window, rotary):
+        p = params[f"layer{i}"]
+        a = small._rms(h, p["attn_norm"], SMALL_TOY["eps"])
+        return small._attention(p, h, a, window, rotary, SMALL_TOY)
+
+    got0, _ = model._attention(params["layer0"], h, 0)
+    got1, _ = model._attention(params["layer1"], h, 1)
+    assert close(got0, reference(0, None, False))
+    assert not close(got0, reference(0, None, True))
+    assert close(got1, reference(1, SMALL_TOY["window"], True))
+    assert not close(got1, reference(1, None, True))
+
+
+# Faults planted in the program, each a property of its configuration: the
+# NoPE layer turned by rotary, the router reading the stream after the
+# attention, SwiGLU experts, no band.
+SMALL_FAULTS = {
+    "rotary-on-the-nope-layer": ("rope", lambda self, i: {
+        "rope_type": "default", "rope_theta": self.rope_theta}),
+    "router-after-the-attention": ("router_first", property(lambda self: False)),
+    "silu-experts": ("expert_act", property(lambda self: "silu")),
+    "no-window": ("window", lambda self, i: None),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(SMALL_FAULTS))
+def test_a_planted_smallthinker_fault_leaves_the_reference(monkeypatch,
+                                                           fault):
+    """The parity check's reach at the toy's size: the program's logits
+    agree with the reference to ``RTOL``, and each planted fault moves
+    them by a hundred times that or more, and the loss past the loss
+    test's tolerance."""
+    model, params, (x, y, w), forward, objective = case("smallthinker-period")
+    want = forward(params, x)
+    assert close(model.apply({"params": params}, x), want)
+    name, planted = SMALL_FAULTS[fault]
+    monkeypatch.setattr(DecoderConfig, name, planted)
+    broken = small_model(4)
+    got = broken.apply({"params": params}, x)
+    assert float(jnp.abs(got - want).max()) > 100 * RTOL * float(
+        jnp.abs(want).max())
+    # ... and the loss test's own tolerance fails too
+    loss, _ = broken.loss(params, x, y, w)
+    want_loss = float(objective(params, x, y, w))
+    assert abs(float(loss) - want_loss) > RTOL * want_loss
+
+
+def test_the_act_zero_share_is_a_direct_count():
+    """``moe_act_zero_share`` is, for each row, the share of ``relu(m
+    W_gate)`` entries that are 0 over the (token, held expert) slots the
+    router chose, meaned over the layers, then over the rows: counted here
+    from the reference's own layers."""
+    model, params, (x, y, w), *_ = case("smallthinker-period")
+    _, aux = model.loss(params, x, y, w)
+    spec = SMALL_TOY
+    rows = []
+    for tokens in x:
+        h, shares = params["embed"][tokens], []
+        for i in range(4):
+            p = params[f"layer{i}"]
+            a = small._rms(h, p["attn_norm"], spec["eps"])
+            weight = small._route(p, a, spec)
+            h = small._attention(p, h, a, None if i % 4 == 0
+                                 else spec["window"], i % 4 != 0, spec)
+            m = small._rms(h, p["mlp_norm"], spec["eps"])
+            g = jnp.einsum("td,edf->tef", m, p["experts"]["gate"])
+            hit = (weight > 0)[..., None]
+            shares.append(float(jnp.sum((g <= 0) & hit))
+                          / float(jnp.sum(hit) * g.shape[-1]))
+            h = h + small._experts(p, m, weight)
+        rows.append(np.mean(shares))
+    assert float(aux["moe_act_zero_share"]) == pytest.approx(np.mean(rows),
+                                                             abs=1e-6)
+
+
+def test_bfloat16_parameters_trace_on_the_fused_experts():
+    """The fused ``dw`` hands the experts' gradients back in the
+    parameters' dtype, so a ``param_dtype`` bfloat16 worker differentiates
+    through the kernels (interpreted here), SwiGLU and ReGLU, and its
+    float32 program is the one it was."""
+    from dopt.ops.grouped_experts import grouped_experts
+
+    rng = np.random.default_rng(9)
+    hidden, width, n = 1024, 128, 64
+    m = jnp.asarray(rng.standard_normal((n, hidden)).astype(np.float32))
+    hit = jnp.asarray(rng.random((n, 4)) < 0.5)
+    weight = jnp.where(hit, 0.5, 0.0)
+    experts = {k: jnp.asarray(0.05 * rng.standard_normal(s), jnp.bfloat16)
+               for k, s in (("gate", (4, hidden, width)),
+                            ("up", (4, hidden, width)),
+                            ("down", (4, width, hidden)))}
+    for act in ("silu", "relu"):
+        grads = jax.grad(lambda e: jnp.sum(grouped_experts(
+            m, hit, weight, e, jnp.zeros_like(m), k=2, dtype=jnp.bfloat16,
+            act=act)[0]))(experts)
+        assert all(g.dtype == jnp.bfloat16 for g in jax.tree.leaves(grads))
+        assert all(bool(jnp.isfinite(g.astype(jnp.float32)).all())
+                   and bool(jnp.any(g != 0)) for g in jax.tree.leaves(grads))
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("window", [None, 4096])
+def test_smallthinker_splash_kernels_compile_for_the_chip(
+        v5e_chip, monkeypatch, precision, window):
+    """The forward and backward splash kernels at the SmallThinker cell's
+    sizes: 8,192 positions, 28 query heads over 4 key/value heads of 128
+    (groups of 7), ``CausalMask`` and ``LocalMask`` of 4,096, through the
+    real XLA:TPU + Mosaic compile, also at the parity check's
+    ``highest``.  Compile only: nothing runs."""
+    from dopt.models.decoder import splash_causal_attention
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def loss(q, k, v):
+        out = splash_causal_attention(q, k, v, window=window, block=512)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=v5e_chip)
+
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape(4, 7, 8192, 128), shape(4, 8192, 128),
+            shape(4, 8192, 128)).compile()
+    text = compiled.as_text()
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_reglu_expert_kernels_compile_for_the_chip(v5e_chip, monkeypatch,
+                                                   precision):
+    """Forward and gradient of SmallThinker's held experts at its cell's
+    sizes (8 held ReGLU experts of 2,560 x 768, rows of 8,192 tokens, 6
+    experts a token, two workers under the engines' ``vmap`` and a row
+    under the model's) through the real XLA:TPU + Mosaic compile: a
+    token's float32 row is 20 rows of lanes, no multiple of 8, and the
+    kernels take it; ONE call of each of the three, named for the scope
+    they stand for.  Compile only: nothing runs."""
+    import re
+
+    from dopt.ops.grouped_experts import KERNEL_NAMES, grouped_experts
+
+    monkeypatch.setattr("dopt.ops.pallas_interpret", lambda: False)
+
+    def loss(m, hit, weight, experts):
+        out, share = jax.vmap(lambda m, hit, weight: grouped_experts(
+            m, hit, weight, experts, jnp.zeros_like(m), k=6,
+            dtype=jnp.bfloat16, keep_name="matmul_products", act="relu"))(
+                m, hit, weight)
+        return jnp.sum(out ** 2) + jnp.sum(share)
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct((2, *dims), dtype, sharding=v5e_chip)
+
+    experts = {"gate": shape(8, 2560, 768), "up": shape(8, 2560, 768),
+               "down": shape(8, 768, 2560)}
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.vmap(jax.grad(loss, argnums=(0, 2, 3)))).lower(
+            shape(1, 8192, 2560), shape(1, 8192, 8, dtype=jnp.bool_),
+            shape(1, 8192, 8), experts).compile()
+    called = re.findall(
+        r'%(\S+) = [^\n]*custom-call\([^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())
+    assert len(called) == 3
+    for kernel in KERNEL_NAMES.values():
+        assert sum(kernel in name for name in called) == 1, kernel
